@@ -23,8 +23,8 @@ Guards (exit 1 on violation — CI regression gate):
 tokens/s + the goodput ledger decomposition (extra.goodput, same shape
 bench.py emits) are recorded for both configurations; the fused/off
 tokens-per-second ratio lands in BENCH_TREND as
-fused_transformer_speedup@<device>. On-chip MFU numbers land on the
-next helper-up round per the established bench.py re-probe flow.
+fused_transformer_speedup@<device>. A CPU run says nothing about the
+Pallas routes: no device number comes from this script.
 
 Run: JAX_PLATFORMS=cpu python benchmarks/fusion_bench.py
 Artifact: benchmarks/FUSION_BENCH.json (+ the trend series entry)
@@ -109,8 +109,7 @@ def _run(flag, steps=STEPS):
 
 def _append_trend(value):
     """One fused_transformer_speedup@<device> point in the cross-round
-    series (same shape bench.py's _attach_trend writes): atomic
-    tmp+replace, series capped at 50."""
+    series: atomic tmp+replace, series capped at 50."""
     trend_p = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                            "BENCH_TREND.json")
     try:
@@ -163,8 +162,7 @@ def main():
         "fused_speedup_x": round(speedup, 4),
         "extra": {"goodput": {"fused": fused_gp, "off": off_gp}},
         "note": ("wall times on CPU measure XLA dispatch through the jnp "
-                 "fallbacks, not the Pallas routes; re-measure on-chip "
-                 "per MEASUREMENT_RUNBOOK.md 'Transformer fusion'"),
+                 "fallbacks, not the Pallas routes: not a device number"),
     }
     print(json.dumps(report, indent=2))
     out = os.path.join(os.path.dirname(os.path.abspath(__file__)),

@@ -26,7 +26,7 @@ Guards (exit 1 on violation — CI regression gate):
 
 Also emits a grad-sync wall-time line per configuration (per-step ms);
 on the CPU container this measures XLA overhead, not ICI — the number
-that matters is the on-chip rerun (MEASUREMENT_RUNBOOK.md).
+that matters is an on-chip run.
 
 Run: JAX_PLATFORMS=cpu python benchmarks/quant_collective_bench.py
 Artifact: benchmarks/QUANT_COLLECTIVE_BENCH.json
@@ -162,7 +162,7 @@ def main():
             "quantized": round(q_wall * 1e3, 3),
         },
         "note": ("wall times on CPU measure XLA dispatch, not ICI; "
-                 "re-measure on-chip per MEASUREMENT_RUNBOOK.md"),
+                 "not a device number"),
     }
     print(json.dumps(report, indent=2))
     out = os.path.join(os.path.dirname(os.path.abspath(__file__)),

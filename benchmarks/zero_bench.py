@@ -180,7 +180,7 @@ def main():
             "zero2_int8_ef": round(q_wall * 1e3, 3),
         },
         "note": ("wall times on CPU measure XLA dispatch, not HBM/ICI; "
-                 "re-measure on-chip per MEASUREMENT_RUNBOOK.md"),
+                 "not a device number"),
     }
     print(json.dumps(report, indent=2))
     out = os.path.join(os.path.dirname(os.path.abspath(__file__)),

@@ -11,6 +11,7 @@ GSPMD's propagation pass — nothing to reimplement.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Dict, List, Optional, Sequence
 
 import jax
@@ -234,6 +235,53 @@ def with_partial_annotation(x, spec: P):
     return with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
+# Pallas (Mosaic) kernels cannot be partitioned by GSPMD: under a mesh a
+# kernel call has to sit inside shard_map, each device running it on its
+# own slice. compile_train_step arms its mesh here while the step traces;
+# the models' kernel call sites go through shard_kernel.
+_kernel_meshes: List[Mesh] = []
+
+
+@contextlib.contextmanager
+def kernel_mesh_guard(mesh: Mesh):
+    _kernel_meshes.append(mesh)
+    try:
+        yield
+    finally:
+        _kernel_meshes.pop()
+
+
+def shard_kernel(fn, in_specs, out_specs, batch: int, heads: int = 1):
+    """`fn`, or `fn` inside shard_map over the mesh of the sharded step
+    being traced (none armed, or one device: `fn` itself).
+
+    Specs are PartitionSpecs over two ROLES, resolved against the mesh:
+    "data" — the batch dim, split over the plan's dp and sharding axes
+    as far as their running product divides `batch` (data_axes_for);
+    "mp" — the heads / intermediate dim, split over mp when it divides
+    `heads`. Whatever a role does not resolve to is replicated, so the
+    kernel always gets whole rows and whole heads. Weights enter
+    replicated over the data axes: shard_map gathers the FSDP shards on
+    the way in and sums the weight gradient over them on the way out."""
+    mesh = _kernel_meshes[-1] if _kernel_meshes else None
+    if mesh is None or mesh.size == 1:
+        return fn
+    data = data_axes_for(batch, mesh)
+    roles = {"data": data if len(data) > 1 else (data[0] if data else None),
+             "mp": ("mp" if "mp" in mesh.axis_names and mesh.shape["mp"] > 1
+                    and heads % mesh.shape["mp"] == 0 else None)}
+
+    def resolve(spec):
+        return P(*[roles[e] if e is not None else None for e in spec])
+
+    return jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=tuple(resolve(sp) for sp in in_specs),
+        out_specs=(resolve(out_specs) if isinstance(out_specs, P)
+                   else tuple(resolve(sp) for sp in out_specs)),
+        check_vma=False)
+
+
 class ShardingPlan:
     """Placement policy consumed by jit.TrainStep: decides the NamedSharding
     of every model/optimizer array before compilation.
@@ -372,8 +420,11 @@ class ShardingPlan:
         moments is what stage>=1 (ZeRO-1/2) means here."""
         if arr.ndim == 0:
             return P()
+        # keyed by id(param) on the host, by parameter name where the
+        # state crosses a jit boundary (jit.TrainStep._state_keys)
         pid = key[0] if isinstance(key, tuple) else None
-        pname = getattr(self, "_pid_to_name", {}).get(pid)
+        pname = pid if isinstance(pid, str) else getattr(
+            self, "_pid_to_name", {}).get(pid)
         if pname is not None and pname in param_specs:
             pspec = param_specs[pname]
             if len(tuple(pspec)) == arr.ndim or self.stage >= 3:
@@ -488,13 +539,17 @@ class ShardingPlan:
     # -- TrainStep hook ------------------------------------------------------
     def compile_train_step(self, pure, donate):
         mesh = self.mesh
+        untraced = pure
 
-        def shardings_for(tree, spec_fn):
-            return jax.tree_util.tree_map(
-                lambda a: NamedSharding(mesh, spec_fn(a)), tree)
+        def pure(*args):
+            # the models' Pallas call sites read the mesh while this
+            # traces, and wrap themselves in shard_map (shard_kernel)
+            with kernel_mesh_guard(mesh):
+                return untraced(*args)
 
         def _master_spec(self, k, v, p_specs):
-            pname = getattr(self, "_pid_to_name", {}).get(k, "")
+            pname = k if isinstance(k, str) else getattr(
+                self, "_pid_to_name", {}).get(k, "")
             if pname in p_specs and len(tuple(p_specs[pname])) <= v.ndim:
                 return p_specs[pname]
             return self.param_spec(pname, v)
@@ -547,8 +602,8 @@ class ShardingPlan:
 
         cache = {}
 
-        def run(params, buffers, opt_state, master, scaler_state, step_i,
-                lr, key, batch):
+        def jitted(params, buffers, opt_state, master, scaler_state,
+                   step_i, lr, key, batch):
             struct = jax.tree_util.tree_structure(
                 (params, buffers, opt_state, master, scaler_state, batch))
             shapes = tuple(
@@ -559,10 +614,13 @@ class ShardingPlan:
                 cache[sig] = compiled_factory(params, buffers, opt_state,
                                               master, scaler_state, step_i,
                                               lr, key, batch)
-            # place inputs (no-op if already placed)
-            return cache[sig](params, buffers, opt_state, master,
-                              scaler_state, step_i, lr, key, batch)
+            return cache[sig]
 
+        def run(*args):
+            # place inputs (no-op if already placed)
+            return jitted(*args)(*args)
+
+        run.lower = lambda *args: jitted(*args).lower(*args)
         return run
 
     # -- quantized grad-sync TrainStep hook (ISSUE 8) -----------------------
@@ -627,8 +685,6 @@ class ShardingPlan:
         the implicit GSPMD psum. Params/optimizer state stay replicated
         (enforced); the error-feedback residual tree rides sharded on
         the sync axis (one per-rank residual slice each)."""
-        from jax.experimental.shard_map import shard_map
-
         mesh = self.mesh
         axis, _n = self.quant_sync_axis()
         repl = NamedSharding(mesh, P())
@@ -652,8 +708,8 @@ class ShardingPlan:
             in_specs = (P(), P(), P(), P(), P(), P(), P(), P(),
                         batch_specs, ef_specs)
             out_specs = (P(), P(), P(), P(), P(), P(), ef_specs)
-            fn = shard_map(pure_local, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
+            fn = jax.shard_map(pure_local, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_specs, check_vma=False)
             batch_sh = jax.tree_util.tree_map(
                 lambda s: NamedSharding(mesh, s), batch_specs)
             ef_sh = jax.tree_util.tree_map(
@@ -709,8 +765,6 @@ class ShardingPlan:
         every rank materializes only its own (s,)-slice — the HBM win.
         The error-feedback residual tree (quantized wire only) rides
         sharded exactly as in the grad_sync path."""
-        from jax.experimental.shard_map import shard_map
-
         mesh = self.mesh
         axis, _n = self.quant_sync_axis()
         repl = NamedSharding(mesh, P())
@@ -741,8 +795,8 @@ class ShardingPlan:
             # known abstractly; P(axis) as a spec PREFIX covers every
             # slot the body creates
             out_specs = (P(), P(), P(), P(axis), P(), P(), ef_specs)
-            fn = shard_map(pure_local, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
+            fn = jax.shard_map(pure_local, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_specs, check_vma=False)
             batch_sh = jax.tree_util.tree_map(
                 lambda s: NamedSharding(mesh, s), batch_specs)
             ef_sh = jax.tree_util.tree_map(

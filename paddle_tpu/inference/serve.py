@@ -74,8 +74,10 @@ def main(argv=None):
     args = _build_parser().parse_args(argv)
     from .. import observability as obs
     from ..framework import core as _core
+    from ..framework.compile_cache import use_compile_cache
     from . import gateway as gw
 
+    use_compile_cache()
     obs.enable(True)
     if args.metrics_port:
         _core.set_flags({"FLAGS_metrics_port": args.metrics_port})

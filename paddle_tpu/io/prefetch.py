@@ -16,8 +16,7 @@ through a bounded queue.
 time the training loop sat blocked on an empty staged-batch queue. If it
 grows while `dataloader.producer_wait_seconds` stays flat, raise
 `num_workers`; if the staged queue is always full and the counter still
-grows, the step itself is the bottleneck (see
-benchmarks/MEASUREMENT_RUNBOOK.md "Input pipeline").
+grows, the step itself is the bottleneck.
 
 Kill switch: FLAGS_dataloader_prefetch=false bypasses this module
 entirely (DataLoader yields un-staged batches exactly as before).

@@ -497,10 +497,32 @@ class TrainStep:
                 for k, v in params.items()}
         return self._ef_state
 
+    def _state_keys(self):
+        """(to_names, to_ids): optimizer state is keyed by id(param) on
+        the host and crosses the jit boundary keyed by parameter NAME.
+        An id is a memory address: as a pytree key it names — and, by
+        sort order, places — the step's arguments differently in every
+        process, so no two processes would ever lower the same program
+        and the persistent compile cache could never hit."""
+        name_of = {id(t): k for k, t in self.model.state_dict().items()}
+        for i, p in enumerate(self.optimizer._parameter_list):
+            name_of.setdefault(id(p), f"optimizer.param.{i}")
+        id_of = {n: i for i, n in name_of.items()}
+
+        def rekey(table):
+            def key(k):
+                if isinstance(k, tuple):
+                    return (table.get(k[0], k[0]),) + k[1:]
+                return table.get(k, k)
+            return lambda state: {key(k): v for k, v in state.items()}
+
+        return rekey(name_of), rekey(id_of)
+
     def _build(self):
         model = self.model
         opt = self.optimizer
         step_fn = self.step_fn
+        to_names, to_ids = self._to_names, self._to_ids = self._state_keys()
         scaler = self.scaler
         accum = self._accum
         # quantized grad sync arms at BUILD time so the kill switch
@@ -629,9 +651,9 @@ class TrainStep:
                             if scaler is not None else None)
             with model.use_state(state):
                 with core.rng_key_context(key):
-                    opt._state = dict(opt_state)
+                    opt._state = to_ids(opt_state)
                     opt._step_count = step_i
-                    opt._master_weights = dict(master)
+                    opt._master_weights = to_ids(master)
                     # ALWAYS run the compiled update off the per-call lr
                     # argument: __call__ evaluates scheduler/value on the
                     # host each step. Keeping a scheduler object here
@@ -678,8 +700,8 @@ class TrainStep:
                         sd = model.state_dict()
                         new_params = {k: sd[k].data for k in params}
                         new_buffers = {k: sd[k].data for k in buffers}
-                        new_opt_state = dict(opt._state)
-                        new_master = dict(opt._master_weights)
+                        new_opt_state = to_names(opt._state)
+                        new_master = to_names(opt._master_weights)
                         new_scaler = (scaler._get_traced_state()
                                       if scaler is not None else {})
                     finally:
@@ -742,7 +764,9 @@ class TrainStep:
         else:
             self._compiled = jax.jit(pure, donate_argnums=donate)
 
-    def __call__(self, *batch):
+    def _call_args(self, batch):
+        """The compiled step's arguments for this batch and the current
+        model/optimizer state (building the step on first use)."""
         if self._compiled is None:
             # materialize optimizer state before the first trace: otherwise
             # the state tree widens after step 1 and the whole step
@@ -794,16 +818,29 @@ class TrainStep:
             batch_arrays = self.shard.reshard_batch(batch_arrays)
         scaler_state = (self.scaler._get_traced_state()
                         if self.scaler is not None else {})
+        call_args = (params, buffers, self._to_names(opt._state),
+                     self._to_names(opt._master_weights), scaler_state,
+                     step_i, lr, key, batch_arrays)
+        if self._quant is not None or self._zero is not None:
+            call_args = call_args + (self._ensure_ef_state(params),)
+        return call_args
+
+    def lower(self, *batch):
+        """The step as jax lowers it for this batch and the current state
+        — the program `__call__` runs, for `.compile().as_text()` /
+        `.memory_analysis()`. Nothing executes."""
+        call_args = self._call_args(batch)
+        with _devev.tagged(self._exec_tag):
+            return self._compiled.lower(*call_args)
+
+    def __call__(self, *batch):
         bench = core.get_bool_flag("FLAGS_benchmark")
         if bench:
             import time as _time
             _t0 = _time.perf_counter()
         armed = _om.enabled()
-        call_args = (params, buffers, dict(opt._state),
-                     dict(opt._master_weights), scaler_state,
-                     step_i, lr, key, batch_arrays)
-        if self._quant is not None or self._zero is not None:
-            call_args = call_args + (self._ensure_ef_state(params),)
+        call_args = self._call_args(batch)
+        opt = self.optimizer
         if armed and self._step_flops is None:
             # must run BEFORE the call: args 0-3 are donated by it
             self._step_flops = self._lower_flops(call_args)
@@ -828,8 +865,8 @@ class TrainStep:
             sd[k].data = v
         for k, v in new_buffers.items():
             sd[k].data = v
-        opt._state = dict(new_opt_state)
-        opt._master_weights = dict(new_master)
+        opt._state = self._to_ids(new_opt_state)
+        opt._master_weights = self._to_ids(new_master)
         if self._opt_state_bytes is None:
             # the build step materialized every state slot (primed, or
             # shard-created under ZeRO) — record the per-rank footprint

@@ -29,6 +29,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ._tpu import on_tpu as _on_tpu
+
 __all__ = ["block_attention_stats", "supported"]
 
 _NEG = -1e30
@@ -51,13 +53,6 @@ def _block_size(s: int, which: str = "q") -> int:
         if s % b == 0:
             return b
     raise AssertionError(f"supported() admitted unaligned size {s}")
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 def supported(q_shape, k_shape) -> bool:
@@ -127,9 +122,8 @@ def _pallas_fwd(q, k, v, mask, scale, bias=None, interpret=None):
 
     if interpret is None:
         interpret = not _on_tpu()
-    # jax >= 0.7 renamed TPUCompilerParams -> CompilerParams
-    _CP = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    params = _CP(dimension_semantics=("parallel", "parallel", "arbitrary"))
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
     mask_spec = (pl.BlockSpec((bq, bk), lambda n, i, j: (i, j)) if use_mask
                  else pl.BlockSpec((bq, bk), lambda n, i, j: (0, 0)))
     bias_spec = (pl.BlockSpec((1, bq, bk), lambda n, i, j: (n, i, j))
@@ -159,6 +153,7 @@ def _pallas_fwd(q, k, v, mask, scale, bias=None, interpret=None):
                         pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=None if interpret else params,
         interpret=interpret,
+        name="block_attention_stats",
     )(q, k, v, mask, bias)
     return m[..., 0], l[..., 0], o
 

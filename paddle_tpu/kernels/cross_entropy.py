@@ -25,16 +25,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ._tpu import on_tpu as _on_tpu
+
 __all__ = ["fused_cross_entropy", "supported"]
 
 _NEG_INF = -1e30
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 def supported(n_classes: int, min_vocab: int = 4096) -> bool:
@@ -127,9 +122,8 @@ def _pallas_common(n, v, bn, bv):
     grid = (pl.cdiv(n, bn), pl.cdiv(v, bv))
     x_spec = pl.BlockSpec((bn, bv), lambda i, j: (i, j))
     row_spec = pl.BlockSpec((bn, 1), lambda i, j: (i, 0))
-    # jax >= 0.7 renamed TPUCompilerParams -> CompilerParams
-    _CP = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    params = _CP(dimension_semantics=("parallel", "arbitrary"))
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"))
     return pl, pltpu, grid, x_spec, row_spec, params
 
 
@@ -159,6 +153,7 @@ def _fwd(logits, labels, ignore_index, blocks=None):
         scratch_shapes=[pltpu.VMEM((bn, 1), jnp.float32)] * 3,
         compiler_params=None if interpret else params,
         interpret=interpret,
+        name="fused_ce_fwd",
     )(logits, lbl2)
     return loss[:, 0], (logits, lbl2, m, l)
 
@@ -182,6 +177,7 @@ def _bwd_rule(ignore_index, blocks, res, g):
         out_shape=jax.ShapeDtypeStruct((n, v), logits.dtype),
         compiler_params=None if interpret else params,
         interpret=interpret,
+        name="fused_ce_bwd",
     )(logits, lbl2, m, l, g.astype(jnp.float32).reshape(n, 1))
     return dx, None
 

@@ -26,16 +26,11 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ._tpu import on_tpu as _on_tpu
+
 # lane width is 128; the kernel pads smaller head dims, profitable down to 64
 _MIN_HEAD_DIM = 64
 _SEQ_ALIGN = 128
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 def supported(q_shape, k_shape, causal_or_none: bool,

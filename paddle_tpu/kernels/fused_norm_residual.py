@@ -31,24 +31,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-    _HAS_TPU = True
-except Exception:  # pragma: no cover
-    _HAS_TPU = False
+from ._tpu import on_tpu as _on_tpu
+from ._tpu import row_block
 
 __all__ = ["fused_add_rms_norm", "supported", "sweep_block_sizes"]
 
 # tests flip this to exercise the Pallas path through the interpreter on
 # CPU (interpret mode is orders of magnitude slower than the fallback)
 _FORCE_PALLAS = False
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 def supported(shape) -> bool:
@@ -66,10 +56,10 @@ def _size_class(h: int) -> int:
     return c
 
 
-def _block_rows(rows: int, H: int, block_rows=None) -> int:
+def _block_rows(rows: int, H: int, itemsize: int, block_rows=None) -> int:
     """Rows per grid step: explicit override (sweeps), else the autotune
-    winner for this hidden-size class, else min(256, rows) — shrunk to a
-    divisor of the row count either way."""
+    winner for this hidden-size class, else 256 — held to what VMEM
+    takes: x, residual, y and h blocks, each double-buffered."""
     if block_rows is None:
         from . import autotune
         hit = autotune.lookup(autotune.cache_key("fused_norm",
@@ -79,15 +69,12 @@ def _block_rows(rows: int, H: int, block_rows=None) -> int:
                              else hit)
     if not block_rows or block_rows <= 0:
         block_rows = 256
-    block_rows = max(1, min(block_rows, rows))
-    while rows % block_rows:
-        block_rows -= 1
-    return block_rows
+    return row_block(rows, 8 * H * itemsize, want=block_rows)
 
 
 def _route(shape, use_pallas):
     if use_pallas is None:
-        return _HAS_TPU and supported(shape) and (_on_tpu() or _FORCE_PALLAS)
+        return supported(shape) and (_on_tpu() or _FORCE_PALLAS)
     if use_pallas and not supported(shape):
         # an EXPLICIT True must not silently time/run the fallback — a
         # sweep would record noise winners and callers would believe
@@ -126,8 +113,8 @@ def _fwd_impl(x, residual, weight, eps, use_pallas, block_rows):
     xf = x.reshape(-1, H)
     rf = residual.reshape(-1, H)
     rows = xf.shape[0]
-    br = _block_rows(rows, H, block_rows)
-    grid = (rows // br,)
+    br = _block_rows(rows, H, x.dtype.itemsize, block_rows)
+    grid = (pl.cdiv(rows, br),)
     y, h = pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps),
         out_shape=(jax.ShapeDtypeStruct(xf.shape, x.dtype),
@@ -141,6 +128,7 @@ def _fwd_impl(x, residual, weight, eps, use_pallas, block_rows):
         out_specs=(pl.BlockSpec((br, H), lambda i: (i, 0)),
                    pl.BlockSpec((br, H), lambda i: (i, 0))),
         interpret=not _on_tpu(),
+        name="fused_add_rms_norm",
     )(xf, rf, weight)
     return y.reshape(orig_shape), h.reshape(orig_shape)
 
@@ -215,5 +203,7 @@ def sweep_block_sizes(shape, dtype=jnp.bfloat16, iters=8, sweep=None):
         return run
 
     return autotune.autotune(key, [32, 64, 128, 256, 512], make_fn,
-                             default=_block_rows(rows, H), iters=iters,
+                             default=_block_rows(
+                                 rows, H, jnp.dtype(dtype).itemsize),
+                             iters=iters,
                              sweep=sweep)

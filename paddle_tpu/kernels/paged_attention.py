@@ -16,17 +16,12 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ._tpu import on_tpu as _on_tpu
+
 __all__ = ["decode_attention", "paged_decode_attention", "paginate_cache",
            "supported"]
 
 _PAGE = 16  # tokens per page (multiple of the sublane tile)
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 def supported(q_shape, pages_shape) -> bool:

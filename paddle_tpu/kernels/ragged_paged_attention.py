@@ -37,19 +37,14 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ._tpu import on_tpu as _on_tpu
+
 __all__ = ["ragged_paged_attention", "supported"]
 
 _NEG = -1e30
 # tests flip this to exercise the Pallas path through the interpreter on
 # CPU (interpret mode is orders of magnitude slower than the fallback)
 _FORCE_PALLAS = False
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 def supported(q_shape, pages_shape) -> bool:
@@ -259,15 +254,15 @@ def _pallas_path(q, k_pages, v_pages, q_start, q_len, kv_len, page_table,
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
     )
-    # jax >= 0.7 renamed TPUCompilerParams -> CompilerParams
-    _CP = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    params = _CP(dimension_semantics=("parallel", "parallel", "parallel",
-                                      "arbitrary"))
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel",
+                             "arbitrary"))
     out = pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nh, q_pad, d), jnp.float32),
         compiler_params=None if interpret else params,
         interpret=interpret,
+        name="ragged_paged_attention",
     )(q_len.astype(jnp.int32), kv_len.astype(jnp.int32),
       page_table.astype(jnp.int32), qp, k_pages, v_pages)
 

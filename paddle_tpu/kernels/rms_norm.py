@@ -17,11 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-    _HAS_TPU = True
-except Exception:  # pragma: no cover
-    _HAS_TPU = False
+from ._tpu import on_tpu as _on_tpu
+from ._tpu import row_block
 
 
 def _rms_kernel(x_ref, w_ref, o_ref, *, eps):
@@ -32,7 +29,7 @@ def _rms_kernel(x_ref, w_ref, o_ref, *, eps):
 
 
 def _fwd_impl(x, weight, eps):
-    if not _HAS_TPU or jax.default_backend() != "tpu":
+    if not _on_tpu():
         x32 = x.astype(jnp.float32)
         ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
         return (x32 * jax.lax.rsqrt(ms + eps) * weight.astype(jnp.float32)
@@ -41,10 +38,9 @@ def _fwd_impl(x, weight, eps):
     H = orig_shape[-1]
     xf = x.reshape(-1, H)
     rows = xf.shape[0]
-    block_rows = max(1, min(256, rows))
-    while rows % block_rows:
-        block_rows -= 1
-    grid = (rows // block_rows,)
+    # x and out blocks, each double-buffered
+    block_rows = row_block(rows, 4 * H * x.dtype.itemsize)
+    grid = (pl.cdiv(rows, block_rows),)
     out = pl.pallas_call(
         functools.partial(_rms_kernel, eps=eps),
         out_shape=jax.ShapeDtypeStruct(xf.shape, x.dtype),
@@ -54,6 +50,7 @@ def _fwd_impl(x, weight, eps):
             pl.BlockSpec((H,), lambda i: (0,)),
         ],
         out_specs=pl.BlockSpec((block_rows, H), lambda i: (i, 0)),
+        name="rms_norm",
     )(xf, weight)
     return out.reshape(orig_shape)
 

@@ -31,12 +31,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_TPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_TPU = False
+from jax.experimental.pallas import tpu as pltpu
+
+from ._tpu import LANES, SUBLANES
+from ._tpu import on_tpu as _on_tpu
 
 __all__ = ["swiglu", "supported", "sweep_block_sizes"]
 
@@ -45,11 +43,13 @@ __all__ = ["swiglu", "supported", "sweep_block_sizes"]
 _FORCE_PALLAS = False
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+# The backward holds a (rows, H) operand, its (rows, H) output and an f32
+# accumulator of that shape at once, and the weight-gradient kernel two
+# (H, cols) outputs with their accumulators: at H=4096 no (8, 128)-tiled
+# block pair fits the 16 MiB a kernel is scoped to by default, so these
+# kernels ask for more of v5e's 128 MiB VMEM and size blocks to 3/4 of it
+_VMEM_LIMIT = 64 * 1024 * 1024
+_VMEM_BLOCK_BUDGET = 48 * 1024 * 1024
 
 
 def supported(a_shape, w_shape) -> bool:
@@ -68,10 +68,26 @@ def _size_class(n: int) -> int:
     return c
 
 
-def _blocks(T: int, M: int, blocks=None):
+def _vmem_bytes(bt: int, bm: int, H: int, itemsize: int) -> int:
+    """What the larger of the two backward kernels keeps in VMEM for a
+    (bt, bm) block pair: operands and outputs double-buffered, f32
+    accumulators, the four f32 g/u/dg/du tiles."""
+    w = 4 * H * bm * itemsize                    # wg + wu, two buffers
+    tiles = bt * bm * (2 * itemsize + 16)        # do + g/u/dg/du
+    da = bt * H * (4 * itemsize + 4) + w + tiles
+    dw = H * bm * (4 * itemsize + 8) + w + 2 * bt * H * itemsize + tiles
+    return max(da, dw)
+
+
+def _blocks(T: int, H: int, M: int, itemsize: int, blocks=None):
     """(row-block, column-block) per grid step: explicit override
     (sweeps), else the autotune winner for this size class, else
-    (256, 512) — each shrunk to a divisor of its extent."""
+    (256, 512) — then made to tile and to fit. The column block is a
+    multiple of 128 that divides M, so the up half of w_gate_up starts
+    on a block boundary (M % 128 == 0 is what supported() admits: 256
+    for M=2816 and 11008, 128 for M=5504). The row block is a multiple
+    of 16, or all T rows; rows that do not fill the last block are
+    padded on read and masked where they would be summed."""
     if blocks is None:
         from . import autotune
         hit = autotune.lookup(autotune.cache_key(
@@ -80,19 +96,32 @@ def _blocks(T: int, M: int, blocks=None):
             blocks = (int(hit[0]), int(hit[1]))
     if blocks is None:
         blocks = (256, 512)
-    bt, bm = blocks
-    bt = max(1, min(int(bt), T))
-    while T % bt:
-        bt -= 1
-    bm = max(1, min(int(bm), M))
-    while M % bm:
-        bm -= 1
-    return bt, bm
+    want_bt = max(SUBLANES, int(blocks[0]) // SUBLANES * SUBLANES)
+    want_bm = max(LANES, int(blocks[1]) // LANES * LANES)
+    bms = [b for b in range(min(want_bm, M), 0, -LANES) if M % b == 0]
+    bt = want_bt
+    while True:
+        for bm in bms:
+            if _vmem_bytes(bt, bm, H, itemsize) <= _VMEM_BLOCK_BUDGET:
+                return (T if T <= bt else bt), bm
+        if bt == SUBLANES:
+            raise ValueError(
+                f"swiglu: no (rows, columns) block of H={H}, M={M}, "
+                f"itemsize={itemsize} fits {_VMEM_BLOCK_BUDGET} bytes of "
+                f"VMEM")
+        bt = max(SUBLANES, bt // 2 // SUBLANES * SUBLANES)
+
+
+def _compiler_params(interpret, *semantics):
+    if interpret:
+        return None
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=_VMEM_LIMIT)
 
 
 def _route(a_shape, w_shape, use_pallas):
     if use_pallas is None:
-        return (_HAS_TPU and supported(a_shape, w_shape)
+        return (supported(a_shape, w_shape)
                 and (_on_tpu() or _FORCE_PALLAS))
     if use_pallas and not supported(a_shape, w_shape):
         # an EXPLICIT True must not silently time/run the fallback
@@ -154,7 +183,7 @@ def _bwd_da_kernel(a_ref, wg_ref, wu_ref, do_ref, da_ref, acc_ref, *, nm):
 
 
 def _bwd_dw_kernel(a_ref, wg_ref, wu_ref, do_ref, dwg_ref, dwu_ref,
-                   accg_ref, accu_ref, *, nt):
+                   accg_ref, accu_ref, *, nt, rows):
     t = pl.program_id(1)
 
     @pl.when(t == 0)
@@ -164,6 +193,15 @@ def _bwd_dw_kernel(a_ref, wg_ref, wu_ref, do_ref, dwg_ref, dwu_ref,
 
     dg, du = _dgu_tile(a_ref, wg_ref, wu_ref, do_ref)
     a = a_ref[...]
+    bt = a.shape[0]
+    if rows % bt:
+        # the last row block reads past T: what it read there is
+        # arbitrary and must not reach the sum over rows
+        live = (t * bt + jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)
+                < rows)
+        a = jnp.where(live, a, jnp.zeros_like(a))
+        dg = jnp.where(live, dg, 0.0)
+        du = jnp.where(live, du, 0.0)
     dims = (((0,), (0,)), ((), ()))          # contract the row-block axis
     accg_ref[...] += jax.lax.dot_general(
         a, dg, dims, preferred_element_type=jnp.float32)
@@ -184,19 +222,22 @@ def _fwd_impl(a, w_gate_up, use_pallas, blocks):
     M = w_gate_up.shape[-1] // 2
     af = a.reshape(-1, H)
     T = af.shape[0]
-    bt, bm = _blocks(T, M, blocks)
+    bt, bm = _blocks(T, H, M, a.dtype.itemsize, blocks)
     nm = M // bm
+    interpret = not _on_tpu()
     out = pl.pallas_call(
         _fwd_kernel,
         out_shape=jax.ShapeDtypeStruct((T, M), a.dtype),
-        grid=(T // bt, nm),
+        grid=(pl.cdiv(T, bt), nm),
         in_specs=[
             pl.BlockSpec((bt, H), lambda i, j: (i, 0)),
             pl.BlockSpec((H, bm), lambda i, j: (0, j)),
             pl.BlockSpec((H, bm), lambda i, j, nm=nm: (0, j + nm)),
         ],
         out_specs=pl.BlockSpec((bt, bm), lambda i, j: (i, j)),
-        interpret=not _on_tpu(),
+        compiler_params=_compiler_params(interpret, "parallel", "parallel"),
+        interpret=interpret,
+        name="swiglu_fwd",
     )(af, w_gate_up, w_gate_up)
     return out.reshape(orig_shape[:-1] + (M,))
 
@@ -213,9 +254,9 @@ def _bwd_impl(a, w_gate_up, g, use_pallas, blocks):
     af = a.reshape(-1, H)
     gf = g.reshape(-1, M)
     T = af.shape[0]
-    bt, bm = _blocks(T, M, blocks)
-    nt, nm = T // bt, M // bm
-    scratch = pltpu.VMEM if _HAS_TPU and pltpu is not None else None
+    bt, bm = _blocks(T, H, M, a.dtype.itemsize, blocks)
+    nt, nm = pl.cdiv(T, bt), M // bm
+    interpret = not _on_tpu()
     da = pl.pallas_call(
         functools.partial(_bwd_da_kernel, nm=nm),
         out_shape=jax.ShapeDtypeStruct((T, H), a.dtype),
@@ -227,11 +268,13 @@ def _bwd_impl(a, w_gate_up, g, use_pallas, blocks):
             pl.BlockSpec((bt, bm), lambda i, j: (i, j)),
         ],
         out_specs=pl.BlockSpec((bt, H), lambda i, j: (i, 0)),
-        scratch_shapes=[scratch((bt, H), jnp.float32)],
-        interpret=not _on_tpu(),
+        scratch_shapes=[pltpu.VMEM((bt, H), jnp.float32)],
+        compiler_params=_compiler_params(interpret, "parallel", "arbitrary"),
+        interpret=interpret,
+        name="swiglu_bwd_da",
     )(af, w_gate_up, w_gate_up, gf)
     dwg, dwu = pl.pallas_call(
-        functools.partial(_bwd_dw_kernel, nt=nt),
+        functools.partial(_bwd_dw_kernel, nt=nt, rows=T),
         out_shape=(jax.ShapeDtypeStruct((H, M), w_gate_up.dtype),
                    jax.ShapeDtypeStruct((H, M), w_gate_up.dtype)),
         grid=(nm, nt),
@@ -243,9 +286,11 @@ def _bwd_impl(a, w_gate_up, g, use_pallas, blocks):
         ],
         out_specs=(pl.BlockSpec((H, bm), lambda m, t: (0, m)),
                    pl.BlockSpec((H, bm), lambda m, t: (0, m))),
-        scratch_shapes=[scratch((H, bm), jnp.float32),
-                        scratch((H, bm), jnp.float32)],
-        interpret=not _on_tpu(),
+        scratch_shapes=[pltpu.VMEM((H, bm), jnp.float32),
+                        pltpu.VMEM((H, bm), jnp.float32)],
+        compiler_params=_compiler_params(interpret, "parallel", "arbitrary"),
+        interpret=interpret,
+        name="swiglu_bwd_dw",
     )(af, w_gate_up, w_gate_up, gf)
     dw = jnp.concatenate([dwg, dwu], axis=-1)
     return da.reshape(orig_shape), dw
@@ -314,4 +359,5 @@ def sweep_block_sizes(a_shape, w_shape, dtype=jnp.bfloat16, iters=8,
 
     return autotune.autotune(
         key, [(128, 128), (128, 512), (256, 256), (256, 512), (512, 512)],
-        make_fn, default=_blocks(rows, M), iters=iters, sweep=sweep)
+        make_fn, default=_blocks(rows, H, M, jnp.dtype(dtype).itemsize),
+        iters=iters, sweep=sweep)

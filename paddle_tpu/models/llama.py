@@ -102,9 +102,17 @@ class LlamaRMSNorm(Layer):
                              dtype="float32")
 
     def forward(self, x):
+        from ..distributed.sharding import shard_kernel
         from ..kernels import rms_norm as krn
-        return apply_op(lambda a, w: krn.rms_norm(a, w, self.eps),
-                        to_tensor_like(x), self.weight, name="rms_norm")
+
+        def norm(a, w):
+            rows = P("data", *[None] * (a.ndim - 1))
+            return shard_kernel(
+                lambda a_, w_: krn.rms_norm(a_, w_, self.eps),
+                (rows, P(None)), rows, batch=a.shape[0])(a, w)
+
+        return apply_op(norm, to_tensor_like(x), self.weight,
+                        name="rms_norm")
 
 
 class LlamaAttention(Layer):
@@ -135,7 +143,12 @@ class LlamaAttention(Layer):
             # GQA/MQA is native in the kernel wrapper (splash MQA mode —
             # no materialized kv repeat); dense fallback broadcasts
             if fa.supported(q.shape, k.shape, True):
-                return fa.flash_attention_bshd(q, k, v, causal=True)
+                from ..distributed.sharding import shard_kernel
+                bshd = P("data", None, "mp", None)
+                return shard_kernel(
+                    lambda q_, k_, v_: fa.flash_attention_bshd(
+                        q_, k_, v_, causal=True),
+                    (bshd, bshd, bshd), bshd, batch=B, heads=kvh)(q, k, v)
             if kvh != nh:
                 rep = nh // kvh
                 k = jnp.repeat(k, rep, axis=2)
@@ -224,8 +237,7 @@ class LlamaMLP(Layer):
                 # for the save_only_these_names remat policy
                 def mlp(a, wgu, wd):
                     from jax.ad_checkpoint import checkpoint_name
-                    from ..kernels.swiglu import swiglu
-                    o = checkpoint_name(swiglu(a, wgu), "llama_swiglu")
+                    o = checkpoint_name(_swiglu(a, wgu), "llama_swiglu")
                     return checkpoint_name(o @ wd, "llama_mlp_down")
 
                 return apply_op(mlp, to_tensor_like(x), self.gate_up_proj,
@@ -241,6 +253,24 @@ class LlamaMLP(Layer):
             lambda a, wg, wu, wd: (jax.nn.silu(a @ wg) * (a @ wu)) @ wd,
             to_tensor_like(x), self.gate_proj, self.up_proj, self.down_proj,
             name="llama_mlp")
+
+
+def _swiglu(a, wgu):
+    """kernels/swiglu on a [B, ..., H] activation. Under a sharded step the
+    kernel runs per device (shard_kernel): batch over the data axes, the
+    intermediate dim over mp. w_gate_up is [H, gate | up], so a column
+    split would hand one device gate columns only — it enters as
+    [H, 2, M], split on M, and each device folds its [H, 2, M/mp] back
+    into a local gate | up layout."""
+    from ..distributed.sharding import shard_kernel
+    from ..kernels.swiglu import swiglu
+    H, m = wgu.shape[0], wgu.shape[1] // 2
+    lead = ("data",) + (None,) * (a.ndim - 2)
+    run = shard_kernel(
+        lambda a_, w3: swiglu(a_, w3.reshape(H, -1)),
+        (P(*lead, None), P(None, None, "mp")),
+        P(*lead, "mp"), batch=a.shape[0], heads=m // 128)
+    return run(a, wgu.reshape(H, 2, m))
 
 
 class LlamaDecoderLayer(Layer):
@@ -260,11 +290,16 @@ class LlamaDecoderLayer(Layer):
             # fused hot path: the residual add + post-attention RMSNorm
             # collapse into one Pallas pass that emits BOTH the summed
             # stream h and the normalized a2 (kernels/fused_norm_residual)
+            from ..distributed.sharding import shard_kernel
             from ..kernels.fused_norm_residual import fused_add_rms_norm
             attn_out = self.self_attn(self.input_layernorm(x), position_ids)
             eps = self.post_attention_layernorm.eps
+            bsh = P("data", None, None)
             a2, h = apply_op(
-                lambda r, dlt, w: fused_add_rms_norm(r, dlt, w, eps),
+                lambda r, dlt, w: shard_kernel(
+                    lambda r_, d_, w_: fused_add_rms_norm(r_, d_, w_, eps),
+                    (bsh, bsh, P(None)), (bsh, bsh),
+                    batch=r.shape[0])(r, dlt, w),
                 to_tensor_like(x), attn_out,
                 self.post_attention_layernorm.weight,
                 n_outputs=2, name="fused_add_rms_norm")

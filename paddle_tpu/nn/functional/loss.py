@@ -57,9 +57,15 @@ def cross_entropy(input, label, weight=None, ignore_index=-100,
                 if lbl.ndim == logits.ndim and lbl.shape[ax] == 1:
                     lbl = jnp.squeeze(lbl, ax)
                 lbl = lbl.astype(jnp.int32)
-                loss = _fck.fused_cross_entropy(
-                    logits.reshape(-1, n_class), lbl.reshape(-1),
-                    ignore_index).reshape(lbl.shape)
+                from ...distributed.sharding import shard_kernel
+                from jax.sharding import PartitionSpec as _P
+                flat = logits.reshape(-1, n_class)
+                loss = shard_kernel(
+                    lambda x, y: _fck.fused_cross_entropy(
+                        x, y, ignore_index),
+                    (_P("data", None), _P("data")), _P("data"),
+                    batch=flat.shape[0])(flat, lbl.reshape(-1))
+                loss = loss.reshape(lbl.shape)
                 if reduction == "mean":
                     nvalid = jnp.sum((lbl != ignore_index).astype(
                         jnp.float32))

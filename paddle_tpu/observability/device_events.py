@@ -190,12 +190,9 @@ def note_traced_collective(op: str) -> None:
     stack = getattr(_tl, "stack", None)
     if not stack:
         return
-    try:
-        import jax
-        if jax.core.trace_state_clean():
-            return                   # eager call, not a trace
-    except Exception:
-        return
+    import jax
+    if jax.core.trace_ctx.is_top_level():
+        return                       # eager call, not a trace
     f = stack[-1]
     f.fresh[op] = f.fresh.get(op, 0) + 1
 
@@ -244,8 +241,5 @@ def install_listener() -> None:
     if _listener_installed:
         return
     _listener_installed = True
-    try:
-        from jax import monitoring
-        monitoring.register_event_duration_secs_listener(_on_duration)
-    except Exception:
-        pass                         # jax absent/old: histograms stay 0
+    from jax import monitoring
+    monitoring.register_event_duration_secs_listener(_on_duration)

@@ -233,23 +233,38 @@ def reset() -> None:
         _last_mfu = 0.0
 
 
-# bf16 peak FLOP/s by TPU generation (bench.py's table; order matters —
-# "v5e"/"v5lite" before the bare "v5" -> v5p fallback)
+# bf16 peak FLOP/s per chip by TPU generation (Google Cloud TPU
+# documentation, one page per generation) — the one table of the repo,
+# bench.py reads it too. Matched as a substring of the normalized
+# device_kind, most specific tag first. A TPU that is not listed has NO
+# peak: nothing is assumed for it.
 _PEAK = {
     "v3": 123e12,
     "v4": 275e12,
     "v5litepod": 197e12, "v5lite": 197e12, "v5e": 197e12,
     "v6e": 918e12, "trillium": 918e12,
-    "v5p": 459e12, "v5": 459e12,
+    "v5p": 459e12, "v5": 459e12,     # a v5p reports "TPU v5"
 }
 
 _peak_cache: Optional[float] = None
 
 
+def peak_for_device_kind(device_kind: str) -> Optional[float]:
+    """The table's bf16 peak FLOP/s for a `device.device_kind`, or None
+    where the table does not know the device."""
+    kind = device_kind.lower().replace(" ", "")
+    for tag, peak in _PEAK.items():
+        if tag in kind:
+            return peak
+    return None
+
+
 def peak_flops_per_sec() -> float:
     """Peak FLOP/s of the local chip for the MFU gauge.
-    PADDLE_PEAK_FLOPS overrides (tests, unlisted hardware); 0.0 on
-    backends with no known peak (CPU) — the gauge then stays unset."""
+    PADDLE_PEAK_FLOPS overrides (tests, unlisted hardware); 0.0 on a
+    device the table does not list (a CPU, or a TPU generation nobody
+    entered) — the gauge then stays unset, and an unlisted TPU is named
+    once on stderr so that a missing MFU is not a mystery."""
     global _peak_cache
     env = os.environ.get("PADDLE_PEAK_FLOPS")
     if env:
@@ -259,18 +274,14 @@ def peak_flops_per_sec() -> float:
             pass
     if _peak_cache is not None:
         return _peak_cache
-    peak = 0.0
-    try:
-        import jax
-        d = jax.local_devices()[0]
-        kind = getattr(d, "device_kind", "").lower().replace(" ", "")
-        for tag, p in _PEAK.items():
-            if tag in kind:
-                peak = p
-                break
-        if not peak and d.platform == "tpu":
-            peak = 459e12            # assume v5p (BASELINE.md hardware)
-    except Exception:
-        peak = 0.0
-    _peak_cache = peak
-    return peak
+    import jax
+    d = jax.local_devices()[0]
+    peak = peak_for_device_kind(getattr(d, "device_kind", ""))
+    if peak is None and d.platform == "tpu":
+        import sys
+        print(f"goodput: no peak FLOP/s is listed for TPU device_kind "
+              f"{d.device_kind!r}; MFU is not reported (add it to "
+              f"observability/goodput.py _PEAK or set PADDLE_PEAK_FLOPS)",
+              file=sys.stderr)
+    _peak_cache = peak or 0.0
+    return _peak_cache

@@ -17,7 +17,7 @@ the gateway, honored when a client sends one) and records, per trace id:
   single mark, so the buckets partition the request's lifetime with no
   gaps and no double counting (fp association error only, << 1e-6).
 
-Event names are a REGISTERED TAXONOMY (`EVENTS`): call sites pass
+Event names are a REGISTERED VOCABULARY (`EVENTS`): call sites pass
 literal snake_case ids and `emit()` rejects anything unregistered, so
 free-form strings cannot fork series (the graft-lint metric-names pass
 enforces the same discipline on the call-site literals).
@@ -49,7 +49,7 @@ __all__ = ["EVENTS", "BUCKETS", "RequestTrace", "mint_trace_id",
            "parse_trace_header", "new_trace", "get_trace", "lookup",
            "traces", "clear", "set_sink", "sink_path", "set_store_size"]
 
-# -- registered taxonomy -----------------------------------------------------
+# -- registered vocabulary -----------------------------------------------------
 
 # Every event a request timeline may carry. Literal snake_case ids at
 # call sites (lint-enforced); emit() raises on anything else so a typo
@@ -77,7 +77,7 @@ EVENTS = frozenset((
 ))
 
 # The attribution buckets. queue_wait/prefill_compute/preempted/
-# page_wait/draft_overhead/failover/stream_write are the ISSUE taxonomy;
+# page_wait/draft_overhead/failover/stream_write are the ISSUE vocabulary;
 # decode_compute completes the partition (without it decode time would
 # have to hide inside another bucket and the exactness invariant would
 # be a lie).

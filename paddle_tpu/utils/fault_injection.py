@@ -56,7 +56,7 @@ replica).
 
 Every point literal is linted by graft-lint's ``fault-point-hygiene``
 pass: unique to one module, ``subsystem.name`` snake_case, and listed
-in the fault-point table of ``benchmarks/MEASUREMENT_RUNBOOK.md``.
+in the fault-point table of ``tools/FAULT_POINTS.md``.
 """
 from __future__ import annotations
 

@@ -46,7 +46,13 @@ def test_cache_hit_values_and_grads_match_uncached():
     assert hits > 0, "warm loop must hit the cache"
     loss_u, grad_u = _loss_and_grad(x_np, False)
     np.testing.assert_allclose(loss_c, loss_u, rtol=1e-6)
-    np.testing.assert_allclose(grad_c, grad_u, rtol=1e-6, atol=1e-7)
+    # the cached op is one fused XLA program, the uncached path runs op
+    # by op: their tanh may differ by one f32 ulp (6e-8), and where tanh
+    # saturates its gradient 2(1 - y^2) is a difference of nearly equal
+    # numbers, so that ulp shows as up to ~2.4e-7 ABSOLUTE however small
+    # the gradient is. The bound is four ulps of 1.0; the gradients here
+    # are >= 5e-4, so a wrong one is still caught by rtol.
+    np.testing.assert_allclose(grad_c, grad_u, rtol=1e-6, atol=5e-7)
 
 
 def test_profiler_exposes_nonzero_hits_after_warm_loop():

@@ -258,7 +258,7 @@ def test_fault_point_serving_sites_documented_and_clean():
     serving module passes the hygiene bar."""
     from tools.graft_lint.passes.fault_points import parse_runbook_table
     table = parse_runbook_table(
-        REPO / "benchmarks" / "MEASUREMENT_RUNBOOK.md")
+        REPO / "tools" / "FAULT_POINTS.md")
     assert {"serving.tick", "serving.admit",
             "serving.page_alloc"} <= table
     res = _run([FaultPointsPass()],

@@ -8,7 +8,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 import paddle_tpu as paddle
@@ -106,7 +106,7 @@ class TestQuantizedCollectiveAPI:
 
         x = np.random.RandomState(0).randn(N_DEV, 600).astype(np.float32)
         f = jax.jit(shard_map(body, mesh=mesh, in_specs=P("dp"),
-                              out_specs=P("dp"), check_rep=False))
+                              out_specs=P("dp"), check_vma=False))
         return np.asarray(f(x)), x.sum(0, keepdims=True).repeat(N_DEV, 0)
 
     def test_quantized_all_reduce_close_to_exact(self):
@@ -149,7 +149,7 @@ class TestQuantizedCollectiveAPI:
             return t.data[None]
 
         f = jax.jit(shard_map(body, mesh=mesh, in_specs=P("dp"),
-                              out_specs=P("dp"), check_rep=False))
+                              out_specs=P("dp"), check_vma=False))
         out = np.asarray(f(x))                 # rank i keeps shard i
         ref = x.sum(axis=0)                    # (N_DEV, 40)
         rel = np.abs(out - ref).max() / np.abs(ref).max()

@@ -1,6 +1,6 @@
 """Request-scope tracing (ISSUE 18): the attribution ledger's
 sum(buckets)==wall-by-construction invariant, the registered event
-taxonomy, the JSONL sink, the engine timeline, the gateway/router trace
+vocabulary, the JSONL sink, the engine timeline, the gateway/router trace
 id plumbing (X-Request-Trace in, X-Request-Id + SSE trace_id out), the
 fleet-scope `GET /v1/trace/<id>` merge that survives a dead replica,
 heat-oracle freshness (TTL expiry + evict-on-refresh + eject clears),
@@ -156,7 +156,12 @@ class TestEngineTraces:
         for must in ("arrival", "admitted", "prefill_chunk",
                      "first_token", "finished"):
             assert must in names, names
-        assert rec["decode_ticks"] >= 5
+        # 6 tokens: the first from the prefill chunk, five from decode
+        # ticks — fewer ticks where the self-speculative drafter had a
+        # token accepted (this model repeats itself, so it does)
+        accepted = sum(e["n"] for e in rec["events"]
+                       if e["ev"] == "draft_accepted")
+        assert rec["decode_ticks"] + accepted >= 5
         assert rec["buckets"]["prefill_compute"] > 0
         assert rec["buckets"]["decode_compute"] > 0
 

@@ -254,6 +254,9 @@ class TestServingTelemetry:
     def test_ttft_tpot_pages_preemptions_recorded(self):
         from paddle_tpu.observability import metrics
         model = _tiny_model()
+        # the registry is the process's: under xdist an armed test of
+        # another file may have counted requests on this worker before
+        metrics.reset()
         obs.enable(True)
         eng = ContinuousBatchingEngine(model, max_batch=2, max_seq=64,
                                        total_pages=5, max_chunk_tokens=8)

@@ -1,0 +1,234 @@
+"""The Pallas kernels of the main path, compiled for a DESCRIBED TPU v5e
+at the widths the repo ships (llama_350m / llama_1b / llama_7b).
+
+Interpret mode cannot see what the chip's compiler refuses: a block that
+does not tile (8, 128), a kernel that wants more VMEM than it is scoped
+to, a Mosaic call GSPMD is asked to partition. The TPU compiler is
+installed without a chip, so these compile against
+`topologies.get_topology_desc("v5e:2x2")` — nothing runs, no device is
+touched. A compile that passes here is not a chip run.
+
+Only one process may hold libtpu, so the topology is described inside a
+module-scoped fixture (never at import, in a skipif or in a parametrize
+argument) and every such test lives in this one file: under xdist only
+the worker that is handed the file loads the library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from paddle_tpu.kernels import block_attention as ba
+from paddle_tpu.kernels import cross_entropy as ce
+from paddle_tpu.kernels import flash_attention as fa
+from paddle_tpu.kernels import fused_norm_residual as fnr
+from paddle_tpu.kernels import paged_attention as pa
+from paddle_tpu.kernels import ragged_paged_attention as rpa
+from paddle_tpu.kernels import rms_norm as rn
+from paddle_tpu.kernels import swiglu as sg
+
+# (hidden, intermediate) of models/llama.py's shipped configs
+WIDTHS = {"350m": (1024, 2816), "1b": (2048, 5504), "7b": (4096, 11008)}
+DTYPES = {"bf16": jnp.bfloat16, "f32": jnp.float32}
+ROWS = 4 * 2048
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _as_on_the_chip(monkeypatch):
+    """Steer every kernel module's backend test to "TPU" (compiled route,
+    interpret off), compile at the program's own matmul precision, and
+    keep these compiles out of the persistent cache: an entry written
+    for a described chip cannot be read back without one."""
+    for mod in (ba, ce, fa, fnr, pa, rpa, rn, sg):
+        monkeypatch.setattr(mod, "_on_tpu", lambda: True)
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    cache_was = jax.config.jax_enable_compilation_cache
+    precision_was = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_enable_compilation_cache", False)
+    # conftest.py asks for "highest" so that CPU goldens are true f32;
+    # the chip runs the program's own default (Mosaic has no fp32-
+    # precision matmul of bf16 operands)
+    jax.config.update("jax_default_matmul_precision", None)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_default_matmul_precision", precision_was)
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    cc.reset_cache()
+
+
+def _compile(fn, *args):
+    """Compile for the described chip; the text must hold a Mosaic call."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    return text
+
+
+def _sum32(tree):
+    return sum(jnp.sum(x.astype(jnp.float32))
+               for x in jax.tree_util.tree_leaves(tree))
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("width", WIDTHS)
+def test_swiglu_fwd_and_grad(one_chip, width, dtype):
+    H, M = WIDTHS[width]
+    a = jax.ShapeDtypeStruct((ROWS, H), DTYPES[dtype], sharding=one_chip)
+    w = jax.ShapeDtypeStruct((H, 2 * M), DTYPES[dtype], sharding=one_chip)
+    _compile(sg.swiglu, a, w)
+    text = _compile(jax.grad(lambda a_, w_: _sum32(sg.swiglu(a_, w_)),
+                             argnums=(0, 1)), a, w)
+    assert "swiglu_bwd_da" in text and "swiglu_bwd_dw" in text
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("width", WIDTHS)
+def test_fused_add_rms_norm_fwd_and_grad(one_chip, width, dtype):
+    H, _ = WIDTHS[width]
+    x = jax.ShapeDtypeStruct((4, 2048, H), DTYPES[dtype], sharding=one_chip)
+    w = jax.ShapeDtypeStruct((H,), jnp.float32, sharding=one_chip)
+    _compile(fnr.fused_add_rms_norm, x, x, w)
+    _compile(jax.grad(
+        lambda x_, r_, w_: _sum32(fnr.fused_add_rms_norm(x_, r_, w_)),
+        argnums=(0, 1, 2)), x, x, w)
+
+
+@pytest.mark.parametrize("rows", [1, 20, 300])
+def test_serving_row_counts_tile(one_chip, rows):
+    # decode steps and prefill chunks hand the row-blocked kernels row
+    # counts that are no multiple of 8
+    H, M = WIDTHS["7b"]
+    x = jax.ShapeDtypeStruct((rows, H), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((H, 2 * M), jnp.bfloat16, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((H,), jnp.float32, sharding=one_chip)
+    _compile(sg.swiglu, x, w)
+    _compile(rn.rms_norm, x, g)
+    _compile(fnr.fused_add_rms_norm, x, x, g)
+
+
+@pytest.mark.parametrize("kv_heads", [32, 8], ids=["mha", "gqa"])
+def test_flash_attention_fwd_and_grad(one_chip, kv_heads):
+    q = jax.ShapeDtypeStruct((1, 2048, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 2048, kv_heads, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    assert fa.supported(q.shape, kv.shape, True)
+
+    def f(q_, k_, v_):
+        return fa.flash_attention_bshd(q_, k_, v_, causal=True)
+
+    _compile(f, q, kv, kv)
+    _compile(jax.grad(lambda *a: _sum32(f(*a)), argnums=(0, 1, 2)),
+             q, kv, kv)
+
+
+def test_fused_cross_entropy_fwd_and_grad(one_chip):
+    x = jax.ShapeDtypeStruct((2048, 32000), jnp.bfloat16, sharding=one_chip)
+    y = jax.ShapeDtypeStruct((2048,), jnp.int32, sharding=one_chip)
+    _compile(ce.fused_cross_entropy, x, y)
+    _compile(jax.grad(lambda x_, y_: jnp.sum(
+        ce.fused_cross_entropy(x_, y_))), x, y)
+
+
+def _pool(one_chip, kvh=32, n_pages=129, page=16, d=128):
+    return jax.ShapeDtypeStruct((kvh, n_pages, page, d), jnp.bfloat16,
+                                sharding=one_chip)
+
+
+def test_paged_decode_attention(one_chip):
+    q = jax.ShapeDtypeStruct((4, 32, 128), jnp.bfloat16, sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((4, 16), jnp.int32, sharding=one_chip)
+    pool = _pool(one_chip)
+    assert pa.supported(q.shape, pool.shape)
+    _compile(pa.paged_decode_attention, q, pool, pool, lens, table)
+
+
+def test_ragged_paged_attention(one_chip):
+    q = jax.ShapeDtypeStruct((64, 32, 128), jnp.bfloat16, sharding=one_chip)
+    seq = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((4, 16), jnp.int32, sharding=one_chip)
+    pool = _pool(one_chip)
+    text = _compile(rpa.ragged_paged_attention, q, pool, pool, seq, seq,
+                    seq, table)
+    assert "ragged_paged_attention" in text
+
+
+def test_block_attention_stats(one_chip):
+    q = jax.ShapeDtypeStruct((1, 512, 8, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    bias = jax.ShapeDtypeStruct((1, 8, 512, 512), jnp.float32,
+                                sharding=one_chip)
+    _compile(lambda q_, k_, v_, b_: ba.block_attention_stats(
+        q_, k_, v_, None, 0.125, b_, True), q, q, q, bias)
+
+
+def test_rms_norm(one_chip):
+    x = jax.ShapeDtypeStruct((4, 2048, 4096), jnp.bfloat16,
+                             sharding=one_chip)
+    w = jax.ShapeDtypeStruct((4096,), jnp.float32, sharding=one_chip)
+    _compile(rn.rms_norm, x, w)
+    # the backward is analytic jnp and needs no forward output: the
+    # gradient program holds no kernel, it only has to compile
+    jax.jit(jax.grad(lambda x_, w_: _sum32(rn.rms_norm(x_, w_)),
+                     argnums=(0, 1))).lower(x, w).compile()
+
+
+def test_sharded_operands_compile_through_shard_kernel(topo):
+    """Batch- and head-sharded operands on a 2x2 mesh: plain jit refuses
+    to partition a Mosaic call; the models' call sites wrap it in
+    shard_map over the armed mesh (distributed/sharding.shard_kernel)."""
+    from paddle_tpu.distributed.sharding import (kernel_mesh_guard,
+                                                 shard_kernel)
+    from paddle_tpu.models.llama import _swiglu
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("sharding", "mp"))
+
+    def arg(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    H, M = WIDTHS["7b"]
+    q = arg((2, 2048, 32, 128), jnp.bfloat16,
+            P("sharding", None, "mp", None))
+    x = arg((2, 2048, H), jnp.bfloat16, P("sharding", None, None))
+    g = arg((H,), jnp.float32, P(None))
+    w = arg((H, 2 * M), jnp.bfloat16, P("sharding", "mp"))
+    bshd, bsh = P("data", None, "mp", None), P("data", None, None)
+
+    def attend(q_, k_, v_):
+        return fa.flash_attention_bshd(q_, k_, v_, causal=True)
+
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        jax.jit(attend).lower(q, q, q).compile()
+
+    def step(q_, x_, g_, w_):
+        with kernel_mesh_guard(mesh):
+            o = shard_kernel(attend, (bshd,) * 3, bshd, batch=2,
+                             heads=32)(q_, q_, q_)
+            y, h = shard_kernel(fnr.fused_add_rms_norm, (bsh, bsh, P(None)),
+                                (bsh, bsh), batch=2)(x_, x_, g_)
+            return _sum32((o, h, _swiglu(y, w_)))
+
+    text = _compile(jax.grad(step, argnums=(0, 1, 2, 3)), q, x, g, w)
+    for kernel in ("flash_attention", "fused_add_rms_norm", "swiglu_bwd_da",
+                   "swiglu_bwd_dw"):
+        assert kernel in text, kernel

@@ -289,7 +289,7 @@ class TestZeroCollectives:
     def test_rs_shard_matches_mean_and_ag_roundtrips(self):
         """zero_grad_reduce_scatter shards the exact mean (both stages);
         zero_param_all_gather reassembles the padded flat vector."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from paddle_tpu.distributed.collective import (
             zero_grad_reduce_scatter, zero_param_all_gather)
@@ -307,7 +307,7 @@ class TestZeroCollectives:
         for stage in (1, 2):
             f = jax.jit(shard_map(
                 lambda r, st=stage: body(r, st), mesh=mesh,
-                in_specs=P("dp"), out_specs=P("dp"), check_rep=False))
+                in_specs=P("dp"), out_specs=P("dp"), check_vma=False))
             out = np.asarray(f(x))      # every rank: the padded mean
             ref = np.pad(x.mean(0), (0, padded - numel))
             for r in range(N_DEV):

@@ -19,7 +19,7 @@ DEFAULT — must:
    (Multiple sites in one file are fine: `elastic.restore` fires from
    two branches of one logical operation.);
 4. be listed in the fault-point table of
-   `benchmarks/MEASUREMENT_RUNBOOK.md` (between the
+   `tools/FAULT_POINTS.md` (between the
    `fault-point-table:begin/end` markers) — an undocumented point is a
    chaos lever nobody can find, and a documented point with no live
    site (the inverse check, full-scope runs only) is a runbook lying
@@ -34,7 +34,7 @@ from typing import Dict, List, Set, Tuple
 
 from ..core import FileContext, Finding, LintPass
 
-RUNBOOK_RELPATH = "benchmarks/MEASUREMENT_RUNBOOK.md"
+RUNBOOK_RELPATH = "tools/FAULT_POINTS.md"
 NAME_RE = re.compile(r"^[a-z][a-z0-9_]*\.[a-z][a-z0-9_]*$")
 _TABLE_BEGIN = "<!-- fault-point-table:begin -->"
 _TABLE_END = "<!-- fault-point-table:end -->"

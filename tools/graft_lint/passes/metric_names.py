@@ -35,7 +35,7 @@ names, but only when the site executes; this lint catches the typo'd
 event (which would fork a timeline series the trace tooling cannot
 merge) before any request has to hit the path. A conditional between
 two registered literals (`"resumed" if ... else "admitted"`) is fine —
-both arms are validated. The taxonomy is read from reqtrace.py's AST,
+both arms are validated. The vocabulary is read from reqtrace.py's AST,
 not imported, so the linter never pays the jax import chain.
 
 Collector-bridged ids (register_collector rows) are data, not creation
@@ -80,9 +80,9 @@ _REQTRACE_PATH = REPO / "paddle_tpu" / "observability" / "reqtrace.py"
 
 
 def _load_trace_events():
-    """The registered taxonomy, from reqtrace.py's AST: the module-level
+    """The registered vocabulary, from reqtrace.py's AST: the module-level
     `EVENTS = frozenset((...))` literal. None when unreadable (the
-    taxonomy checks then stand down; literal/shape checks still run)."""
+    vocabulary checks then stand down; literal/shape checks still run)."""
     try:
         tree = ast.parse(_REQTRACE_PATH.read_text())
     except (OSError, SyntaxError):
@@ -259,6 +259,6 @@ class MetricNamesPass(LintPass):
                         ctx, node.lineno,
                         f"trace event {name!r} is not registered in "
                         f"observability.reqtrace.EVENTS — add it to the "
-                        f"taxonomy (with a comment saying what it "
+                        f"vocabulary (with a comment saying what it "
                         f"marks) or fix the typo"))
         return out

@@ -4,7 +4,7 @@ times (VERDICT r3 weak #5: the estimator had never been compared to a
 real TPU step; its pruning could discard the TPU-best candidate).
 
 Reads every measured llama record it can find — BENCH_R4_PRE_SWEEP.json,
-BENCH_LAST_GOOD.json, ONCHIP_R{4,5}.jsonl bench_350m* sections — and
+ONCHIP_R{4,5}.jsonl bench_350m* sections — and
 prints, per record, the estimator's step time for the same (model,
 batch, seq, 1-chip) point next to the measurement, with BOTH the raw
 ratio (uncalibrated hardware ceilings) and the calibrated ratio
@@ -28,8 +28,7 @@ sys.path.insert(0, REPO)
 
 def _records():
     bdir = os.path.join(REPO, "benchmarks")
-    for path in (os.path.join(bdir, "BENCH_R4_PRE_SWEEP.json"),
-                 os.path.join(bdir, "BENCH_LAST_GOOD.json")):
+    for path in (os.path.join(bdir, "BENCH_R4_PRE_SWEEP.json"),):
         try:
             with open(path) as f:
                 rec = json.load(f)
