@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..framework import core
+from ..observability import scopes as _scopes
 
 
 # set to static.record_op by paddle.enable_static(); None in dynamic mode
@@ -518,11 +519,16 @@ def apply_op(fn: Callable, *args, n_outputs: int = 1, name: str = "",
             raise _with_op_context(e, name, datas)
         vjp_fn = functools.partial(entry.bwd, dyn_vals)
     else:
+        carried = _scopes.carried()     # see observability/scopes.py
+
         def partial_fn(*diff_vals):
             full = list(datas)
             for i, v in zip(diff_idx, diff_vals):
                 full[i] = v
-            return fn(*full, **static_kwargs)
+            if carried is None:
+                return fn(*full, **static_kwargs)
+            with jax.named_scope(carried):
+                return fn(*full, **static_kwargs)
 
         try:
             out, vjp_fn = jax.vjp(partial_fn, *[datas[i] for i in diff_idx])

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..observability import scopes as _scopes
 from ..tensor import Parameter, Tensor
 
 
@@ -251,6 +252,20 @@ def kernel_mesh_guard(mesh: Mesh):
         _kernel_meshes.pop()
 
 
+def tp_all_reduce():
+    """Scope `tp/all_reduce` while a step traces over a mesh with an mp
+    axis, no scope otherwise: for the call sites whose tensor-parallel
+    all-reduce is not in the program's own text. A row-parallel matmul's
+    output is all-reduced in the forward pass and a column-parallel
+    matmul's input gradient in the backward pass, both put in by the
+    partitioner under the matmul's op_name; shard_map's transpose
+    all-reduces (`psum`) the gradient of whatever entered replicated."""
+    mesh = _kernel_meshes[-1] if _kernel_meshes else None
+    if mesh is None or "mp" not in mesh.axis_names or mesh.shape["mp"] < 2:
+        return contextlib.nullcontext()
+    return _scopes.scope("tp/all_reduce")
+
+
 def shard_kernel(fn, in_specs, out_specs, batch: int, heads: int = 1):
     """`fn`, or `fn` inside shard_map over the mesh of the sharded step
     being traced (none armed, or one device: `fn` itself).
@@ -274,12 +289,18 @@ def shard_kernel(fn, in_specs, out_specs, batch: int, heads: int = 1):
     def resolve(spec):
         return P(*[roles[e] if e is not None else None for e in spec])
 
-    return jax.shard_map(
+    mapped = jax.shard_map(
         fn, mesh=mesh,
         in_specs=tuple(resolve(sp) for sp in in_specs),
         out_specs=(resolve(out_specs) if isinstance(out_specs, P)
                    else tuple(resolve(sp) for sp in out_specs)),
         check_vma=False)
+
+    def run(*args):
+        with tp_all_reduce():
+            return mapped(*args)
+
+    return run
 
 
 class ShardingPlan:

@@ -25,6 +25,8 @@ from ..framework import core
 from ..observability import device_events as _devev
 from ..observability import goodput as _goodput
 from ..observability import metrics as _om
+from ..observability import scopes as _scopes
+from ..observability import spans as _spans
 from ..tensor import Tensor
 
 __all__ = ["to_static", "not_to_static", "TrainStep", "train_step", "save",
@@ -307,7 +309,9 @@ def _zero_sharded_update(model, opt, ef, axis, nranks, stage, cfg, block):
             if hasattr(t, "optimize_attr") else lr
         if t.regularizer is not None:
             gs = gs + t.regularizer(w_shard)
-        new_shard = opt._apply_one(t, w_shard, gs, plr).astype(w_shard.dtype)
+        with jax.named_scope("optimizer"):
+            new_shard = opt._apply_one(t, w_shard, gs,
+                                       plr).astype(w_shard.dtype)
         full = _coll.zero_param_all_gather(new_shard, axis=axis)
         t.data = full[:numel].reshape(t.data.shape)
         if new_res is not None and ef and k in ef:
@@ -453,6 +457,8 @@ class TrainStep:
         n = next(_TRAIN_STEP_TAGS)
         self._exec_tag = "train_step" if n == 1 else f"train_step_{n}"
         self._step_flops = None   # executable cost_analysis FLOPs (MFU)
+        self._traces = 0          # times the step body was traced
+        self._executed = False    # the compiled step has run once
         self._accum = int(accumulate_steps)
         self._quant = None        # (axis, nranks, CommQuantConfig) at build
         # (axis, nranks, zero_stage, cfg_or_None, block) at build
@@ -525,6 +531,7 @@ class TrainStep:
         to_names, to_ids = self._to_names, self._to_ids = self._state_keys()
         scaler = self.scaler
         accum = self._accum
+        tag = self._exec_tag
         # quantized grad sync arms at BUILD time so the kill switch
         # (FLAGS_quant_collectives=0) restores the plain GSPMD-psum
         # compile path bitwise, opted-in plan or not
@@ -595,8 +602,10 @@ class TrainStep:
                 for t, b in zip(btensors, bufs):
                     t.data = b
                 with core.rng_key_context(jax.random.wrap_key_data(mk)):
-                    loss = step_fn(*_tree_box(mb))
-                    loss.backward()
+                    with _scopes.phase("forward", tag):
+                        loss = step_fn(*_tree_box(mb))
+                    with _scopes.phase("backward", tag):
+                        loss.backward()
                 new_acc = []
                 for i, (a, p) in enumerate(zip(acc, ptensors)):
                     g = p.grad
@@ -620,7 +629,8 @@ class TrainStep:
             for i, (p, g) in enumerate(zip(ptensors, grads)):
                 if i in touched:
                     p.grad = _TT((g * inv_k).astype(g.dtype))
-            opt.step()
+            with _scopes.phase("optimizer", tag):
+                opt.step()
             return _TT(loss_sum * inv_k)
 
         def _pure_body(params, buffers, opt_state, master, scaler_state,
@@ -664,36 +674,39 @@ class TrainStep:
                         scaler._set_traced_state(scaler_state)
                     try:
                         new_ef = ef
+                        if accum > 1:
+                            loss = run_accum(batch, key)
+                        else:
+                            with _scopes.phase("forward", tag):
+                                loss = step_fn(*_tree_box(batch))
+                            with _scopes.phase("backward", tag):
+                                (scaler.scale(loss) if scaler is not None
+                                 else loss).backward()
                         if zero is not None:
                             # ZeRO sharded update: backward yields LOCAL
                             # grads (per-shard body); the rs -> shard
                             # update -> ag sequence replaces opt.step()
-                            loss = step_fn(*_tree_box(batch))
-                            loss.backward()
-                            new_ef = _zero_sharded_update(
-                                model, opt, ef, zero[0], zero[1],
-                                zero[2], zero[3], zero[4])
+                            with _scopes.phase("grad_sync", tag):
+                                new_ef = _zero_sharded_update(
+                                    model, opt, ef, zero[0], zero[1],
+                                    zero[2], zero[3], zero[4])
                         elif quant is not None:
                             # quantized DP sync: the body is per-shard
                             # (shard_map) so backward yields LOCAL
                             # grads; the explicit quantized chain is
                             # their mean before the update
-                            loss = step_fn(*_tree_box(batch))
-                            loss.backward()
-                            new_ef = _quant_sync_grads(
-                                model, ef, quant[0], quant[1], quant[2])
-                            opt.step()
+                            with _scopes.phase("grad_sync", tag):
+                                new_ef = _quant_sync_grads(
+                                    model, ef, quant[0], quant[1], quant[2])
+                            with _scopes.phase("optimizer", tag):
+                                opt.step()
                         elif scaler is not None:
-                            loss = step_fn(*_tree_box(batch))
-                            scaler.scale(loss).backward()
-                            scaler.step(opt)
-                            scaler.update()
-                        elif accum > 1:
-                            loss = run_accum(batch, key)
-                        else:
-                            loss = step_fn(*_tree_box(batch))
-                            loss.backward()
-                            opt.step()
+                            with _scopes.phase("optimizer", tag):
+                                scaler.step(opt)
+                                scaler.update()
+                        elif accum == 1:
+                            with _scopes.phase("optimizer", tag):
+                                opt.step()
                         # in-trace: drop grads entirely — zero-filled
                         # grads here would be traced values leaking out
                         opt.clear_grad(set_to_zero=False)
@@ -731,12 +744,22 @@ class TrainStep:
 
         def pure(params, buffers, opt_state, master, scaler_state, step_i,
                  lr, key, batch, ef=None):
+            # Python that runs only while the step TRACES: count it. A
+            # trace after the first execution is a recompile in the
+            # middle of a run (new shapes, a widened state tree)
+            self._traces += 1
+            _spans.setup_event("train_step.traced", executable=tag,
+                               n=self._traces,
+                               after_first_execution=self._executed)
+            _devev.note_step_traced(None)
             # arm the jax.checkpoint policy for THIS trace — the models'
             # remat sites (_scan_stack/_recompute_stack) read it via
             # core.current_remat_policy() while the body traces
             with core.remat_policy_guard(remat_pol):
-                return _pure_body(params, buffers, opt_state, master,
-                                  scaler_state, step_i, lr, key, batch, ef)
+                out = _pure_body(params, buffers, opt_state, master,
+                                 scaler_state, step_i, lr, key, batch, ef)
+            _devev.note_step_traced(tag)
+            return out
 
         # FLAGS_eager_delete_tensor_gb < 0 disables buffer donation (the
         # reference's eager-deletion kill switch maps to donation here);
@@ -829,9 +852,12 @@ class TrainStep:
         """The step as jax lowers it for this batch and the current state
         — the program `__call__` runs, for `.compile().as_text()` /
         `.memory_analysis()`. Nothing executes."""
-        call_args = self._call_args(batch)
-        with _devev.tagged(self._exec_tag):
-            return self._compiled.lower(*call_args)
+        tag = self._exec_tag
+        with _spans.setup_span("train_step.lower", executable=tag):
+            with _spans.setup_span("train_step.call_args", executable=tag):
+                call_args = self._call_args(batch)
+            with _devev.tagged(tag):
+                return self._compiled.lower(*call_args)
 
     def __call__(self, *batch):
         bench = core.get_bool_flag("FLAGS_benchmark")
@@ -839,34 +865,38 @@ class TrainStep:
             import time as _time
             _t0 = _time.perf_counter()
         armed = _om.enabled()
-        call_args = self._call_args(batch)
+        # three host parts, each a TraceAnnotation (_scopes.STEP_SPANS):
+        # with no profiler session one costs a relaxed atomic load; in a
+        # traced run they name the device's idle gaps
+        with jax.profiler.TraceAnnotation("train_step.call_args"):
+            call_args = self._call_args(batch)
         opt = self.optimizer
         if armed and self._step_flops is None:
             # must run BEFORE the call: args 0-3 are donated by it
             self._step_flops = self._lower_flops(call_args)
-        if armed:
-            # execution window: xla.dispatch_seconds{executable=tag} +
-            # per-execution collective counts replayed from the tag's
-            # trace-time composition (observability/device_events.py)
-            with _devev.execution(self._exec_tag):
-                outs = self._compiled(*call_args)
-        else:
+        # execution window (disarmed: one bool check): xla.dispatch_seconds
+        # {executable=tag} + per-execution collective counts replayed from
+        # the tag's trace-time composition (observability/device_events.py)
+        with _devev.execution(self._exec_tag), \
+                jax.profiler.TraceAnnotation("train_step.dispatch"):
             outs = self._compiled(*call_args)
-        if self._quant is not None or self._zero is not None:
-            (loss, new_params, new_buffers, new_opt_state, new_master,
-             new_scaler, new_ef) = outs
-            if new_ef:
-                self._ef_state = new_ef
-        else:
-            (loss, new_params, new_buffers, new_opt_state, new_master,
-             new_scaler) = outs
-        sd = self.model.state_dict()
-        for k, v in new_params.items():
-            sd[k].data = v
-        for k, v in new_buffers.items():
-            sd[k].data = v
-        opt._state = self._to_ids(new_opt_state)
-        opt._master_weights = self._to_ids(new_master)
+        self._executed = True
+        with jax.profiler.TraceAnnotation("train_step.write_back"):
+            if self._quant is not None or self._zero is not None:
+                (loss, new_params, new_buffers, new_opt_state, new_master,
+                 new_scaler, new_ef) = outs
+                if new_ef:
+                    self._ef_state = new_ef
+            else:
+                (loss, new_params, new_buffers, new_opt_state, new_master,
+                 new_scaler) = outs
+            sd = self.model.state_dict()
+            for k, v in new_params.items():
+                sd[k].data = v
+            for k, v in new_buffers.items():
+                sd[k].data = v
+            opt._state = self._to_ids(new_opt_state)
+            opt._master_weights = self._to_ids(new_master)
         if self._opt_state_bytes is None:
             # the build step materialized every state slot (primed, or
             # shard-created under ZeRO) — record the per-rank footprint

@@ -30,6 +30,7 @@ from ..nn import initializer as I
 from ..nn.layer.common import Dropout, Linear
 from ..nn.layer.container import LayerList
 from ..nn.layer.layers import Layer
+from ..observability.scopes import scope
 from ..ops import manipulation as M
 from ..ops._helpers import to_tensor_like
 from ..tensor import Tensor
@@ -86,6 +87,14 @@ class LlamaConfig:
         return self.num_key_value_heads or self.num_attention_heads
 
 
+def _tp_all_reduce():
+    """distributed.sharding.tp_all_reduce (imported late, as shard_kernel
+    is): the scope of a matmul whose tensor-parallel all-reduce the
+    partitioner puts in."""
+    from ..distributed.sharding import tp_all_reduce
+    return tp_all_reduce()
+
+
 def _param(layer, shape, pspec, std=0.02, init=None, dtype=None):
     p = layer.create_parameter(
         shape, dtype=dtype,
@@ -107,9 +116,10 @@ class LlamaRMSNorm(Layer):
 
         def norm(a, w):
             rows = P("data", *[None] * (a.ndim - 1))
-            return shard_kernel(
-                lambda a_, w_: krn.rms_norm(a_, w_, self.eps),
-                (rows, P(None)), rows, batch=a.shape[0])(a, w)
+            with scope("norm"):
+                return shard_kernel(
+                    lambda a_, w_: krn.rms_norm(a_, w_, self.eps),
+                    (rows, P(None)), rows, batch=a.shape[0])(a, w)
 
         return apply_op(norm, to_tensor_like(x), self.weight,
                         name="rms_norm")
@@ -142,23 +152,30 @@ class LlamaAttention(Layer):
             from ..kernels import flash_attention as fa
             # GQA/MQA is native in the kernel wrapper (splash MQA mode —
             # no materialized kv repeat); dense fallback broadcasts
-            if fa.supported(q.shape, k.shape, True):
-                from ..distributed.sharding import shard_kernel
-                bshd = P("data", None, "mp", None)
-                return shard_kernel(
-                    lambda q_, k_, v_: fa.flash_attention_bshd(
-                        q_, k_, v_, causal=True),
-                    (bshd, bshd, bshd), bshd, batch=B, heads=kvh)(q, k, v)
-            if kvh != nh:
-                rep = nh // kvh
-                k = jnp.repeat(k, rep, axis=2)
-                v = jnp.repeat(v, rep, axis=2)
-            return _sdpa(q, k, v)
+            with scope("attn/core"):
+                if fa.supported(q.shape, k.shape, True):
+                    from ..distributed.sharding import shard_kernel
+                    bshd = P("data", None, "mp", None)
+                    return shard_kernel(
+                        lambda q_, k_, v_: fa.flash_attention_bshd(
+                            q_, k_, v_, causal=True),
+                        (bshd, bshd, bshd), bshd, batch=B,
+                        heads=kvh)(q, k, v)
+                if kvh != nh:
+                    rep = nh // kvh
+                    k = jnp.repeat(k, rep, axis=2)
+                    v = jnp.repeat(v, rep, axis=2)
+                return _sdpa(q, k, v)
 
         def _attend(q, k, v):
             from ..kernels.rope import apply_rope
-            q, k = apply_rope(q, k, base=cfg.rope_theta)
+            with scope("attn/rope"):
+                q, k = apply_rope(q, k, base=cfg.rope_theta)
             return _core(q, k, v)
+
+        def _out(o, wo):
+            with scope("attn/out"), _tp_all_reduce():    # row-parallel
+                return o.reshape(B, -1, nh * d) @ wo
 
         if cfg.fuse_attention_qkv:
             if core.get_bool_flag("FLAGS_fused_transformer", True):
@@ -169,32 +186,35 @@ class LlamaAttention(Layer):
                 def attn(a, wqkv, wo):
                     from jax.ad_checkpoint import checkpoint_name
                     from ..kernels.rope import fused_qkv_rope
-                    q, k, v = fused_qkv_rope(a, wqkv, nh, kvh, d,
-                                             base=cfg.rope_theta)
+                    # projection and rotation are ONE fused prologue:
+                    # both carry attn/qkv
+                    with scope("attn/qkv"), _tp_all_reduce():
+                        q, k, v = fused_qkv_rope(a, wqkv, nh, kvh, d,
+                                                 base=cfg.rope_theta)
                     o = _core(q, k, v)
-                    return checkpoint_name(
-                        o.reshape(B, -1, nh * d) @ wo, "llama_attn_o")
+                    return checkpoint_name(_out(o, wo), "llama_attn_o")
 
                 return apply_op(attn, to_tensor_like(x), self.qkv_proj,
                                 self.o_proj, name="llama_attn_fused")
 
             def attn(a, wqkv, wo):
-                qkv = a @ wqkv
-                q = qkv[..., : nh * d].reshape(B, -1, nh, d)
-                k = qkv[..., nh * d: (nh + kvh) * d].reshape(B, -1, kvh, d)
-                v = qkv[..., (nh + kvh) * d:].reshape(B, -1, kvh, d)
-                o = _attend(q, k, v)
-                return o.reshape(B, -1, nh * d) @ wo
+                with scope("attn/qkv"):
+                    qkv = a @ wqkv
+                    q = qkv[..., : nh * d].reshape(B, -1, nh, d)
+                    k = qkv[..., nh * d: (nh + kvh) * d].reshape(
+                        B, -1, kvh, d)
+                    v = qkv[..., (nh + kvh) * d:].reshape(B, -1, kvh, d)
+                return _out(_attend(q, k, v), wo)
 
             return apply_op(attn, to_tensor_like(x), self.qkv_proj,
                             self.o_proj, name="llama_attn")
 
         def attn(a, wq, wk, wv, wo):
-            q = (a @ wq).reshape(B, -1, nh, d)
-            k = (a @ wk).reshape(B, -1, kvh, d)
-            v = (a @ wv).reshape(B, -1, kvh, d)
-            o = _attend(q, k, v)
-            return o.reshape(B, -1, nh * d) @ wo
+            with scope("attn/qkv"):
+                q = (a @ wq).reshape(B, -1, nh, d)
+                k = (a @ wk).reshape(B, -1, kvh, d)
+                v = (a @ wv).reshape(B, -1, kvh, d)
+            return _out(_attend(q, k, v), wo)
 
         return apply_op(attn, to_tensor_like(x), self.q_proj, self.k_proj,
                         self.v_proj, self.o_proj, name="llama_attn")
@@ -237,22 +257,27 @@ class LlamaMLP(Layer):
                 # for the save_only_these_names remat policy
                 def mlp(a, wgu, wd):
                     from jax.ad_checkpoint import checkpoint_name
-                    o = checkpoint_name(_swiglu(a, wgu), "llama_swiglu")
-                    return checkpoint_name(o @ wd, "llama_mlp_down")
+                    with scope("mlp"):
+                        o = checkpoint_name(_swiglu(a, wgu), "llama_swiglu")
+                        with _tp_all_reduce():           # row-parallel
+                            return checkpoint_name(o @ wd, "llama_mlp_down")
 
                 return apply_op(mlp, to_tensor_like(x), self.gate_up_proj,
                                 self.down_proj, name="llama_mlp_fused")
 
             def mlp(a, wgu, wd):
-                gu = a @ wgu
-                return (jax.nn.silu(gu[..., :m]) * gu[..., m:]) @ wd
+                with scope("mlp"):
+                    gu = a @ wgu
+                    return (jax.nn.silu(gu[..., :m]) * gu[..., m:]) @ wd
 
             return apply_op(mlp, to_tensor_like(x), self.gate_up_proj,
                             self.down_proj, name="llama_mlp")
-        return apply_op(
-            lambda a, wg, wu, wd: (jax.nn.silu(a @ wg) * (a @ wu)) @ wd,
-            to_tensor_like(x), self.gate_proj, self.up_proj, self.down_proj,
-            name="llama_mlp")
+        def mlp(a, wg, wu, wd):
+            with scope("mlp"):
+                return (jax.nn.silu(a @ wg) * (a @ wu)) @ wd
+
+        return apply_op(mlp, to_tensor_like(x), self.gate_proj,
+                        self.up_proj, self.down_proj, name="llama_mlp")
 
 
 def _swiglu(a, wgu):
@@ -270,7 +295,9 @@ def _swiglu(a, wgu):
         lambda a_, w3: swiglu(a_, w3.reshape(H, -1)),
         (P(*lead, None), P(None, None, "mp")),
         P(*lead, "mp"), batch=a.shape[0], heads=m // 128)
-    return run(a, wgu.reshape(H, 2, m))
+    with scope("tp/relayout"):
+        w3 = wgu.reshape(H, 2, m)
+    return run(a, w3)
 
 
 class LlamaDecoderLayer(Layer):
@@ -295,12 +322,17 @@ class LlamaDecoderLayer(Layer):
             attn_out = self.self_attn(self.input_layernorm(x), position_ids)
             eps = self.post_attention_layernorm.eps
             bsh = P("data", None, None)
+
+            def add_norm(r, dlt, w):
+                with scope("norm"):
+                    return shard_kernel(
+                        lambda r_, d_, w_: fused_add_rms_norm(r_, d_, w_,
+                                                              eps),
+                        (bsh, bsh, P(None)), (bsh, bsh),
+                        batch=r.shape[0])(r, dlt, w)
+
             a2, h = apply_op(
-                lambda r, dlt, w: shard_kernel(
-                    lambda r_, d_, w_: fused_add_rms_norm(r_, d_, w_, eps),
-                    (bsh, bsh, P(None)), (bsh, bsh),
-                    batch=r.shape[0])(r, dlt, w),
-                to_tensor_like(x), attn_out,
+                add_norm, to_tensor_like(x), attn_out,
                 self.post_attention_layernorm.weight,
                 n_outputs=2, name="fused_add_rms_norm")
             return h + self.mlp(a2)
@@ -334,8 +366,11 @@ class LlamaModel(Layer):
                     lyr.weight.data = lyr.weight.data.astype(jnp.float32)
 
     def forward(self, input_ids, position_ids=None):
-        x = apply_op(lambda ids, w: jnp.take(w, ids.astype(jnp.int32), axis=0),
-                     to_tensor_like(input_ids), self.embed_tokens,
+        def embed(ids, w):
+            with scope("embed"):
+                return jnp.take(w, ids.astype(jnp.int32), axis=0)
+
+        x = apply_op(embed, to_tensor_like(input_ids), self.embed_tokens,
                      name="embed")
         if self.cfg.scan_layers and position_ids is None:
             x = _scan_stack(list(self.layers), x,
@@ -360,8 +395,6 @@ def _scan_stack(layers, x, use_remat=True):
     all_params = [p for lyr in layers for _, p in lyr.named_parameters()]
 
     def run(a, *ws):
-        stacks = [jnp.stack(ws[i::n_per]) for i in range(n_per)]
-
         def body(h, pl):
             with _swap_param_data(objs, pl):
                 return _call_pure(template, h), None
@@ -371,7 +404,11 @@ def _scan_stack(layers, x, use_remat=True):
         # checkpoint_name-stamped matmul outputs via the core context
         b = jax.checkpoint(body, policy=core.current_remat_policy()) \
             if use_remat else body
-        h, _ = jax.lax.scan(b, a, tuple(stacks))
+        # `layers`: the stack's own plumbing (stacking weights, slicing
+        # a layer's out, writing its gradients back) has a name too
+        with scope("layers"):
+            stacks = [jnp.stack(ws[i::n_per]) for i in range(n_per)]
+            h, _ = jax.lax.scan(b, a, tuple(stacks))
         return h
 
     return apply_op(run, x, *all_params, name="decoder_scan")
@@ -385,7 +422,7 @@ def _recompute_stack(layers, x, position_ids):
         params = [p for _, p in lyr.named_parameters()]
 
         def run(a, *ws, _lyr=lyr, _params=params):
-            with _swap_param_data(_params, ws):
+            with _swap_param_data(_params, ws), scope("layers"):
                 return _call_pure(_lyr, a)
 
         ckpt = jax.checkpoint(run, policy=core.current_remat_policy())
@@ -455,6 +492,16 @@ def _translate_fusion_keys(sd, cfg):
     return out
 
 
+def _head(a, w):
+    with scope("head"), _tp_all_reduce():                # column-parallel
+        return a @ w
+
+
+def _head_tied(a, w):
+    with scope("head"), _tp_all_reduce():
+        return a @ jnp.swapaxes(w, 0, 1)
+
+
 class LlamaForCausalLM(Layer):
     def __init__(self, cfg: LlamaConfig):
         super().__init__()
@@ -479,17 +526,18 @@ class LlamaForCausalLM(Layer):
     def forward(self, input_ids, position_ids=None):
         h = self.model(input_ids, position_ids)
         if self.lm_head is not None:
-            return apply_op(lambda a, w: a @ w, h, self.lm_head, name="lm_head")
-        return apply_op(lambda a, w: a @ jnp.swapaxes(w, 0, 1), h,
-                        self.model.embed_tokens, name="lm_head_tied")
+            return apply_op(_head, h, self.lm_head, name="lm_head")
+        return apply_op(_head_tied, h, self.model.embed_tokens,
+                        name="lm_head_tied")
 
     def loss(self, input_ids, labels):
         """Shifted next-token CE in f32 (fused logsumexp path)."""
         logits = self(input_ids)
         B, S, V = logits.shape
-        lg = M.reshape(logits[:, :-1, :], [-1, V])
-        lb = M.reshape(labels[:, 1:], [-1])
-        return F.cross_entropy(lg, lb, ignore_index=-100)
+        with scope("loss"):
+            lg = M.reshape(logits[:, :-1, :], [-1, V])
+            lb = M.reshape(labels[:, 1:], [-1])
+            return F.cross_entropy(lg, lb, ignore_index=-100)
 
     # -- decode path (prefill + compiled greedy/sampling scan) --------------
     def generate(self, input_ids, max_new_tokens=32, max_length=None,

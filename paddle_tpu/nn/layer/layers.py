@@ -11,6 +11,7 @@ import contextlib
 from collections import OrderedDict
 from typing import Callable, Iterator, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -96,6 +97,7 @@ class Layer:
                 if d is not None and name in d:
                     del d[name]
             layers[name] = value
+            value._scope_name = name
             self.__dict__.pop(name, None)
             bump_struct_version()
         else:
@@ -162,6 +164,8 @@ class Layer:
 
     def add_sublayer(self, name, sublayer):
         self._sub_layers[str(name)] = sublayer
+        if isinstance(sublayer, Layer):
+            sublayer._scope_name = str(name)
         bump_struct_version()
         return sublayer
 
@@ -333,6 +337,20 @@ class Layer:
         raise NotImplementedError
 
     def __call__(self, *inputs, **kwargs):
+        if jax.core.trace_ctx.is_top_level():
+            return self._call(inputs, kwargs)
+        # while a trace is in progress every device operation of this
+        # layer carries its name (op_name in XProf and compiled text):
+        # the name the parent registered it under; the class name at the
+        # root and for the numbered entries of a list (a scanned stack
+        # runs ONE body: the scope names the kind of layer, not an index)
+        name = self.__dict__.get("_scope_name")
+        if name is None or name.isdigit():
+            name = type(self).__name__
+        with jax.named_scope(name):
+            return self._call(inputs, kwargs)
+
+    def _call(self, inputs, kwargs):
         for hook in self._forward_pre_hooks.values():
             out = hook(self, inputs)
             if out is not None:

@@ -6,16 +6,32 @@ One subsystem (see the per-module docstrings):
   histograms with labels; disarmed by default (single bool check per
   record site, the fault_injection.py discipline).
 - `spans`    — `span(name, **attrs)` context manager: bounded in-memory
-  ring + jax.profiler.TraceAnnotation forwarding (XProf correlation).
+  ring + jax.profiler.TraceAnnotation forwarding (XProf correlation);
+  `setup_span` / `setup_event` for what runs once per trace or compile
+  (recorded armed or not, pinned in the ring).
+- `scopes`   — the ONE vocabulary of the compiled train step: the
+  `jax.named_scope` names on its device operations (phases `forward`,
+  `backward`, `grad_sync`, `optimizer`; components `embed`, `layers`,
+  `norm`, `attn/qkv`, `attn/rope`, `attn/core`, `attn/out`, `mlp`, `head`,
+  `loss`; collectives `tp/all_reduce`, `tp/relayout`), TrainStep's three
+  per-call host spans (`train_step.call_args`, `.dispatch`,
+  `.write_back`: bare TraceAnnotations, a relaxed atomic load each when
+  no profiler listens) and its set-up events (`train_step.lower` and the
+  phases inside it, `train_step.trace`, `.to_mlir`, `.traced`,
+  `xla.backend_compile`, `xla.to_mlir`, `xla.cache_hit/miss`). Scopes are
+  metadata of the compiled program: no flag, nothing at run time. Read
+  them in XProf / `paddle.profiler` (op names, host spans on one clock),
+  from `spans.ring()`, or with `chipbench/run.py --trace 1`.
 - `export`   — Prometheus text dump (+ optional HTTP endpoint via
   FLAGS_metrics_port), atomic JSON / append-only JSONL writers, and the
   crash flight recorder (FLAGS_flight_recorder) that leaves a
   post-mortem artifact when a trainer hangs, crashes or is killed.
 - `goodput`  — the goodput ledger: step-window wall time decomposed into
   labeled productive/badput buckets + a live MFU gauge.
-- `device_events` — per-execution device telemetry: jax.monitoring
-  compile-duration bridge + per-executable execute accounting keyed by
-  a trace-time tag (closes the trace-time-only collective caveat).
+- `device_events` — per-execution telemetry: the jax.monitoring bridge
+  (compile durations and cache events, kept as set-up events armed or
+  not) + per-executable dispatch accounting keyed by a trace-time tag
+  (closes the trace-time-only collective caveat).
 - `federation` — per-rank snapshot publishing (FLAGS_metrics_snapshot)
   + the launch supervisor's job-level merged /metrics.
 - `view`     — `python -m paddle_tpu.observability.view`: merge flight
@@ -35,7 +51,7 @@ import os
 import threading
 
 from . import (device_events, export, goodput, metrics,  # noqa: F401
-               reqtrace, spans)
+               reqtrace, scopes, spans)
 from .export import (append_jsonl, flight_dump,  # noqa: F401
                      install_flight_recorder, prometheus_text,
                      serve_metrics, uninstall_flight_recorder,
@@ -53,13 +69,10 @@ __all__ = ["metrics", "spans", "export", "goodput", "device_events",
 
 def enable(on: bool = True) -> None:
     """Arm (or disarm) the metrics registry and span tracing together.
-    Arming also installs the jax.monitoring duration listener once (it
-    bails on the armed bool when disarmed, so there is nothing to
-    uninstall)."""
+    (The jax.monitoring listeners are installed when device_events is
+    imported; disarmed they keep only the set-up events.)"""
     metrics.enable(on)
     spans.enable(on)
-    if on:
-        device_events.install_listener()
 
 
 def enabled() -> bool:

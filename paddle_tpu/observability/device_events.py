@@ -14,12 +14,10 @@ seams:
   "serving.prefill"). Each exit observes `xla.dispatch_seconds{
   executable=tag}` — HOST-observed dispatch wall: exact on synchronous
   backends, a dispatch-side lower bound under async TPU dispatch. The
-  series is NAMED for what it measures (ISSUE 18 honesty pass):
-  `xla.execute_seconds` is reserved for DEVICE-side execute durations,
-  fed by the jax.monitoring bridge where the runtime reports them (and
-  by `note_device_execute()` for an XProf post-processor); on backends
-  with no device-side source the series is honestly EMPTY instead of
-  silently republishing host wall under a device name.
+  series is NAMED for what it measures (ISSUE 18 honesty pass). Device
+  time is not a series here: no runtime reports it to the host, and it
+  is read from a profiler trace by the program's own scope names
+  (observability/scopes.py; `chipbench/scope_reduce.py`).
 - `note_traced_collective(op)` — called by the collective wrapper while
   a TRACE is in progress inside an open execution window. The noted ops
   become the tag's composition; every later execution of that tag then
@@ -28,10 +26,20 @@ seams:
   trace-time composition x execution count. A re-trace (new shapes)
   REPLACES the composition, so recompiles never double it.
 
-The jax.monitoring listener feeds `xla.compile_seconds{executable=tag}`
-(and the goodput ledger's `compile` bucket) from the
-`/jax/core/compile/*` duration events; it is registered once on first
-arming and bails on the armed bool when disarmed.
+The jax.monitoring listeners are registered at import. Armed or not,
+they keep what happens once per trace or compile as SET-UP events in
+`spans.ring()`: every backend compile and top-level jaxpr-to-MLIR
+lowering (`xla.backend_compile`, `xla.to_mlir`, with jax's `fun_name`
+and the set-up span open on the thread: a backend compile inside
+`train_step.lower` is a small eager program, not the step), the
+persistent cache's hits and misses (`xla.cache_hit`, `xla.cache_miss`),
+and for a TrainStep the OUTERMOST jaxpr trace and its lowering
+(`train_step.trace`, `train_step.to_mlir`: `jaxpr_trace_duration` also
+fires for every inner jit, hundreds of times a step, so the one that
+follows `note_step_traced()` is taken, never the sum). Armed, they also
+feed `xla.compile_seconds{executable=tag}` and the goodput ledger's
+`compile` bucket. These events fire on compiles and traces only: a
+steady step never reaches the listener.
 """
 from __future__ import annotations
 
@@ -41,9 +49,10 @@ from typing import Dict
 
 from . import goodput as _goodput
 from . import metrics as _m
+from . import spans as _spans
 
 __all__ = ["execution", "tagged", "note_traced_collective",
-           "note_device_execute", "install_listener", "current_tag",
+           "note_step_traced", "install_listener", "current_tag",
            "tag_composition"]
 
 # wide-range buckets: compiles run seconds-to-minutes, executes ms-to-s
@@ -56,14 +65,8 @@ _H_DISPATCH = _m.histogram(
     "xla.dispatch_seconds",
     "HOST-observed wall seconds per dispatched call of a tagged "
     "executable; under async dispatch this is a dispatch-side LOWER "
-    "BOUND on device time, not device execute seconds (those are "
-    "xla.execute_seconds, device-derived where available)")
-_H_EXECUTE = _m.histogram(
-    "xla.execute_seconds",
-    "DEVICE-side execute seconds per tagged executable, XProf/"
-    "jax.monitoring-derived; empty when the backend reports no "
-    "device-side durations (host-observed wall lives in "
-    "xla.dispatch_seconds)")
+    "BOUND on device time, not device execute seconds (those come "
+    "from a profiler trace, by scope name)")
 _C_COLL_EXEC = _m.counter(
     "collective.executed_calls_total",
     "per-EXECUTION collective counts: trace-time composition of a "
@@ -197,49 +200,69 @@ def note_traced_collective(op: str) -> None:
     f.fresh[op] = f.fresh.get(op, 0) + 1
 
 
-# device-side execute duration events, where this jax/runtime version
-# reports them (older jaxlibs report none — xla.execute_seconds then
-# stays honestly empty rather than echoing host dispatch wall)
-_EXECUTE_EVENT_PREFIXES = ("/jax/core/execute", "/jax/pjit/execute",
-                           "/xla/execute")
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_TO_MLIR_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "xla.cache_hit",
+                 "/jax/compilation_cache/cache_misses": "xla.cache_miss"}
 
 
-def note_device_execute(tag: str, seconds: float) -> None:
-    """Feed a DEVICE-measured execute duration for `tag` into
-    xla.execute_seconds — the hook for an XProf trace post-processor
-    (profiler integration) or any backend that exposes real device
-    durations out-of-band."""
-    if not _m.enabled():
-        return
-    _H_EXECUTE.observe(float(seconds), executable=tag)
+def note_step_traced(tag: str) -> None:
+    """Called by a TrainStep as its traced body returns: the next
+    jaxpr-trace duration on this thread is the step's own (the
+    outermost), and the lowering that follows it is the step's."""
+    _tl.step_traced = tag
+    _tl.step_lowering = None
 
 
 def _on_duration(event, duration, **kw) -> None:
-    if not _m.enabled():
-        return
-    if event.startswith(_EXECUTE_EVENT_PREFIXES):
-        # runtime-reported DEVICE execute duration: the honest source
-        # for xla.execute_seconds
-        _H_EXECUTE.observe(float(duration),
-                           executable=current_tag() or "untagged")
-        return
     # exact compile-phase events only: a bare "compile" substring would
     # also match /jax/compilation_cache/compile_time_saved_sec — time
     # that was NOT spent (warm persistent cache), which would inject a
     # phantom compile stall bigger than the window wall
     if not event.startswith("/jax/core/compile/"):
         return
+    duration = float(duration)
+    if event == _TRACE_EVENT:
+        tag = getattr(_tl, "step_traced", None)
+        if tag is not None:
+            _tl.step_traced, _tl.step_lowering = None, tag
+            _spans.setup_event("train_step.trace", duration, executable=tag)
+    elif event == _TO_MLIR_EVENT:
+        tag = getattr(_tl, "step_lowering", None)
+        if tag is not None:
+            _tl.step_lowering = None
+            _spans.setup_event("train_step.to_mlir", duration,
+                               executable=tag)
+        else:
+            _spans.setup_event("xla.to_mlir", duration,
+                               fun_name=kw.get("fun_name", ""))
+    elif event == _BACKEND_COMPILE_EVENT:
+        _spans.setup_event("xla.backend_compile", duration,
+                           fun_name=kw.get("fun_name", ""))
+    if not _m.enabled():
+        return
     tag = current_tag() or "untagged"
-    _H_COMPILE.observe(float(duration), executable=tag)
-    _goodput.attribute("compile", float(duration))
+    _H_COMPILE.observe(duration, executable=tag)
+    _goodput.attribute("compile", duration)
+
+
+def _on_event(event, **kw) -> None:
+    name = _CACHE_EVENTS.get(event)
+    if name is not None:
+        _spans.setup_event(name)
 
 
 def install_listener() -> None:
-    """Register the jax.monitoring duration listener once per process
-    (jax has no unregister; the callback bails on the armed bool)."""
+    """Register the jax.monitoring listeners once per process (jax has
+    no unregister; disarmed they only keep the set-up events)."""
     global _listener_installed
     if _listener_installed:
         return
     _listener_installed = True
     from jax import monitoring
     monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_listener(_on_event)
+
+
+install_listener()

@@ -1,0 +1,75 @@
+"""The names the compiled train step carries, in ONE place.
+
+`jax.named_scope` names land in every device operation's `op_name`
+(XProf, `compiled.as_text()`); spans name what the host does while it
+traces or dispatches. Both are read by name (`chipbench/components.json`,
+`chipbench/scope_reduce.py`, PERF.md section 3), so the names live here
+and nowhere else. Scopes are metadata of the compiled program: no flag,
+no cost at run time, and no part of the compile-cache key.
+"""
+from __future__ import annotations
+
+import contextlib
+import threading
+
+import jax
+
+from . import spans as _spans
+
+# jit.TrainStep: the statements of the step body, as scopes on the device
+# and (first trace) as set-up spans `train_step.<phase>` on the host
+PHASES = ("forward", "backward", "grad_sync", "optimizer")
+# models: what a Layer's registered name does not say
+COMPONENTS = ("embed", "layers", "norm", "attn/qkv", "attn/rope",
+              "attn/core", "attn/out", "mlp", "head", "loss")
+# distributed/sharding: collectives the program itself issues
+COLLECTIVES = ("tp/all_reduce", "tp/relayout")
+# jit.TrainStep.__call__: TraceAnnotations, on the profiler's host plane
+STEP_SPANS = ("train_step.call_args", "train_step.dispatch",
+              "train_step.write_back")
+# set-up events in spans.ring(): lower() and its parts, the trace count,
+# and what jax.monitoring reports of lowering, compiling and the cache
+SETUP = ("train_step.lower", "train_step.call_args", "train_step.trace",
+         "train_step.to_mlir", "train_step.traced", "xla.to_mlir",
+         "xla.backend_compile", "xla.cache_hit", "xla.cache_miss")
+
+_SCOPES = frozenset(COMPONENTS + COLLECTIVES)
+_tl = threading.local()          # .open: scopes open on this thread
+
+
+@contextlib.contextmanager
+def scope(name: str):
+    """`jax.named_scope(name)` for a name of the vocabulary."""
+    if name not in _SCOPES:
+        raise ValueError(f"scope {name!r} is not in observability.scopes")
+    stack = getattr(_tl, "open", None)
+    if stack is None:
+        stack = _tl.open = []
+    stack.append(name)
+    try:
+        with jax.named_scope(name):
+            yield
+    finally:
+        stack.pop()
+
+
+def carried():
+    """The innermost scope open on this thread, or None. `jax.vjp` keeps
+    only the names entered INSIDE the function it differentiates, so the
+    tape (autograd.apply_op) re-enters this one inside every op it
+    records: the op's backward operations then carry it too."""
+    stack = getattr(_tl, "open", None)
+    return stack[-1] if stack else None
+
+
+@contextlib.contextmanager
+def phase(name: str, executable: str):
+    """One statement of the step body: the device operations it traces
+    carry `name`, and the host seconds the tracing took are the set-up
+    span `train_step.<name>` (Python tracing time IS what lower() is
+    made of)."""
+    if name not in PHASES:
+        raise ValueError(f"phase {name!r} is not in observability.scopes")
+    with _spans.setup_span("train_step." + name, executable=executable), \
+            jax.named_scope(name):        # not carried: see carried()
+        yield

@@ -15,6 +15,15 @@ write-through each event to an append-only JSONL file, which is what
 lets a SIGKILLed trainer leave a post-mortem artifact naming the span
 that was open at death (the begin line is on disk; the end line never
 happens).
+
+SET-UP events are the exception to armed/disarmed: `setup_span(...)` and
+`setup_event(...)` sit where the program traces, lowers or compiles —
+once per executable, never per step — so they record whether or not the
+registry is armed, and into a pinned part of the ring that armed
+per-call spans cannot evict. They are what says where the seconds before
+a job's first step went (`TrainStep.lower` and its phases, every backend
+compile and persistent-cache hit with the set-up span open around it);
+`ring()` returns them first, each marked `"setup": True`.
 """
 from __future__ import annotations
 
@@ -24,8 +33,9 @@ import time
 from collections import deque
 from typing import Callable, Dict, List
 
-__all__ = ["span", "enable", "enabled", "ring", "clear", "set_ring_size",
-           "open_spans", "add_sink", "remove_sink"]
+__all__ = ["span", "setup_span", "setup_event", "enable",
+           "enabled", "ring", "clear", "set_ring_size", "open_spans",
+           "add_sink", "remove_sink"]
 
 _enabled = False
 _DEFAULT_RING = 512
@@ -35,6 +45,9 @@ _DEFAULT_RING = 512
 # thread mid-hold — a plain Lock would deadlock the dying process
 _lock = threading.RLock()
 _ring: deque = deque(maxlen=_DEFAULT_RING)
+# set-up events (once per trace / compile): pinned, bounded on their own
+_pinned: deque = deque(maxlen=2048)
+_tl = threading.local()          # .setup: set-up spans open on this thread
 _seq = itertools.count(1)
 _open: Dict[int, dict] = {}      # sid -> begin event (all threads)
 _sinks: List[Callable] = []
@@ -60,13 +73,15 @@ def set_ring_size(n: int) -> None:
 
 
 def ring() -> list:
+    """Set-up events (pinned) first, then the newest per-call events."""
     with _lock:
-        return list(_ring)
+        return list(_pinned) + list(_ring)
 
 
 def clear() -> None:
     with _lock:
         _ring.clear()
+        _pinned.clear()
         _open.clear()
 
 
@@ -92,7 +107,7 @@ def remove_sink(fn: Callable) -> None:
 
 def _emit(ev: dict) -> None:
     with _lock:
-        _ring.append(ev)
+        (_pinned if ev.get("setup") else _ring).append(ev)
         sinks = list(_sinks)
     for s in sinks:
         try:
@@ -126,13 +141,14 @@ class span:
     XProf TraceAnnotation. Disarmed: one bool check."""
 
     __slots__ = ("name", "attrs", "_sid", "_p0", "_ann")
+    _always = False              # setup_span: recorded armed or not
 
     def __init__(self, name: str, **attrs):
         self.name = name
         self.attrs = attrs
 
     def __enter__(self):
-        if not _enabled:
+        if not (_enabled or self._always):
             self._sid = None
             return self
         self._sid = next(_seq)
@@ -142,6 +158,8 @@ class span:
               "thread_name": threading.current_thread().name}
         if self.attrs:
             ev["attrs"] = {k: str(v) for k, v in self.attrs.items()}
+        if self._always:
+            _mark_setup(ev).append(self)
         with _lock:
             _open[self._sid] = ev
         _emit(ev)
@@ -166,7 +184,47 @@ class span:
               "dur_s": time.perf_counter() - self._p0}
         if exc_type is not None:
             ev["error"] = exc_type.__name__
+        if self._always:
+            stack = getattr(_tl, "setup", ())
+            if self in stack:
+                stack.remove(self)
+            _mark_setup(ev)
         with _lock:
             _open.pop(self._sid, None)
         _emit(ev)
         return False
+
+
+def _mark_setup(ev: dict) -> list:
+    """Mark `ev` as a set-up event, with the set-up spans open on this
+    thread (outermost first) as `within`; returns that stack."""
+    stack = getattr(_tl, "setup", None)
+    if stack is None:
+        stack = _tl.setup = []
+    ev["setup"] = True
+    if stack:
+        ev["within"] = "/".join(sp.name for sp in stack)
+    return stack
+
+
+class setup_span(span):
+    """A span around work that runs once per trace or compile (never per
+    step): recorded whether or not the registry is armed, kept in the
+    ring's pinned part, and on this thread's stack of open set-up spans
+    while open, so that compile events can say which phase they fired in."""
+
+    __slots__ = ()
+    _always = True
+
+
+def setup_event(name: str, dur_s=None, **attrs) -> None:
+    """One pinned set-up record without a block of its own (a duration
+    jax.monitoring reports, a trace count): `within` names the set-up
+    spans open on this thread when it fired, outermost first."""
+    ev = {"ev": "setup_event", "name": name, "ts": time.time()}
+    if dur_s is not None:
+        ev["dur_s"] = float(dur_s)
+    if attrs:
+        ev["attrs"] = {k: str(v) for k, v in attrs.items()}
+    _mark_setup(ev)
+    _emit(ev)

@@ -48,7 +48,9 @@ class TestPSAuth:
         from paddle_tpu.distributed._auth import derive_authkey
         for var in ("PADDLE_MASTER", "PADDLE_TRAINER_ENDPOINTS",
                     "PADDLE_PSERVERS_IP_PORT_LIST", "PADDLE_PS_AUTHKEY",
-                    "PADDLE_P2P_AUTHKEY"):
+                    "PADDLE_P2P_AUTHKEY", "PADDLE_JOB_AUTHKEY"):
+            # PADDLE_JOB_AUTHKEY too: an in-process launcher run earlier
+            # in the same worker leaves it in os.environ (launch/main.py)
             monkeypatch.delenv(var, raising=False)
         monkeypatch.setenv("HOME", str(tmp_path))
         k1 = derive_authkey("PADDLE_P2P_AUTHKEY", "p2p")

@@ -232,3 +232,69 @@ def test_sharded_operands_compile_through_shard_kernel(topo):
     for kernel in ("flash_attention", "fused_add_rms_norm", "swiglu_bwd_da",
                    "swiglu_bwd_dw"):
         assert kernel in text, kernel
+
+
+@pytest.mark.parametrize("cell", ["yi-6b-1chip.pretrain",
+                                  "yi-6b-4chip.pretrain"])
+def test_train_step_names_its_device_operations(topo, cell):
+    """The whole `TrainStep` of a benchmark cell at depth 2 (as
+    `chipbench/rehearse_compile.py` builds it: abstract weights placed
+    as the plan places them), for one described chip and under ZeRO-3 x
+    TP for four: of what a device executes (matmuls, fusions, custom
+    calls, collectives; not what sits inside a fusion) nearly everything
+    that carries an op_name resolves, through `chipbench/components.json`,
+    to a component the program named (`observability/scopes.py`), and
+    backward and recomputed work is told apart."""
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    import paddle_tpu as paddle
+    import paddle_tpu.optimizer as popt
+    from chipbench import harness, scope_reduce, weights
+    from chipbench import run as bench_run
+    from chipbench.drivers import train
+
+    _, _, cell_json, config, traffic = bench_run.load_cell(root, cell)
+    config = dict(config, num_hidden_layers=2)
+    devices = list(topo.devices[:config["chips"]])
+    plan = train._plan(config, devices)
+    model, shapes = weights.skeleton(weights.model_config(config))
+    one = SingleDeviceSharding(devices[0]) if plan is None else None
+    shard = ({k: one for k in shapes} if plan is None
+             else harness.plan_shardings(plan, model, shapes))
+    abstract = {k: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=shard[k])
+                for k, s in shapes.items()}
+    weights.install(model, abstract)
+    opt = popt.AdamW(learning_rate=1e-3, parameters=model.parameters())
+    for name, t in model.state_dict().items():   # what prime() would make
+        for slot in ("moment1", "moment2"):
+            opt._state[(id(t), slot)] = abstract[name]
+    step = paddle.jit.TrainStep(model, opt, lambda i, l: model.loss(i, l),
+                                shard=plan)
+    step._build()
+    x = paddle.to_tensor(np.zeros((1, 8), np.int32))
+    x.data = jax.ShapeDtypeStruct(
+        (cell_json["batch_size"], traffic["seq_len"]), np.int32, sharding=one)
+    args = step._call_args((x, x))
+    if one is not None:                      # host scalars -> the device
+        args = tuple(jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype,
+                                          sharding=one)
+                     if isinstance(a, (np.ndarray, np.generic)) else a
+                     for a in args)
+    text = step._compiled.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    _, total, counts, missed = scope_reduce.text_coverage(text)
+    named_missed = [m for m in missed if m[2]]
+    carrying = total - (len(missed) - len(named_missed))
+    assert carrying >= 100 and len(named_missed) <= 0.05 * carrying, (
+        named_missed[:10])
+    for component in ("attn/qkv", "attn/core", "attn/out", "mlp", "head",
+                      "loss", "norm"):
+        assert counts[(component, "forward")] > 0, component
+        assert counts[(component, "backward")] > 0, component
+    assert counts[("attn/core", "recomputed")] > 0
+    assert counts[("optimizer", "update")] > 0
+    assert counts[("layers", "backward")] > 0
+    if plan is not None:
+        for cls in ("tp_all_reduce", "tp_relayout", "zero3"):
+            assert sum(n for (c, _), n in counts.items() if c == cls), cls
