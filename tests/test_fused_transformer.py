@@ -240,6 +240,71 @@ class TestSwiGLU:
             lambda *x: sg.swiglu(*x, use_pallas=False),
             (a, w), rtol=1e-4, atol=1e-4)
 
+    @pytest.mark.parametrize("rows, blocks", [
+        # (fwd, da, dw): dw on its own blocks, ragged rows
+        (40, ((16, 128), (16, 128), (16, 256))),
+        # da and dw ragged at different rows
+        (40, ((16, 128), (32, 256), (16, 128))),
+        # 384 does not divide 2M = 512: dw's last column block is partial
+        (72, ((32, 256), (16, 128), (32, 384))),
+        (40, None),                  # few rows: one block in each kernel
+    ], ids=["dw-own-blocks", "both-ragged", "dw-partial-columns",
+            "one-row-block"])
+    def test_interpret_parity_backward_pair(self, rows, blocks):
+        # swiglu_bwd_da hands the gate | up cotangent to swiglu_bwd_dw
+        # through HBM: whatever the two kernels' blocks, rows past T and
+        # columns past 2M stay out of both gradients
+        a = _rand((rows, 256), jnp.float32)
+        w = _rand((256, 512), jnp.float32, seed=1) * 0.05
+        _check_grads(
+            lambda *x: sg.swiglu(*x, use_pallas=True, blocks=blocks),
+            lambda *x: sg.swiglu(*x, use_pallas=False),
+            (a, w), rtol=1e-4, atol=1e-4)
+
+    def test_interpret_parity_grads_bf16(self):
+        # dg/du are formed and summed in f32 and cross HBM in the
+        # activation's dtype, as the unfused expression's gradient does:
+        # the pair stays nearer the f32 gradient than that expression in
+        # bf16, and within 2^-8 of its norm
+        a = _rand((40, 256), jnp.bfloat16)
+        w = _rand((256, 512), jnp.bfloat16, seed=1) * 0.05
+
+        def grads(route, *x):
+            return jax.grad(lambda *x_: _weighted_sum(sg.swiglu(
+                *x_, use_pallas=route, blocks=(16, 128))), (0, 1))(*x)
+
+        exact = grads(False, a.astype(jnp.float32), w.astype(jnp.float32))
+        for got, unfused, want in zip(grads(True, a, w), grads(False, a, w),
+                                      exact):
+            assert got.dtype == jnp.bfloat16
+            want = np.asarray(want)
+            err, unfused_err = (
+                np.linalg.norm(np.asarray(x, np.float32) - want)
+                / np.linalg.norm(want) for x in (got, unfused))
+            assert err < unfused_err and err < 2 ** -8
+
+    def test_dw_is_one_array_gate_columns_first(self, monkeypatch):
+        a = _rand((40, 256), jnp.float32)
+        wg = _rand((256, 256), jnp.float32, seed=1) * 0.05
+        wu = _rand((256, 256), jnp.float32, seed=2) * 0.05
+        g = _rand((40, 256), jnp.float32, seed=3)
+        recomputed = []
+        real = sg._dgu_tile
+        monkeypatch.setattr(sg, "_dgu_tile", lambda *r: (
+            recomputed.append(1), real(*r))[1])
+        da, dw = sg._bwd_impl(a, jnp.concatenate([wg, wu], axis=-1), g,
+                              True, (16, 128))
+        # g and u are recomputed in ONE kernel: swiglu_bwd_dw is a matmul
+        assert len(recomputed) == 1
+        assert dw.shape == (256, 512) and da.shape == a.shape
+        _, vjp = jax.vjp(
+            lambda a_, g_, u_: jax.nn.silu(a_ @ g_) * (a_ @ u_), a, wg, wu)
+        want_da, want_dwg, want_dwu = vjp(g)
+        for got, want in ((da, want_da), (dw[:, :256], want_dwg),
+                          (dw[:, 256:], want_dwu)):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=1e-4, atol=1e-4)
+
     def test_blocks_override_changes_blocking_not_results(self):
         a = _rand((64, 256), jnp.float32)
         w = _rand((256, 512), jnp.float32, seed=1) * 0.05
@@ -273,17 +338,90 @@ class TestSwiGLU:
         key = autotune.cache_key("swiglu", M=sg._size_class(256))
         monkeypatch.setattr(autotune, "lookup",
                             lambda k: [64, 128] if k == key else None)
-        assert sg._blocks(512, 128, 256, 4) == (64, 128)
+        assert sg._blocks("fwd", 512, 128, 256, 4) == (64, 128)
         # default chain: (256, 512), columns shrunk to a 128-multiple
         # divisor of M
         monkeypatch.setattr(autotune, "lookup", lambda k: None)
-        assert sg._blocks(512, 128, 256, 4) == (256, 256)
+        assert sg._blocks("fwd", 512, 128, 256, 4) == (256, 256)
         # the shipped widths: column blocks tile (8, 128) and divide M
-        assert sg._blocks(8192, 1024, 2816, 2) == (256, 256)
-        assert sg._blocks(8192, 2048, 5504, 2) == (256, 128)
-        assert sg._blocks(8192, 4096, 11008, 2) == (256, 256)
+        assert sg._blocks("fwd", 8192, 1024, 2816, 2) == (256, 256)
+        assert sg._blocks("fwd", 8192, 2048, 5504, 2) == (256, 128)
+        assert sg._blocks("fwd", 8192, 4096, 11008, 2) == (256, 256)
         # few rows go in one block
-        assert sg._blocks(4, 4096, 11008, 2) == (4, 256)
+        assert sg._blocks("fwd", 4, 4096, 11008, 2) == (4, 256)
+
+    @pytest.mark.parametrize("kernel, key", [
+        ("da", "swiglu_bwd_da"), ("dw", "swiglu_bwd_dw")])
+    def test_backward_blocks_consult_autotune(self, monkeypatch, kernel,
+                                              key):
+        from paddle_tpu.kernels import autotune
+        key = autotune.cache_key(key, M=sg._size_class(256))
+        monkeypatch.setattr(autotune, "lookup",
+                            lambda k: [64, 128] if k == key else None)
+        assert sg._blocks(kernel, 512, 128, 256, 4) == (64, 128)
+        # the other kernels' winners are not this kernel's
+        assert sg._blocks("fwd", 512, 128, 256, 4) == (256, 256)
+
+    def test_backward_blocks_from_the_shapes(self, monkeypatch):
+        from paddle_tpu.kernels import autotune
+        monkeypatch.setattr(autotune, "lookup", lambda k: None)
+        # the benchmark's cells, a device: da keeps a column block that
+        # divides M under twice the forward's rows
+        assert sg._blocks("da", 4096, 4096, 11008, 2) == (512, 256)
+        assert sg._blocks("da", 4096, 4096, 5504, 2) == (512, 128)
+        # dw runs over the flat 2M columns and is not held to a divisor
+        assert sg._blocks("dw", 4096, 4096, 11008, 2) == (1024, 512)
+        assert sg._blocks("dw", 4096, 4096, 5504, 2) == (1024, 512)
+        # what does not fit: da gives up columns, then rows; dw rows
+        assert sg._blocks("da", 4096, 4096, 11008, 4) == (256, 256)
+        assert sg._blocks("dw", 4096, 4096, 11008, 4) == (512, 512)
+        assert sg._blocks("dw", 4096, 8192, 11008, 2) == (256, 512)
+        # a narrow layer is one column block; few rows one row block
+        assert sg._blocks("dw", 40, 256, 128, 4) == (40, 256)
+        # one override for every kernel, or one each (fwd, da, dw)
+        each = ((16, 128), (32, 256), (64, 384))
+        assert [sg._blocks(k, 512, 256, 256, 4, each)
+                for k in ("fwd", "da", "dw")] == list(each)
+        assert [sg._blocks(k, 512, 256, 256, 4, (16, 128))
+                for k in ("fwd", "da", "dw")] == [(16, 128)] * 3
+        # the dw kernel's need is stated without weight buffers
+        assert (sg._vmem_bytes("da", 256, 256, 4096, 2)
+                - sg._vmem_bytes("dw", 256, 256, 4096, 2)
+                >= 4 * 4096 * 256 * 2)
+
+    def test_sweep_compiles_a_candidate_once_and_uses_both_grads(
+            self, monkeypatch):
+        # what the sweep times is the kernels, not a compile a call: the
+        # first run of a candidate traces, a second does not; candidates
+        # that shrink to the same blocks are timed once; each kernel is
+        # swept under its own key, beside the winners so far
+        from paddle_tpu.kernels import autotune
+        traced, swept = [], []
+        for name in ("_fwd_kernel", "_bwd_dw_kernel"):
+            monkeypatch.setattr(sg, name, lambda *r, _real=getattr(
+                sg, name), **k: (traced.append(1), _real(*r, **k))[1])
+
+        def fake(key, candidates, make_fn, default, iters, sweep):
+            fns = [f for f in map(make_fn, candidates) if f is not None]
+            before = len(traced)
+            float(fns[0]())
+            first = len(traced) - before
+            float(fns[0]())
+            swept.append((key.split(":")[0], len(fns), first,
+                          len(traced) - before))
+            return default
+
+        monkeypatch.setattr(autotune, "autotune", fake)
+        got = sg.sweep_block_sizes((32, 128), (128, 256), jnp.float32,
+                                   iters=1)
+        assert got == ((32, 128), (32, 128), (32, 256))
+        # M = 128, 32 rows: every fwd / da candidate is the one (32, 128)
+        # block; dw's are (32, 256), and the (32, 128) that none asks for
+        assert [(k, n) for k, n, _, _ in swept] == [
+            ("swiglu", 1), ("swiglu_bwd_da", 1), ("swiglu_bwd_dw", 1)]
+        # the forward and swiglu_bwd_dw are both in what is timed
+        assert all(first >= 2 and total == first
+                   for _, _, first, total in swept)
 
     def test_mp_split_under_a_sharded_step_matches_unsharded(self):
         # w_gate_up is [H, gate | up]: under shard_kernel it is split on

@@ -100,6 +100,45 @@ def test_swiglu_fwd_and_grad(one_chip, width, dtype):
     assert "swiglu_bwd_da" in text and "swiglu_bwd_dw" in text
 
 
+# intermediate size a device of the benchmark's training cells (hidden
+# 4096, 4096 rows a device): Yi-6B whole, and split over mp=2
+CELL_MLPS = {"yi-6b-1chip": 11008, "yi-6b-4chip": 5504}
+
+
+def _mosaic_calls(text, kernel):
+    return sum('custom_call_target="tpu_custom_call"' in line
+               and kernel in line for line in text.splitlines())
+
+
+@pytest.mark.parametrize("cell", CELL_MLPS)
+def test_swiglu_backward_at_the_cells_widths(one_chip, cell):
+    """One layer's swiglu backward as the cells run it: g and u are
+    recomputed in swiglu_bwd_da alone, which hands the [T, 2M] gate | up
+    cotangent to the matmul kernel swiglu_bwd_dw; the weight gradient
+    leaves that kernel in one piece; each kernel's blocks fit what it
+    is scoped to (the compiler refuses a kernel that does not)."""
+    T, H, M = 4096, 4096, CELL_MLPS[cell]
+    a = jax.ShapeDtypeStruct((T, H), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((H, 2 * M), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(jax.grad(
+        lambda a_, w_: _sum32(sg.swiglu(a_, w_)), argnums=(0, 1))).lower(
+            a, w).compile()
+    text = compiled.as_text()
+    assert _mosaic_calls(text, "swiglu_bwd_da") == 1
+    assert _mosaic_calls(text, "swiglu_bwd_dw") == 1
+    assert _mosaic_calls(text, "swiglu_fwd") == 0     # no third recompute
+    assert "concatenate" not in text
+    # what the backward adds to the device's memory is the cotangent
+    # buffer between the two kernels, and nothing of its size beside it
+    dgu_bytes = T * 2 * M * 2
+    assert dgu_bytes <= compiled.memory_analysis().temp_size_in_bytes \
+        < 1.1 * dgu_bytes
+    for kernel in ("da", "dw"):
+        bt, bc = sg._blocks(kernel, T, H, M, 2)
+        assert (sg._vmem_bytes(kernel, bt, bc, H, 2)
+                <= sg._VMEM_BLOCK_BUDGET < sg._VMEM_LIMIT)
+
+
 @pytest.mark.parametrize("dtype", DTYPES)
 @pytest.mark.parametrize("width", WIDTHS)
 def test_fused_add_rms_norm_fwd_and_grad(one_chip, width, dtype):
