@@ -28,6 +28,19 @@ class MoELayer(Layer):
     Args mirror the reference (moe_layer.py:263): d_model, experts given by
     d_hidden + num_experts (stacked SwiGLU/GeLU FFN), gate name or object,
     recompute handled by the caller.
+
+    The models do NOT use this layer: `models/solar_open2.py` runs
+    `paddle_tpu.nn.DroplessMoE` (`nn/layer/moe.py`), which sorts the
+    routed pairs by expert and multiplies them in groups over one row
+    buffer, is told which experts it holds, and drops nothing. This layer
+    keeps the reference's API and its gshard / switch gates, whose
+    one-hot `[tokens, experts, capacity]` dispatch drops what exceeds an
+    expert's capacity and grows with tokens x experts: fine for the
+    sizes its tests use, out of reach at 32768 tokens x 320 experts. It
+    was not re-pointed: the gates' capacity semantics (position by
+    cumulative count, second choice dropped first, the aux loss) are the
+    contract of its tests, and a dropless path has no capacity to drop
+    by.
     """
 
     def __init__(self, d_model: int, d_hidden: int, num_experts: int,

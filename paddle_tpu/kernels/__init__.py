@@ -7,6 +7,8 @@ fallbacks in nn.functional are used instead.
 """
 from . import flash_attention  # noqa: F401
 from . import fused_norm_residual  # noqa: F401
+from . import gated_delta_rule  # noqa: F401
+from . import grouped_matmul  # noqa: F401
 from . import rms_norm  # noqa: F401
 from . import rope  # noqa: F401
 from . import swiglu  # noqa: F401

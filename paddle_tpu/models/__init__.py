@@ -14,3 +14,7 @@ from .ernie import (  # noqa: F401
 from .unet import (  # noqa: F401
     UNet2DConditionModel, UNetConfig, unet_sd15, unet_tiny,
 )
+from .solar_open2 import (  # noqa: F401
+    SolarOpen2Config, SolarOpen2ForCausalLM, SolarOpen2Model,
+    solar_open2_tiny,
+)

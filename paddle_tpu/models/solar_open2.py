@@ -1,0 +1,417 @@
+"""Solar-Open2: a hybrid decoder whose layers are of two kinds in a fixed
+pattern, each followed by a mixture of experts.
+
+  * `gqa_layers` (every fourth): causal softmax attention over
+    grouped-query heads WITHOUT rotary embedding, its output gated per
+    channel by sigmoid(x Wg) before the output projection
+    (`kernels/flash_attention.py`)
+  * every other layer: gated delta-rule linear attention ("KDA"): a
+    4-tap causal depthwise convolution and SiLU on q, k, v, L2-normalised
+    q and k, a per-channel decay from a low-rank projection, beta in
+    (0, 2), the chunked operator of `kernels/gated_delta_rule.py`, a
+    per-head RMSNorm and a low-rank sigmoid gate
+  * every layer's feed-forward: `nn.DroplessMoE`, sigmoid-routed top-k
+    over all the router's experts, computing the experts this model is
+    TOLD it holds (`experts_held` from `expert_offset`) plus one shared
+    expert
+
+The equations are written out in `tests/reference/solar_open2.py`, which
+the tests hold this file to.
+
+Memory at long sequences decides the structure. Each half of a layer
+(residual + mixer, residual + experts) is ONE taped operation whose
+backward recomputes it. A mixer goes over its heads a group at a time
+(one KV head with its query heads; `kda_head_group` linear-attention
+heads), projections included, so that no [tokens, heads x dim] tensor
+ever exists; a group is recomputed in the backward (`jax.checkpoint`)
+and only the layer's input is kept. The last norm, the head and the
+cross-entropy go over the rows in blocks (`F.linear_cross_entropy`'s
+body) and are recomputed as well.
+"""
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from ..autograd.tape import apply_op
+from ..framework import core
+from ..nn import initializer as I
+from ..nn.layer.container import LayerList
+from ..nn.layer.layers import Layer
+from ..nn.layer.moe import DroplessMoE
+from ..observability.scopes import scope
+from ..ops._helpers import to_tensor_like
+from .llama import LlamaRMSNorm, _param, _sdpa
+
+__all__ = ["SolarOpen2Config", "SolarOpen2Model", "SolarOpen2ForCausalLM",
+           "solar_open2_tiny"]
+
+
+@dataclass
+class SolarOpen2Config:
+    vocab_size: int = 196608
+    hidden_size: int = 4096
+    num_hidden_layers: int = 48
+    num_attention_heads: int = 64
+    num_key_value_heads: int = 8
+    head_dim: int = 128
+    gqa_layers: Optional[Tuple[int, ...]] = None   # None: 0, 4, 8, ...
+    linear_num_heads: int = 64
+    linear_head_dim: int = 128
+    short_conv_kernel_size: int = 4
+    kda_low_rank: int = 128          # of the decay and of the output gate
+    moe_intermediate_size: int = 1280
+    n_routed_experts: int = 320      # the router's outputs
+    num_experts_per_tok: int = 8
+    n_shared_experts: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    rms_norm_eps: float = 1e-5
+    # expert parallelism: the experts [expert_offset, + experts_held) of
+    # every layer live here (None: all of them)
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
+    # rows of an expert layer's buffer (None: every pair could come here)
+    moe_rows: Optional[int] = None
+    kda_chunk: int = 64
+    kda_head_group: int = 4
+    loss_block_rows: int = 2048
+    dtype: str = "bfloat16"
+
+    def __post_init__(self):
+        if self.gqa_layers is None:
+            self.gqa_layers = tuple(range(0, self.num_hidden_layers, 4))
+        self.gqa_layers = tuple(self.gqa_layers)
+
+
+def solar_open2_tiny(**kw):
+    """Every mechanism at widths a CPU test can afford."""
+    base = dict(vocab_size=96, hidden_size=32, num_hidden_layers=4,
+                num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+                linear_num_heads=4, linear_head_dim=8, kda_low_rank=4,
+                moe_intermediate_size=16, n_routed_experts=8,
+                num_experts_per_tok=2, kda_chunk=16, kda_head_group=2,
+                loss_block_rows=8, dtype="float32")
+    base.update(kw)
+    return SolarOpen2Config(**base)
+
+
+def _rms(a, w, eps):
+    from ..kernels import rms_norm as krn
+    with scope("norm"):
+        return krn.rms_norm(a, w, eps)
+
+
+def _group_of(w, parts, groups, g):
+    """Columns of group g: w [rows, parts * groups * n] viewed as
+    [rows, parts, groups, n] -> [rows, parts * n]."""
+    rows = w.shape[0]
+    w4 = w.reshape(rows, parts, groups, -1)
+    return jax.lax.dynamic_index_in_dim(w4, g, 2, keepdims=False).reshape(
+        rows, -1)
+
+
+def _sum_of_groups(group, n, x, ws):
+    """sum over g < n of group(g, x, *ws), in x's dtype: one group of heads
+    at a time, summed in float32; the backward recomputes a group
+    (`jax.checkpoint`) and keeps none."""
+    run = jax.checkpoint(group)
+
+    def body(acc, g):
+        return acc + run(g, x, *ws), None
+
+    acc, _ = jax.lax.scan(body, jnp.zeros(x.shape, jnp.float32),
+                          jnp.arange(n))
+    return acc.astype(x.dtype)
+
+
+# -- the softmax layer ---------------------------------------------------------
+
+class GatedAttention(Layer):
+    """x + Wo[(causal softmax attention, no rotary) * sigmoid(x Wg)]."""
+
+    def __init__(self, cfg: SolarOpen2Config):
+        super().__init__()
+        self.cfg = cfg
+        h, d = cfg.hidden_size, cfg.head_dim
+        nh, kvh = cfg.num_attention_heads, cfg.num_key_value_heads
+        self.qkv_proj = _param(self, (h, (nh + 2 * kvh) * d), P(None, "mp"),
+                               dtype=cfg.dtype)
+        self.gate_proj = _param(self, (h, nh * d), P(None, "mp"),
+                                dtype=cfg.dtype)
+        self.o_proj = _param(self, (nh * d, h), P("mp", None),
+                             dtype=cfg.dtype)
+
+    def _group(self, g, x, ln_w, wqkv, wg, wo):
+        """KV head g with its query heads: their part of the output
+        projection, [B, T, H] float32 (the groups' parts are summed)."""
+        cfg = self.cfg
+        B, T, h = x.shape
+        nh, kvh, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                      cfg.head_dim)
+        rep = nh // kvh
+        xn = _rms(x, ln_w, cfg.rms_norm_eps)
+        with scope("attn/qkv"):
+            q = (xn @ _group_of(wqkv[:, :nh * d], 1, kvh, g)).reshape(
+                B, T, rep, d)
+            k = (xn @ _group_of(wqkv[:, nh * d:(nh + kvh) * d], 1, kvh,
+                                g)).reshape(B, T, 1, d)
+            v = (xn @ _group_of(wqkv[:, (nh + kvh) * d:], 1, kvh,
+                                g)).reshape(B, T, 1, d)
+        with scope("attn/core"):
+            from ..kernels import flash_attention as fa
+            if fa.supported(q.shape, k.shape, True):
+                o = fa.flash_attention_bshd(q, k, v, causal=True)
+            else:
+                o = _sdpa(q, jnp.repeat(k, rep, axis=2),
+                          jnp.repeat(v, rep, axis=2))
+        with scope("attn/gate"):
+            gate = jax.nn.sigmoid(
+                (xn @ _group_of(wg, 1, kvh, g)).astype(jnp.float32))
+            o = (o.reshape(B, T, rep * d).astype(jnp.float32)
+                 * gate).astype(x.dtype)
+        with scope("attn/out"):
+            wo_g = jax.lax.dynamic_index_in_dim(
+                wo.reshape(kvh, rep * d, h), g, 0, keepdims=False)
+            return jnp.matmul(o, wo_g, preferred_element_type=jnp.float32)
+
+    def block(self, x, *ws):
+        mixed = _sum_of_groups(self._group, self.cfg.num_key_value_heads,
+                               x, ws)
+        with scope("attn/out"):
+            return x + mixed
+
+    def forward(self, x, ln_w):
+        return apply_op(self.block, to_tensor_like(x), ln_w, self.qkv_proj,
+                        self.gate_proj, self.o_proj, name="gated_attention")
+
+
+# -- the linear-attention layer ------------------------------------------------
+
+def _causal_conv_silu(x, w):
+    """x [B, T, C], w [taps, C]: tap j multiplies x_{t - (taps-1) + j}."""
+    taps, T = w.shape[0], x.shape[1]
+    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
+    w = w.astype(jnp.float32)
+    return jax.nn.silu(sum(xp[:, j:j + T] * w[j] for j in range(taps)))
+
+
+def _l2norm(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+
+
+class KDAttention(Layer):
+    """x + the gated delta-rule mixer of RMSNorm(x); see the module
+    docstring for why it goes over the heads in groups."""
+
+    def __init__(self, cfg: SolarOpen2Config):
+        super().__init__()
+        self.cfg = cfg
+        h, r = cfg.hidden_size, cfg.kda_low_rank
+        nl, dl = cfg.linear_num_heads, cfg.linear_head_dim
+        dt = cfg.dtype
+        self.qkv_proj = _param(self, (h, 3 * nl * dl), P(None, "mp"),
+                               dtype=dt)
+        self.conv_weight = _param(
+            self, (cfg.short_conv_kernel_size, 3 * nl * dl), P(None, "mp"),
+            init=I.Uniform(-0.5, 0.5), dtype=dt)
+        self.decay_down = _param(self, (h, r), P(None, None), dtype=dt)
+        self.decay_up = _param(self, (r, nl * dl), P(None, "mp"), dtype=dt)
+        # a decay of exp(-A dt) a token: A in (1, 16), dt in (1e-3, 1e-1),
+        # so heads remember from a few tokens to a few thousand
+        self.A_log = _param(self, (nl,), P(None), init=I.Uniform(1.0, 16.0),
+                            dtype="float32")
+        self.A_log.data = jnp.log(self.A_log.data)
+        self.dt_bias = _param(
+            self, (nl * dl,), P(None),
+            init=I.Uniform(math.log(1e-3), math.log(1e-1)), dtype="float32")
+        step = jnp.exp(self.dt_bias.data)
+        self.dt_bias.data = step + jnp.log(-jnp.expm1(-step))
+        self.beta_proj = _param(self, (h, nl), P(None, "mp"), dtype=dt)
+        self.gate_down = _param(self, (h, r), P(None, None), dtype=dt)
+        self.gate_up = _param(self, (r, nl * dl), P(None, "mp"), dtype=dt)
+        self.o_norm = LlamaRMSNorm(dl, cfg.rms_norm_eps)
+        self.o_proj = _param(self, (nl * dl, h), P("mp", None), dtype=dt)
+
+    def _groups(self):
+        nl, hg = self.cfg.linear_num_heads, self.cfg.kda_head_group
+        return nl // hg if nl % hg == 0 else 1
+
+    def _group(self, g, x, ln_w, wqkv, wconv, wdd, wdu, a_log, dt_bias,
+               wbeta, wgd, wgu, o_norm_w, wo):
+        """Heads [g * hg, (g + 1) * hg): their part of the mixer's output,
+        [B, T, H] float32 (the groups' parts are summed)."""
+        from ..kernels.gated_delta_rule import chunk_gated_delta_rule
+        cfg = self.cfg
+        G = self._groups()
+        hg, dl = cfg.linear_num_heads // G, cfg.linear_head_dim
+        B, T, _ = x.shape
+        f32 = jnp.float32
+        xn = _rms(x, ln_w, cfg.rms_norm_eps)
+        with scope("kda/proj"):
+            pre = xn @ _group_of(wqkv, 3, G, g)          # [B, T, 3 hg dl]
+        with scope("kda/conv"):
+            act = _causal_conv_silu(pre, _group_of(wconv, 3, G, g))
+            q, k, v = (act[..., i * hg * dl:(i + 1) * hg * dl].reshape(
+                B, T, hg, dl) for i in range(3))
+            q, k = _l2norm(q).astype(x.dtype), _l2norm(k).astype(x.dtype)
+            v = v.astype(x.dtype)
+        with scope("kda/gate"):
+            soft = jax.nn.softplus(
+                ((xn @ wdd) @ _group_of(wdu, 1, G, g)).astype(f32)
+                + jax.lax.dynamic_index_in_dim(
+                    dt_bias.reshape(G, hg * dl), g, 0, keepdims=False))
+            rate = jnp.exp(jax.lax.dynamic_index_in_dim(
+                a_log.reshape(G, hg), g, 0, keepdims=False).astype(f32))
+            decay = -rate[:, None] * soft.reshape(B, T, hg, dl)
+            beta = 2.0 * jax.nn.sigmoid(
+                (xn @ _group_of(wbeta, 1, G, g)).astype(f32))
+        with scope("kda/core"):
+            o = chunk_gated_delta_rule(q, k, v, decay, beta,
+                                       chunk=cfg.kda_chunk)
+        with scope("kda/out"):
+            o = o.astype(f32)
+            o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
+                                  + cfg.rms_norm_eps) * o_norm_w
+            gate = jax.nn.sigmoid(
+                ((xn @ wgd) @ _group_of(wgu, 1, G, g)).astype(f32))
+            o = (o.reshape(B, T, hg * dl) * gate).astype(x.dtype)
+            wo_g = jax.lax.dynamic_index_in_dim(
+                wo.reshape(G, hg * dl, -1), g, 0, keepdims=False)
+            return jnp.matmul(o, wo_g, preferred_element_type=f32)
+
+    def block(self, x, *ws):
+        mixed = _sum_of_groups(self._group, self._groups(), x, ws)
+        with scope("kda/out"):
+            return x + mixed
+
+    def forward(self, x, ln_w):
+        return apply_op(
+            self.block, to_tensor_like(x), ln_w, self.qkv_proj,
+            self.conv_weight, self.decay_down, self.decay_up, self.A_log,
+            self.dt_bias, self.beta_proj, self.gate_down, self.gate_up,
+            self.o_norm.weight, self.o_proj, name="kda_attention")
+
+
+# -- a layer, the stack, the model ---------------------------------------------
+
+class SolarOpen2DecoderLayer(Layer):
+    def __init__(self, cfg: SolarOpen2Config, index: int):
+        super().__init__()
+        self.cfg = cfg
+        self.input_layernorm = LlamaRMSNorm(cfg.hidden_size,
+                                            cfg.rms_norm_eps)
+        if index in cfg.gqa_layers:
+            self.self_attn = GatedAttention(cfg)
+        else:
+            self.linear_attn = KDAttention(cfg)
+        self.post_attention_layernorm = LlamaRMSNorm(cfg.hidden_size,
+                                                     cfg.rms_norm_eps)
+        self.mlp = DroplessMoE(
+            cfg.hidden_size, cfg.moe_intermediate_size, cfg.n_routed_experts,
+            cfg.num_experts_per_tok, experts_held=cfg.experts_held,
+            first_expert=cfg.expert_offset,
+            shared_experts=cfg.n_shared_experts,
+            norm_topk_prob=cfg.norm_topk_prob,
+            routed_scaling_factor=cfg.routed_scaling_factor,
+            rows=cfg.moe_rows, dtype=cfg.dtype)
+
+    def _experts(self, h, ln_w, *ws):
+        y, counts, dropped = self.mlp.compute(
+            _rms(h, ln_w, self.cfg.rms_norm_eps), *ws)
+        return h + y, counts, dropped
+
+    def forward(self, x):
+        """Two taped operations: the mixer keeps only x and recomputes a
+        group of heads at a time; the expert half keeps the mixer's
+        output and recomputes itself whole."""
+        mixer = self.self_attn if hasattr(self, "self_attn") \
+            else self.linear_attn
+        h = mixer(x, self.input_layernorm.weight)
+        run = jax.checkpoint(self._experts,
+                             policy=core.current_remat_policy())
+        y, counts, dropped = apply_op(
+            run, h, self.post_attention_layernorm.weight,
+            *self.mlp.weights(), n_outputs=3, name="moe_block")
+        self.mlp.record(counts.data, dropped.data)
+        return y
+
+
+class SolarOpen2Model(Layer):
+    def __init__(self, cfg: SolarOpen2Config):
+        super().__init__()
+        self.cfg = cfg
+        self.embed_tokens = _param(self, (cfg.vocab_size, cfg.hidden_size),
+                                   P("mp", None), dtype=cfg.dtype)
+        self.layers = LayerList([SolarOpen2DecoderLayer(cfg, i)
+                                 for i in range(cfg.num_hidden_layers)])
+        self.norm = LlamaRMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
+
+    def forward(self, input_ids, final_norm=True):
+        def embed(ids, w):
+            with scope("embed"):
+                return jnp.take(w, ids.astype(jnp.int32), axis=0)
+
+        x = apply_op(embed, to_tensor_like(input_ids), self.embed_tokens,
+                     name="embed")
+        for lyr in self.layers:
+            with scope("layers"):
+                x = lyr(x)
+        return self.norm(x) if final_norm else x
+
+
+def _head(a, w):
+    with scope("head"):
+        return a @ w
+
+
+class SolarOpen2ForCausalLM(Layer):
+    def __init__(self, cfg: SolarOpen2Config):
+        super().__init__()
+        self.cfg = cfg
+        self.model = SolarOpen2Model(cfg)
+        self.lm_head = _param(self, (cfg.hidden_size, cfg.vocab_size),
+                              P(None, "mp"), dtype=cfg.dtype)
+
+    def forward(self, input_ids):
+        return apply_op(_head, self.model(input_ids), self.lm_head,
+                        name="lm_head")
+
+    def loss(self, input_ids, labels):
+        """Shifted next-token cross-entropy, the head and the loss a block
+        of rows at a time: the last position of a sequence has no label."""
+        from ..nn.functional.loss import _linear_cross_entropy
+        cfg = self.cfg
+        lb = to_tensor_like(labels).data
+        nxt = jnp.concatenate(
+            [lb[:, 1:], jnp.full((lb.shape[0], 1), -100, lb.dtype)],
+            axis=1).reshape(-1)
+
+        def head_loss(x, norm_w, w):
+            # the last norm is recomputed with the blocks of logits: its
+            # output is not kept either
+            xn = _rms(x, norm_w, cfg.rms_norm_eps)
+            return _linear_cross_entropy(
+                xn.reshape(-1, xn.shape[-1]), w, nxt, cfg.loss_block_rows,
+                -100)
+
+        return apply_op(jax.checkpoint(head_loss),
+                        self.model(input_ids, final_norm=False),
+                        self.model.norm.weight, self.lm_head,
+                        name="head_loss")
+
+    def moe_counters(self):
+        """{"expert_tokens": [layers, experts held], "dropped_pairs":
+        [layers]} as the last step left them (host arrays; not for a
+        timed region: reading waits for the device)."""
+        import numpy as np
+        mlps = [lyr.mlp for lyr in self.model.layers]
+        return {"expert_tokens": np.stack(
+                    [np.asarray(m.expert_tokens.data) for m in mlps]),
+                "dropped_pairs": np.asarray(
+                    [np.asarray(m.dropped_pairs.data) for m in mlps])}

@@ -11,6 +11,7 @@ from .layer.pooling import *      # noqa: F401,F403
 from .layer.loss import *         # noqa: F401,F403
 from .layer.container import *    # noqa: F401,F403
 from .layer.transformer import *  # noqa: F401,F403
+from .layer.moe import *          # noqa: F401,F403
 from .layer.rnn import *          # noqa: F401,F403
 from .decode import (BeamSearchDecoder, Decoder,  # noqa: F401
                      dynamic_decode)

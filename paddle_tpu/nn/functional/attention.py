@@ -16,7 +16,8 @@ from ...framework import core
 from ...ops._helpers import to_tensor_like, unwrap
 
 __all__ = ["scaled_dot_product_attention", "flash_attention",
-           "flash_attn_unpadded", "sdp_kernel", "sparse_attention"]
+           "flash_attn_unpadded", "sdp_kernel", "sparse_attention",
+           "gated_delta_rule"]
 
 
 def _sdpa_ref(q, k, v, mask, dropout_p, causal, scale):
@@ -298,3 +299,21 @@ def sparse_attention(query, key, value, sparse_csr_offset,
         return _masked_attention_core(qd, kd, vd, mask)
 
     return apply_op(f, q, k, v, *extra, name="sparse_attention")
+
+
+def gated_delta_rule(query, key, value, log_decay, beta, chunk=64,
+                     scale=None):
+    """Gated delta-rule linear attention with a per-channel decay:
+    S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T,
+    o_t = S_t^T q_t * scale, from a zero state, computed a chunk of
+    tokens at a time (kernels/gated_delta_rule.py). query, key, log_decay
+    [B, T, H, dk], value [B, T, H, dv], beta [B, T, H]; differentiable in
+    all five. Returns [B, T, H, dv]."""
+    from ...kernels.gated_delta_rule import chunk_gated_delta_rule
+
+    def fn(q, k, v, g, b):
+        return chunk_gated_delta_rule(q, k, v, g, b, chunk=chunk,
+                                      scale=scale)
+
+    return apply_op(fn, *(to_tensor_like(t) for t in (
+        query, key, value, log_decay, beta)), name="gated_delta_rule")

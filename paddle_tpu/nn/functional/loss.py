@@ -20,6 +20,7 @@ __all__ = [
     "soft_margin_loss", "sigmoid_focal_loss", "dice_loss", "log_loss",
     "square_error_cost", "ctc_loss", "poisson_nll_loss", "gaussian_nll_loss",
     "multi_margin_loss", "hsigmoid_loss", "npair_loss", "rnnt_loss",
+    "linear_cross_entropy",
 ]
 
 
@@ -585,3 +586,54 @@ def margin_cross_entropy(logits, label, margin1=1.0, margin2=0.5,
     if return_softmax:
         return loss, sm
     return loss
+
+
+def _linear_cross_entropy(h, w, labels, block_rows, ignore_index):
+    """Mean cross-entropy of (h @ w) against labels, a block of rows at a
+    time: h [N, H], w [H, V], labels [N]. The [N, V] logits never exist:
+    a block's are made, reduced and dropped, and the backward makes them
+    again (`jax.checkpoint`)."""
+    from ...observability.scopes import scope
+    n, hidden = h.shape
+    block = min(block_rows, n)
+    pad = -n % block
+    if pad:
+        h = jnp.pad(h, ((0, pad), (0, 0)))
+        labels = jnp.pad(labels, (0, pad), constant_values=ignore_index)
+
+    def rows(args):
+        hb, lb = args
+        with scope("head"):
+            lg = jnp.matmul(hb, w, preferred_element_type=jnp.float32)
+        with scope("loss"):
+            valid = lb != ignore_index
+            lse = jax.nn.logsumexp(lg, axis=-1)
+            tgt = jnp.take_along_axis(
+                lg, jnp.where(valid, lb, 0)[:, None], axis=-1)[:, 0]
+            return (jnp.sum(jnp.where(valid, lse - tgt, 0.0)),
+                    jnp.sum(valid))
+
+    sums, counts = jax.lax.map(
+        jax.checkpoint(rows), (h.reshape(-1, block, hidden),
+                               labels.astype(jnp.int32).reshape(-1, block)))
+    with scope("loss"):
+        return jnp.sum(sums) / jnp.maximum(jnp.sum(counts), 1)
+
+
+def linear_cross_entropy(hidden, weight, labels, block_rows=2048,
+                         ignore_index=-100):
+    """The output head and its cross-entropy in one: mean over the rows
+    whose label is not `ignore_index` of CE(hidden @ weight, labels),
+    computed `block_rows` rows at a time so that the logits of a long
+    sequence over a large vocabulary are never held whole (at 32768 rows
+    x 24576 columns they are 3.2 GB in float32, and as much again for
+    their gradient). hidden [..., H], weight [H, V], labels [...]."""
+    hidden, weight = to_tensor_like(hidden), to_tensor_like(weight)
+    lb = unwrap(labels).reshape(-1)
+    H = hidden.shape[-1]
+
+    def fn(h, w):
+        return _linear_cross_entropy(h.reshape(-1, H), w, lb, block_rows,
+                                     ignore_index)
+
+    return apply_op(fn, hidden, weight, name="linear_cross_entropy")

@@ -1,0 +1,175 @@
+"""A dropless mixture-of-experts layer that is told which experts it
+holds.
+
+The router scores every token against ALL `num_experts` experts (a
+sigmoid each), keeps the `top_k` largest and normalises their scores
+into weights. This layer holds the experts
+[first_expert, first_expert + experts_held): it sorts the (token,
+expert) pairs routed to them by expert, gathers those tokens' rows into
+one row buffer, runs two grouped matrix products over it (SwiGLU experts,
+`kernels/grouped_matmul.py`), and adds each row back to its token with
+its weight. Pairs routed to experts held elsewhere add nothing here:
+under expert parallelism their chips add them, and on one chip the
+partial sum is the result. A shared expert, if any, is added once.
+
+Dropless: the row buffer has `rows` rows for the whole layer, not a
+capacity an expert. Every pair of a held expert gets a row while
+sum(pairs here) <= rows; what does not fit is COUNTED (`dropped_pairs`,
+a running sum the compiled step writes), never silently lost.
+`rows=None` sizes the buffer for the worst case (every pair here).
+
+Counters are non-trainable buffers, written by the step that runs the
+layer and read by the host between steps: `expert_tokens` [experts_held]
+(the last call's rows per held expert) and `dropped_pairs` [].
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from ...autograd.tape import apply_op
+from ...observability.scopes import scope
+from ...ops._helpers import to_tensor_like
+from ...tensor import Tensor
+from .. import initializer as I
+from .layers import Layer
+
+__all__ = ["DroplessMoE", "dropless_moe", "route_top_k"]
+
+
+def route_top_k(x, w_router, top_k, norm_topk=True, scaling=1.0):
+    """(expert ids [T, k] int32, weights [T, k] f32): sigmoid scores over
+    all the router's outputs, accumulated in float32 at full precision (a
+    score's rounding picks another expert; bf16 operands multiply
+    exactly, so no float32 copy of x is made), the top-k, normalised."""
+    s = jax.nn.sigmoid(jnp.matmul(
+        x, w_router, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32))
+    top_s, top_i = jax.lax.top_k(s, top_k)
+    if norm_topk:
+        top_s = top_s / jnp.sum(top_s, -1, keepdims=True)
+    return top_i.astype(jnp.int32), top_s * scaling
+
+
+def dropless_moe(x, w_router, w_gate_up, w_down, *, first_expert, top_k,
+                 norm_topk=True, scaling=1.0, rows=None):
+    """The held experts' part of the routed sum for x [T, H]. w_gate_up
+    [E_held, H, 2M] (gate | up), w_down [E_held, M, H]. Returns
+    (y [T, H] in x's dtype, rows per held expert [E_held] int32,
+    pairs that found no row [] int32)."""
+    from ...kernels.grouped_matmul import ROW_TILE, grouped_matmul
+    T, H = x.shape
+    E, M = w_gate_up.shape[0], w_down.shape[1]
+    pairs = T * top_k
+    rows = -(-pairs // ROW_TILE) * ROW_TILE if rows is None \
+        else min(rows, -(-pairs // ROW_TILE) * ROW_TILE)
+    with scope("moe/router"):
+        top_i, top_w = route_top_k(x, w_router, top_k, norm_topk, scaling)
+    with scope("moe/dispatch"):
+        local = top_i - first_expert
+        key = jnp.where((local >= 0) & (local < E), local, E).reshape(-1)
+        order = jnp.argsort(key, stable=True)
+        if rows > pairs:           # a buffer larger than the pairs: pad
+            order = jnp.pad(order, (0, rows - pairs))
+            key = jnp.pad(key, (0, rows - pairs), constant_values=E)
+        order = order[:rows]
+        counts = jnp.sum(key[:, None] == jnp.arange(E, dtype=jnp.int32),
+                         axis=0, dtype=jnp.int32)
+        ends = jnp.minimum(jnp.cumsum(counts), rows)
+        sizes = jnp.diff(ends, prepend=0)
+        dropped = jnp.sum(counts) - ends[-1]
+        valid = jnp.arange(rows) < ends[-1]
+        token = jnp.where(valid, order // top_k, 0)
+        w_row = jnp.where(valid, jnp.take(top_w.reshape(-1), order), 0.0)
+        xs = jnp.where(valid[:, None], jnp.take(x, token, axis=0), 0)
+    with scope("moe/experts"):
+        gu = grouped_matmul(xs, w_gate_up, sizes)
+        act = (jax.nn.silu(gu[:, :M].astype(jnp.float32))
+               * gu[:, M:].astype(jnp.float32)).astype(x.dtype)
+        out = grouped_matmul(act, w_down, sizes)
+    with scope("moe/combine"):
+        # weighted in float32, summed per token in x's dtype (at most
+        # top_k rows a token): no [T, H] float32 buffer
+        out = jnp.where(valid[:, None],
+                        out.astype(jnp.float32) * w_row[:, None], 0.0)
+        y = jnp.zeros((T, H), x.dtype).at[token].add(out.astype(x.dtype))
+    return y, counts, dropped
+
+
+class DroplessMoE(Layer):
+    """Sigmoid-routed top-k mixture of SwiGLU experts over the experts
+    held here, plus `shared_experts` shared SwiGLU experts of the same
+    width (0 or 1). See the module docstring."""
+
+    def __init__(self, hidden_size, expert_size, num_experts, top_k,
+                 experts_held=None, first_expert=0, shared_experts=1,
+                 norm_topk_prob=True, routed_scaling_factor=1.0, rows=None,
+                 dtype=None, std=0.02):
+        super().__init__()
+        held = num_experts if experts_held is None else experts_held
+        if not 0 <= first_expert <= first_expert + held <= num_experts:
+            raise ValueError(
+                f"experts [{first_expert}, {first_expert + held}) are not "
+                f"among the router's {num_experts}")
+        if shared_experts not in (0, 1):
+            raise ValueError("shared_experts is 0 or 1")
+        self.num_experts, self.top_k = num_experts, top_k
+        self.first_expert, self.experts_held = first_expert, held
+        self.norm_topk_prob = norm_topk_prob
+        self.routed_scaling_factor = routed_scaling_factor
+        self.rows = rows
+        h, m = hidden_size, expert_size
+
+        def param(shape, pspec):
+            p = self.create_parameter(shape, dtype=dtype,
+                                      default_initializer=I.Normal(0.0, std))
+            p.pspec = pspec
+            return p
+
+        self.router = param((h, num_experts), P(None, None))
+        self.experts_gate_up = param((held, h, 2 * m), P("ep", None, None))
+        self.experts_down = param((held, m, h), P("ep", None, None))
+        if shared_experts:
+            self.shared_gate_up = param((h, 2 * m), P(None, None))
+            self.shared_down = param((m, h), P(None, None))
+        else:
+            self.shared_gate_up = self.shared_down = None
+        self.register_buffer("expert_tokens",
+                             Tensor(jnp.zeros((held,), jnp.int32)))
+        self.register_buffer("dropped_pairs", Tensor(jnp.zeros((), jnp.int32)))
+
+    def compute(self, x, w_router, w_gate_up, w_down, w_shared_gate_up=None,
+                w_shared_down=None):
+        """Raw arrays in, (y, rows per held expert, dropped pairs) out:
+        what `forward` records, for a caller that runs the layer inside a
+        `jax.checkpoint` and records the counters outside it."""
+        lead = x.shape[:-1]
+        x2 = x.reshape(-1, x.shape[-1])
+        y, counts, dropped = dropless_moe(
+            x2, w_router, w_gate_up, w_down, first_expert=self.first_expert,
+            top_k=self.top_k, norm_topk=self.norm_topk_prob,
+            scaling=self.routed_scaling_factor, rows=self.rows)
+        if w_shared_gate_up is not None:
+            from ...kernels.swiglu import swiglu
+            with scope("moe/shared"):
+                y = y + swiglu(x2, w_shared_gate_up) @ w_shared_down
+        return y.reshape(lead + (y.shape[-1],)), counts, dropped
+
+    def weights(self):
+        ws = [self.router, self.experts_gate_up, self.experts_down]
+        if self.shared_gate_up is not None:
+            ws += [self.shared_gate_up, self.shared_down]
+        return ws
+
+    def record(self, counts, dropped):
+        """Write the counters (arrays or tracers of the running step)."""
+        self.expert_tokens.data = counts
+        self.dropped_pairs.data = self.dropped_pairs.data + dropped
+
+    def forward(self, x):
+        y, counts, dropped = apply_op(
+            self.compute, to_tensor_like(x), *self.weights(), n_outputs=3,
+            name="dropless_moe")
+        self.record(counts.data, dropped.data)
+        return y

@@ -21,7 +21,12 @@ from . import spans as _spans
 PHASES = ("forward", "backward", "grad_sync", "optimizer")
 # models: what a Layer's registered name does not say
 COMPONENTS = ("embed", "layers", "norm", "attn/qkv", "attn/rope",
-              "attn/core", "attn/out", "mlp", "head", "loss")
+              "attn/core", "attn/out", "mlp", "head", "loss",
+              # models/solar_open2: the gate of a gated softmax layer, the
+              # parts of a gated delta-rule layer, and of an expert layer
+              "attn/gate", "kda/proj", "kda/conv", "kda/gate", "kda/core",
+              "kda/out", "moe/router", "moe/dispatch", "moe/experts",
+              "moe/shared", "moe/combine")
 # distributed/sharding: collectives the program itself issues
 COLLECTIVES = ("tp/all_reduce", "tp/relayout")
 # jit.TrainStep.__call__: TraceAnnotations, on the profiler's host plane

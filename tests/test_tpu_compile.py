@@ -26,6 +26,8 @@ from paddle_tpu.kernels import block_attention as ba
 from paddle_tpu.kernels import cross_entropy as ce
 from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels import fused_norm_residual as fnr
+from paddle_tpu.kernels import gated_delta_rule as gdr
+from paddle_tpu.kernels import grouped_matmul as gm
 from paddle_tpu.kernels import paged_attention as pa
 from paddle_tpu.kernels import ragged_paged_attention as rpa
 from paddle_tpu.kernels import rms_norm as rn
@@ -59,7 +61,7 @@ def _as_on_the_chip(monkeypatch):
     interpret off), compile at the program's own matmul precision, and
     keep these compiles out of the persistent cache: an entry written
     for a described chip cannot be read back without one."""
-    for mod in (ba, ce, fa, fnr, pa, rpa, rn, sg):
+    for mod in (ba, ce, fa, fnr, gdr, gm, pa, rpa, rn, sg):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
     from jax.experimental.compilation_cache import compilation_cache as cc
     cache_was = jax.config.jax_enable_compilation_cache
@@ -337,3 +339,54 @@ def test_train_step_names_its_device_operations(topo, cell):
     if plan is not None:
         for cls in ("tp_all_reduce", "tp_relayout", "zero3"):
             assert sum(n for (c, _), n in counts.items() if c == cls), cls
+
+
+# -- models/solar_open2.py at the shapes of solar-open2-250b-ep40.pretrain-32k
+
+def test_solar_open2_kernels_at_the_cells_shapes(one_chip):
+    """What one group of heads and one expert layer hand the compiler at
+    32768 tokens and the published widths: the routed experts' grouped
+    products (megablox gmm forward, gmm + tgmm backward) over the 16384-row
+    buffer, the shared expert's swiglu at M = 1280, and splash attention
+    over one KV head with its 8 query heads."""
+    bf = jnp.bfloat16
+
+    def sds(shape, dtype=bf):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    x, w = sds((16384, 4096)), sds((8, 4096, 2560))
+    sizes = sds((8,), jnp.int32)
+    assert gm.supported(16384, 4096, 2560)
+    text = _compile(jax.grad(
+        lambda x_, w_, s_: _sum32(gm.grouped_matmul(x_, w_, s_)),
+        argnums=(0, 1)), x, w, sizes)
+    assert _mosaic_calls(text, "gmm") >= 2 and _mosaic_calls(text, "tgmm") == 1
+    a, wgu = sds((32768, 4096)), sds((4096, 2 * 1280))
+    text = _compile(jax.grad(lambda a_, w_: _sum32(sg.swiglu(a_, w_)),
+                             argnums=(0, 1)), a, wgu)
+    assert "swiglu_bwd_da" in text and "swiglu_bwd_dw" in text
+    q, kv = sds((1, 32768, 8, 128)), sds((1, 32768, 1, 128))
+    assert fa.supported(q.shape, kv.shape, True)
+    _compile(jax.grad(lambda q_, k_, v_: _sum32(fa.flash_attention_bshd(
+        q_, k_, v_, causal=True)), argnums=(0, 1, 2)), q, kv, kv)
+
+
+def test_gated_delta_rule_compiles_for_the_chip(one_chip):
+    """The chunked operator at the cell's own length and group of heads:
+    the chip's compiler has to take its triangular solve and its pairwise
+    sub-blocks, with bf16 operands and float32 decays, and the two Mosaic
+    kernels that walk the chunks (a head's state in VMEM), forward and
+    backward. No loop over the chunks is left for XLA to run."""
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    qkv = sds((1, 32768, 4, 128))
+    g, beta = sds((1, 32768, 4, 128), jnp.float32), sds((1, 32768, 4),
+                                                        jnp.float32)
+    compiled = jax.jit(jax.grad(
+        lambda *a: _sum32(gdr.chunk_gated_delta_rule(*a)),
+        argnums=(0, 1, 2, 3, 4))).lower(qkv, qkv, qkv, g, beta).compile()
+    text = compiled.as_text()
+    assert "triangular-solve" in text or "triangular_solve" in text
+    assert _mosaic_calls(text, "kda_chunk_states_bwd") == 1
+    assert _mosaic_calls(text, "kda_chunk_states") == 2    # and its _bwd
