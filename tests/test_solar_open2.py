@@ -5,6 +5,7 @@ the chunked delta-rule operator against the token-by-token recurrence,
 the expert layer's shares against the uncut layer, droplessness under a
 skewed router, the counters the compiled step writes, the blocked head +
 loss, and the names the compiled step carries."""
+import functools
 import os
 import sys
 
@@ -122,56 +123,64 @@ def test_chunked_delta_rule_matches_the_recurrence(T, chunk, strong):
                                    atol=2e-5 * float(jnp.abs(b).max()))
 
 
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
-                                       (jnp.bfloat16, 1e-2)])
+def _through_the_kernels(*a, chunk):
+    """The chip's route (one Pallas kernel a pass), here through the
+    interpreter."""
+    return gdr._fused(*a, chunk, 1.0 / np.sqrt(a[0].shape[-1]), True)
+
+
+def _cast(args, dtype):
+    return tuple(x.astype(dtype) for x in args[:3]) + tuple(args[3:])
+
+
+def _out_and_grads(fn, args):
+    return (fn(*args),) + jax.grad(
+        lambda *a: jnp.sum(jnp.sin(fn(*a).astype(jnp.float32))),
+        argnums=(0, 1, 2, 3, 4))(*args)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 2e-2)])
 def test_chunk_state_kernels_walk_the_chunks_as_the_scan_does(dtype, tol):
-    """The chip's route for the sequential part (two Pallas kernels, here
-    through the interpreter) against the scan jax transposes: each chunk's
-    starting state, U, and the cotangent of every operand."""
-    N, B, H, C, d = 5, 1, 2, 16, 128
-    ks = jax.random.split(jax.random.key(1), 6)
-    Ut = jax.random.normal(ks[0], (N, B, H, C, d))
-    W = (0.1 * jax.random.normal(ks[1], (N, B, H, C, d))).astype(dtype)
-    K = (0.1 * jax.random.normal(ks[2], (N, B, H, C, d))).astype(dtype)
-    decay = jax.random.uniform(ks[3], (N, B, H, d), minval=0.5, maxval=1.0)
-    cs = jax.random.normal(ks[4], (N, B, H, d, d))
-    cu = jax.random.normal(ks[5], (N, B, H, C, d))
+    """The kernel route against the `jax.numpy` route on the same inputs,
+    at the head width the kernels tile (128): the output and the
+    cotangent of every operand, in the operands' own dtypes."""
+    args = _cast(_qkvgb(70, H=2, dk=128, dv=128, batch=1, seed=1), dtype)
 
-    def both(fn):
-        def loss(*a):
-            s, u = fn(*a)
-            return jnp.sum(s * cs) + jnp.sum(u.astype(jnp.float32) * cu)
-        return fn(Ut, W, K, decay) + jax.grad(loss, argnums=(0, 1, 2, 3))(
-            Ut, W, K, decay)
-
-    for got, want in zip(both(gdr._states_pallas), both(gdr._states_scan)):
+    for got, want in zip(
+            _out_and_grads(functools.partial(_through_the_kernels, chunk=16),
+                           args),
+            _out_and_grads(functools.partial(chunk_gated_delta_rule, chunk=16),
+                           args)):
         assert got.shape == want.shape and got.dtype == want.dtype
         want = np.asarray(want, np.float32)
         np.testing.assert_allclose(np.asarray(got, np.float32), want,
                                    atol=tol * np.abs(want).max())
 
 
+@pytest.mark.parametrize("T,chunk,strong,dtype,tol", [
+    (70, 16, False, jnp.float32, 2e-5),
+    (150, 64, True, jnp.float32, 5e-5),
+    (100, 64, False, jnp.bfloat16, 1e-2),
+    (40, 16, True, jnp.bfloat16, 1e-2)])
 def test_chunked_delta_rule_through_the_kernels_matches_the_recurrence(
-        monkeypatch):
-    """The whole operator with the kernels in the scan's place, at the
-    head width they tile (128), a length the chunk does not divide."""
-    monkeypatch.setattr(gdr, "_chunk_states", gdr._states_pallas)
-    args = _qkvgb(70, H=2, dk=128, dv=128, seed=2)
+        T, chunk, strong, dtype, tol):
+    """The fused forward and backward kernels against the token-by-token
+    recurrence, output and all five gradients: a length the chunk does
+    not divide, the weak and the strong decay (down to exp(-20) a token),
+    float32 and bf16 operands, a chunk of one sub-block and of four."""
+    args = _qkvgb(T, H=2, dk=128, dv=128, strong=strong, seed=2)
 
     def chunked(*a):
-        return chunk_gated_delta_rule(*(x[None] for x in a), chunk=16)[0]
+        return _through_the_kernels(
+            *(x[None] for x in _cast(a, dtype)), chunk=chunk)[0]
 
-    np.testing.assert_allclose(
-        np.asarray(chunked(*args)),
-        np.asarray(ref.delta_rule_recurrence(*args)), atol=1e-5)
-
-    def grads(fn):
-        return jax.grad(lambda *a: jnp.sum(jnp.sin(fn(*a))),
-                        argnums=(0, 1, 2, 3, 4))(*args)
-
-    for a, b in zip(grads(chunked), grads(ref.delta_rule_recurrence)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=2e-5 * float(jnp.abs(b).max()))
+    got = _out_and_grads(chunked, args)
+    assert got[0].dtype == dtype and bool(jnp.isfinite(got[0]).all())
+    want = _out_and_grads(ref.delta_rule_recurrence, args)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b),
+                                   atol=tol * float(jnp.abs(b).max()))
 
 
 def test_gated_delta_rule_is_public_and_batched():

@@ -373,10 +373,12 @@ def test_solar_open2_kernels_at_the_cells_shapes(one_chip):
 
 def test_gated_delta_rule_compiles_for_the_chip(one_chip):
     """The chunked operator at the cell's own length and group of heads:
-    the chip's compiler has to take its triangular solve and its pairwise
-    sub-blocks, with bf16 operands and float32 decays, and the two Mosaic
-    kernels that walk the chunks (a head's state in VMEM), forward and
-    backward. No loop over the chunks is left for XLA to run."""
+    each pass is ONE Mosaic kernel that takes bf16 q, k, v and the float32
+    decay as they come and does the pairwise sub-blocks, the solve and the
+    walk over the chunks in VMEM. Nothing of it is left for XLA: no
+    triangular solve, no loop, no concatenate, no float32 copy of an
+    operand; under a `jax.checkpoint`, as the model calls it, the forward
+    runs twice (once keeping what the backward reads)."""
     def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
@@ -387,6 +389,20 @@ def test_gated_delta_rule_compiles_for_the_chip(one_chip):
         lambda *a: _sum32(gdr.chunk_gated_delta_rule(*a)),
         argnums=(0, 1, 2, 3, 4))).lower(qkv, qkv, qkv, g, beta).compile()
     text = compiled.as_text()
-    assert "triangular-solve" in text or "triangular_solve" in text
+    assert "triangular-solve" not in text and "triangular_solve" not in text
+    assert " while(" not in text and "concatenate" not in text
     assert _mosaic_calls(text, "kda_chunk_states_bwd") == 1
     assert _mosaic_calls(text, "kda_chunk_states") == 2    # and its _bwd
+    # what the backward reads, a token a head: 1/64 of a chunk's starting
+    # state, a row of A, P and the solve's inverse (64 wide, stored 128
+    # wide), of U~ and W; beside it o, its cotangent and beta by columns,
+    # and no [N, B, H, C, d] float32 copy of q, k, v, g
+    kept = 32768 * 4 * (128 * 128 // 64 * 4 + 128 * (4 + 4 + 2)
+                        + 128 * (4 + 2))
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.35 * kept
+
+    remat = jax.jit(jax.grad(lambda *a: _sum32(jax.checkpoint(
+        gdr.chunk_gated_delta_rule)(*a)), argnums=(0, 1, 2, 3, 4))).lower(
+            qkv, qkv, qkv, g, beta).compile().as_text()
+    assert _mosaic_calls(remat, "kda_chunk_states_bwd") == 1
+    assert _mosaic_calls(remat, "kda_chunk_states") == 2
