@@ -378,8 +378,8 @@ _flags: dict = {
     "FLAGS_dataloader_prefetch": True,
     # -- autotune (consumed by kernels/autotune.sweeps_enabled) --------
     "FLAGS_use_autotune": True,
-    # kernel-route kill switches (the on-chip ablation levers; analog of
-    # the reference's cudnn/flash deterministic+enable toggles)
+    # kernel-route kill switch (an on-chip ablation lever; analog of
+    # the reference's cudnn/flash deterministic+enable toggles).
     # Default FALSE: the only two on-chip measurements bracket the
     # route — r2 (XLA CE) 23,126 tok/s/chip vs r4 (fused CE on,
     # UNTUNED — its autotune sweep died mid-run) 19,011. Until the
@@ -387,16 +387,6 @@ _flags: dict = {
     # configuration is the default; FLAGS_use_fused_ce=1 opts in
     # (ROADMAP.md S2).
     "FLAGS_use_fused_ce": False,       # Pallas blockwise CE vs XLA CE
-    "FLAGS_use_flash_attention": True,  # Pallas flash vs dense XLA attn
-    # fused transformer hot path (consumed by models/llama.py): fused
-    # residual+RMSNorm and SwiGLU Pallas kernels plus the fused QKV+RoPE
-    # prologue, one kernel surface for train (LlamaDecoderLayer /
-    # _scan_stack / _recompute_stack) and serve (_block_with_cache /
-    # _block_paged / _block_ragged). 0 is the kill switch restoring the
-    # unfused jnp paths bitwise (greedy serving tokens identical,
-    # training loss trajectory within 1e-6 over 40 steps —
-    # benchmarks/fusion_bench.py is the gate)
-    "FLAGS_fused_transformer": True,
     # -- serving (consumed by inference/serving.py): ragged paged
     # attention + chunked-prefill continuous batching; 0 is the kill
     # switch restoring the bucketed-prefill engine exactly

@@ -45,14 +45,6 @@ def supported(q_shape, k_shape, causal_or_none: bool,
     is first-class.
     """
     del has_padding_mask  # handled via segment ids — never gated out
-    try:
-        from ..framework import core
-        if not core.get_bool_flag("FLAGS_use_flash_attention", True):
-            # per-route kill switch / ablation lever (ref: the
-            # reference's flash enable toggles)
-            return False
-    except Exception:
-        pass
     if not _on_tpu():
         return False
     if not causal_or_none and not has_bias:
@@ -386,12 +378,6 @@ def packed_supported(total_q, total_k, n_heads_q, n_heads_k, D) -> bool:
     128 alignment, so any total works on TPU; only head-dim rules and
     the GQA group structure (q heads a multiple of kv heads — the splash
     kernel's MQA mode carries packed GQA) gate it."""
-    try:
-        from ..framework import core
-        if not core.get_bool_flag("FLAGS_use_flash_attention", True):
-            return False  # same kill switch as supported()
-    except Exception:
-        pass
     if not _on_tpu():
         return False
     d_ok = (D % 64 == 0) if D <= 128 else (D % 128 == 0)

@@ -14,11 +14,12 @@ rstd from the saved h instead of storing normalized activations:
   dh = gh + r*(gy*w) - h * r^3/H * sum(gy*w*h)    (dx = dresidual = dh)
   dw = sum_rows(gy * h * r)
 
-The jnp fallback reproduces the unfused `(x + residual)` + rms_norm
-sequence bitwise (same op order, same f32 casts), so the
-FLAGS_fused_transformer=0 comparison and the interpret-mode parity
-tests share one reference. Tests flip `_FORCE_PALLAS` to drive the
-Pallas path through the interpreter on CPU.
+Off the TPU, and for a hidden size that is not a multiple of 128, the
+jnp fallback is the separate `(x + residual)` then rms_norm sequence,
+bitwise (same op order, same f32 casts): what LlamaDecoderLayer runs
+under sequence parallelism, and the reference of the interpret-mode
+parity tests. Tests flip `_FORCE_PALLAS` to drive the Pallas path
+through the interpreter on CPU.
 
 Block sizes come from kernels/autotune.py (key "fused_norm", quantized
 hidden-size class) — sweep via `sweep_block_sizes`.
@@ -86,9 +87,9 @@ def _route(shape, use_pallas):
 
 
 def _fwd_kernel(x_ref, r_ref, w_ref, y_ref, h_ref, *, eps):
-    # round h to the stream dtype BEFORE normalizing — the unfused path
-    # norms the rounded residual stream, and parity with it is the
-    # contract the kill switch and the interpret tests check
+    # round h to the stream dtype BEFORE normalizing — a separate add
+    # then norm sees the rounded residual stream, and parity with that
+    # sequence is the contract the interpret tests check
     h = (x_ref[...].astype(jnp.float32)
          + r_ref[...].astype(jnp.float32)).astype(h_ref.dtype)
     h_ref[...] = h
@@ -100,8 +101,8 @@ def _fwd_kernel(x_ref, r_ref, w_ref, y_ref, h_ref, *, eps):
 
 def _fwd_impl(x, residual, weight, eps, use_pallas, block_rows):
     if not _route(x.shape, use_pallas):
-        # exact jnp mirror of the unfused path: Tensor add (f32 compute,
-        # round to stream dtype) then the rms_norm fallback on h
+        # add (f32 compute, round to stream dtype), then the rms_norm
+        # fallback on h
         h = x + residual
         h32 = h.astype(jnp.float32)
         ms = jnp.mean(h32 * h32, axis=-1, keepdims=True)

@@ -37,7 +37,7 @@ def fused_qkv_rope(a, w_qkv, num_heads, num_kv_heads, head_dim,
 
     a: [B, S, H] (or [S, H] packed rows); w_qkv:
     [H, (num_heads + 2*num_kv_heads) * head_dim] with q|k|v column
-    layout (the fuse_attention_qkv checkpoint layout). Returns
+    layout (LlamaAttention.qkv_proj's stored layout). Returns
     (q, k, v) shaped [..., heads, head_dim] with rope already applied
     to q and k. position_ids/seq_len follow apply_rope (packed [S]
     rows get a broadcast batch dim internally)."""

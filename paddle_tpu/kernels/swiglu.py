@@ -2,7 +2,7 @@
 fused_gate_attention + fused_bias_act; TPU-native blockwise Pallas
 kernel with the silu(g)*u epilogue fused into the gate/up matmul).
 
-The unfused MLP materializes `gu = a @ w_gate_up` — a [T, 2M] tensor
+A plain MLP materializes `gu = a @ w_gate_up` — a [T, 2M] tensor
 (4H-wide at llama ratios) that exists only to be split, activated and
 multiplied — an HBM round trip XLA does not reliably elide across the
 autograd seam. Here the gate/up products are streamed block-by-block
@@ -22,11 +22,12 @@ inside one layer's backward. The forward's gu is still not saved: that
 would drop the remaining recomputation too, at the price of [T, 2M]
 more bytes a layer kept between forward and backward.
 
-The jnp fallback computes the exact unfused expression
-`silu(gu[..., :M]) * gu[..., M:]`, and the fallback backward is
-jax.vjp of that expression, so FLAGS_fused_transformer=0 parity and
-interpret-mode tests share one reference. Tests flip `_FORCE_PALLAS`
-to drive the Pallas path through the interpreter on CPU.
+Off the TPU, and for shapes the kernels do not tile (H or M not a
+multiple of 128), the jnp fallback computes the plain expression
+`silu(gu[..., :M]) * gu[..., M:]`, and the fallback backward is jax.vjp
+of that expression: it is also the reference of the interpret-mode
+tests. Tests flip `_FORCE_PALLAS` to drive the Pallas path through the
+interpreter on CPU.
 
 Each kernel has its own block sizes (`_blocks`): from kernels/autotune.py
 (keys "swiglu" for the forward, "swiglu_bwd_da", "swiglu_bwd_dw";
@@ -170,7 +171,8 @@ def _route(a_shape, w_shape, use_pallas):
 
 
 def _ref(a, w_gate_up):
-    """The exact unfused expression (LlamaMLP's fused-weight path)."""
+    """The plain expression: what runs where the kernels do not, and what
+    the interpret-mode tests compare them with."""
     m = w_gate_up.shape[-1] // 2
     gu = a @ w_gate_up
     return jax.nn.silu(gu[..., :m]) * gu[..., m:]
@@ -303,8 +305,7 @@ def _fwd_impl(a, w_gate_up, use_pallas, blocks):
 
 def _bwd_impl(a, w_gate_up, g, use_pallas, blocks):
     if not _route(a.shape, w_gate_up.shape, use_pallas):
-        # autodiff of the exact unfused expression — bitwise the
-        # FLAGS_fused_transformer=0 tape on CPU
+        # autodiff of the plain expression
         _, vjp = jax.vjp(_ref, a, w_gate_up)
         return vjp(g)
     orig_shape = a.shape

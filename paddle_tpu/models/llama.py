@@ -20,7 +20,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..autograd.tape import apply_op
@@ -38,8 +37,8 @@ from ..tensor import Tensor
 __all__ = ["LlamaConfig", "LlamaModel", "LlamaForCausalLM", "llama_tiny",
            "llama_350m", "llama_1b", "llama_7b"]
 
-# matmul outputs stamped with jax.ad_checkpoint.checkpoint_name on the
-# FLAGS_fused_transformer hot path — the name vocabulary that
+# matmul outputs stamped with jax.ad_checkpoint.checkpoint_name in the
+# attention and MLP bodies — the name vocabulary that
 # jit.TrainStep's default remat_policy="save_matmul_outputs"
 # (save_only_these_names) keeps across the backward, so norms and
 # activations recompute instead of living through it
@@ -70,12 +69,6 @@ class LlamaConfig:
     # fleet/utils/sequence_parallel_utils.py); GSPMD derives the
     # all-gather/reduce-scatter pairs from the annotations
     sequence_parallel: bool = False
-    # fuse q/k/v (and gate/up) projections into single wide matmuls — the
-    # K=hidden contraction underutilizes the MXU at small N, and one
-    # [h, (nh+2kvh)d] matmul runs markedly faster than three narrow ones
-    # (ref: the reference's fuse_attention_qkv / fused_feedforward path)
-    fuse_attention_qkv: bool = True
-    fuse_mlp: bool = True
     dtype: str = "bfloat16"
 
     @property
@@ -127,20 +120,17 @@ class LlamaRMSNorm(Layer):
 
 class LlamaAttention(Layer):
     """Column-parallel qkv, row-parallel o (ref mp_layers.py:335,542 layout,
-    expressed as GSPMD specs instead of explicit collectives)."""
+    expressed as GSPMD specs instead of explicit collectives). q, k and v
+    are stored as ONE [h, (nh + 2 kvh) d] projection: the K=hidden
+    contraction underuses the MXU at small N, and one wide matmul runs
+    markedly faster than three narrow ones."""
 
     def __init__(self, cfg: LlamaConfig):
         super().__init__()
         self.cfg = cfg
         h, d = cfg.hidden_size, cfg.head_dim
         nh, kvh = cfg.num_attention_heads, cfg.kv_heads
-        if cfg.fuse_attention_qkv:
-            self.qkv_proj = _param(self, (h, (nh + 2 * kvh) * d),
-                                   P(None, "mp"))
-        else:
-            self.q_proj = _param(self, (h, nh * d), P(None, "mp"))
-            self.k_proj = _param(self, (h, kvh * d), P(None, "mp"))
-            self.v_proj = _param(self, (h, kvh * d), P(None, "mp"))
+        self.qkv_proj = _param(self, (h, (nh + 2 * kvh) * d), P(None, "mp"))
         self.o_proj = _param(self, (nh * d, h), P("mp", None))
 
     def forward(self, x, position_ids=None, kv_cache=None):
@@ -167,57 +157,27 @@ class LlamaAttention(Layer):
                     v = jnp.repeat(v, rep, axis=2)
                 return _sdpa(q, k, v)
 
-        def _attend(q, k, v):
-            from ..kernels.rope import apply_rope
-            with scope("attn/rope"):
-                q, k = apply_rope(q, k, base=cfg.rope_theta)
-            return _core(q, k, v)
-
         def _out(o, wo):
             with scope("attn/out"), _tp_all_reduce():    # row-parallel
                 return o.reshape(B, -1, nh * d) @ wo
 
-        if cfg.fuse_attention_qkv:
-            if core.get_bool_flag("FLAGS_fused_transformer", True):
-                # fused QKV+RoPE prologue: one wide projection, rope on
-                # the q/k slices in-register (kernels/rope.py), matmul
-                # outputs stamped for the save_only_these_names remat
-                # policy (jit.TrainStep remat_policy=)
-                def attn(a, wqkv, wo):
-                    from jax.ad_checkpoint import checkpoint_name
-                    from ..kernels.rope import fused_qkv_rope
-                    # projection and rotation are ONE fused prologue:
-                    # both carry attn/qkv
-                    with scope("attn/qkv"), _tp_all_reduce():
-                        q, k, v = fused_qkv_rope(a, wqkv, nh, kvh, d,
-                                                 base=cfg.rope_theta)
-                    o = _core(q, k, v)
-                    return checkpoint_name(_out(o, wo), "llama_attn_o")
+        # fused QKV+RoPE prologue: one wide projection, rope on the q/k
+        # slices in-register (kernels/rope.py), matmul outputs stamped
+        # for the save_only_these_names remat policy (jit.TrainStep
+        # remat_policy=)
+        def attn(a, wqkv, wo):
+            from jax.ad_checkpoint import checkpoint_name
+            from ..kernels.rope import fused_qkv_rope
+            # projection and rotation are ONE fused prologue: both
+            # carry attn/qkv
+            with scope("attn/qkv"), _tp_all_reduce():
+                q, k, v = fused_qkv_rope(a, wqkv, nh, kvh, d,
+                                         base=cfg.rope_theta)
+            o = _core(q, k, v)
+            return checkpoint_name(_out(o, wo), "llama_attn_o")
 
-                return apply_op(attn, to_tensor_like(x), self.qkv_proj,
-                                self.o_proj, name="llama_attn_fused")
-
-            def attn(a, wqkv, wo):
-                with scope("attn/qkv"):
-                    qkv = a @ wqkv
-                    q = qkv[..., : nh * d].reshape(B, -1, nh, d)
-                    k = qkv[..., nh * d: (nh + kvh) * d].reshape(
-                        B, -1, kvh, d)
-                    v = qkv[..., (nh + kvh) * d:].reshape(B, -1, kvh, d)
-                return _out(_attend(q, k, v), wo)
-
-            return apply_op(attn, to_tensor_like(x), self.qkv_proj,
-                            self.o_proj, name="llama_attn")
-
-        def attn(a, wq, wk, wv, wo):
-            with scope("attn/qkv"):
-                q = (a @ wq).reshape(B, -1, nh, d)
-                k = (a @ wk).reshape(B, -1, kvh, d)
-                v = (a @ wv).reshape(B, -1, kvh, d)
-            return _out(_attend(q, k, v), wo)
-
-        return apply_op(attn, to_tensor_like(x), self.q_proj, self.k_proj,
-                        self.v_proj, self.o_proj, name="llama_attn")
+        return apply_op(attn, to_tensor_like(x), self.qkv_proj,
+                        self.o_proj, name="llama_attn_fused")
 
 
 def _sdpa(q, k, v):
@@ -234,50 +194,28 @@ def _sdpa(q, k, v):
 
 
 class LlamaMLP(Layer):
-    """SwiGLU; gate/up column-parallel, down row-parallel."""
+    """SwiGLU; gate | up stored as one column-parallel [h, 2m] projection,
+    down row-parallel."""
 
     def __init__(self, cfg: LlamaConfig):
         super().__init__()
         h, m = cfg.hidden_size, cfg.intermediate_size
-        self._m = m
-        self._fused = cfg.fuse_mlp
-        if self._fused:
-            self.gate_up_proj = _param(self, (h, 2 * m), P(None, "mp"))
-        else:
-            self.gate_proj = _param(self, (h, m), P(None, "mp"))
-            self.up_proj = _param(self, (h, m), P(None, "mp"))
+        self.gate_up_proj = _param(self, (h, 2 * m), P(None, "mp"))
         self.down_proj = _param(self, (m, h), P("mp", None))
 
     def forward(self, x):
-        m = self._m
-        if self._fused:
-            if core.get_bool_flag("FLAGS_fused_transformer", True):
-                # blockwise Pallas SwiGLU: the [T, 2M] gate/up tensor
-                # never hits HBM (kernels/swiglu.py); outputs stamped
-                # for the save_only_these_names remat policy
-                def mlp(a, wgu, wd):
-                    from jax.ad_checkpoint import checkpoint_name
-                    with scope("mlp"):
-                        o = checkpoint_name(_swiglu(a, wgu), "llama_swiglu")
-                        with _tp_all_reduce():           # row-parallel
-                            return checkpoint_name(o @ wd, "llama_mlp_down")
-
-                return apply_op(mlp, to_tensor_like(x), self.gate_up_proj,
-                                self.down_proj, name="llama_mlp_fused")
-
-            def mlp(a, wgu, wd):
-                with scope("mlp"):
-                    gu = a @ wgu
-                    return (jax.nn.silu(gu[..., :m]) * gu[..., m:]) @ wd
-
-            return apply_op(mlp, to_tensor_like(x), self.gate_up_proj,
-                            self.down_proj, name="llama_mlp")
-        def mlp(a, wg, wu, wd):
+        # blockwise Pallas SwiGLU: the [T, 2M] gate/up tensor never hits
+        # HBM (kernels/swiglu.py); outputs stamped for the
+        # save_only_these_names remat policy
+        def mlp(a, wgu, wd):
+            from jax.ad_checkpoint import checkpoint_name
             with scope("mlp"):
-                return (jax.nn.silu(a @ wg) * (a @ wu)) @ wd
+                o = checkpoint_name(_swiglu(a, wgu), "llama_swiglu")
+                with _tp_all_reduce():                   # row-parallel
+                    return checkpoint_name(o @ wd, "llama_mlp_down")
 
-        return apply_op(mlp, to_tensor_like(x), self.gate_proj,
-                        self.up_proj, self.down_proj, name="llama_mlp")
+        return apply_op(mlp, to_tensor_like(x), self.gate_up_proj,
+                        self.down_proj, name="llama_mlp_fused")
 
 
 def _swiglu(a, wgu):
@@ -312,11 +250,10 @@ class LlamaDecoderLayer(Layer):
         self.sequence_parallel = cfg.sequence_parallel
 
     def forward(self, x, position_ids=None):
-        if core.get_bool_flag("FLAGS_fused_transformer", True) and \
-                not self.sequence_parallel:
-            # fused hot path: the residual add + post-attention RMSNorm
-            # collapse into one Pallas pass that emits BOTH the summed
-            # stream h and the normalized a2 (kernels/fused_norm_residual)
+        if not self.sequence_parallel:
+            # the residual add + post-attention RMSNorm collapse into one
+            # Pallas pass that emits BOTH the summed stream h and the
+            # normalized a2 (kernels/fused_norm_residual)
             from ..distributed.sharding import shard_kernel
             from ..kernels.fused_norm_residual import fused_add_rms_norm
             attn_out = self.self_attn(self.input_layernorm(x), position_ids)
@@ -336,17 +273,14 @@ class LlamaDecoderLayer(Layer):
                 self.post_attention_layernorm.weight,
                 n_outputs=2, name="fused_add_rms_norm")
             return h + self.mlp(a2)
-        if self.sequence_parallel:
-            from ..distributed.fleet.utils.sequence_parallel_utils import \
-                scatter
-            x = scatter(x)
+        # sequence parallelism: the residual stream is sharded along seq
+        # between the TP blocks, so add and norm stay separate operations
+        # the partitioner can place
+        from ..distributed.fleet.utils.sequence_parallel_utils import scatter
+        x = scatter(x)
         h = x + self.self_attn(self.input_layernorm(x), position_ids)
         h = h + self.mlp(self.post_attention_layernorm(h))
-        if self.sequence_parallel:
-            from ..distributed.fleet.utils.sequence_parallel_utils import \
-                scatter
-            h = scatter(h)
-        return h
+        return scatter(h)
 
 
 class LlamaModel(Layer):
@@ -452,43 +386,30 @@ def _call_pure(layer, a):
     return out.data
 
 
-def _translate_fusion_keys(sd, cfg):
-    """Convert between fused (qkv_proj / gate_up_proj) and unfused
-    (q/k/v_proj, gate/up_proj) checkpoint layouts to match `cfg`."""
+def _translate_fusion_keys(sd):
+    """Join a checkpoint in the published layout (q_proj / k_proj / v_proj
+    and gate_proj / up_proj keys) into the stored one (qkv_proj,
+    gate_up_proj). A q_proj without its k_proj and v_proj, or a gate_proj
+    without its up_proj, is left as it came: set_state_dict then reports
+    it as unexpected and the wide key it should have made as missing."""
     def _arr(v):
-        return v.data if hasattr(v, "data") else jnp.asarray(np.asarray(v))
+        return v.data if isinstance(v, Tensor) else jnp.asarray(v)
 
-    nh, kvh, d = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
-    m = cfg.intermediate_size
     out = dict(sd)
-    for key in list(sd.keys()):
+    for key in sd:
         base, _, leaf = key.rpartition(".")
-        if cfg.fuse_attention_qkv and leaf == "q_proj":
-            k_key, v_key = f"{base}.k_proj", f"{base}.v_proj"
-            if k_key in sd and v_key in sd:
-                out[f"{base}.qkv_proj"] = jnp.concatenate(
-                    [_arr(sd[key]), _arr(sd[k_key]), _arr(sd[v_key])],
-                    axis=-1)
-                for k2 in (key, k_key, v_key):
-                    out.pop(k2, None)
-        elif not cfg.fuse_attention_qkv and leaf == "qkv_proj":
-            qkv = _arr(sd[key])
-            out[f"{base}.q_proj"] = qkv[..., : nh * d]
-            out[f"{base}.k_proj"] = qkv[..., nh * d: (nh + kvh) * d]
-            out[f"{base}.v_proj"] = qkv[..., (nh + kvh) * d:]
-            out.pop(key, None)
-        elif cfg.fuse_mlp and leaf == "gate_proj":
-            up_key = f"{base}.up_proj"
-            if up_key in sd:
-                out[f"{base}.gate_up_proj"] = jnp.concatenate(
-                    [_arr(sd[key]), _arr(sd[up_key])], axis=-1)
-                out.pop(key, None)
-                out.pop(up_key, None)
-        elif not cfg.fuse_mlp and leaf == "gate_up_proj":
-            gu = _arr(sd[key])
-            out[f"{base}.gate_proj"] = gu[..., :m]
-            out[f"{base}.up_proj"] = gu[..., m:]
-            out.pop(key, None)
+        if leaf == "q_proj":
+            parts, wide = (key, f"{base}.k_proj", f"{base}.v_proj"), \
+                f"{base}.qkv_proj"
+        elif leaf == "gate_proj":
+            parts, wide = (key, f"{base}.up_proj"), f"{base}.gate_up_proj"
+        else:
+            continue
+        if all(k in sd for k in parts):
+            out[wide] = jnp.concatenate([_arr(sd[k]) for k in parts],
+                                        axis=-1)
+            for k in parts:
+                del out[k]
     return out
 
 
@@ -514,10 +435,9 @@ class LlamaForCausalLM(Layer):
             self.lm_head = None
 
     def set_state_dict(self, state_dict, use_structured_name=True):
-        """Loads fused and unfused checkpoints interchangeably: q/k/v and
-        gate/up keys are concatenated (or a fused key split) to match this
-        model's fuse_attention_qkv / fuse_mlp layout."""
-        state_dict = _translate_fusion_keys(dict(state_dict), self.cfg)
+        """Loads a checkpoint in the stored or the published layout: q/k/v
+        and gate/up keys are joined into qkv_proj / gate_up_proj."""
+        state_dict = _translate_fusion_keys(dict(state_dict))
         return super().set_state_dict(state_dict, use_structured_name)
 
     load_dict = set_state_dict
@@ -648,75 +568,13 @@ class LlamaForCausalLM(Layer):
 
 
 def _gather_layer_weights(state, cfg):
-    """Stack per-layer weights [L, ...] from a state dict for lax.scan;
-    fused qkv / gate_up layouts are split into the unfused views the cache
-    path consumes."""
+    """Stack per-layer weights [L, ...] from a state dict for lax.scan."""
     L = cfg.num_hidden_layers
-
-    def stack(n):
-        return jnp.stack([state[f"model.layers.{i}.{n}"] for i in range(L)])
-
-    out = {n: stack(n) for n in
-           ["input_layernorm.weight", "post_attention_layernorm.weight",
-            "self_attn.o_proj", "mlp.down_proj"]}
-    nh, kvh, d = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
-    if core.get_bool_flag("FLAGS_fused_transformer", True):
-        # keep (or build) the WIDE projections: the serving blocks run
-        # one qkv matmul + fused_qkv_rope and the swiglu kernel instead
-        # of splitting into narrow per-projection matmuls
-        if cfg.fuse_attention_qkv:
-            out["self_attn.qkv_proj"] = stack("self_attn.qkv_proj")
-        else:
-            out["self_attn.qkv_proj"] = jnp.concatenate(
-                [stack("self_attn.q_proj"), stack("self_attn.k_proj"),
-                 stack("self_attn.v_proj")], axis=-1)
-        if cfg.fuse_mlp:
-            out["mlp.gate_up_proj"] = stack("mlp.gate_up_proj")
-        else:
-            out["mlp.gate_up_proj"] = jnp.concatenate(
-                [stack("mlp.gate_proj"), stack("mlp.up_proj")], axis=-1)
-        return out
-    if cfg.fuse_attention_qkv:
-        qkv = stack("self_attn.qkv_proj")
-        out["self_attn.q_proj"] = qkv[..., : nh * d]
-        out["self_attn.k_proj"] = qkv[..., nh * d: (nh + kvh) * d]
-        out["self_attn.v_proj"] = qkv[..., (nh + kvh) * d:]
-    else:
-        for n in ("self_attn.q_proj", "self_attn.k_proj",
-                  "self_attn.v_proj"):
-            out[n] = stack(n)
-    if cfg.fuse_mlp:
-        gu = stack("mlp.gate_up_proj")
-        m = cfg.intermediate_size
-        out["mlp.gate_proj"] = gu[..., :m]
-        out["mlp.up_proj"] = gu[..., m:]
-    else:
-        out["mlp.gate_proj"] = stack("mlp.gate_proj")
-        out["mlp.up_proj"] = stack("mlp.up_proj")
-    return out
-
-
-def _rms(x, w, eps):
-    """RMSNorm for the serving cache paths — routed through
-    kernels/rms_norm.py (Pallas on TPU; its jnp fallback is bitwise the
-    inline expression this used to carry). FLAGS_fused_transformer=0
-    keeps the historical inline jnp, bitwise."""
-    if core.get_bool_flag("FLAGS_fused_transformer", True):
-        from ..kernels.rms_norm import rms_norm
-        return rms_norm(x, w, eps)
-    xf = x.astype(jnp.float32)
-    out = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (out * w.astype(jnp.float32)).astype(x.dtype)
-
-
-def _serving_mlp(a2, wl):
-    """SwiGLU for the serving blocks: the Pallas kernel over the wide
-    gate_up layout when FLAGS_fused_transformer built `wl` that way,
-    else the historical unfused expression (bitwise)."""
-    if "mlp.gate_up_proj" in wl:
-        from ..kernels.swiglu import swiglu
-        return swiglu(a2, wl["mlp.gate_up_proj"])
-    return jax.nn.silu(a2 @ wl["mlp.gate_proj"]) * (a2 @ wl["mlp.up_proj"])
+    return {n: jnp.stack([state[f"model.layers.{i}.{n}"] for i in range(L)])
+            for n in ("input_layernorm.weight",
+                      "post_attention_layernorm.weight",
+                      "self_attn.qkv_proj", "self_attn.o_proj",
+                      "mlp.gate_up_proj", "mlp.down_proj")}
 
 
 def _block_with_cache(cfg, h, wl, ck, cv, pos_ids, cache_mask):
@@ -727,23 +585,17 @@ def _block_with_cache(cfg, h, wl, ck, cv, pos_ids, cache_mask):
     cache slots are valid AFTER this step's keys are written.
     Returns (h_out, ck_new, cv_new).
     """
-    from ..kernels.rope import apply_rope
+    from ..kernels.rms_norm import rms_norm
+    from ..kernels.rope import fused_qkv_rope
+    from ..kernels.swiglu import swiglu
 
     B, T = h.shape[0], h.shape[1]
     nh, kvh, d = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
-    a = _rms(h, wl["input_layernorm.weight"], cfg.rms_norm_eps)
+    a = rms_norm(h, wl["input_layernorm.weight"], cfg.rms_norm_eps)
     max_pos = max(cfg.max_position_embeddings, ck.shape[1])
-    if "self_attn.qkv_proj" in wl:     # FLAGS_fused_transformer layout
-        from ..kernels.rope import fused_qkv_rope
-        q, k, v = fused_qkv_rope(a, wl["self_attn.qkv_proj"], nh, kvh, d,
-                                 position_ids=pos_ids, base=cfg.rope_theta,
-                                 seq_len=max_pos)
-    else:
-        q = (a @ wl["self_attn.q_proj"]).reshape(B, T, nh, d)
-        k = (a @ wl["self_attn.k_proj"]).reshape(B, T, kvh, d)
-        v = (a @ wl["self_attn.v_proj"]).reshape(B, T, kvh, d)
-        q, k = apply_rope(q, k, position_ids=pos_ids, base=cfg.rope_theta,
-                          seq_len=max_pos)
+    q, k, v = fused_qkv_rope(a, wl["self_attn.qkv_proj"], nh, kvh, d,
+                             position_ids=pos_ids, base=cfg.rope_theta,
+                             seq_len=max_pos)
     # write new keys/values into the cache at their absolute positions
     oh = jax.nn.one_hot(pos_ids, ck.shape[1], dtype=ck.dtype)  # [B,T,S_max]
     ck = ck * (1 - oh.sum(1)[:, :, None, None]) + jnp.einsum(
@@ -776,8 +628,9 @@ def _block_with_cache(cfg, h, wl, ck, cv, pos_ids, cache_mask):
         o = jnp.einsum("bhts,bshd->bthd", p, vv.astype(jnp.float32))
         o = o.astype(h.dtype).reshape(B, T, nh * d)
     h = h + o @ wl["self_attn.o_proj"]
-    a2 = _rms(h, wl["post_attention_layernorm.weight"], cfg.rms_norm_eps)
-    up = _serving_mlp(a2, wl)
+    a2 = rms_norm(h, wl["post_attention_layernorm.weight"],
+                  cfg.rms_norm_eps)
+    up = swiglu(a2, wl["mlp.gate_up_proj"])
     return h + up @ wl["mlp.down_proj"], ck, cv
 
 
@@ -785,6 +638,8 @@ def _forward_with_cache(state, cfg, ids, cache_k, cache_v, cur_len):
     """ids: [B, T] new tokens (T=prompt at prefill, 1 at decode);
     cache_k/v: [L, B, S_max, kvh, d]; cur_len: [B] int32 tokens already
     cached. Returns (logits[B, T, V], cache_k, cache_v)."""
+    from ..kernels.rms_norm import rms_norm
+
     B, T = ids.shape
     S_max = cache_k.shape[2]
     emb = state["model.embed_tokens"]
@@ -802,7 +657,7 @@ def _forward_with_cache(state, cfg, ids, cache_k, cache_v, cur_len):
 
     h, (cache_k, cache_v) = jax.lax.scan(
         body, h, (wls, cache_k, cache_v))
-    h = _rms(h, state["model.norm.weight"], cfg.rms_norm_eps)
+    h = rms_norm(h, state["model.norm.weight"], cfg.rms_norm_eps)
     if "lm_head" in state:
         logits = h @ state["lm_head"]
     else:
@@ -829,24 +684,18 @@ def _block_paged(cfg, h, wl, kp, vp, pos_ids, pg, off, page_table, lens):
     BEFORE this step.
     """
     from ..kernels.paged_attention import paged_decode_attention
-    from ..kernels.rope import apply_rope
+    from ..kernels.rms_norm import rms_norm
+    from ..kernels.rope import fused_qkv_rope
+    from ..kernels.swiglu import swiglu
 
     B = h.shape[0]
     nh, kvh, d = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
-    a = _rms(h, wl["input_layernorm.weight"], cfg.rms_norm_eps)
+    a = rms_norm(h, wl["input_layernorm.weight"], cfg.rms_norm_eps)
     max_pos = max(cfg.max_position_embeddings,
                   page_table.shape[1] * kp.shape[2])
-    if "self_attn.qkv_proj" in wl:     # FLAGS_fused_transformer layout
-        from ..kernels.rope import fused_qkv_rope
-        q, k, v = fused_qkv_rope(a, wl["self_attn.qkv_proj"], nh, kvh, d,
-                                 position_ids=pos_ids, base=cfg.rope_theta,
-                                 seq_len=max_pos)
-    else:
-        q = (a @ wl["self_attn.q_proj"]).reshape(B, 1, nh, d)
-        k = (a @ wl["self_attn.k_proj"]).reshape(B, 1, kvh, d)
-        v = (a @ wl["self_attn.v_proj"]).reshape(B, 1, kvh, d)
-        q, k = apply_rope(q, k, position_ids=pos_ids, base=cfg.rope_theta,
-                          seq_len=max_pos)
+    q, k, v = fused_qkv_rope(a, wl["self_attn.qkv_proj"], nh, kvh, d,
+                             position_ids=pos_ids, base=cfg.rope_theta,
+                             seq_len=max_pos)
     # scatter this token's k/v into page (pg[b], off[b]) — a B-element
     # scatter, not a cache rewrite
     kp = kp.at[:, pg, off].set(jnp.moveaxis(k[:, 0], 1, 0).astype(kp.dtype))
@@ -856,8 +705,9 @@ def _block_paged(cfg, h, wl, kp, vp, pos_ids, pg, off, page_table, lens):
                                scale=1.0 / math.sqrt(d))
     o = o.astype(h.dtype).reshape(B, 1, nh * d)
     h = h + o @ wl["self_attn.o_proj"]
-    a2 = _rms(h, wl["post_attention_layernorm.weight"], cfg.rms_norm_eps)
-    up = _serving_mlp(a2, wl)
+    a2 = rms_norm(h, wl["post_attention_layernorm.weight"],
+                  cfg.rms_norm_eps)
+    up = swiglu(a2, wl["mlp.gate_up_proj"])
     return h + up @ wl["mlp.down_proj"], kp, vp
 
 
@@ -870,6 +720,8 @@ def _decode_step_paged(state, cfg, toks, k_pool, v_pool, page_table, lens,
     already cached; active: bool[B]. Inactive slots write to the scratch
     page and their logits are ignored by the caller.
     Returns (logits[B, V] for the new token, k_pool, v_pool)."""
+    from ..kernels.rms_norm import rms_norm
+
     B = toks.shape[0]
     emb = state["model.embed_tokens"]
     h = jnp.take(emb, toks.astype(jnp.int32), axis=0)[:, None]
@@ -888,7 +740,7 @@ def _decode_step_paged(state, cfg, toks, k_pool, v_pool, page_table, lens,
         return h, (kp, vp)
 
     h, (k_pool, v_pool) = jax.lax.scan(body, h, (wls, k_pool, v_pool))
-    h = _rms(h, state["model.norm.weight"], cfg.rms_norm_eps)
+    h = rms_norm(h, state["model.norm.weight"], cfg.rms_norm_eps)
     if "lm_head" in state:
         logits = h @ state["lm_head"]
     else:
@@ -917,25 +769,18 @@ def _block_ragged(cfg, h, wl, kp, vp, pos, page_ids, offs, page_table,
     per-sequence row metadata (kv_len INCLUDES this step's rows).
     """
     from ..kernels.ragged_paged_attention import ragged_paged_attention
-    from ..kernels.rope import apply_rope
+    from ..kernels.rms_norm import rms_norm
+    from ..kernels.rope import fused_qkv_rope
+    from ..kernels.swiglu import swiglu
 
     T = h.shape[0]
     nh, kvh, d = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
-    a = _rms(h, wl["input_layernorm.weight"], cfg.rms_norm_eps)
+    a = rms_norm(h, wl["input_layernorm.weight"], cfg.rms_norm_eps)
     max_pos = max(cfg.max_position_embeddings,
                   page_table.shape[1] * kp.shape[2])
-    if "self_attn.qkv_proj" in wl:     # FLAGS_fused_transformer layout
-        from ..kernels.rope import fused_qkv_rope
-        q, k, v = fused_qkv_rope(a, wl["self_attn.qkv_proj"], nh, kvh, d,
-                                 position_ids=pos, base=cfg.rope_theta,
-                                 seq_len=max_pos)
-    else:
-        q = (a @ wl["self_attn.q_proj"]).reshape(T, nh, d)
-        k = (a @ wl["self_attn.k_proj"]).reshape(T, kvh, d)
-        v = (a @ wl["self_attn.v_proj"]).reshape(T, kvh, d)
-        q4, k4 = apply_rope(q[None], k[None], position_ids=pos[None],
-                            base=cfg.rope_theta, seq_len=max_pos)
-        q, k = q4[0], k4[0]
+    q, k, v = fused_qkv_rope(a, wl["self_attn.qkv_proj"], nh, kvh, d,
+                             position_ids=pos, base=cfg.rope_theta,
+                             seq_len=max_pos)
     # ONE T-row page scatter per layer (prefill chunks and decode tokens
     # alike); duplicate scratch-page writes from padding rows are benign
     kp = kp.at[:, page_ids, offs].set(jnp.moveaxis(k, 1, 0).astype(kp.dtype))
@@ -943,8 +788,9 @@ def _block_ragged(cfg, h, wl, kp, vp, pos, page_ids, offs, page_table,
     o = ragged_paged_attention(q, kp, vp, q_start, q_len, kv_len,
                                page_table, scale=1.0 / math.sqrt(d))
     h = h + o.astype(h.dtype).reshape(T, nh * d) @ wl["self_attn.o_proj"]
-    a2 = _rms(h, wl["post_attention_layernorm.weight"], cfg.rms_norm_eps)
-    up = _serving_mlp(a2, wl)
+    a2 = rms_norm(h, wl["post_attention_layernorm.weight"],
+                  cfg.rms_norm_eps)
+    up = swiglu(a2, wl["mlp.gate_up_proj"])
     return h + up @ wl["mlp.down_proj"], kp, vp
 
 
@@ -966,6 +812,8 @@ def _ragged_step_paged(state, cfg, toks, pos, k_pool, v_pool, page_ids,
     leading slots — callers mask). The engine verifies draft tokens
     against the greedy argmax at each draft's own position without
     paying lm-head for every prefill-chunk row in the packed batch."""
+    from ..kernels.rms_norm import rms_norm
+
     T = toks.shape[0]
     emb = state["model.embed_tokens"]
     h = jnp.take(emb, toks.astype(jnp.int32), axis=0)        # [T, H]
@@ -978,7 +826,7 @@ def _ragged_step_paged(state, cfg, toks, pos, k_pool, v_pool, page_ids,
         return h, (kp, vp)
 
     h, (k_pool, v_pool) = jax.lax.scan(body, h, (wls, k_pool, v_pool))
-    h = _rms(h, state["model.norm.weight"], cfg.rms_norm_eps)
+    h = rms_norm(h, state["model.norm.weight"], cfg.rms_norm_eps)
     # rank-3 matmul on purpose (both branches): XLA CPU's rank-2 bf16
     # gemm accumulates differently than the batched form every other
     # decode path uses, which flips greedy argmax at bf16 logit ties

@@ -234,8 +234,8 @@ def reset() -> None:
 
 
 # bf16 peak FLOP/s per chip by TPU generation (Google Cloud TPU
-# documentation, one page per generation) — the one table of the repo,
-# bench.py reads it too. Matched as a substring of the normalized
+# documentation, one page per generation) — the one table of the repo.
+# Matched as a substring of the normalized
 # device_kind, most specific tag first. A TPU that is not listed has NO
 # peak: nothing is assumed for it.
 _PEAK = {

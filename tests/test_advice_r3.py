@@ -197,11 +197,10 @@ class TestConfigWarnOnce:
 
 
 def test_kernel_route_kill_switches():
-    """FLAGS_use_fused_ce / FLAGS_use_flash_attention gate the Pallas
-    routes (the on-chip ablation levers; ref: phi kill-switch flags)."""
+    """FLAGS_use_fused_ce gates the Pallas cross-entropy route (the
+    on-chip ablation lever; ref: phi kill-switch flags)."""
     import paddle_tpu as paddle
     from paddle_tpu.kernels import cross_entropy as fck
-    from paddle_tpu.kernels import flash_attention as fa
 
     # defaults: gates defer to the backend check only (False on CPU,
     # but the flag consult must not throw and must honor an override).
@@ -214,15 +213,6 @@ def test_kernel_route_kill_switches():
         assert fck.supported(32000) is False
     finally:
         paddle.set_flags({"FLAGS_use_fused_ce": prior_ce})
-
-    prior_fa = paddle.get_flags(["FLAGS_use_flash_attention"])[
-        "FLAGS_use_flash_attention"]
-    paddle.set_flags({"FLAGS_use_flash_attention": False})
-    try:
-        assert fa.supported((2, 256, 8, 64), (2, 256, 8, 64),
-                            True) is False
-    finally:
-        paddle.set_flags({"FLAGS_use_flash_attention": prior_fa})
 
     # env-string form (the bench/session ablation path) normalizes
     import os

@@ -158,18 +158,6 @@ def test_launcher_pins_its_master_to_the_cpu_and_not_its_workers(
     assert env["JAX_PLATFORMS"] == "tpu" and "PYTHONPATH" not in env
 
 
-def test_bench_refuses_a_cpu_and_writes_nothing(tmp_path):
-    before = set(os.listdir(os.path.join(ROOT, "benchmarks")))
-    p = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "bench.py")],
-        capture_output=True, text=True, cwd=tmp_path, timeout=300,
-        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT))
-    assert p.returncode != 0 and p.stdout == ""
-    assert "needs a TPU" in p.stderr
-    assert os.listdir(tmp_path) == []
-    assert set(os.listdir(os.path.join(ROOT, "benchmarks"))) == before
-
-
 def test_train_step_lowers_the_same_program_in_every_process():
     # optimizer state crosses the jit boundary keyed by parameter name:
     # an id() in a pytree key would name the outputs, and order the

@@ -4,7 +4,7 @@ CPU CI can't execute the Pallas TPU kernel, but it CAN cross-platform-lower
 for the tpu target (jax.export) — so these tests assert the bench-relevant
 models actually hit the Mosaic kernel in their lowered HLO, which is exactly
 the property round 1 lacked. Numerics of the kernel itself are validated on
-the real chip by bench.py / the driver.
+the real chip by chipbench/run.py.
 
 Ref parity anchors: phi/kernels/gpu/flash_attn_kernel.cu (gating),
 python/paddle/nn/functional/flash_attention.py:147 (API).

@@ -106,28 +106,59 @@ def test_under_jit_and_grad_through_matmul():
                                rtol=1e-4)
 
 
-def test_llama_fusion_checkpoint_translation():
-    """Unfused checkpoints load into fused models and vice versa
-    (models/llama.py _translate_fusion_keys)."""
+def _published_layout(model):
+    """The model's state dict as a published checkpoint has it: q_proj /
+    k_proj / v_proj and gate_proj / up_proj, sliced out of the stored
+    qkv_proj and gate_up_proj."""
+    cfg = model.cfg
+    nh, kvh, d = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
+    m = cfg.intermediate_size
+    cuts = {"qkv_proj": (("q_proj", 0, nh * d),
+                         ("k_proj", nh * d, (nh + kvh) * d),
+                         ("v_proj", (nh + kvh) * d, (nh + 2 * kvh) * d)),
+            "gate_up_proj": (("gate_proj", 0, m), ("up_proj", m, 2 * m))}
+    out = {}
+    for key, t in model.state_dict().items():
+        base, _, leaf = key.rpartition(".")
+        if leaf in cuts:
+            for name, lo, hi in cuts[leaf]:
+                out[f"{base}.{name}"] = np.asarray(t.numpy())[..., lo:hi]
+        else:
+            out[key] = t
+    return out
+
+
+def _tiny_llama(seed):
     import paddle_tpu as paddle
     from paddle_tpu.models import llama as L
+    paddle.seed(seed)
+    return L.LlamaForCausalLM(L.llama_tiny(use_recompute=False,
+                                           num_key_value_heads=2))
 
-    def build(fused):
-        cfg = L.llama_tiny(use_recompute=False)
-        cfg.fuse_attention_qkv = fused
-        cfg.fuse_mlp = fused
-        paddle.seed(0)
-        return L.LlamaForCausalLM(cfg)
 
-    unfused = build(False)
-    fused = build(True)
-    missing, unexpected = fused.set_state_dict(dict(unfused.state_dict()))
+def test_llama_published_layout_checkpoint_loads():
+    """A checkpoint with q/k/v_proj and gate/up_proj keys is joined into
+    the stored layout by set_state_dict (models/llama.py
+    _translate_fusion_keys): nothing missing, nothing unexpected, the
+    same logits."""
+    import paddle_tpu as paddle
+    src, dst = _tiny_llama(0), _tiny_llama(1)
+    ckpt = _published_layout(src)
+    assert not any(k.endswith(("qkv_proj", "gate_up_proj")) for k in ckpt)
+    missing, unexpected = dst.set_state_dict(ckpt)
     assert not missing and not unexpected, (missing, unexpected)
-    ids = paddle.to_tensor(np.zeros((1, 16), np.int32))
-    np.testing.assert_allclose(
-        np.asarray(fused(ids).numpy(), np.float32),
-        np.asarray(unfused(ids).numpy(), np.float32), atol=2e-2)
-    # and back: fused checkpoint into an unfused model
-    unfused2 = build(False)
-    missing, unexpected = unfused2.set_state_dict(dict(fused.state_dict()))
-    assert not missing and not unexpected, (missing, unexpected)
+    ids = paddle.to_tensor(np.arange(16, dtype=np.int32)[None])
+    np.testing.assert_array_equal(np.asarray(dst(ids).numpy(), np.float32),
+                                  np.asarray(src(ids).numpy(), np.float32))
+
+
+def test_llama_incomplete_published_checkpoint_is_reported():
+    """q_proj without its k_proj cannot be joined: set_state_dict names
+    the keys it could not place and the wide key it did not get."""
+    dst = _tiny_llama(1)
+    ckpt = _published_layout(_tiny_llama(0))
+    del ckpt["model.layers.0.self_attn.k_proj"]
+    missing, unexpected = dst.set_state_dict(ckpt)
+    assert missing == ["model.layers.0.self_attn.qkv_proj"]
+    assert sorted(unexpected) == ["model.layers.0.self_attn.q_proj",
+                                  "model.layers.0.self_attn.v_proj"]

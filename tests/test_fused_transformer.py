@@ -1,4 +1,4 @@
-"""Fused transformer hot path (FLAGS_fused_transformer; ISSUE 20):
+"""The dense decoder's hot path (ISSUE 20; one path since ISSUE 29):
 fused residual+RMSNorm and SwiGLU Pallas kernels, fused QKV+RoPE
 prologue, remat-policy knob and the donation audit.
 
@@ -20,21 +20,12 @@ import jax.numpy as jnp
 
 import paddle_tpu as paddle
 import paddle_tpu.optimizer as opt
-from paddle_tpu.framework import core
 from paddle_tpu.kernels import fused_norm_residual as fnr
 from paddle_tpu.kernels import rope
 from paddle_tpu.kernels import swiglu as sg
 from paddle_tpu.kernels.rms_norm import rms_norm
 from paddle_tpu.models import llama
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
-
-
-@pytest.fixture
-def fused_flag():
-    """Restore FLAGS_fused_transformer after tests that flip it."""
-    prior = core.get_bool_flag("FLAGS_fused_transformer", True)
-    yield
-    paddle.set_flags({"FLAGS_fused_transformer": prior})
 
 
 # ---------------------------------------------------------------- harness
@@ -155,7 +146,7 @@ class TestFusedNormResidual:
 
     def test_fallback_grads_match_unfused_autodiff(self):
         """The custom bwd vs plain autodiff of the unfused sequence —
-        the tape FLAGS_fused_transformer=0 would build."""
+        the tape LlamaDecoderLayer builds under sequence parallelism."""
         for dtype, rtol, atol in ((jnp.float32, 1e-5, 1e-4),
                                   (jnp.bfloat16, 0.06, 0.5)):
             x = _rand((2, 8, 256), dtype)
@@ -494,72 +485,124 @@ class TestFusedQKVRope:
             assert np.array_equal(np.asarray(g), np.asarray(t))
 
 
-# ----------------------------------------- model-level flag parity
+# ------------------------- the model against a plain float32 decoder
 
-def _tiny_model(seed=0):
+def _tiny_model(seed=0, **kw):
     paddle.seed(seed)
-    cfg = llama_tiny(dtype="float32")
+    cfg = llama_tiny(dtype="float32", **kw)
     return LlamaForCausalLM(cfg)
 
 
-def _loss_and_grads(flag):
-    paddle.set_flags({"FLAGS_fused_transformer": flag})
-    m = _tiny_model()
-    rng = np.random.RandomState(3)
-    ids = paddle.to_tensor(rng.randint(0, 1024, (2, 16)).astype(np.int64))
-    loss = m.loss(ids, ids)
-    loss.backward()
-    grads = {k: np.asarray(p.grad.data)
-             for k, p in m.state_dict().items()
-             if getattr(p, "grad", None) is not None}
-    return float(loss.numpy()), grads
+def _plain_logits(state, cfg, ids):
+    """Pre-norm decoder from the architecture's equations in jax.numpy:
+    no kernel, no cache, nothing of models/llama.py but the state dict's
+    layout (qkv_proj = q | k | v columns, gate_up_proj = gate | up)."""
+    nh, kvh, d = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
+    m, eps = cfg.intermediate_size, cfg.rms_norm_eps
+    T = ids.shape[1]
+
+    def rms(x, w):
+        return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True)
+                                 + eps) * w
+
+    inv = 1.0 / (cfg.rope_theta ** (jnp.arange(0, d, 2) / d))
+    f = jnp.arange(T)[:, None] * inv[None]
+    cos, sin = jnp.cos(f)[None, :, None], jnp.sin(f)[None, :, None]
+
+    def rope(x):
+        x1, x2 = x[..., :d // 2], x[..., d // 2:]
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                               -1)
+
+    x = state["model.embed_tokens"][ids]
+    for i in range(cfg.num_hidden_layers):
+        w = {k[len(f"model.layers.{i}."):]: v for k, v in state.items()
+             if k.startswith(f"model.layers.{i}.")}
+        qkv = rms(x, w["input_layernorm.weight"]) @ w["self_attn.qkv_proj"]
+        q = rope(qkv[..., :nh * d].reshape(-1, T, nh, d))
+        k = rope(qkv[..., nh * d:(nh + kvh) * d].reshape(-1, T, kvh, d))
+        v = qkv[..., (nh + kvh) * d:].reshape(-1, T, kvh, d)
+        k, v = (jnp.repeat(t, nh // kvh, axis=2) for t in (k, v))
+        s = jnp.einsum("bthd,bshd->bhts", q, k) / np.sqrt(d)
+        s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -jnp.inf)
+        o = jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(s, -1), v)
+        x = x + o.reshape(-1, T, nh * d) @ w["self_attn.o_proj"]
+        gu = rms(x, w["post_attention_layernorm.weight"]) \
+            @ w["mlp.gate_up_proj"]
+        x = x + (jax.nn.silu(gu[..., :m]) * gu[..., m:]) @ w["mlp.down_proj"]
+    return rms(x, state["model.norm.weight"]) @ state["lm_head"]
 
 
-class TestModelFlagParity:
-    def test_train_tape_bitwise_on_cpu(self, fused_flag):
-        """Fused path vs FLAGS_fused_transformer=0 — on CPU every fused
-        route falls back to jnp mirrors of the unfused math, so loss
-        AND all grads are bitwise."""
-        loss_on, g_on = _loss_and_grads(True)
-        loss_off, g_off = _loss_and_grads(False)
-        assert loss_on == loss_off
-        assert g_on.keys() == g_off.keys() and g_on
-        for k in g_on:
-            assert np.array_equal(g_on[k], g_off[k]), k
+def _plain_loss(state, cfg, ids):
+    lg = _plain_logits(state, cfg, ids)[:, :-1]
+    tgt = jnp.take_along_axis(lg, ids[:, 1:, None], axis=-1)[..., 0]
+    return jnp.mean(jax.nn.logsumexp(lg, axis=-1) - tgt)
 
-    def test_greedy_serving_tokens_identical(self, fused_flag):
-        rng = np.random.RandomState(5)
-        prompt = rng.randint(0, 1024, (2, 8)).astype(np.int64)
-        toks = {}
-        for flag in (True, False):
-            paddle.set_flags({"FLAGS_fused_transformer": flag})
-            m = _tiny_model()
-            toks[flag] = np.asarray(
-                m.generate(paddle.to_tensor(prompt),
-                           max_new_tokens=6).data)
-        assert np.array_equal(toks[True], toks[False])
 
-    def test_rms_dedupe_routes_through_kernel(self, fused_flag,
-                                              monkeypatch):
-        """Satellite (a): llama's serving _rms is the kernels/rms_norm
-        implementation when the flag is on."""
+class TestModelAgainstPlainDecoder:
+    def test_train_loss_and_grads_match_plain_decoder(self):
+        """Loss and EVERY gradient of llama_tiny (GQA 4/2) against
+        autodiff of the plain decoder."""
+        m = _tiny_model(num_key_value_heads=2)
+        ids = np.random.RandomState(3).randint(0, 1024, (2, 16))
+        t = paddle.to_tensor(ids.astype(np.int64))
+        loss = m.loss(t, t)
+        loss.backward()
+        state = {k: p.data for k, p in m.state_dict().items()}
+        ref, g_ref = jax.jit(jax.value_and_grad(
+            lambda s, i: _plain_loss(s, m.cfg, i)))(state, ids)
+        np.testing.assert_allclose(float(loss.numpy()), float(ref),
+                                   rtol=1e-5)
+        for k, p in m.state_dict().items():
+            assert p.grad is not None, k
+            np.testing.assert_allclose(
+                np.asarray(p.grad.data), np.asarray(g_ref[k]), rtol=2e-3,
+                atol=1e-5 * float(jnp.abs(g_ref[k]).max()) + 1e-8,
+                err_msg=k)
+
+    def test_greedy_generate_matches_plain_decoder(self):
+        """generate() (prefill + decode scan over the KV cache) against
+        greedy decoding without a cache through the plain decoder."""
+        m = _tiny_model(num_key_value_heads=2)
+        prompt = np.random.RandomState(5).randint(0, 1024, (2, 8))
+        got = np.asarray(m.generate(
+            paddle.to_tensor(prompt.astype(np.int64)),
+            max_new_tokens=6).data)
+        state = {k: p.data for k, p in m.state_dict().items()}
+        # one program for every length: the mask is causal, so what
+        # stands after position n - 1 does not reach its logits
+        plain = jax.jit(lambda s, i: _plain_logits(s, m.cfg, i))
+        ids = np.zeros((2, 8 + 6), np.int64)
+        ids[:, :8] = prompt
+        for n in range(8, 8 + 6):
+            ids[:, n] = np.argmax(plain(state, ids)[:, n - 1], -1)
+        assert np.array_equal(got, ids[:, 8:])
+
+    def test_layout_options_are_refused_by_name(self):
+        """The stored layout is not an option: the retired fields are
+        rejected, not silently ignored."""
+        with pytest.raises(TypeError, match="fuse_mlp"):
+            llama.LlamaConfig(fuse_mlp=False)
+        with pytest.raises(TypeError, match="fuse_attention_qkv"):
+            llama_tiny(fuse_attention_qkv=False)
+
+    def test_serving_rms_is_the_kernel_module(self, monkeypatch):
+        """The serving blocks' RMSNorm is kernels/rms_norm.rms_norm: no
+        second implementation in models/llama.py."""
         from paddle_tpu.kernels import rms_norm as rn
         calls = []
         real = rn.rms_norm
 
         def spy(x, w, eps=1e-6):
-            calls.append(1)
+            calls.append(x.shape)
             return real(x, w, eps)
 
         monkeypatch.setattr(rn, "rms_norm", spy)
-        x = _rand((4, 256), jnp.float32)
-        w = jnp.ones((256,), jnp.float32)
-        paddle.set_flags({"FLAGS_fused_transformer": True})
-        on = np.asarray(llama._rms(x, w, 1e-6))
-        assert calls
-        paddle.set_flags({"FLAGS_fused_transformer": False})
-        off = np.asarray(llama._rms(x, w, 1e-6))
-        assert np.array_equal(on, off)
+        m = _tiny_model()
+        m.generate(paddle.to_tensor(np.zeros((1, 4), np.int64)),
+                   max_new_tokens=2)
+        # per trace: two norms a layer (scanned: traced once) + the final
+        assert len(calls) >= 6 and not hasattr(llama, "_rms")
 
 
 # ------------------------------- remat-policy knob + donation audit
@@ -576,11 +619,10 @@ class TestRematPolicyAndDonation:
         with pytest.raises(ValueError, match="remat_policy"):
             resolve("save_everything_twice")
 
-    def test_policies_bitwise_and_donation_clean(self, fused_flag):
+    def test_policies_bitwise_and_donation_clean(self):
         """Remat policies move memory, not values: the same losses.
         Donation audit: the old param buffers are actually consumed
         (donated) and XLA emits no donation-ignored warnings."""
-        paddle.set_flags({"FLAGS_fused_transformer": True})
         rng = np.random.RandomState(11)
         ids = paddle.to_tensor(
             rng.randint(0, 1024, (2, 16)).astype(np.int64))

@@ -394,9 +394,13 @@ def test_serve_cli_end_to_end(model, tmp_path):
         cwd=repo, env=env, stdout=subprocess.PIPE,
         stderr=subprocess.STDOUT, text=True)
     try:
-        line = proc.stdout.readline()
-        m = re.search(r"http://127\.0\.0\.1:(\d+)", line)
-        assert m, f"no startup line: {line!r}"
+        # stderr is merged in: XLA may log (a compile-cache load, say)
+        # before the startup line, as the fleet's supervisor allows for
+        m, seen = None, []
+        while m is None and (line := proc.stdout.readline()):
+            seen.append(line)
+            m = re.search(r"http://127\.0\.0\.1:(\d+)", line)
+        assert m, f"no startup line: {seen!r}"
         port = int(m.group(1))
         ref = _reference_generate(model, [3, 5, 7], 5)
         r = _post(port, {"prompt": [3, 5, 7], "max_new_tokens": 5})

@@ -9,11 +9,18 @@ import paddle_tpu.optimizer as opt
 from paddle_tpu.models.unet import UNet2DConditionModel, unet_tiny
 
 
-def test_unet_forward_shape():
-    cfg = unet_tiny()
+def _eval_model():
+    """In eval() and as ONE compiled program a forward: run eagerly the
+    model is hundreds of one-operation programs, and the cases below
+    would time the CPU compiler."""
     paddle.seed(0)
-    m = UNet2DConditionModel(cfg)
+    m = UNet2DConditionModel(unet_tiny())
     m.eval()
+    return paddle.jit.to_static(m)
+
+
+def test_unet_forward_shape():
+    m = _eval_model()
     x = paddle.to_tensor(np.random.randn(2, 4, 16, 16).astype(np.float32))
     t = paddle.to_tensor(np.array([1, 999], np.int32))
     ctx = paddle.to_tensor(np.random.randn(2, 8, 64).astype(np.float32))
@@ -45,10 +52,7 @@ def test_unet_denoising_trains():
 
 
 def test_unet_cross_attention_uses_context():
-    cfg = unet_tiny()
-    paddle.seed(0)
-    m = UNet2DConditionModel(cfg)
-    m.eval()
+    m = _eval_model()
     x = paddle.to_tensor(np.random.randn(1, 4, 16, 16).astype(np.float32))
     t = paddle.to_tensor(np.array([5], np.int32))
     c1 = paddle.to_tensor(np.random.randn(1, 8, 64).astype(np.float32))

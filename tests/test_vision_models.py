@@ -9,17 +9,23 @@ import paddle_tpu.nn.functional as F
 from paddle_tpu.vision import models as M
 
 
+def _forward(model):
+    """The whole forward as ONE compiled program, in eval(), at the
+    smallest input the five stride-2 stages admit: what is asserted is a
+    shape, and run eagerly a model is hundreds of one-operation programs
+    that time the CPU compiler."""
+    model.eval()
+    x = paddle.to_tensor(np.random.randn(1, 3, 32, 32).astype(np.float32))
+    return paddle.jit.to_static(model)(x)
+
+
 @pytest.mark.parametrize("ctor", [
     M.vgg11, M.mobilenet_v1, M.mobilenet_v2, M.mobilenet_v3_small,
     M.mobilenet_v3_large, M.densenet121,
 ], ids=lambda f: f.__name__)
 def test_model_forward(ctor):
     paddle.seed(0)
-    m = ctor(num_classes=10)
-    m.eval()
-    x = paddle.to_tensor(np.random.randn(1, 3, 64, 64).astype(np.float32))
-    out = m(x)
-    assert out.shape == [1, 10]
+    assert _forward(ctor(num_classes=10)).shape == [1, 10]
 
 
 def test_vgg_backward():
@@ -34,6 +40,4 @@ def test_vgg_backward():
 
 
 def test_mobilenet_v2_scale():
-    m = M.mobilenet_v2(scale=0.5, num_classes=5)
-    x = paddle.to_tensor(np.random.randn(1, 3, 64, 64).astype(np.float32))
-    assert m(x).shape == [1, 5]
+    assert _forward(M.mobilenet_v2(scale=0.5, num_classes=5)).shape == [1, 5]

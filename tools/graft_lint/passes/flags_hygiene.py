@@ -63,7 +63,6 @@ COMPAT_ACCEPTED = {
 # non-package trees whose FLAGS_ references keep a flag alive (harness
 # knobs); scanned textually in finish()
 _EXTERNAL_REF_DIRS = ("tests", "benchmarks", "tools")
-_EXTERNAL_REF_FILES = ("bench.py",)
 
 
 def _docstring_ids(tree) -> Set[int]:
@@ -162,7 +161,7 @@ class FlagsHygienePass(LintPass):
 
     def _external_refs(self) -> Set[str]:
         """Flags referenced from harness trees (tests/, benchmarks/,
-        tools/, bench.py) — textual scan, comments included: a flag a
+        tools/) — textual scan, comments included: a flag a
         test sets is live even if the package reads it via env only."""
         refs: Set[str] = set()
         roots = [self._repo / d for d in _EXTERNAL_REF_DIRS]
@@ -170,7 +169,6 @@ class FlagsHygienePass(LintPass):
         for r in roots:
             if r.is_dir():
                 files.extend(r.rglob("*.py"))
-        files.extend(self._repo / f for f in _EXTERNAL_REF_FILES)
         for f in files:
             if "__pycache__" in f.parts or not f.is_file():
                 continue
