@@ -56,8 +56,11 @@ CPU, head widths that do not tile) the same equations are plain
 depend on the chunk before, a triangular solve, and jax's transpose of
 all that as the backward. The route is decided from the platform and the
 shapes alone. Callers bound the memory of either by the heads they pass
-at once (`models/solar_open2.py` passes a group of heads under
-`jax.checkpoint`, which runs the forward kernel a second time).
+at once: `models/solar_open2.py` passes a group of heads under
+`jax.checkpoint`, which runs the forward kernel a second time, and hands
+it q, k, v as `kernels/short_conv.py`'s `kda_conv_fwd` writes them
+([B, T, heads * d] in the model's dtype, so the reshapes between the two
+kernels move nothing); dq, dk, dv go back to `kda_conv_bwd` the same way.
 
 What is rounded where, on both routes: matmul operands take the dtype of
 `q` (bf16 in a bf16 model, as the MXU would round them anyway; float32

@@ -7,9 +7,10 @@ pattern, each followed by a mixture of experts.
     (`kernels/flash_attention.py`)
   * every other layer: gated delta-rule linear attention ("KDA"): a
     4-tap causal depthwise convolution and SiLU on q, k, v, L2-normalised
-    q and k, a per-channel decay from a low-rank projection, beta in
-    (0, 2), the chunked operator of `kernels/gated_delta_rule.py`, a
-    per-head RMSNorm and a low-rank sigmoid gate
+    q and k (`kernels/short_conv.py`: one kernel a pass), a per-channel
+    decay from a low-rank projection, beta in (0, 2), the chunked
+    operator of `kernels/gated_delta_rule.py`, a per-head RMSNorm and a
+    low-rank sigmoid gate
   * every layer's feed-forward: `nn.DroplessMoE`, sigmoid-routed top-k
     over all the router's experts, computing the experts this model is
     TOLD it holds (`experts_held` from `expert_offset`) plus one shared
@@ -193,18 +194,6 @@ class GatedAttention(Layer):
 
 # -- the linear-attention layer ------------------------------------------------
 
-def _causal_conv_silu(x, w):
-    """x [B, T, C], w [taps, C]: tap j multiplies x_{t - (taps-1) + j}."""
-    taps, T = w.shape[0], x.shape[1]
-    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
-    w = w.astype(jnp.float32)
-    return jax.nn.silu(sum(xp[:, j:j + T] * w[j] for j in range(taps)))
-
-
-def _l2norm(x):
-    return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
-
-
 class KDAttention(Layer):
     """x + the gated delta-rule mixer of RMSNorm(x); see the module
     docstring for why it goes over the heads in groups."""
@@ -247,6 +236,7 @@ class KDAttention(Layer):
         """Heads [g * hg, (g + 1) * hg): their part of the mixer's output,
         [B, T, H] float32 (the groups' parts are summed)."""
         from ..kernels.gated_delta_rule import chunk_gated_delta_rule
+        from ..kernels.short_conv import conv_silu_l2norm
         cfg = self.cfg
         G = self._groups()
         hg, dl = cfg.linear_num_heads // G, cfg.linear_head_dim
@@ -256,11 +246,7 @@ class KDAttention(Layer):
         with scope("kda/proj"):
             pre = xn @ _group_of(wqkv, 3, G, g)          # [B, T, 3 hg dl]
         with scope("kda/conv"):
-            act = _causal_conv_silu(pre, _group_of(wconv, 3, G, g))
-            q, k, v = (act[..., i * hg * dl:(i + 1) * hg * dl].reshape(
-                B, T, hg, dl) for i in range(3))
-            q, k = _l2norm(q).astype(x.dtype), _l2norm(k).astype(x.dtype)
-            v = v.astype(x.dtype)
+            q, k, v = conv_silu_l2norm(pre, _group_of(wconv, 3, G, g), hg)
         with scope("kda/gate"):
             soft = jax.nn.softplus(
                 ((xn @ wdd) @ _group_of(wdu, 1, G, g)).astype(f32)

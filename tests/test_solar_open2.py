@@ -91,6 +91,25 @@ def test_one_layer_of_each_kind_matches_the_reference(kind):
     assert ("linear_attn" in names) == (kind == "kda")
 
 
+def test_kda_layer_through_the_conv_kernels_matches_the_reference(monkeypatch):
+    """A delta-rule layer at the head width the convolution's kernels tile
+    (128; the tiny configuration's 8 never reaches them), two groups of
+    two heads, the kernels in the Pallas interpreter: `KDAttention` is
+    held to the reference through the chip's route too."""
+    from paddle_tpu.kernels import short_conv as sc
+    through, fused = [], sc._fused
+
+    def interpreted(pre, w, heads, _):
+        through.append(pre.shape)
+        return fused(pre, w, heads, True)
+
+    monkeypatch.setattr(sc, "_on_tpu", lambda: True)
+    monkeypatch.setattr(sc, "_fused", interpreted)
+    _against_reference(solar_open2_tiny(
+        num_hidden_layers=1, gqa_layers=(), linear_head_dim=128))
+    assert through and set(through) == {(2, 37, 3 * 2 * 128)}
+
+
 def test_whole_model_matches_the_reference_with_a_share_of_the_experts():
     """Four layers in the published pattern, 3 of 8 experts held from
     expert 2 on: logits, loss and every gradient leaf."""

@@ -31,6 +31,7 @@ from paddle_tpu.kernels import grouped_matmul as gm
 from paddle_tpu.kernels import paged_attention as pa
 from paddle_tpu.kernels import ragged_paged_attention as rpa
 from paddle_tpu.kernels import rms_norm as rn
+from paddle_tpu.kernels import short_conv as sc
 from paddle_tpu.kernels import swiglu as sg
 
 # (hidden, intermediate) of models/llama.py's shipped configs
@@ -61,7 +62,7 @@ def _as_on_the_chip(monkeypatch):
     interpret off), compile at the program's own matmul precision, and
     keep these compiles out of the persistent cache: an entry written
     for a described chip cannot be read back without one."""
-    for mod in (ba, ce, fa, fnr, gdr, gm, pa, rpa, rn, sg):
+    for mod in (ba, ce, fa, fnr, gdr, gm, pa, rpa, rn, sc, sg):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
     from jax.experimental.compilation_cache import compilation_cache as cc
     cache_was = jax.config.jax_enable_compilation_cache
@@ -412,3 +413,28 @@ def test_gated_delta_rule_compiles_for_the_chip(one_chip):
             qkv, qkv, qkv, g, beta).compile().as_text()
     assert _mosaic_calls(remat, "kda_chunk_states_bwd") == 1
     assert _mosaic_calls(remat, "kda_chunk_states") == 2
+
+
+def test_short_conv_compiles_for_the_chip(one_chip):
+    """The delta-rule layer's convolution + SiLU + L2 norm at the cell's
+    own shape (a group of 4 heads x 128, q | k | v side by side), as the
+    model calls it, under a `jax.checkpoint` and in front of something
+    that reads q, k, v again in its backward (here their squares): the
+    forward kernel runs twice and the backward once, XLA is left no
+    convolution and no padded copy, and nothing the size of a float32
+    [T, C] exists: the temporaries are q, k, v and their cotangents in
+    bf16 and dw's partial sums."""
+    T, C, taps = 32768, 1536, 4
+    pre = jax.ShapeDtypeStruct((1, T, C), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((taps, C), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(jax.value_and_grad(
+        lambda p, w_: _sum32(jax.checkpoint(lambda *a: [
+            x * x for x in sc.conv_silu_l2norm(*a, 4)])(p, w_)),
+        argnums=(0, 1))).lower(pre, w).compile()
+    text = compiled.as_text()
+    assert _mosaic_calls(text, "kda_conv_fwd") == 2
+    assert _mosaic_calls(text, "kda_conv_bwd") == 1
+    assert "convolution" not in text and " pad(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        2 * T * C * 2 + taps * 8 * C * 4) * 1.05 < T * C * 4 * 1.1
+
