@@ -35,6 +35,15 @@ and the shapes alone.
 What is rounded where, on both routes: everything between `pre` and the
 one rounding of q, k, v is float32, and so is everything between their
 cotangents and the one rounding of dpre and of dw.
+
+A second form, the state-space layer's (`conv_bias_silu`): the same
+convolution with a bias a channel and no norm, a = silu(y + b), over the
+x | B | C columns together, handed out as one output a part. It is the
+same two kernel bodies (`ssm_conv_fwd`, `ssm_conv_bwd`) over the same
+row-blocked tiles: the bias, the norm and the outputs' widths are
+decided where the kernel is traced, so the delta-rule layer's kernels
+are what they were; the bias's gradient leaves with dw, as the partial
+sums of one more tap that reads ones.
 """
 from __future__ import annotations
 
@@ -48,7 +57,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ._tpu import LANES, SUBLANES, row_block
 from ._tpu import on_tpu as _on_tpu
 
-__all__ = ["conv_silu_l2norm"]
+__all__ = ["conv_silu_l2norm", "conv_bias_silu"]
 
 _F32 = jnp.float32
 _EPS = 1e-6
@@ -58,12 +67,13 @@ _ROWS = 32         # rows a turn of the kernels' inner loop
 
 # -- the plain route -----------------------------------------------------------
 
-def _causal_conv_silu(x, w):
+def _causal_conv_silu(x, w, bias=None):
     """x [B, T, C], w [taps, C]: tap j multiplies x_{t - (taps-1) + j}."""
     taps, T = w.shape[0], x.shape[1]
     xp = jnp.pad(x.astype(_F32), ((0, 0), (taps - 1, 0), (0, 0)))
     w = w.astype(_F32)
-    return jax.nn.silu(sum(xp[:, j:j + T] * w[j] for j in range(taps)))
+    y = sum(xp[:, j:j + T] * w[j] for j in range(taps))
+    return jax.nn.silu(y if bias is None else y + bias.astype(_F32))
 
 
 def _l2norm(x):
@@ -112,9 +122,21 @@ def _act(xs_ref, w, r0, cols):
     return xj, sum(x * w_j for x, w_j in zip(xj, w))
 
 
-def _fwd_kernel(x_ref, w_ref, q_ref, k_ref, v_ref, xs_ref, *, d):
+def _places(widths, d):
+    """(output, its first column) of each tile of d columns of the
+    input, for outputs of `widths` columns side by side."""
+    return [(i, c) for i, n in enumerate(widths) for c in range(0, n, d)]
+
+
+def _fwd_kernel(x_ref, w_ref, *rest, d, normed, bias):
+    """`rest`: the bias [1, C] where there is one, the outputs (the
+    input's columns side by side, the first `normed` of them normalised
+    a tile of d), the scratch."""
+    b_ref = rest[0] if bias else None
+    o_refs, xs_ref = rest[bias:-1], rest[-1]
     R, taps = x_ref.shape[0], w_ref.shape[0]
-    C, n = x_ref.shape[-1], q_ref.shape[-1]
+    C = x_ref.shape[-1]
+    places = _places([o.shape[-1] for o in o_refs], d)
 
     @pl.when(pl.program_id(1) == 0)
     def _():
@@ -123,24 +145,32 @@ def _fwd_kernel(x_ref, w_ref, q_ref, k_ref, v_ref, xs_ref, *, d):
     xs_ref[_HALO:] = x_ref[...].astype(_F32)
 
     def tile(r0):
-        for c in range(0, C, d):
+        for c, (o, at) in zip(range(0, C, d), places):
             cols = slice(c, c + d)
             w = [w_ref[j:j + 1, cols] for j in range(taps)]
             y = _act(xs_ref, w, r0, cols)[1]
+            if bias:
+                y = y + b_ref[:, cols]
             a = y * jax.nn.sigmoid(y)
-            if c < 2 * n:
+            if c < normed:
                 a = a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + _EPS)
-            o_ref = (q_ref, k_ref, v_ref)[c // n]
-            o_ref[pl.ds(r0, _ROWS), c % n:c % n + d] = a.astype(o_ref.dtype)
+            o_refs[o][pl.ds(r0, _ROWS), at:at + d] = a.astype(
+                o_refs[o].dtype)
 
     _tiles(R, tile)
     xs_ref[:_HALO] = xs_ref[R:]
 
 
-def _bwd_kernel(x_ref, halo_ref, w_ref, dq_ref, dk_ref, dv_ref, dx_ref,
-                dw_ref, xs_ref, da_ref, *, d, T):
+def _bwd_kernel(x_ref, halo_ref, w_ref, *rest, d, T, normed, bias):
+    """`rest`: the bias where there is one, the outputs' cotangents, then
+    dx, dw (the bias's gradient in eight rows after the taps') and the
+    two scratches."""
+    b_ref = rest[0] if bias else None
+    ct_refs = rest[bias:-4]
+    dx_ref, dw_ref, xs_ref, da_ref = rest[-4:]
     R, taps = x_ref.shape[0], w_ref.shape[0]
-    C, n = x_ref.shape[-1], dq_ref.shape[-1]
+    C = x_ref.shape[-1]
+    places = _places([ct.shape[-1] for ct in ct_refs], d)
     blk = pl.num_programs(1) - 1 - pl.program_id(1)
 
     @pl.when(pl.program_id(1) == 0)
@@ -160,14 +190,15 @@ def _bwd_kernel(x_ref, halo_ref, w_ref, dq_ref, dk_ref, dv_ref, dx_ref,
         blk > 0, halo_ref[...].astype(_F32)[halo_ref.shape[0] - _HALO:], 0.0)
 
     def tile(r0):
-        for c in range(0, C, d):
+        for c, (o, at) in zip(range(0, C, d), places):
             cols = slice(c, c + d)
             w = [w_ref[j:j + 1, cols] for j in range(taps)]
             xj, y = _act(xs_ref, w, r0, cols)
+            if bias:
+                y = y + b_ref[:, cols]
             s = jax.nn.sigmoid(y)
-            da = (dq_ref, dk_ref, dv_ref)[c // n][
-                pl.ds(r0, _ROWS), c % n:c % n + d].astype(_F32)
-            if c < 2 * n:
+            da = ct_refs[o][pl.ds(r0, _ROWS), at:at + d].astype(_F32)
+            if c < normed:
                 a = y * s
                 r = jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + _EPS)
                 da = r * da - a * (r * r * r * jnp.sum(
@@ -180,10 +211,12 @@ def _bwd_kernel(x_ref, halo_ref, w_ref, dq_ref, dk_ref, dv_ref, dx_ref,
             dx = sum(a_j * w_j for a_j, w_j in zip(_shifted(
                 da_ref, r0, cols, [taps - 1 - j for j in range(taps)]), w))
             dx_ref[pl.ds(r0, _ROWS), cols] = dx.astype(dx_ref.dtype)
-            # dw_j, eight partial sums a column: a sublane each
-            for j, x_j in enumerate(xj):
+            # dw_j, eight partial sums a column: a sublane each; the
+            # bias's are those of a tap that reads ones
+            for j, x_j in enumerate(xj + [None] * bias):
                 dw_ref[j * _HALO:(j + 1) * _HALO, cols] += sum(
-                    (dy * x_j)[lo:lo + _HALO] for lo in range(0, _ROWS, _HALO))
+                    (dy if x_j is None else dy * x_j)[lo:lo + _HALO]
+                    for lo in range(0, _ROWS, _HALO))
 
     _tiles(R, tile, backwards=True)
     da_ref[R:] = da_ref[:_HALO]
@@ -192,41 +225,51 @@ def _bwd_kernel(x_ref, halo_ref, w_ref, dq_ref, dk_ref, dv_ref, dx_ref,
 def _block_rows(T, C, itemsize):
     """Rows of a block of either pass, a multiple of _ROWS: what fits the
     backward's three [rows, C] operands in the model's dtype, each
-    double-buffered, and its two float32 scratches."""
+    double-buffered, and its two float32 scratches; where the fit does
+    not divide T and a block no less than half of it does, that one (a
+    ragged last block costs the backward a select a tile)."""
     R = row_block(T, C * (6 * itemsize + 8))
     if R == T:                     # the whole sequence, and a ragged turn
         return -(-T // _ROWS) * _ROWS
-    return max(_ROWS, R // _ROWS * _ROWS)
+    R = max(_ROWS, R // _ROWS * _ROWS)
+    whole = [r for r in range(R, R // 2, -_ROWS) if T % r == 0]
+    return whole[0] if whole else R
 
 
-# jitted, so that a model's layers share one trace and one lowering
-@functools.partial(jax.jit, static_argnames=("heads", "interpret"))
-def _fused_fwd(pre, w, heads, interpret):
+def _call_fwd(pre, w, bias, widths, d, normed, name, interpret):
+    """The outputs [B, T, width] of the forward kernel: `pre`'s columns
+    side by side, a tile of d columns at a time."""
     B, T, C = pre.shape
-    taps, n = w.shape[0], C // 3
+    taps = w.shape[0]
     R = _block_rows(T, C, pre.dtype.itemsize)
 
     def rows(width):
         return pl.BlockSpec((None, R, width), lambda b, i: (b, i, 0))
 
-    q, k, v = pl.pallas_call(
-        functools.partial(_fwd_kernel, d=n // heads),
+    def whole(n):
+        return pl.BlockSpec((n, C), lambda b, i: (0, 0))
+
+    has_bias = bias is not None
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, d=d, normed=normed, bias=has_bias),
         grid=(B, pl.cdiv(T, R)),
-        in_specs=[rows(C), pl.BlockSpec((taps, C), lambda b, i: (0, 0))],
-        out_specs=[rows(n)] * 3,
-        out_shape=[jax.ShapeDtypeStruct((B, T, n), pre.dtype)] * 3,
+        in_specs=[rows(C), whole(taps)] + [whole(1)] * has_bias,
+        out_specs=[rows(n) for n in widths],
+        out_shape=[jax.ShapeDtypeStruct((B, T, n), pre.dtype)
+                   for n in widths],
         scratch_shapes=[pltpu.VMEM((R + _HALO, C), _F32)],
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret, name="kda_conv_fwd")(pre, w.astype(_F32))
-    return tuple(x.reshape(B, T, heads, -1) for x in (q, k, v)), (pre, w)
+        interpret=interpret, name=name)(
+            pre, w.astype(_F32),
+            *([bias.astype(_F32)[None]] if has_bias else []))
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _fused_bwd(heads, interpret, saved, cts):
-    pre, w = saved
+def _call_bwd(pre, w, bias, cts, d, normed, name, interpret):
+    """(dpre, dw [taps (+ 1 with a bias: its gradient), C] float32) of
+    the backward kernel from the outputs' cotangents [B, T, width]."""
     B, T, C = pre.shape
-    taps, n = w.shape[0], C // 3
+    taps = w.shape[0]
     R = _block_rows(T, C, pre.dtype.itemsize)
     N = pl.cdiv(T, R)
     per = R // SUBLANES        # 16-row blocks of `pre` a block of rows
@@ -234,24 +277,50 @@ def _fused_bwd(heads, interpret, saved, cts):
     def rows(width):
         return pl.BlockSpec((None, R, width), lambda b, i: (b, N - 1 - i, 0))
 
+    def whole(n):
+        return pl.BlockSpec((n, C), lambda b, i: (0, 0))
+
+    has_bias = bias is not None
+    sums = (taps + has_bias) * _HALO
     dpre, dw = pl.pallas_call(
-        functools.partial(_bwd_kernel, d=n // heads, T=T),
+        functools.partial(_bwd_kernel, d=d, T=T, normed=normed,
+                          bias=has_bias),
         grid=(B, N),
         in_specs=[rows(C),
                   pl.BlockSpec((None, SUBLANES, C), lambda b, i: (
                       b, jnp.maximum((N - 1 - i) * per - 1, 0), 0)),
-                  pl.BlockSpec((taps, C), lambda b, i: (0, 0))]
-        + [rows(n)] * 3,
-        out_specs=[rows(C), pl.BlockSpec((None, taps * _HALO, C),
+                  whole(taps)] + [whole(1)] * has_bias
+        + [rows(ct.shape[-1]) for ct in cts],
+        out_specs=[rows(C), pl.BlockSpec((None, sums, C),
                                          lambda b, i: (b, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct(pre.shape, pre.dtype),
-                   jax.ShapeDtypeStruct((B, taps * _HALO, C), _F32)],
+                   jax.ShapeDtypeStruct((B, sums, C), _F32)],
         scratch_shapes=[pltpu.VMEM((R + _HALO, C), _F32)] * 2,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret, name="kda_conv_bwd")(
-            pre, pre, w.astype(_F32), *(x.reshape(B, T, n) for x in cts))
-    dw = dw.reshape(B, taps, _HALO, C).sum((0, 2))
+        interpret=interpret, name=name)(
+            pre, pre, w.astype(_F32),
+            *([bias.astype(_F32)[None]] if has_bias else []), *cts)
+    return dpre, dw.reshape(B, taps + has_bias, _HALO, C).sum((0, 2))
+
+
+# jitted, so that a model's layers share one trace and one lowering
+@functools.partial(jax.jit, static_argnames=("heads", "interpret"))
+def _fused_fwd(pre, w, heads, interpret):
+    B, T, C = pre.shape
+    n = C // 3
+    q, k, v = _call_fwd(pre, w, None, (n, n, n), n // heads, 2 * n,
+                        "kda_conv_fwd", interpret)
+    return tuple(x.reshape(B, T, heads, -1) for x in (q, k, v)), (pre, w)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _fused_bwd(heads, interpret, saved, cts):
+    pre, w = saved
+    B, T, C = pre.shape
+    n = C // 3
+    dpre, dw = _call_bwd(pre, w, None, [x.reshape(B, T, n) for x in cts],
+                         n // heads, 2 * n, "kda_conv_bwd", interpret)
     return dpre, dw.astype(w.dtype)
 
 
@@ -263,6 +332,42 @@ def _fused(pre, w, heads, interpret=False):
 
 
 _fused.defvjp(_fused_fwd, _fused_bwd)
+
+
+# the state-space layer's form: a bias, no norm, the outputs by widths
+
+@functools.partial(jax.jit, static_argnames=("widths", "interpret"))
+def _bias_fwd(pre, w, bias, widths, interpret):
+    outs = _call_fwd(pre, w, bias, widths, LANES, 0, "ssm_conv_fwd",
+                     interpret)
+    return tuple(outs), (pre, w, bias)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _bias_bwd(widths, interpret, saved, cts):
+    pre, w, bias = saved
+    dpre, dw = _call_bwd(pre, w, bias, list(cts), LANES, 0, "ssm_conv_bwd",
+                         interpret)
+    return dpre, dw[:-1].astype(w.dtype), dw[-1].astype(bias.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _fused_bias(pre, w, bias, widths, interpret=False):
+    return _bias_fwd(pre, w, bias, widths, interpret)[0]
+
+
+_fused_bias.defvjp(_bias_fwd, _bias_bwd)
+
+
+def conv_bias_silu(pre, w, bias, widths):
+    """silu(causal conv(pre) + bias), its columns handed out side by side:
+    pre [B, T, C], w [taps, C], bias [C], `widths` a tuple that sums to
+    C. Returns one [B, T, width] a width, in `pre`'s dtype."""
+    if _on_tpu() and all(n % LANES == 0 for n in widths):
+        return _fused_bias(pre, w, bias, tuple(widths), False)
+    a = _causal_conv_silu(pre, w, bias).astype(pre.dtype)
+    edges = [sum(widths[:i]) for i in range(len(widths) + 1)]
+    return tuple(a[..., lo:hi] for lo, hi in zip(edges, edges[1:]))
 
 
 def conv_silu_l2norm(pre, w, heads):

@@ -18,3 +18,7 @@ from .solar_open2 import (  # noqa: F401
     SolarOpen2Config, SolarOpen2ForCausalLM, SolarOpen2Model,
     solar_open2_tiny,
 )
+from .granite_hybrid import (  # noqa: F401
+    GraniteHybridConfig, GraniteHybridForCausalLM, GraniteHybridModel,
+    granite_hybrid_tiny,
+)

@@ -588,12 +588,17 @@ def margin_cross_entropy(logits, label, margin1=1.0, margin2=0.5,
     return loss
 
 
-def _linear_cross_entropy(h, w, labels, block_rows, ignore_index):
+def _linear_cross_entropy(h, w, labels, block_rows, ignore_index,
+                          tied=False, logit_scale=None):
     """Mean cross-entropy of (h @ w) against labels, a block of rows at a
     time: h [N, H], w [H, V], labels [N]. The [N, V] logits never exist:
     a block's are made, reduced and dropped, and the backward makes them
-    again (`jax.checkpoint`)."""
+    again (`jax.checkpoint`). `tied`: w is the embedding table [V, H],
+    multiplied as it is stored (no transposed copy is made);
+    `logit_scale` multiplies the float32 logits (a model that divides
+    them by a constant)."""
     from ...observability.scopes import scope
+    dims = (((1,), (1 if tied else 0,)), ((), ()))
     n, hidden = h.shape
     block = min(block_rows, n)
     pad = -n % block
@@ -604,7 +609,10 @@ def _linear_cross_entropy(h, w, labels, block_rows, ignore_index):
     def rows(args):
         hb, lb = args
         with scope("head"):
-            lg = jnp.matmul(hb, w, preferred_element_type=jnp.float32)
+            lg = jax.lax.dot_general(hb, w, dims,
+                                     preferred_element_type=jnp.float32)
+            if logit_scale is not None:
+                lg = lg * logit_scale
         with scope("loss"):
             valid = lb != ignore_index
             lse = jax.nn.logsumexp(lg, axis=-1)
@@ -621,19 +629,21 @@ def _linear_cross_entropy(h, w, labels, block_rows, ignore_index):
 
 
 def linear_cross_entropy(hidden, weight, labels, block_rows=2048,
-                         ignore_index=-100):
+                         ignore_index=-100, tied=False, logit_scale=None):
     """The output head and its cross-entropy in one: mean over the rows
     whose label is not `ignore_index` of CE(hidden @ weight, labels),
     computed `block_rows` rows at a time so that the logits of a long
     sequence over a large vocabulary are never held whole (at 32768 rows
     x 24576 columns they are 3.2 GB in float32, and as much again for
-    their gradient). hidden [..., H], weight [H, V], labels [...]."""
+    their gradient). hidden [..., H], weight [H, V] (or, `tied`, the
+    embedding table [V, H]), labels [...]; `logit_scale` multiplies the
+    logits."""
     hidden, weight = to_tensor_like(hidden), to_tensor_like(weight)
     lb = unwrap(labels).reshape(-1)
     H = hidden.shape[-1]
 
     def fn(h, w):
         return _linear_cross_entropy(h.reshape(-1, H), w, lb, block_rows,
-                                     ignore_index)
+                                     ignore_index, tied, logit_scale)
 
     return apply_op(fn, hidden, weight, name="linear_cross_entropy")

@@ -26,7 +26,12 @@ COMPONENTS = ("embed", "layers", "norm", "attn/qkv", "attn/rope",
               # parts of a gated delta-rule layer, and of an expert layer
               "attn/gate", "kda/proj", "kda/conv", "kda/gate", "kda/core",
               "kda/out", "moe/router", "moe/dispatch", "moe/experts",
-              "moe/shared", "moe/combine")
+              "moe/shared", "moe/combine",
+              # models/granite_hybrid: the parts of a Mamba-2 layer (proj:
+              # z | xBC | dt; dt: softplus, decay, running sums; norm: the
+              # gate and the norm)
+              "ssm/proj", "ssm/conv", "ssm/dt", "ssm/core", "ssm/norm",
+              "ssm/out")
 # distributed/sharding: collectives the program itself issues
 COLLECTIVES = ("tp/all_reduce", "tp/relayout")
 # jit.TrainStep.__call__: TraceAnnotations, on the profiler's host plane

@@ -2,7 +2,10 @@
 SiLU, the heads' L2 norm; forward and backward), through the Pallas
 interpreter, against the module's plain `jax.numpy` route, and the choice
 between the routes. The plain route itself is held to
-`tests/reference/solar_open2.py` by `tests/test_solar_open2.py`."""
+`tests/reference/solar_open2.py` by `tests/test_solar_open2.py`. The
+state-space layer's form of the same kernels (a bias, no norm, the
+outputs by widths) likewise; its plain route is held to
+`chipbench/reference_granitemoehybrid.py` by `tests/test_granite_hybrid.py`."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -63,3 +66,52 @@ def test_the_route_is_chosen_from_the_platform_and_the_head_width(monkeypatch):
         assert q.shape == k.shape == v.shape == (1, 5, 2, d)
         np.testing.assert_allclose(np.asarray(jnp.sum(q * q, -1)), 1.0,
                                    rtol=1e-4)
+
+
+# -- the state-space layer's form: conv + bias + SiLU, outputs by widths -------
+
+WIDTHS = (256, 128, 128)
+
+
+def _bias_out_and_grads(fn, pre, w, b):
+    """The outputs and the cotangents of `pre`, `w` and the bias, one
+    program."""
+    def run(*a):
+        outs, vjp = jax.vjp(fn, *a)
+        return tuple(outs) + vjp(tuple(
+            jnp.cos(3.0 * o.astype(jnp.float32)).astype(o.dtype)
+            for o in outs))
+    return jax.jit(run)(pre, w, b)
+
+
+@pytest.mark.parametrize("dtype,tol,T", [
+    (jnp.float32, 1e-5, 2 * sc._block_rows(10 ** 6, sum(WIDTHS), 4) + 5),
+    (jnp.bfloat16, 2 ** -7, 37)])
+def test_bias_form_of_the_kernels_matches_the_plain_route(dtype, tol, T):
+    """x | B | C side by side, a bias a channel, no norm: the values of
+    each part and the gradients of `pre`, the taps and the bias."""
+    C = sum(WIDTHS)
+    ks = jax.random.split(jax.random.key(T), 3)
+    pre = jax.random.normal(ks[0], (2, T, C)).astype(dtype)
+    w = jax.random.uniform(ks[1], (4, C), minval=-0.5,
+                           maxval=0.5).astype(dtype)
+    b = (0.3 * jax.random.normal(ks[2], (C,))).astype(dtype)
+    got = _bias_out_and_grads(
+        lambda *a: sc._fused_bias(*a, WIDTHS, True), pre, w, b)
+    want = _bias_out_and_grads(
+        lambda *a: sc.conv_bias_silu(*a, WIDTHS), pre, w, b)
+    assert [x.shape[-1] for x in got[:3]] == list(WIDTHS)
+    for a, e in zip(got, want):
+        assert a.shape == e.shape and a.dtype == e.dtype
+        e = np.asarray(e, np.float32)
+        np.testing.assert_allclose(np.asarray(a, np.float32), e,
+                                   atol=tol * np.abs(e).max())
+
+
+def test_the_bias_moves_the_output_by_silu_of_it():
+    """Zero input: every channel reads silu(bias), at every token."""
+    b = jnp.linspace(-2.0, 2.0, 8)
+    (out,) = sc.conv_bias_silu(jnp.zeros((1, 5, 8)), jnp.ones((4, 8)), b, (8,))
+    np.testing.assert_allclose(np.asarray(out),
+                               np.broadcast_to(jax.nn.silu(b), (1, 5, 8)),
+                               rtol=1e-6)

@@ -32,6 +32,7 @@ from paddle_tpu.kernels import paged_attention as pa
 from paddle_tpu.kernels import ragged_paged_attention as rpa
 from paddle_tpu.kernels import rms_norm as rn
 from paddle_tpu.kernels import short_conv as sc
+from paddle_tpu.kernels import ssd
 from paddle_tpu.kernels import swiglu as sg
 
 # (hidden, intermediate) of models/llama.py's shipped configs
@@ -62,7 +63,7 @@ def _as_on_the_chip(monkeypatch):
     interpret off), compile at the program's own matmul precision, and
     keep these compiles out of the persistent cache: an entry written
     for a described chip cannot be read back without one."""
-    for mod in (ba, ce, fa, fnr, gdr, gm, pa, rpa, rn, sc, sg):
+    for mod in (ba, ce, fa, fnr, gdr, gm, pa, rpa, rn, sc, sg, ssd):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
     from jax.experimental.compilation_cache import compilation_cache as cc
     cache_was = jax.config.jax_enable_compilation_cache
@@ -438,3 +439,80 @@ def test_short_conv_compiles_for_the_chip(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < (
         2 * T * C * 2 + taps * 8 * C * 4) * 1.05 < T * C * 4 * 1.1
 
+
+
+# -- models/granite_hybrid.py at granite-4.0-h-micro-pp4.pretrain-32k's shapes
+
+def test_ssd_compiles_for_the_chip(one_chip):
+    """The chunked state-space operator at the cell's own shape (64 heads
+    of 64, a state of 128, chunks of 256, 32768 tokens), as the model
+    calls it, under a `jax.checkpoint`: each pass is ONE Mosaic kernel
+    (the backward makes the forward again, keeping each chunk's starting
+    state; the first forward's output is not read here, so it is gone),
+    nothing of it is left for XLA as a loop, and nothing the size of L
+    (64 x 128 x 256 x 256 float32: 2.1 GB) exists."""
+    T, H, P, N, Q = 32768, 64, 64, 128, 256
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sds((1, T, H, P)), sds((1, T, H), jnp.float32),
+            sds((1, T, H), jnp.float32), sds((1, T, N)), sds((1, T, N)),
+            sds((H,), jnp.float32))
+    assert ssd._tiles_ok(H, P, N, Q)
+    compiled = jax.jit(jax.grad(lambda *a: _sum32(jax.checkpoint(
+        lambda *b: ssd.ssd_chunk_scan(*b, chunk=Q))(*a)),
+        argnums=tuple(range(6)))).lower(*args).compile()
+    text = compiled.as_text()
+    assert " while(" not in text
+    assert _mosaic_calls(text, "ssd_chunk_scan_bwd") == 1
+    assert _mosaic_calls(text, "ssd_chunk_scan") == 2      # and its _bwd
+    states = T // Q * N * H * P * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        states + 6 * T * H * P * 2) < H * (T // Q) * Q * Q * 4
+
+
+def test_ssm_conv_compiles_for_the_chip(one_chip):
+    """The state-space layer's convolution + bias + SiLU over the 4352
+    x | B | C channels at 32768 tokens, x, B and C handed out as three
+    outputs, under a `jax.checkpoint`: the forward kernel twice, the
+    backward once, no convolution and no padded copy left for XLA."""
+    T, C = 32768, 4352
+    pre = jax.ShapeDtypeStruct((1, T, C), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((4, C), jnp.bfloat16, sharding=one_chip)
+    b = jax.ShapeDtypeStruct((C,), jnp.bfloat16, sharding=one_chip)
+    text = _compile(jax.value_and_grad(
+        lambda *a: _sum32(jax.checkpoint(lambda *c: [
+            x * x for x in sc.conv_bias_silu(*c, (4096, 128, 128))])(*a)),
+        argnums=(0, 1, 2)), pre, w, b)
+    assert _mosaic_calls(text, "ssm_conv_fwd") == 2
+    assert _mosaic_calls(text, "ssm_conv_bwd") == 1
+    assert "convolution" not in text and " pad(" not in text
+
+
+def test_granite_attention_and_tied_head_at_the_cells_shapes(one_chip):
+    """Splash attention at head width 64 (32 query heads over 8 KV heads,
+    32768 tokens, scores times 1/64), never run at a benchmark shape
+    before this cell; and a block of the tied head + loss: 1024 rows
+    against the 100352-row table as it is stored, no transposed copy of
+    it."""
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, kv = sds((1, 32768, 32, 64)), sds((1, 32768, 8, 64))
+    assert fa.supported(q.shape, kv.shape, True)
+    text = _compile(jax.grad(lambda q_, k_, v_: _sum32(
+        fa.flash_attention_bshd(q_, k_, v_, causal=True, scale=0.015625)),
+        argnums=(0, 1, 2)), q, kv, kv)
+    assert "splash_mqa" in text
+    from paddle_tpu.nn.functional.loss import _linear_cross_entropy
+    h, table = sds((4096, 2048)), sds((100352, 2048))
+    labels = sds((4096,), jnp.int32)
+    compiled = jax.jit(jax.grad(
+        lambda h_, t_, l_: _linear_cross_entropy(
+            h_, t_, l_, 1024, -100, tied=True, logit_scale=0.125),
+        argnums=(0, 1))).lower(h, table, labels).compile()
+    # float32 logits of a block and their cotangent, the table's float32
+    # gradient: no second [100352, 2048] beside them
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        4 * 1024 * 100352 * 4 + 100352 * 2048 * 4 * 1.5)
