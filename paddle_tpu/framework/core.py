@@ -174,6 +174,20 @@ def current_remat_policy():
     return _remat_state.policy
 
 
+def remat_keeps(name: str) -> bool:
+    """Whether the policy armed for this trace keeps a value stamped
+    `checkpoint_name(.., name)` across the backward (asked of the policy
+    itself, as jax.checkpoint asks: the name primitive and its name)."""
+    policy = _remat_state.policy
+    if policy is None:
+        return False
+    from jax.ad_checkpoint import Saveable, checkpoint_name
+    stamp = jax.make_jaxpr(lambda a: checkpoint_name(a, name))(0.0).eqns[0]
+    kept = policy(stamp.primitive, *(v.aval for v in stamp.invars),
+                  **stamp.params)
+    return kept is True or kept is Saveable
+
+
 @contextlib.contextmanager
 def remat_policy_guard(policy):
     prev = _remat_state.policy

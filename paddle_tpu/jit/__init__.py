@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from ..framework import core
+from ..kernels.flash_attention import SPLASH_RESIDUALS
 from ..observability import device_events as _devev
 from ..observability import goodput as _goodput
 from ..observability import metrics as _om
@@ -319,16 +320,27 @@ def _zero_sharded_update(model, opt, ef, axis, nranks, stage, cfg, block):
     return new_ef
 
 
+# what TrainStep's default remat policy keeps across the backward, by
+# jax.ad_checkpoint.checkpoint_name: the dense decoder's matmul outputs
+# (models/llama.py and kernels/rope.py stamp them, so norms and
+# activations recompute instead of living through the backward) and what
+# the attention kernel's backward needs from its forward
+# (kernels/flash_attention.py: out and logsumexp, so the forward kernel
+# runs once a step)
+KEPT_CHECKPOINT_NAMES = ("llama_qkv", "llama_attn_o", "llama_swiglu",
+                         "llama_mlp_down", SPLASH_RESIDUALS)
+
+
 def resolve_remat_policy(policy):
     """Map TrainStep's remat_policy= knob onto a jax.checkpoint policy.
 
     None             -> jax.checkpoint's own default (save nothing,
                         recompute everything) — bitwise the pre-knob remat
     "save_matmul_outputs" (the TrainStep default) ->
-                        save_only_these_names over the
-                        checkpoint_name-stamped matmul outputs
-                        (models.llama.MATMUL_CHECKPOINT_NAMES); models
-                        that stamp no names degrade to the save-nothing
+                        save_only_these_names over KEPT_CHECKPOINT_NAMES:
+                        the checkpoint_name-stamped matmul outputs and
+                        the attention kernel's residuals; models that
+                        stamp no names degrade to the save-nothing
                         default
     "nothing"        -> nothing_saveable (explicit recompute-everything)
     "dots"           -> checkpoint_dots (save every unnamed matmul too)
@@ -340,9 +352,8 @@ def resolve_remat_policy(policy):
     if policy is None or callable(policy):
         return policy
     if policy == "save_matmul_outputs":
-        from ..models.llama import MATMUL_CHECKPOINT_NAMES
         return jax.checkpoint_policies.save_only_these_names(
-            *MATMUL_CHECKPOINT_NAMES)
+            *KEPT_CHECKPOINT_NAMES)
     if policy in ("nothing", "recompute_all"):
         return jax.checkpoint_policies.nothing_saveable
     if policy == "dots":
@@ -444,7 +455,8 @@ class TrainStep:
         self._donate = donate
         # jax.checkpoint policy armed while the step traces (consumed by
         # the models' remat sites via core.current_remat_policy). The
-        # default saves the checkpoint_name-stamped matmul outputs so
+        # default saves the checkpoint_name-stamped matmul outputs and
+        # the attention kernel's residuals (KEPT_CHECKPOINT_NAMES) so
         # norms/activations recompute instead of living across the
         # backward; models that stamp no names degrade to
         # jax.checkpoint's save-nothing default — bitwise the old remat

@@ -17,6 +17,18 @@ Three routes, all Pallas:
 
 Block sizes come from the autotune cache (kernels/autotune.py) when a
 sweep has recorded a winner for the shape class, else a 512 heuristic.
+
+What the backward needs from the forward has a name. The splash route
+stamps its forward's `out` and `logsumexp` (the residuals of the kernel's
+`custom_vjp`) with `SPLASH_RESIDUALS`, and `jit.TrainStep`'s default remat
+policy keeps that name (`jit.KEPT_CHECKPOINT_NAMES`): under a
+`jax.checkpoint` armed with it the forward kernel runs once a step and
+the backward kernels read the stored arrays; under `remat_policy=None` or
+"nothing" it runs again in the backward, as everything else does. With
+no surrounding checkpoint, or no differentiation (serving prefill), the
+stamp is an identity. The MHA route (q_heads == kv_heads, jax's older
+flash kernel) takes no name: its forward is recomputed under every
+policy.
 """
 from __future__ import annotations
 
@@ -28,6 +40,9 @@ import jax.numpy as jnp
 
 from ._tpu import on_tpu as _on_tpu
 
+# checkpoint_name of the splash forward's (out, logsumexp): see the
+# module docstring
+SPLASH_RESIDUALS = "splash_residuals"
 # lane width is 128; the kernel pads smaller head dims, profitable down to 64
 _MIN_HEAD_DIM = 64
 _SEQ_ALIGN = 128
@@ -113,7 +128,7 @@ def _splash_gqa(qt, kt, vt, causal, scale, padding_mask, interpret=False,
     mask = sm.MultiHeadMask([mask_cls] * group)
     kernel = sk.make_splash_mqa_single_device(
         mask, block_sizes=_splash_block_sizes(Sq, Sk, D, blocks),
-        interpret=interpret)
+        residual_checkpoint_name=SPLASH_RESIDUALS, interpret=interpret)
     # splash takes pre-scaled q and no sm_scale argument
     qg = (qt * scale).reshape(B, Hk, group, Sq, D)
     seg = None
@@ -265,8 +280,20 @@ def flash_attention_biased(q, k, v, kind, params, causal=False, scale=None,
     return out.astype(q.dtype)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("causal", "scale", "interpret", "blocks"))
+def _note_kept(batch, heads, seq, dim, dtype):
+    """The set-up event `train_step.kept`, once a call of the splash
+    route whose stamped residuals the remat policy armed for this trace
+    keeps: the name and the bytes ONE call holds across the backward
+    (out in the inputs' dtype, logsumexp float32 a row a head; a scan
+    around the call stacks that many a turn)."""
+    from ..framework import core
+    if core.remat_keeps(SPLASH_RESIDUALS):
+        from ..observability import spans
+        spans.setup_event(
+            "train_step.kept", kept=SPLASH_RESIDUALS,
+            bytes=batch * heads * seq * (dim * jnp.dtype(dtype).itemsize + 4))
+
+
 def flash_attention_bshd(q, k, v, causal=False, scale=None,
                          padding_mask=None, bias=None, interpret=False,
                          blocks=None):
@@ -282,6 +309,19 @@ def flash_attention_bshd(q, k, v, causal=False, scale=None,
     handled without materializing a kv repeat on either route (splash-MQA
     when bias is None; per-chunk broadcast otherwise).
     """
+    if bias is None and q.shape[2] != k.shape[2]:
+        B, Sq, Hq, D = q.shape
+        _note_kept(B, Hq, Sq, D, q.dtype)
+    return _bshd(q, k, v, causal=causal, scale=scale,
+                 padding_mask=padding_mask, bias=bias, interpret=interpret,
+                 blocks=blocks)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("causal", "scale", "interpret", "blocks"))
+def _bshd(q, k, v, causal, scale, padding_mask, bias, interpret, blocks):
+    """flash_attention_bshd's body (jitted apart: the event above is noted
+    once a call, a cached trace would note it once a shape)."""
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         SegmentIds, flash_attention)
     if scale is None:
@@ -421,6 +461,7 @@ def flash_attention_packed(q, k, v, seg_q, seg_kv, causal=False,
     kt = jnp.swapaxes(kp, 0, 1)[None]
     vt = jnp.swapaxes(vp, 0, 1)[None]
     if Hq != Hk:
+        _note_kept(1, Hq, qt.shape[2], D, q.dtype)
         out = _splash_gqa(qt, kt, vt, causal, scale, None,
                           segments=(sq[None], sk[None]))
         out = jnp.swapaxes(out[0], 0, 1)[:Tq]

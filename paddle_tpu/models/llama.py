@@ -37,14 +37,6 @@ from ..tensor import Tensor
 __all__ = ["LlamaConfig", "LlamaModel", "LlamaForCausalLM", "llama_tiny",
            "llama_350m", "llama_1b", "llama_7b"]
 
-# matmul outputs stamped with jax.ad_checkpoint.checkpoint_name in the
-# attention and MLP bodies — the name vocabulary that
-# jit.TrainStep's default remat_policy="save_matmul_outputs"
-# (save_only_these_names) keeps across the backward, so norms and
-# activations recompute instead of living through it
-MATMUL_CHECKPOINT_NAMES = ("llama_qkv", "llama_attn_o", "llama_swiglu",
-                           "llama_mlp_down")
-
 
 @dataclass
 class LlamaConfig:

@@ -120,8 +120,10 @@ def _group_of(w, parts, groups, g):
 def _sum_of_groups(group, n, x, ws):
     """sum over g < n of group(g, x, *ws), in x's dtype: one group of heads
     at a time, summed in float32; the backward recomputes a group
-    (`jax.checkpoint`) and keeps none."""
-    run = jax.checkpoint(group)
+    (`jax.checkpoint`) and keeps of it what the armed remat policy names
+    (the attention kernel's out and logsumexp, stacked over the groups by
+    the scan; a delta-rule group stamps nothing)."""
+    run = jax.checkpoint(group, policy=core.current_remat_policy())
 
     def body(acc, g):
         return acc + run(g, x, *ws), None

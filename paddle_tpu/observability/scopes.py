@@ -38,10 +38,13 @@ COLLECTIVES = ("tp/all_reduce", "tp/relayout")
 STEP_SPANS = ("train_step.call_args", "train_step.dispatch",
               "train_step.write_back")
 # set-up events in spans.ring(): lower() and its parts, the trace count,
-# and what jax.monitoring reports of lowering, compiling and the cache
+# what the armed remat policy keeps of a kernel's forward for its backward
+# (kernels/flash_attention: name, bytes a call), and what jax.monitoring
+# reports of lowering, compiling and the cache
 SETUP = ("train_step.lower", "train_step.call_args", "train_step.trace",
-         "train_step.to_mlir", "train_step.traced", "xla.to_mlir",
-         "xla.backend_compile", "xla.cache_hit", "xla.cache_miss")
+         "train_step.to_mlir", "train_step.traced", "train_step.kept",
+         "xla.to_mlir", "xla.backend_compile", "xla.cache_hit",
+         "xla.cache_miss")
 
 _SCOPES = frozenset(COMPONENTS + COLLECTIVES)
 _tl = threading.local()          # .open: scopes open on this thread

@@ -655,6 +655,10 @@ class TestRematPolicyAndDonation:
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=0)
 
     def test_checkpoint_name_stamps_exist(self):
-        assert llama.MATMUL_CHECKPOINT_NAMES == (
+        # the ONE tuple the default policy is built from: the dense
+        # decoder's four matmul outputs and the attention kernel's
+        # residuals (tests/test_kept_residuals.py)
+        assert paddle.jit.KEPT_CHECKPOINT_NAMES == (
             "llama_qkv", "llama_attn_o", "llama_swiglu",
-            "llama_mlp_down")
+            "llama_mlp_down", "splash_residuals")
+        assert not hasattr(llama, "MATMUL_CHECKPOINT_NAMES")

@@ -327,10 +327,13 @@ def test_train_step_names_its_device_operations(topo, cell):
     text = step._compiled.lower(*args).compile().as_text()
     if plan is None:
         # the scanned layer forward, recomputed and backward: splash
-        # forward x 2, dq, dkv; swiglu forward, da, dw; fused add+norm
-        # x 2; rms_norm x 3 (the last norm among them). A change of route
-        # shows here before it shows on the chip
-        assert _mosaic_calls(text, "") == 12, harness.kernels_in(text)
+        # forward ONCE (the default remat policy keeps its out and
+        # logsumexp for dq and dkv: tests/test_kept_residuals.py), dq,
+        # dkv; swiglu forward, da, dw; fused add+norm x 2; rms_norm x 3
+        # (the last norm among them). A change of route shows here
+        # before it shows on the chip
+        assert _mosaic_calls(text, "") == 11, harness.kernels_in(text)
+        assert _mosaic_calls(text, "splash_mqa_fwd") == 1
     assert "tpu_custom_call" in text
     _, total, counts, missed = scope_reduce.text_coverage(text)
     named_missed = [m for m in missed if m[2]]
