@@ -4,13 +4,19 @@ kernel's MQA/GQA + bias support is matched here, flash_attn_kernel.cu
 accepts num_heads_k != num_heads and an attn additive mask).
 
 Three routes, all Pallas:
-- MHA (q_heads == kv_heads): the tuned in-tree TPU flash kernel
+- MHA (q_heads == kv_heads), no window, values as wide as keys: the
+  tuned in-tree TPU flash kernel
   (jax.experimental.pallas.ops.tpu.flash_attention) — online-softmax
   MXU-shaped tiles, native causal block skipping, segment-id padding
   masks, and an additive-bias operand (`ab`) for arbitrary masks.
 - GQA/MQA causal/full without bias: the splash kernel in MQA mode,
   vmapped over kv heads with q grouped [kv_heads, group, Sq, D] — no
-  materialized kv repeat, and block-sparse causal skipping.
+  materialized kv repeat, and block-sparse causal skipping. The same
+  route takes a sliding `window` (a query sees its own position and the
+  window - 1 before it: splash's `LocalMask`, blocks outside the band
+  skipped) and values of another width than the keys (latent attention:
+  keys no-rope | rope, values narrower); with either, MHA goes this way
+  too, as groups of one.
 - GQA with bias: kv heads broadcast to q heads (autodiff sums the kv
   grads over the group), then the MHA route — still the flash kernel,
   never the O(S^2) dense fallback.
@@ -26,9 +32,10 @@ policy keeps that name (`jit.KEPT_CHECKPOINT_NAMES`): under a
 the backward kernels read the stored arrays; under `remat_policy=None` or
 "nothing" it runs again in the backward, as everything else does. With
 no surrounding checkpoint, or no differentiation (serving prefill), the
-stamp is an identity. The MHA route (q_heads == kv_heads, jax's older
-flash kernel) takes no name: its forward is recomputed under every
-policy.
+stamp is an identity. MHA through splash (a window, or values of their
+own width) is stamped like GQA; plain MHA stays on jax's older flash
+kernel, which takes no name: its forward is recomputed under every
+policy, and its callers lower as they did.
 """
 from __future__ import annotations
 
@@ -50,14 +57,16 @@ _SEQ_ALIGN = 128
 
 def supported(q_shape, k_shape, causal_or_none: bool,
               has_padding_mask: bool = False,
-              has_bias: bool = False) -> bool:
+              has_bias: bool = False, v_dim=None, window=None) -> bool:
     """True when flash_attention_bshd will hit a Pallas kernel.
 
     `causal_or_none`: mask is either causal or absent. Arbitrary
     additive masks route through `bias=` (the kernel's ab operand), so
     pass has_bias=True for those instead of returning False. Padding
     masks map to segment ids. GQA/MQA (q_heads a multiple of kv_heads)
-    is first-class.
+    is first-class. `v_dim`: the values' head width where it is not the
+    keys'; `window`: positions a query sees, its own among them (both
+    the splash route's, causal and without bias).
     """
     del has_padding_mask  # handled via segment ids — never gated out
     if not _on_tpu():
@@ -70,6 +79,11 @@ def supported(q_shape, k_shape, causal_or_none: bool,
     # kernel pads D <= 128 up to the lane width; above that it requires an
     # exact multiple of 128 (so 192/320 must take the dense fallback)
     d_ok = (D % 64 == 0) if D <= 128 else (D % 128 == 0)
+    if v_dim is not None and v_dim != D:
+        d_ok = d_ok and not has_bias and (
+            (v_dim % 64 == 0) if v_dim <= 128 else (v_dim % 128 == 0))
+    if window is not None and (has_bias or not causal_or_none):
+        return False
     return (d_ok and Sq % _SEQ_ALIGN == 0 and Sk % _SEQ_ALIGN == 0
             and Hq % Hk == 0)
 
@@ -110,12 +124,14 @@ def _splash_block_sizes(Sq, Sk, D, blocks=None):
 
 
 def _splash_gqa(qt, kt, vt, causal, scale, padding_mask, interpret=False,
-                blocks=None, segments=None):
-    """GQA via splash MQA mode: qt [B, Hq, Sq, D], kt/vt [B, Hk, Sk, D].
-    No kv repeat materializes; the group dim rides the kernel's q-head
-    axis (is_mqa=True shares one kv head across it). `segments` overrides
-    the padding-mask-derived segment ids with explicit (q_seg [B, Sq],
-    kv_seg [B, Sk]) int32 arrays — the packed-varlen route."""
+                blocks=None, segments=None, window=None):
+    """GQA via splash MQA mode: qt [B, Hq, Sq, D], kt [B, Hk, Sk, D], vt
+    [B, Hk, Sk, Dv]. No kv repeat materializes; the group dim rides the
+    kernel's q-head axis (is_mqa=True shares one kv head across it).
+    `segments` overrides the padding-mask-derived segment ids with
+    explicit (q_seg [B, Sq], kv_seg [B, Sk]) int32 arrays — the
+    packed-varlen route. `window` (causal only): a query sees its own
+    position and the window - 1 before it."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk)
     from jax.experimental.pallas.ops.tpu.splash_attention import (
@@ -124,7 +140,11 @@ def _splash_gqa(qt, kt, vt, causal, scale, padding_mask, interpret=False,
     B, Hq, Sq, D = qt.shape
     Hk, Sk = kt.shape[1], kt.shape[2]
     group = Hq // Hk
-    mask_cls = sm.CausalMask((Sq, Sk)) if causal else sm.FullMask((Sq, Sk))
+    if window is not None:
+        mask_cls = sm.LocalMask((Sq, Sk), (window - 1, 0), 0)
+    else:
+        mask_cls = (sm.CausalMask((Sq, Sk)) if causal
+                    else sm.FullMask((Sq, Sk)))
     mask = sm.MultiHeadMask([mask_cls] * group)
     kernel = sk.make_splash_mqa_single_device(
         mask, block_sizes=_splash_block_sizes(Sq, Sk, D, blocks),
@@ -143,8 +163,8 @@ def _splash_gqa(qt, kt, vt, causal, scale, padding_mask, interpret=False,
     run = jax.vmap(  # batch
         jax.vmap(kernel, in_axes=(0, 0, 0, None)),  # kv heads
         in_axes=(0, 0, 0, 0))
-    out = run(qg, kt, vt, seg)  # [B, Hk, group, Sq, D]
-    return out.reshape(B, Hq, Sq, D)
+    out = run(qg, kt, vt, seg)  # [B, Hk, group, Sq, Dv]
+    return out.reshape(B, Hq, Sq, vt.shape[-1])
 
 
 _NEG = -1e30
@@ -294,9 +314,15 @@ def _note_kept(batch, heads, seq, dim, dtype):
             bytes=batch * heads * seq * (dim * jnp.dtype(dtype).itemsize + 4))
 
 
+def _through_splash(q, k, v, window):
+    """Whether a call without bias takes the splash route."""
+    return (q.shape[2] != k.shape[2] or window is not None
+            or v.shape[-1] != q.shape[-1])
+
+
 def flash_attention_bshd(q, k, v, causal=False, scale=None,
                          padding_mask=None, bias=None, interpret=False,
-                         blocks=None):
+                         blocks=None, window=None):
     """[batch, seq, heads, dim] in/out (paddle flash_attn layout).
 
     padding_mask: optional [batch, kv_seq] bool/int array, True/1 = valid
@@ -307,19 +333,25 @@ def flash_attention_bshd(q, k, v, causal=False, scale=None,
     is never materialized, and narrow biases (e.g. [B, 1, 1, Sk]) are
     sliced narrow per chunk. GQA/MQA (q heads a multiple of kv heads) is
     handled without materializing a kv repeat on either route (splash-MQA
-    when bias is None; per-chunk broadcast otherwise).
+    when bias is None; per-chunk broadcast otherwise). window: a causal
+    query sees its own position and the window - 1 before it; v may be
+    narrower or wider than k (both without bias, through splash).
     """
-    if bias is None and q.shape[2] != k.shape[2]:
-        B, Sq, Hq, D = q.shape
-        _note_kept(B, Hq, Sq, D, q.dtype)
+    if window is not None and (bias is not None or not causal):
+        raise ValueError("a window is causal and takes no bias")
+    if bias is None and _through_splash(q, k, v, window):
+        B, Sq, Hq, _ = q.shape
+        _note_kept(B, Hq, Sq, v.shape[-1], q.dtype)
     return _bshd(q, k, v, causal=causal, scale=scale,
                  padding_mask=padding_mask, bias=bias, interpret=interpret,
-                 blocks=blocks)
+                 blocks=blocks, window=window)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "scale", "interpret", "blocks"))
-def _bshd(q, k, v, causal, scale, padding_mask, bias, interpret, blocks):
+    jax.jit, static_argnames=("causal", "scale", "interpret", "blocks",
+                              "window"))
+def _bshd(q, k, v, causal, scale, padding_mask, bias, interpret, blocks,
+          window=None):
     """flash_attention_bshd's body (jitted apart: the event above is noted
     once a call, a cached trace would note it once a shape)."""
     from jax.experimental.pallas.ops.tpu.flash_attention import (
@@ -347,9 +379,9 @@ def _bshd(q, k, v, causal, scale, padding_mask, bias, interpret, blocks):
     B, Hq, Sq, D = qt.shape
     Hk, Sk = kt.shape[1], kt.shape[2]
 
-    if Hq != Hk:
+    if _through_splash(q, k, v, window):
         out = _splash_gqa(qt, kt, vt, causal, scale, padding_mask,
-                          interpret=interpret, blocks=blocks)
+                          interpret=interpret, blocks=blocks, window=window)
         return jnp.swapaxes(out, 1, 2).astype(q.dtype)
 
     seg = None

@@ -22,3 +22,15 @@ from .granite_hybrid import (  # noqa: F401
     GraniteHybridConfig, GraniteHybridForCausalLM, GraniteHybridModel,
     granite_hybrid_tiny,
 )
+
+
+_LAZY = {"Dots3NoteConfig", "Dots3NoteForCausalLM", "Dots3NoteModel",
+         "dots3_note_tiny"}
+
+
+def __getattr__(name):
+    # models/dots3_note is imported when asked for, not with the package
+    if name in _LAZY:
+        from . import dots3_note
+        return getattr(dots3_note, name)
+    raise AttributeError(name)

@@ -3,8 +3,9 @@ holds.
 
 The router scores every token against ALL `num_experts` experts (a
 sigmoid each), keeps the `top_k` largest and normalises their scores
-into weights. This layer holds the experts
-[first_expert, first_expert + experts_held): it sorts the (token,
+into weights (with a selection bias, the largest of score + bias are
+kept and the weights are still the scores' own). This layer holds the
+experts [first_expert, first_expert + experts_held): it sorts the (token,
 expert) pairs routed to them by expert, gathers those tokens' rows into
 one row buffer, runs two grouped matrix products over it (SwiGLU experts,
 `kernels/grouped_matmul.py`), and adds each row back to its token with
@@ -38,22 +39,31 @@ from .layers import Layer
 __all__ = ["DroplessMoE", "dropless_moe", "route_top_k"]
 
 
-def route_top_k(x, w_router, top_k, norm_topk=True, scaling=1.0):
+def route_top_k(x, w_router, top_k, norm_topk=True, scaling=1.0,
+                bias=None):
     """(expert ids [T, k] int32, weights [T, k] f32): sigmoid scores over
     all the router's outputs, accumulated in float32 at full precision (a
     score's rounding picks another expert; bf16 operands multiply
-    exactly, so no float32 copy of x is made), the top-k, normalised."""
+    exactly, so no float32 copy of x is made), the top-k, normalised.
+    With `bias` [experts] the top-k are those of score + bias and the
+    weights still the scores' own (selection bias: it balances the load
+    and carries no gradient)."""
     s = jax.nn.sigmoid(jnp.matmul(
         x, w_router, precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32))
-    top_s, top_i = jax.lax.top_k(s, top_k)
+    if bias is None:
+        top_s, top_i = jax.lax.top_k(s, top_k)
+    else:
+        _, top_i = jax.lax.top_k(
+            s + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+        top_s = jnp.take_along_axis(s, top_i, axis=-1)
     if norm_topk:
         top_s = top_s / jnp.sum(top_s, -1, keepdims=True)
     return top_i.astype(jnp.int32), top_s * scaling
 
 
 def dropless_moe(x, w_router, w_gate_up, w_down, *, first_expert, top_k,
-                 norm_topk=True, scaling=1.0, rows=None):
+                 norm_topk=True, scaling=1.0, rows=None, bias=None):
     """The held experts' part of the routed sum for x [T, H]. w_gate_up
     [E_held, H, 2M] (gate | up), w_down [E_held, M, H]. Returns
     (y [T, H] in x's dtype, rows per held expert [E_held] int32,
@@ -65,7 +75,8 @@ def dropless_moe(x, w_router, w_gate_up, w_down, *, first_expert, top_k,
     rows = -(-pairs // ROW_TILE) * ROW_TILE if rows is None \
         else min(rows, -(-pairs // ROW_TILE) * ROW_TILE)
     with scope("moe/router"):
-        top_i, top_w = route_top_k(x, w_router, top_k, norm_topk, scaling)
+        top_i, top_w = route_top_k(x, w_router, top_k, norm_topk, scaling,
+                                   bias)
     with scope("moe/dispatch"):
         local = top_i - first_expert
         key = jnp.where((local >= 0) & (local < E), local, E).reshape(-1)
@@ -105,7 +116,7 @@ class DroplessMoE(Layer):
     def __init__(self, hidden_size, expert_size, num_experts, top_k,
                  experts_held=None, first_expert=0, shared_experts=1,
                  norm_topk_prob=True, routed_scaling_factor=1.0, rows=None,
-                 dtype=None, std=0.02):
+                 dtype=None, std=0.02, selection_bias=False):
         super().__init__()
         held = num_experts if experts_held is None else experts_held
         if not 0 <= first_expert <= first_expert + held <= num_experts:
@@ -135,6 +146,13 @@ class DroplessMoE(Layer):
             self.shared_down = param((m, h), P(None, None))
         else:
             self.shared_gate_up = self.shared_down = None
+        # a buffer, not a parameter: no gradient reaches it (a trainer
+        # moves it by the experts' load, outside the step)
+        if selection_bias:
+            self.register_buffer("e_score_correction_bias", Tensor(
+                jnp.zeros((num_experts,), jnp.float32)))
+        else:
+            self.e_score_correction_bias = None
         self.register_buffer("expert_tokens",
                              Tensor(jnp.zeros((held,), jnp.int32)))
         self.register_buffer("dropped_pairs", Tensor(jnp.zeros((), jnp.int32)))
@@ -143,13 +161,16 @@ class DroplessMoE(Layer):
                 w_shared_down=None):
         """Raw arrays in, (y, rows per held expert, dropped pairs) out:
         what `forward` records, for a caller that runs the layer inside a
-        `jax.checkpoint` and records the counters outside it."""
+        `jax.checkpoint` and records the counters outside it. A layer
+        built with `selection_bias` reads its buffer as it stands."""
         lead = x.shape[:-1]
         x2 = x.reshape(-1, x.shape[-1])
+        bias = None if self.e_score_correction_bias is None \
+            else self.e_score_correction_bias.data
         y, counts, dropped = dropless_moe(
             x2, w_router, w_gate_up, w_down, first_expert=self.first_expert,
             top_k=self.top_k, norm_topk=self.norm_topk_prob,
-            scaling=self.routed_scaling_factor, rows=self.rows)
+            scaling=self.routed_scaling_factor, rows=self.rows, bias=bias)
         if w_shared_gate_up is not None:
             from ...kernels.swiglu import swiglu
             with scope("moe/shared"):

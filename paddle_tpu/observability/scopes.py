@@ -31,7 +31,14 @@ COMPONENTS = ("embed", "layers", "norm", "attn/qkv", "attn/rope",
               # z | xBC | dt; dt: softplus, decay, running sums; norm: the
               # gate and the norm)
               "ssm/proj", "ssm/conv", "ssm/dt", "ssm/core", "ssm/norm",
-              "ssm/out")
+              "ssm/out",
+              # models/dots3_note: latent attention's projections and
+              # latent norms are attn/qkv, its head gates attn/gate; the
+              # core is named by its kind (both nest in attn/core); the
+              # learned selection's indexer (projections, scores, its
+              # loss and that loss's backward) and the top-k itself
+              "attn/core/selected", "attn/core/window", "attn/index",
+              "attn/select")
 # distributed/sharding: collectives the program itself issues
 COLLECTIVES = ("tp/all_reduce", "tp/relayout")
 # jit.TrainStep.__call__: TraceAnnotations, on the profiler's host plane

@@ -32,6 +32,7 @@ from paddle_tpu.kernels import paged_attention as pa
 from paddle_tpu.kernels import ragged_paged_attention as rpa
 from paddle_tpu.kernels import rms_norm as rn
 from paddle_tpu.kernels import short_conv as sc
+from paddle_tpu.kernels import sparse_select_attention as dsa
 from paddle_tpu.kernels import ssd
 from paddle_tpu.kernels import swiglu as sg
 
@@ -63,7 +64,7 @@ def _as_on_the_chip(monkeypatch):
     interpret off), compile at the program's own matmul precision, and
     keep these compiles out of the persistent cache: an entry written
     for a described chip cannot be read back without one."""
-    for mod in (ba, ce, fa, fnr, gdr, gm, pa, rpa, rn, sc, sg, ssd):
+    for mod in (ba, ce, dsa, fa, fnr, gdr, gm, pa, rpa, rn, sc, sg, ssd):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
     from jax.experimental.compilation_cache import compilation_cache as cc
     cache_was = jax.config.jax_enable_compilation_cache
@@ -519,3 +520,53 @@ def test_granite_attention_and_tied_head_at_the_cells_shapes(one_chip):
     # gradient: no second [100352, 2048] beside them
     assert compiled.memory_analysis().temp_size_in_bytes < (
         4 * 1024 * 100352 * 4 + 100352 * 2048 * 4 * 1.5)
+
+
+def test_dots3_note_kernels_at_the_cells_shapes(one_chip):
+    """The learned selection's kernels at the dots3-note cell's widths (64
+    index heads of 128, a group of 16 heads of 128 | 64 | 128, an int8 mask
+    shared by the heads) over 4096 tokens, and the window layer's route:
+    MHA through splash under a LocalMask of 513, keys 256 wide, values
+    128 (PR 33). Mosaic reads an int8 tile, slices a lane out of the
+    heads' weights and transposes a float32 tile here or nowhere."""
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    S, J, D, H = 4096, 64, 128, 16
+    f32 = jnp.float32
+    text = _compile(dsa.index_scores, sds((S, J, D), f32), sds((S, D), f32),
+                    sds((S, J), f32))
+    assert "dsa_index_scores" in text
+    # the operands' two bfloat16 halves are made by roundings XLA keeps
+    # (a float32 -> bfloat16 -> float32 round trip is dropped inside a
+    # program, and the low half with it: PR 33)
+    assert text.count("reduce-precision") >= 4
+    heads = (sds((H, S, 128)), sds((H, S, 64)), sds((H, S, 128)),
+             sds((S, 64)), sds((H, S, 128)))
+    mask = sds((S, S), jnp.int8)
+    text = _compile(jax.grad(
+        lambda *a: _sum32(dsa.selected_attention(*a, 192 ** -0.5)[0]),
+        argnums=(0, 1, 2, 3, 4)), *heads, mask)
+    assert all(k in text for k in ("dsa_core_fwd", "dsa_core_bwd_dq",
+                                   "dsa_core_bwd_dkv"))
+    text = _compile(
+        lambda qn, qr, kn, kr, lse, m, acc: dsa.head_prob_sum(
+            qn, qr, kn, kr, lse, m, 192 ** -0.5, acc),
+        *heads[:4], sds((H, S), f32), mask, sds((S, S), f32))
+    assert "dsa_head_probs" in text
+    text = _compile(jax.grad(
+        lambda qi, ki, w, sc, m, lse, ps: dsa.indexer_loss(
+            qi, ki, w, sc, m, lse, ps, 128), argnums=(0, 1, 2)),
+        sds((S, J, D), f32), sds((S, D), f32), sds((S, J), f32),
+        sds((S, S), f32), mask, sds((S,), f32), sds((S, S), f32))
+    assert "dsa_index_bwd_dq" in text and "dsa_index_bwd_dk" in text
+    compiled = jax.jit(lambda sc: dsa.select_top_k(sc, 2048)).lower(
+        sds((S, S), f32)).compile()
+    # a block of rows at a time: no second array of the scores' size
+    assert compiled.memory_analysis().temp_size_in_bytes < S * S * 4
+    q, k, v = sds((1, S, 16, 256)), sds((1, S, 16, 256)), sds((1, S, 16, 128))
+    assert fa.supported(q.shape, k.shape, True, v_dim=128, window=513)
+    text = _compile(jax.grad(lambda q_, k_, v_: _sum32(
+        fa.flash_attention_bshd(q_, k_, v_, causal=True, window=513)),
+        argnums=(0, 1, 2)), q, k, v)
+    assert "splash_mqa_fwd" in text and "splash_mqa_dkv" in text
