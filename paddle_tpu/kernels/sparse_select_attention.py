@@ -29,14 +29,21 @@ function of arrays:
   * `selected_attention(qn, qr, kn, kr, v, mask, scale)` -> (o, lse): the
     heads' attention under the mask, a `jax.custom_vjp` over three Pallas
     kernels on a TPU (`dsa_core_fwd`, `dsa_core_bwd_dq`,
-    `dsa_core_bwd_dkv`; online softmax, tiles above the diagonal skipped,
-    the no-rope and rope products apart so that the 64-wide rope key is
-    read once for all heads). Its forward's `o` and `lse` carry the name
+    `dsa_core_bwd_dkv`; online softmax, the no-rope and rope products
+    apart so that the 64-wide rope key is read once for all heads). Its
+    forward's `o` and `lse` carry the name
     `flash_attention.SPLASH_RESIDUALS`: under `TrainStep`'s remat policy
     the forward runs once a step.
   * `head_prob_sum(qn, qr, kn, kr, lse, mask, scale, acc)` -> acc + the sum
     over these heads of their probabilities [S, S] float32
     (`dsa_head_probs`): the indexer's target, a group of heads a call.
+    These four walk the masked [S, S] tile space alike: the grid's tile
+    axis runs over a prefetched list of the causal triangle's (row block,
+    key block) pairs and no others (`_live_tiles`), and a step takes as
+    many of the call's heads as fit a VMEM budget (`_heads_per_step`), a
+    loop over them inside the body: the mask tile and the rope key are
+    fetched, and the mask widened, once for all of them. Each leaves a
+    set-up event `dsa.grid` a trace (`observability/scopes.py`).
   * `indexer_loss(qi, ki, w, scores, mask, lse_i, psum, heads)` -> L_I, a
     `jax.custom_vjp`: its backward is the gradient of L_I with respect to
     the scores, (softmax_S(I) - p) / S on the selected pairs (kept from
@@ -54,6 +61,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -69,6 +77,10 @@ _F32 = jnp.float32
 _BF16 = jnp.bfloat16
 NEG = -1e30
 _VMEM = 64 * 1024 * 1024
+# of it, what a grid step's blocks (in two buffers) and scratch may take: the
+# rest is the body's own [rows, keys] float32 tiles (scores, probabilities,
+# their gradients: 1 MB each at 512 x 512) and Mosaic's
+_STEP_VMEM = 40 * 1024 * 1024
 _NT = (((1,), (1,)), ((), ()))           # a @ b.T
 
 
@@ -263,9 +275,99 @@ def _core_dense(qn, qr, kn, kr, v, mask, scale):
     return jnp.einsum("hts,hsd->htd", p, f(v)).astype(v.dtype), lse
 
 
-def _core_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, mask_ref, o_ref,
-                     lse_ref, m_scr, l_scr, acc_scr, *, scale, bq, bk, nk):
-    i, j = pl.program_id(1), pl.program_id(2)
+def _live_tiles(S, bq, bk, by_keys=False):
+    """The (row block, key block) pairs of the causal triangle, as two
+    int32 lists the kernels' tile axis walks: a row block's tiles side by
+    side, key blocks ascending, or (`by_keys`) a key block's, row blocks
+    ascending. From S and the blocks alone: with top_k keys of a row kept
+    anywhere below the diagonal no tile of the triangle is empty."""
+    nq, nk = S // bq, S // bk
+    if by_keys:
+        pairs = [(i, j) for j in range(nk) for i in range(j * bk // bq, nq)]
+    else:
+        pairs = [(i, j) for i in range(nq)
+                 for j in range(_diag(i, bq, bk) + 1)]
+    rows, keys = zip(*pairs)
+    return np.asarray(rows, np.int32), np.asarray(keys, np.int32)
+
+
+def _vmem_bytes(shape, dtype):
+    """Bytes of one VMEM buffer of a block: its last two dimensions in
+    the dtype's tiles of (8 x 4 / itemsize, 128)."""
+    item = jnp.dtype(dtype).itemsize
+    dims = [1, 1] + [d or 1 for d in shape]
+    pad = lambda n, m: -(-n // m) * m
+    return (math.prod(dims[:-2]) * pad(dims[-2], 32 // item)
+            * pad(dims[-1], LANES) * item)
+
+
+def _heads_per_step(H, step_bytes):
+    """Heads a grid step takes: the most that divide H and whose step,
+    `step_bytes(heads)`, fits `_STEP_VMEM`."""
+    return max(g for g in range(1, H + 1)
+               if H % g == 0 and (g == 1 or step_bytes(g) <= _STEP_VMEM))
+
+
+def _tile_maps(heads_inner=False):
+    """Index maps for a grid (head group, tile), or (tile, head group),
+    whose tile axis walks the prefetched lists: of the heads' row-side
+    blocks, their key-side blocks, the shared rope key, the mask tile and
+    the heads' row vectors [G, 1, rows]."""
+    def at(f):
+        if heads_inner:
+            return lambda t, h, rows, keys: f(h, rows[t], keys[t])
+        return lambda h, t, rows, keys: f(h, rows[t], keys[t])
+    return (at(lambda h, i, j: (h, i, 0)), at(lambda h, i, j: (h, j, 0)),
+            at(lambda h, i, j: (j, 0)), at(lambda h, i, j: (i, j)),
+            at(lambda h, i, j: (h, 0, i)))
+
+
+def _walk(kernel, name, tiles, blocks, H, specs, args, interpret, heads=None,
+          heads_inner=False, aliases=None):
+    """One of the four kernels over its live tiles, several heads a step:
+    `specs(G)` gives (in_specs, out_specs, out_shape, scratch_shapes) for
+    G heads a step, G the most whose blocks fit (`heads`: the tests'),
+    and the set-up event `dsa.grid` says what grid that made."""
+    from ..observability import spans
+
+    def step_bytes(G):
+        ins, outs, shapes, scratch = specs(G)
+        moved = ([(sp.block_shape, a.dtype) for sp, a in zip(ins, args)]
+                 + [(sp.block_shape, o.dtype) for sp, o in zip(outs, shapes)])
+        return (2 * sum(_vmem_bytes(*b) for b in moved)
+                + sum(_vmem_bytes(m.shape, m.dtype) for m in scratch))
+
+    G = heads or _heads_per_step(H, step_bytes)
+    in_specs, out_specs, out_shape, scratch = specs(G)
+    live = len(tiles[0])
+    grid = (live, H // G) if heads_inner else (H // G, live)
+    spans.setup_event("dsa.grid", kernel=name, grid_steps=math.prod(grid),
+                      live_tiles=live, heads_per_step=G, rows=blocks[0],
+                      keys=blocks[1])
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch),
+        out_shape=out_shape, input_output_aliases=aliases or {},
+        compiler_params=_params("parallel", "arbitrary"),
+        name=name, interpret=interpret,
+    )(*tiles, *args)
+
+
+def _each_head(ref, body):
+    """body(g) for the heads of a step's blocks, in ascending order."""
+    def turn(g, carry):
+        body(g)
+        return carry
+    jax.lax.fori_loop(0, ref.shape[0], turn, 0)
+
+
+def _core_fwd_kernel(rows_ref, keys_ref, qn_ref, qr_ref, kn_ref, kr_ref,
+                     v_ref, mask_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
+                     *, scale, bq, bk):
+    t = pl.program_id(1)
+    i, j = rows_ref[t], keys_ref[t]
 
     @pl.when(j == 0)
     def init():
@@ -273,205 +375,189 @@ def _core_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, mask_ref, o_ref,
         l_scr[...] = jnp.zeros(l_scr.shape, _F32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, _F32)
 
-    @pl.when(j <= _diag(i, bq, bk))
-    def run():
-        keep = mask_ref[...].astype(jnp.int32) != 0
-        s = _scores_of(qn_ref[...], qr_ref[...], kn_ref[...], kr_ref[...],
-                       keep, scale)
-        m_prev = m_scr[...]
+    keep = mask_ref[...].astype(jnp.int32) != 0
+    kr = kr_ref[...]
+
+    def run(g):
+        s = _scores_of(qn_ref[g], qr_ref[g], kn_ref[g], kr, keep, scale)
+        m_prev = m_scr[g]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_next[:, :1])
         alpha = jnp.exp(m_prev - m_next)
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
-        m_scr[...] = m_next
-        acc_scr[...] = alpha[:, :1] * acc_scr[...] + jnp.dot(
-            p.astype(v_ref.dtype), v_ref[...], preferred_element_type=_F32)
+        l_scr[g] = alpha * l_scr[g] + jnp.sum(p, axis=1, keepdims=True)
+        m_scr[g] = m_next
+        acc_scr[g] = alpha[:, :1] * acc_scr[g] + jnp.dot(
+            p.astype(v_ref.dtype), v_ref[g], preferred_element_type=_F32)
 
-    @pl.when(j == nk - 1)
+    _each_head(qn_ref, run)
+
+    @pl.when(j == _diag(i, bq, bk))
     def end():
-        l = l_scr[...]
-        o_ref[...] = (acc_scr[...] / l[:, :1]).astype(o_ref.dtype)
-        lse_ref[...] = m_scr[...] + jnp.log(l)
+        def write(g):
+            l = l_scr[g]
+            o_ref[g] = (acc_scr[g] / l[:, :1]).astype(o_ref.dtype)
+            lse_ref[g] = m_scr[g] + jnp.log(l)
+
+        _each_head(qn_ref, write)
 
 
-def _kv_maps(bq, bk):
-    """Index maps of the key-side blocks for grid (head, rows, keys):
-    a block above the diagonal is not fetched (the map repeats the last
-    block the rows see)."""
-    def last(i, j):
-        return jnp.minimum(j, _diag(i, bq, bk))
-    return (lambda h, i, j: (h, last(i, j), 0),
-            lambda h, i, j: (last(i, j), 0),
-            lambda h, i, j: (i, last(i, j)))
-
-
-def _core_fwd_fused(qn, qr, kn, kr, v, mask, scale, interpret=False):
+def _core_fwd_fused(qn, qr, kn, kr, v, mask, scale, interpret=False,
+                    heads=None, rows=512):
+    """`heads` and `rows` (here and below) are the tests': a given number
+    of heads a step, the 256 rows a step of the grid before."""
     H, S, dn = qn.shape
     dr, dv = qr.shape[-1], v.shape[-1]
-    bq, bk = _blocks(S, 256, 512)
-    per_head, shared, mask_map = _kv_maps(bq, bk)
-    o, lse = pl.pallas_call(
-        functools.partial(_core_fwd_kernel, scale=scale, bq=bq, bk=bk,
-                          nk=S // bk),
-        grid=(H, S // bq, S // bk),
-        in_specs=[pl.BlockSpec((None, bq, dn), lambda h, i, j: (h, i, 0)),
-                  pl.BlockSpec((None, bq, dr), lambda h, i, j: (h, i, 0)),
-                  pl.BlockSpec((None, bk, dn), per_head),
-                  pl.BlockSpec((bk, dr), shared),
-                  pl.BlockSpec((None, bk, dv), per_head),
-                  pl.BlockSpec((bq, bk), mask_map)],
-        out_specs=[pl.BlockSpec((None, bq, dv), lambda h, i, j: (h, i, 0)),
-                   pl.BlockSpec((None, bq, LANES),
-                                lambda h, i, j: (h, i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((H, S, dv), v.dtype),
-                   jax.ShapeDtypeStruct((H, S, LANES), _F32)],
-        scratch_shapes=[pltpu.VMEM((bq, LANES), _F32),
-                        pltpu.VMEM((bq, LANES), _F32),
-                        pltpu.VMEM((bq, dv), _F32)],
-        compiler_params=_params("parallel", "parallel", "arbitrary"),
-        name="dsa_core_fwd", interpret=interpret,
-    )(qn, qr, kn, kr, v, mask)
+    bq, bk = _blocks(S, rows, 512)
+    q_row, k_row, shared, tile, _ = _tile_maps()
+
+    def specs(G):
+        return ([pl.BlockSpec((G, bq, dn), q_row),
+                 pl.BlockSpec((G, bq, dr), q_row),
+                 pl.BlockSpec((G, bk, dn), k_row),
+                 pl.BlockSpec((bk, dr), shared),
+                 pl.BlockSpec((G, bk, dv), k_row),
+                 pl.BlockSpec((bq, bk), tile)],
+                [pl.BlockSpec((G, bq, dv), q_row),
+                 pl.BlockSpec((G, bq, LANES), q_row)],
+                [jax.ShapeDtypeStruct((H, S, dv), v.dtype),
+                 jax.ShapeDtypeStruct((H, S, LANES), _F32)],
+                [pltpu.VMEM((G, bq, LANES), _F32),
+                 pltpu.VMEM((G, bq, LANES), _F32),
+                 pltpu.VMEM((G, bq, dv), _F32)])
+
+    o, lse = _walk(
+        functools.partial(_core_fwd_kernel, scale=scale, bq=bq, bk=bk),
+        "dsa_core_fwd", _live_tiles(S, bq, bk), (bq, bk), H, specs,
+        (qn, qr, kn, kr, v, mask), interpret, heads)
     return o, lse[..., 0]
 
 
-def _p_and_ds(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, mask_ref, lse_ref,
-              do_ref, di_ref, scale):
-    keep = mask_ref[...].astype(jnp.int32) != 0
-    s = _scores_of(qn_ref[...], qr_ref[...], kn_ref[...], kr_ref[...], keep,
-                   scale)
-    p = jnp.exp(s - jnp.expand_dims(lse_ref[0], -1))
-    dp = jax.lax.dot_general(do_ref[...], v_ref[...], _NT,
+def _p_and_ds(g, qn_ref, qr_ref, kn_ref, kr, v_ref, keep, lse_ref, do_ref,
+              di_ref, scale):
+    s = _scores_of(qn_ref[g], qr_ref[g], kn_ref[g], kr, keep, scale)
+    p = jnp.exp(s - jnp.expand_dims(lse_ref[g, 0], -1))
+    dp = jax.lax.dot_general(do_ref[g], v_ref[g], _NT,
                              preferred_element_type=_F32)
-    ds = p * (dp - jnp.expand_dims(di_ref[0], -1)) * scale
+    ds = p * (dp - jnp.expand_dims(di_ref[g, 0], -1)) * scale
     return p, ds
 
 
-def _core_dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, mask_ref, lse_ref,
-                    do_ref, di_ref, dqn_ref, dqr_ref, dqn_scr, dqr_scr, *,
-                    scale, bq, bk, nk):
-    i, j = pl.program_id(1), pl.program_id(2)
+def _core_dq_kernel(rows_ref, keys_ref, qn_ref, qr_ref, kn_ref, kr_ref,
+                    v_ref, mask_ref, lse_ref, do_ref, di_ref, dqn_ref,
+                    dqr_ref, dqn_scr, dqr_scr, *, scale, bq, bk):
+    t = pl.program_id(1)
+    i, j = rows_ref[t], keys_ref[t]
 
     @pl.when(j == 0)
     def init():
         dqn_scr[...] = jnp.zeros(dqn_scr.shape, _F32)
         dqr_scr[...] = jnp.zeros(dqr_scr.shape, _F32)
 
-    @pl.when(j <= _diag(i, bq, bk))
-    def run():
-        _, ds = _p_and_ds(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, mask_ref,
+    keep = mask_ref[...].astype(jnp.int32) != 0
+    kr = kr_ref[...]
+
+    def run(g):
+        _, ds = _p_and_ds(g, qn_ref, qr_ref, kn_ref, kr, v_ref, keep,
                           lse_ref, do_ref, di_ref, scale)
         ds = ds.astype(kn_ref.dtype)
-        dqn_scr[...] += jnp.dot(ds, kn_ref[...], preferred_element_type=_F32)
-        dqr_scr[...] += jnp.dot(ds, kr_ref[...], preferred_element_type=_F32)
+        dqn_scr[g] += jnp.dot(ds, kn_ref[g], preferred_element_type=_F32)
+        dqr_scr[g] += jnp.dot(ds, kr, preferred_element_type=_F32)
 
-    @pl.when(j == nk - 1)
+    _each_head(qn_ref, run)
+
+    @pl.when(j == _diag(i, bq, bk))
     def end():
         dqn_ref[...] = dqn_scr[...].astype(dqn_ref.dtype)
         dqr_ref[...] = dqr_scr[...].astype(dqr_ref.dtype)
 
 
-def _core_dkv_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, mask_ref,
-                     lse_ref, do_ref, di_ref, dkn_ref, dv_ref, dkr_ref,
-                     dkn_scr, dv_scr, dkr_scr, *, scale, bq, bk, nq, H):
-    j, h, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+def _core_dkv_kernel(rows_ref, keys_ref, qn_ref, qr_ref, kn_ref, kr_ref,
+                     v_ref, mask_ref, lse_ref, do_ref, di_ref, dkn_ref,
+                     dv_ref, dkr_ref, dkn_scr, dv_scr, dkr_scr, *, scale, bq,
+                     bk, nq):
+    t = pl.program_id(1)
+    i, j = rows_ref[t], keys_ref[t]
 
-    @pl.when((h == 0) & (i == 0))
-    def init_shared():
-        dkr_scr[...] = jnp.zeros(dkr_scr.shape, _F32)
-
-    @pl.when(i == 0)
+    @pl.when(i == (j * bk) // bq)
     def init():
         dkn_scr[...] = jnp.zeros(dkn_scr.shape, _F32)
         dv_scr[...] = jnp.zeros(dv_scr.shape, _F32)
+        dkr_scr[...] = jnp.zeros(dkr_scr.shape, _F32)
 
-    @pl.when(j <= _diag(i, bq, bk))
-    def run():
-        p, ds = _p_and_ds(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, mask_ref,
+    keep = mask_ref[...].astype(jnp.int32) != 0
+    kr = kr_ref[...]
+
+    def run(g):
+        p, ds = _p_and_ds(g, qn_ref, qr_ref, kn_ref, kr, v_ref, keep,
                           lse_ref, do_ref, di_ref, scale)
-        dv_scr[...] += jnp.dot(p.T.astype(do_ref.dtype), do_ref[...],
-                               preferred_element_type=_F32)
+        dv_scr[g] += jnp.dot(p.T.astype(do_ref.dtype), do_ref[g],
+                             preferred_element_type=_F32)
         dst = ds.T.astype(qn_ref.dtype)
-        dkn_scr[...] += jnp.dot(dst, qn_ref[...], preferred_element_type=_F32)
-        dkr_scr[...] += jnp.dot(dst, qr_ref[...], preferred_element_type=_F32)
+        dkn_scr[g] += jnp.dot(dst, qn_ref[g], preferred_element_type=_F32)
+        dkr_scr[...] += jnp.dot(dst, qr_ref[g], preferred_element_type=_F32)
+
+    _each_head(qn_ref, run)
 
     @pl.when(i == nq - 1)
     def end():
         dkn_ref[...] = dkn_scr[...].astype(dkn_ref.dtype)
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
-
-    @pl.when((h == H - 1) & (i == nq - 1))
-    def end_shared():
         dkr_ref[...] = dkr_scr[...]
 
 
 def _core_bwd_fused(qn, qr, kn, kr, v, mask, o, lse, do, scale,
-                    interpret=False):
+                    interpret=False, heads=None, rows=512):
     H, S, dn = qn.shape
     dr, dv = qr.shape[-1], v.shape[-1]
-    bq, bk = _blocks(S, 256, 512)
-    nq, nk = S // bq, S // bk
     di = jnp.sum(o.astype(_F32) * do.astype(_F32), axis=-1)[:, None, :]
-    lse3 = lse[:, None, :]
-    per_head, shared, mask_map = _kv_maps(bq, bk)
-    row = lambda h, i, j: (h, i, 0)
-    vec = lambda h, i, j: (h, 0, i)
-    dqn, dqr = pl.pallas_call(
-        functools.partial(_core_dq_kernel, scale=scale, bq=bq, bk=bk, nk=nk),
-        grid=(H, nq, nk),
-        in_specs=[pl.BlockSpec((None, bq, dn), row),
-                  pl.BlockSpec((None, bq, dr), row),
-                  pl.BlockSpec((None, bk, dn), per_head),
-                  pl.BlockSpec((bk, dr), shared),
-                  pl.BlockSpec((None, bk, dv), per_head),
-                  pl.BlockSpec((bq, bk), mask_map),
-                  pl.BlockSpec((None, 1, bq), vec),
-                  pl.BlockSpec((None, bq, dv), row),
-                  pl.BlockSpec((None, 1, bq), vec)],
-        out_specs=[pl.BlockSpec((None, bq, dn), row),
-                   pl.BlockSpec((None, bq, dr), row)],
-        out_shape=[jax.ShapeDtypeStruct(qn.shape, qn.dtype),
-                   jax.ShapeDtypeStruct(qr.shape, qr.dtype)],
-        scratch_shapes=[pltpu.VMEM((bq, dn), _F32),
-                        pltpu.VMEM((bq, dr), _F32)],
-        compiler_params=_params("parallel", "parallel", "arbitrary"),
-        name="dsa_core_bwd_dq", interpret=interpret,
-    )(qn, qr, kn, kr, v, mask, lse3, do, di)
+    args = (qn, qr, kn, kr, v, mask, lse[:, None, :], do, di)
+    q_row, k_row, shared, tile, vec = _tile_maps()
 
-    # grid (keys, head, rows): the rope key's gradient sums over the heads
-    # too, so the heads turn inside a block of keys; rows before the
-    # block's first are not fetched
-    def first(j, i):
-        return jnp.maximum(i, (j * bk) // bq)
+    def in_specs(G, bq, bk):
+        return [pl.BlockSpec((G, bq, dn), q_row),
+                pl.BlockSpec((G, bq, dr), q_row),
+                pl.BlockSpec((G, bk, dn), k_row),
+                pl.BlockSpec((bk, dr), shared),
+                pl.BlockSpec((G, bk, dv), k_row),
+                pl.BlockSpec((bq, bk), tile),
+                pl.BlockSpec((G, 1, bq), vec),
+                pl.BlockSpec((G, bq, dv), q_row),
+                pl.BlockSpec((G, 1, bq), vec)]
 
-    qrow = lambda j, h, i: (h, first(j, i), 0)
-    qvec = lambda j, h, i: (h, 0, first(j, i))
-    krow = lambda j, h, i: (h, j, 0)
-    dkn, dvv, dkr = pl.pallas_call(
+    bq, bk = _blocks(S, rows, 512)
+    dqn, dqr = _walk(
+        functools.partial(_core_dq_kernel, scale=scale, bq=bq, bk=bk),
+        "dsa_core_bwd_dq", _live_tiles(S, bq, bk), (bq, bk), H,
+        lambda G: (in_specs(G, bq, bk),
+                   [pl.BlockSpec((G, bq, dn), q_row),
+                    pl.BlockSpec((G, bq, dr), q_row)],
+                   [jax.ShapeDtypeStruct(qn.shape, qn.dtype),
+                    jax.ShapeDtypeStruct(qr.shape, qr.dtype)],
+                   [pltpu.VMEM((G, bq, dn), _F32),
+                    pltpu.VMEM((G, bq, dr), _F32)]),
+        args, interpret, heads)
+
+    # a key block's row blocks side by side, 256 rows as before; the rope
+    # key's gradient sums over the heads of a step inside the kernel, and
+    # over the steps' groups of heads here
+    bq, bk = _blocks(S, 256, 512)
+    dkn, dvv, dkr = _walk(
         functools.partial(_core_dkv_kernel, scale=scale, bq=bq, bk=bk,
-                          nq=nq, H=H),
-        grid=(nk, H, nq),
-        in_specs=[pl.BlockSpec((None, bq, dn), qrow),
-                  pl.BlockSpec((None, bq, dr), qrow),
-                  pl.BlockSpec((None, bk, dn), krow),
-                  pl.BlockSpec((bk, dr), lambda j, h, i: (j, 0)),
-                  pl.BlockSpec((None, bk, dv), krow),
-                  pl.BlockSpec((bq, bk), lambda j, h, i: (first(j, i), j)),
-                  pl.BlockSpec((None, 1, bq), qvec),
-                  pl.BlockSpec((None, bq, dv), qrow),
-                  pl.BlockSpec((None, 1, bq), qvec)],
-        out_specs=[pl.BlockSpec((None, bk, dn), krow),
-                   pl.BlockSpec((None, bk, dv), krow),
-                   pl.BlockSpec((bk, dr), lambda j, h, i: (j, 0))],
-        out_shape=[jax.ShapeDtypeStruct(kn.shape, kn.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype),
-                   jax.ShapeDtypeStruct(kr.shape, _F32)],
-        scratch_shapes=[pltpu.VMEM((bk, dn), _F32),
-                        pltpu.VMEM((bk, dv), _F32),
-                        pltpu.VMEM((bk, dr), _F32)],
-        compiler_params=_params("parallel", "arbitrary", "arbitrary"),
-        name="dsa_core_bwd_dkv", interpret=interpret,
-    )(qn, qr, kn, kr, v, mask, lse3, do, di)
-    return dqn, dqr, dkn, dkr.astype(kr.dtype), dvv
+                          nq=S // bq),
+        "dsa_core_bwd_dkv", _live_tiles(S, bq, bk, by_keys=True), (bq, bk),
+        H,
+        lambda G: (in_specs(G, bq, bk),
+                   [pl.BlockSpec((G, bk, dn), k_row),
+                    pl.BlockSpec((G, bk, dv), k_row),
+                    pl.BlockSpec((None, bk, dr), k_row)],
+                   [jax.ShapeDtypeStruct(kn.shape, kn.dtype),
+                    jax.ShapeDtypeStruct(v.shape, v.dtype),
+                    jax.ShapeDtypeStruct((H // G,) + kr.shape, _F32)],
+                   [pltpu.VMEM((G, bk, dn), _F32),
+                    pltpu.VMEM((G, bk, dv), _F32),
+                    pltpu.VMEM((bk, dr), _F32)]),
+        args, interpret, heads)
+    return dqn, dqr, dkn, jnp.sum(dkr, axis=0).astype(kr.dtype), dvv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
@@ -518,59 +604,55 @@ def _head_probs_dense(qn, qr, kn, kr, lse, mask, scale, acc):
     return jnp.sum(p, axis=0) + (0.0 if acc is None else acc)
 
 
-def _head_probs_kernel(qn_ref, qr_ref, kn_ref, kr_ref, lse_ref, mask_ref,
-                       *rest, scale, bq, bk, H):
+def _head_probs_kernel(rows_ref, keys_ref, qn_ref, qr_ref, kn_ref, kr_ref,
+                       lse_ref, mask_ref, *rest, scale):
     acc_ref = rest[0] if len(rest) == 3 else None
     o_ref, scr = rest[-2:]
-    i, j, h = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    live = j <= _diag(i, bq, bk)
+    h = pl.program_id(1)
 
     @pl.when(h == 0)
     def init():
         scr[...] = (jnp.zeros(scr.shape, _F32) if acc_ref is None
                     else acc_ref[...])
 
-    @pl.when(live)
-    def run():
-        keep = mask_ref[...].astype(jnp.int32) != 0
-        s = _scores_of(qn_ref[...], qr_ref[...], kn_ref[...], kr_ref[...],
-                       keep, scale)
-        scr[...] += jnp.exp(s - jnp.expand_dims(lse_ref[0], -1))
+    keep = mask_ref[...].astype(jnp.int32) != 0
+    kr = kr_ref[...]
 
-    @pl.when(h == H - 1)
+    def run(g):
+        s = _scores_of(qn_ref[g], qr_ref[g], kn_ref[g], kr, keep, scale)
+        scr[...] += jnp.exp(s - jnp.expand_dims(lse_ref[g, 0], -1))
+
+    _each_head(qn_ref, run)
+
+    @pl.when(h == pl.num_programs(1) - 1)
     def end():
         o_ref[...] = scr[...]
 
 
 def _head_probs_fused(qn, qr, kn, kr, lse, mask, scale, acc,
-                      interpret=False):
+                      interpret=False, heads=None, rows=512):
     H, S, dn = qn.shape
     dr = qr.shape[-1]
-    bq, bk = _blocks(S, 256, 512)
-
-    def last(i, j):
-        return jnp.minimum(j, _diag(i, bq, bk))
-
-    tile = pl.BlockSpec((bq, bk), lambda i, j, h: (i, j))
+    bq, bk = _blocks(S, rows, 512)
+    q_row, k_row, shared, tile, vec = _tile_maps(heads_inner=True)
+    tile = pl.BlockSpec((bq, bk), tile)
     more = () if acc is None else (acc,)        # None: start from nothing
-    return pl.pallas_call(
-        functools.partial(_head_probs_kernel, scale=scale, bq=bq, bk=bk,
-                          H=H),
-        grid=(S // bq, S // bk, H),
-        in_specs=[pl.BlockSpec((None, bq, dn), lambda i, j, h: (h, i, 0)),
-                  pl.BlockSpec((None, bq, dr), lambda i, j, h: (h, i, 0)),
-                  pl.BlockSpec((None, bk, dn),
-                               lambda i, j, h: (h, last(i, j), 0)),
-                  pl.BlockSpec((bk, dr), lambda i, j, h: (last(i, j), 0)),
-                  pl.BlockSpec((None, 1, bq), lambda i, j, h: (h, 0, i)),
-                  tile] + [tile] * len(more),
-        out_specs=tile,
-        out_shape=jax.ShapeDtypeStruct((S, S), _F32),
-        scratch_shapes=[pltpu.VMEM((bq, bk), _F32)],
-        input_output_aliases={6: 0} if more else {},
-        compiler_params=_params("parallel", "parallel", "arbitrary"),
-        name="dsa_head_probs", interpret=interpret,
-    )(qn, qr, kn, kr, lse[:, None, :], mask, *more)
+    [out] = _walk(
+        functools.partial(_head_probs_kernel, scale=scale),
+        "dsa_head_probs", _live_tiles(S, bq, bk), (bq, bk), H,
+        lambda G: ([pl.BlockSpec((G, bq, dn), q_row),
+                    pl.BlockSpec((G, bq, dr), q_row),
+                    pl.BlockSpec((G, bk, dn), k_row),
+                    pl.BlockSpec((bk, dr), shared),
+                    pl.BlockSpec((G, 1, bq), vec),
+                    tile] + [tile] * len(more),
+                   [tile], [jax.ShapeDtypeStruct((S, S), _F32)],
+                   [pltpu.VMEM((bq, bk), _F32)]),
+        (qn, qr, kn, kr, lse[:, None, :], mask) + more, interpret, heads,
+        heads_inner=True, aliases={8: 0} if more else None)
+    # tiles above the diagonal are not walked: they keep acc's zeros, and
+    # without acc nothing was written there
+    return out if more else jnp.tril(out)
 
 
 def head_prob_sum(qn, qr, kn, kr, lse, mask, scale, acc, use_pallas=None):

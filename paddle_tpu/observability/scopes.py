@@ -46,11 +46,14 @@ STEP_SPANS = ("train_step.call_args", "train_step.dispatch",
               "train_step.write_back")
 # set-up events in spans.ring(): lower() and its parts, the trace count,
 # what the armed remat policy keeps of a kernel's forward for its backward
-# (kernels/flash_attention: name, bytes a call), and what jax.monitoring
-# reports of lowering, compiling and the cache
+# (kernels/flash_attention: name, bytes a call), the grid a tile-walking
+# kernel of the learned selection was given (kernels/sparse_select_attention:
+# kernel, grid_steps, live_tiles, heads_per_step, rows, keys; once a kernel
+# a trace), and what jax.monitoring reports of lowering, compiling and the
+# cache
 SETUP = ("train_step.lower", "train_step.call_args", "train_step.trace",
          "train_step.to_mlir", "train_step.traced", "train_step.kept",
-         "xla.to_mlir", "xla.backend_compile", "xla.cache_hit",
+         "dsa.grid", "xla.to_mlir", "xla.backend_compile", "xla.cache_hit",
          "xla.cache_miss")
 
 _SCOPES = frozenset(COMPONENTS + COLLECTIVES)

@@ -2,7 +2,9 @@
 exact top-k, attention over the selected keys with its backward, the heads'
 summed probabilities and the indexer's loss. Dense `jax.numpy` route against
 equations written here, and every Pallas kernel through the interpreter
-against the dense route (all interpreter cases share one shape). Beside
+against the dense route (all interpreter cases share one shape), the four
+tile-walking kernels' live-tile grid against the grid they had before
+(`_dsa_parent_grid.py`). Beside
 them the window route of `kernels/flash_attention.py`: splash under a
 `LocalMask`, values narrower than keys, MHA as groups of one."""
 import functools
@@ -259,6 +261,154 @@ def test_indexer_loss_treats_scores_mask_and_target_as_constants(data):
         ps, H, use_pallas=False), argnums=(0, 1))(data["scores"],
                                                   data["psum"])
     assert all(float(jnp.max(jnp.abs(x))) == 0 for x in g)
+
+
+# -- the four tile-walking kernels' grid (ISSUE 35) --------------------------------
+
+@pytest.mark.parametrize("by_keys", [False, True], ids=["by-rows", "by-keys"])
+@pytest.mark.parametrize("size, bq, bk", [
+    (1024, 256, 512), (1024, 512, 512), (2048, 512, 512), (512, 256, 512),
+    (256, 256, 256), (384, 128, 128), (1536, 512, 512), (1024, 512, 128)])
+def test_live_tiles_are_the_causal_triangle_once(size, bq, bk, by_keys):
+    """Every (row block, key block) that holds a pair s <= t is in the
+    prefetched lists exactly once and no other; a row block's (a key
+    block's) tiles are side by side and ascending, which is what lets a
+    kernel initialise at the first and write at the last."""
+    rows, keys = dsa._live_tiles(size, bq, bk, by_keys)
+    assert rows.dtype == keys.dtype == np.int32
+    got = list(zip(rows.tolist(), keys.tolist()))
+    want = {(i, j) for i in range(size // bq) for j in range(size // bk)
+            if j * bk <= i * bq + bq - 1}
+    assert len(got) == len(set(got)) == len(want) and set(got) == want
+    assert got == sorted(got, key=(lambda a: a[::-1]) if by_keys else None)
+    first = [a for a, b in zip(got, [None] + got[:-1])
+             if b is None or a[by_keys] != b[by_keys]]
+    if by_keys:                 # what the dkv kernel takes for first, last
+        assert all(i == j * bk // bq for i, j in first)
+        assert all(a[0] == size // bq - 1 for a, b in zip(
+            got, got[1:] + [None]) if b is None or a[1] != b[1])
+    else:                       # the forward, dq and the probabilities
+        assert all(j == 0 for _, j in first)
+        assert all(a[1] == dsa._diag(a[0], bq, bk) for a, b in zip(
+            got, got[1:] + [None]) if b is None or a[0] != b[0])
+
+
+def test_heads_a_step_divide_the_heads_and_fit_the_budget():
+    """The most heads that divide the call's and whose blocks fit; a block
+    counts in VMEM tiles (a 64-wide bfloat16 row takes 128 lanes, a vector
+    of rows eight sublanes)."""
+    assert dsa._vmem_bytes((16, 512, 64), BF16) == 16 * 512 * 128 * 2
+    assert dsa._vmem_bytes((None, 1, 512), F32) == 8 * 512 * 4
+    assert dsa._vmem_bytes((256, 512), jnp.int8) == 256 * 512
+    for heads in (1, 2, 12, 16):
+        for per_head in (1, 2 << 20, 5 << 20, 50 << 20):
+            step = lambda g: (1 << 20) + g * per_head
+            G = dsa._heads_per_step(heads, step)
+            assert heads % G == 0
+            assert G == 1 or step(G) <= dsa._STEP_VMEM
+            assert all(heads % g or step(g) > dsa._STEP_VMEM
+                       for g in range(G + 1, heads + 1))
+
+
+LEAVES = ("o", "lse", "dqn", "dqr", "dkn", "dkr", "dv", "psum", "psum_fresh")
+
+
+def _walked(mod, d, **kw):
+    """The four kernels of `mod` through the interpreter on d's arrays."""
+    qkv = (d["qn"], d["qr"], d["kn"], d["kr"], d["v"])
+    o, lse = mod._core_fwd_fused(*qkv, d["mask"], SCALE, True, **kw)
+    dqn, dqr, dkn, dkr, dv = mod._core_bwd_fused(
+        *qkv, d["mask"], o, lse, d["do"], SCALE, True, **kw)
+    probs = functools.partial(mod._head_probs_fused, *qkv[:4], lse,
+                              d["mask"], SCALE, interpret=True, **kw)
+    return dict(zip(LEAVES, (o, lse, dqn, dqr, dkn, dkr, dv,
+                             probs(jnp.full(d["mask"].shape, 0.25, F32)),
+                             probs(None))))
+
+
+@functools.lru_cache(maxsize=None)
+def _case(size):
+    """Inputs and the parent's grid over them. 1024: four heads, tiles
+    above the diagonal (row blocks 0 and 1 see key block 0 alone: first
+    and last tile in one step); 256: `data`'s shape, one tile in all."""
+    import _dsa_parent_grid as parent
+    heads = 4 if size == 1024 else H
+    ks = jax.random.split(jax.random.key(size), 7)
+    bf = lambda k, shape: jax.random.normal(k, shape).astype(BF16)
+    d = {"qn": bf(ks[0], (heads, size, DN)), "qr": bf(ks[1], (heads, size, DR)),
+         "kn": bf(ks[2], (heads, size, DN)), "kr": bf(ks[3], (size, DR)),
+         "v": bf(ks[4], (heads, size, DV)), "do": bf(ks[5], (heads, size, DV))}
+    scores = jnp.where(jnp.tril(jnp.ones((size, size), bool)),
+                       jax.random.normal(ks[6], (size, size)), dsa.NEG)
+    d["mask"], _ = dsa.select_top_k(scores, size // 8)
+    return d, _walked(parent, d)
+
+
+@pytest.fixture(scope="module", params=[
+    (1024, 1, 256), (1024, 2, 256), (1024, 4, 256), (1024, 4, 512),
+    (1024, None, 512), (256, 1, 256), (256, 2, 256)],
+    ids=lambda p: "S%d-G%s-rows%d" % p)
+def walked(request):
+    size, heads, rows = request.param
+    d, want = _case(size)
+    return rows, want, _walked(dsa, d, heads=heads, rows=rows)
+
+
+@pytest.mark.parametrize("leaf", LEAVES)
+def test_live_tile_walk_against_the_parents_grid(walked, leaf):
+    """Whatever the heads a step, a row's online softmax sees the same key
+    blocks in the same order: at the parent's 256 rows the forward, dq and
+    the summed probabilities are its bits, at 512 rows to 1e-6 (the CPU's
+    matmul may block another shape another way). dkv keeps its 256 rows:
+    dkn and dv are the parent's bits, the rope key's gradient, summed over
+    the heads in another order, its float32 sum to 1e-6: in bfloat16 the
+    next number at most, and seldom."""
+    rows, want, got = walked
+    a, b = want[leaf], got[leaf]
+    assert a.shape == b.shape and a.dtype == b.dtype
+    top = float(jnp.max(jnp.abs(a.astype(F32))))
+    if leaf != "dkr" and (rows == 256 or leaf in ("dkn", "dv")):
+        assert bool(jnp.all(a == b))
+    elif a.dtype == BF16:
+        assert _gap(a, b) <= 2 ** -8 * top
+        assert float(jnp.mean(a != b)) < 0.01
+    else:
+        assert _gap(a, b) <= 1e-6 * top
+    if leaf == "psum_fresh":        # nothing but zeros above the diagonal
+        assert float(jnp.max(jnp.abs(jnp.triu(b, 1)))) == 0.0
+        assert _gap(b + 0.25, got["psum"]) < 1e-6
+
+
+def test_a_traced_full_layer_takes_no_step_it_skips(monkeypatch):
+    """The `dsa.grid` events of a step's trace: each of the four kernels
+    was given live tiles x head groups steps and none more."""
+    import paddle_tpu as paddle
+    import paddle_tpu.optimizer as popt
+    from paddle_tpu.models.dots3_note import (Dots3NoteForCausalLM,
+                                              dots3_note_tiny)
+    from paddle_tpu.observability import scopes, spans
+    monkeypatch.setattr(dsa, "_on_tpu", lambda: True)
+    paddle.seed(0)
+    model = Dots3NoteForCausalLM(dots3_note_tiny(num_hidden_layers=2))
+    opt = popt.AdamW(learning_rate=1e-3, parameters=model.parameters())
+    step = paddle.jit.TrainStep(model, opt, lambda i, l: model.loss(i, l))
+    x = paddle.to_tensor(np.zeros((1, 1024), np.int32))
+    step._build()
+    spans.clear()
+    step._compiled.trace(*step._call_args((x, x)))
+    events = [ev["attrs"] for ev in spans.ring() if ev["name"] == "dsa.grid"]
+    assert {ev["kernel"] for ev in events} == {
+        "dsa_core_fwd", "dsa_core_bwd_dq", "dsa_core_bwd_dkv",
+        "dsa_head_probs"}
+    heads = model.cfg.head_group
+    for ev in events:
+        n = {k: int(v) for k, v in ev.items() if k != "kernel"}
+        assert n["grid_steps"] * n["heads_per_step"] == n["live_tiles"] * heads
+        assert n["keys"] == 512 and n["rows"] == (
+            256 if ev["kernel"] == "dsa_core_bwd_dkv" else 512)
+        # the triangle of 1024 tokens: 3 of 4 tiles at 512 rows, 6 of 8 at 256
+        assert n["live_tiles"] == (6 if n["rows"] == 256 else 3)
+    assert "dsa.grid" in scopes.SETUP
 
 
 # -- the window route of flash_attention ------------------------------------------

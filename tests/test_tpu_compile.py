@@ -525,7 +525,8 @@ def test_granite_attention_and_tied_head_at_the_cells_shapes(one_chip):
 def test_dots3_note_kernels_at_the_cells_shapes(one_chip):
     """The learned selection's kernels at the dots3-note cell's widths (64
     index heads of 128, a group of 16 heads of 128 | 64 | 128, an int8 mask
-    shared by the heads) over 4096 tokens, and the window layer's route:
+    shared by the heads) over 4096 tokens (the four tile-walking kernels over
+    the cell's 16384), and the window layer's route:
     MHA through splash under a LocalMask of 513, keys 256 wide, values
     128 (PR 33). Mosaic reads an int8 tile, slices a lane out of the
     heads' weights and transposes a float32 tile here or nowhere."""
@@ -541,19 +542,24 @@ def test_dots3_note_kernels_at_the_cells_shapes(one_chip):
     # (a float32 -> bfloat16 -> float32 round trip is dropped inside a
     # program, and the low half with it: PR 33)
     assert text.count("reduce-precision") >= 4
-    heads = (sds((H, S, 128)), sds((H, S, 64)), sds((H, S, 128)),
-             sds((S, 64)), sds((H, S, 128)))
-    mask = sds((S, S), jnp.int8)
+    # the four that walk the masked tiles, at the cell's 16384 tokens: 512
+    # rows and as many heads a step as `_heads_per_step` gives them have to
+    # fit the VMEM limit the file sets (ISSUE 35)
+    L = 16384
+    heads = (sds((H, L, 128)), sds((H, L, 64)), sds((H, L, 128)),
+             sds((L, 64)), sds((H, L, 128)))
     text = _compile(jax.grad(
         lambda *a: _sum32(dsa.selected_attention(*a, 192 ** -0.5)[0]),
-        argnums=(0, 1, 2, 3, 4)), *heads, mask)
+        argnums=(0, 1, 2, 3, 4)), *heads, sds((L, L), jnp.int8))
     assert all(k in text for k in ("dsa_core_fwd", "dsa_core_bwd_dq",
                                    "dsa_core_bwd_dkv"))
-    text = _compile(
-        lambda qn, qr, kn, kr, lse, m, acc: dsa.head_prob_sum(
-            qn, qr, kn, kr, lse, m, 192 ** -0.5, acc),
-        *heads[:4], sds((H, S), f32), mask, sds((S, S), f32))
-    assert "dsa_head_probs" in text
+    for acc in ((sds((L, L), f32),), ()):     # a later group's call, the first
+        text = _compile(
+            lambda qn, qr, kn, kr, lse, m, *acc: dsa.head_prob_sum(
+                qn, qr, kn, kr, lse, m, 192 ** -0.5, *(acc or (None,))),
+            *heads[:4], sds((H, L), f32), sds((L, L), jnp.int8), *acc)
+        assert "dsa_head_probs" in text
+    mask = sds((S, S), jnp.int8)
     text = _compile(jax.grad(
         lambda qi, ki, w, sc, m, lse, ps: dsa.indexer_loss(
             qi, ki, w, sc, m, lse, ps, 128), argnums=(0, 1, 2)),
