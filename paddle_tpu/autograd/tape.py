@@ -17,6 +17,7 @@ sweep with per-node cotangent buffers (ref: GradTensorHolder).
 from __future__ import annotations
 
 import functools
+import math
 from collections import OrderedDict
 from typing import Any, Callable, List, Optional, Sequence
 
@@ -313,13 +314,15 @@ def _dispatch_key(fn, datas, diff_set, name, n_outputs, static_kwargs,
 class GradNode:
     """One recorded op: pullback + input edges (ref: GradNodeBase)."""
 
-    __slots__ = ("vjp_fn", "inputs", "out_meta", "name", "__weakref__")
+    __slots__ = ("vjp_fn", "inputs", "out_meta", "name", "scope",
+                 "__weakref__")
 
-    def __init__(self, vjp_fn, inputs, out_meta, name=""):
+    def __init__(self, vjp_fn, inputs, out_meta, name="", scope=None):
         self.vjp_fn = vjp_fn          # pullback: cotangents -> input cotangents
         self.inputs = inputs           # list[Tensor] (forward inputs, may be None)
         self.out_meta = out_meta       # list[(shape, dtype)] for each output
         self.name = name
+        self.scope = scope             # scopes.carried() when recorded
 
     def __repr__(self):
         return f"<GradNode {self.name}>"
@@ -518,6 +521,7 @@ def apply_op(fn: Callable, *args, n_outputs: int = 1, name: str = "",
         except Exception as e:
             raise _with_op_context(e, name, datas)
         vjp_fn = functools.partial(entry.bwd, dyn_vals)
+        carried = None
     else:
         carried = _scopes.carried()     # see observability/scopes.py
 
@@ -545,7 +549,7 @@ def apply_op(fn: Callable, *args, n_outputs: int = 1, name: str = "",
         if jnp.issubdtype(out.dtype, jnp.floating) or \
                 jnp.issubdtype(out.dtype, jnp.complexfloating):
             node = GradNode(vjp_fn, diff_inputs,
-                            [(out.shape, out.dtype)], name)
+                            [(out.shape, out.dtype)], name, carried)
             t = Tensor(out, stop_gradient=False)
             t._node, t._out_idx = node, 0
         else:
@@ -553,7 +557,8 @@ def apply_op(fn: Callable, *args, n_outputs: int = 1, name: str = "",
         _maybe_record((t,))
         return t
     out = tuple(out)
-    node = GradNode(vjp_fn, diff_inputs, [(o.shape, o.dtype) for o in out], name)
+    node = GradNode(vjp_fn, diff_inputs, [(o.shape, o.dtype) for o in out],
+                    name, carried)
     res = []
     for i, o in enumerate(out):
         t = Tensor(o, stop_gradient=False)
@@ -707,6 +712,48 @@ def _free_graph(t):
                 inp._node = None
         n.vjp_fn = None
         n.inputs = ()
+
+
+def kept_residuals(roots, step_inputs=()):
+    """What the pullbacks reachable from `roots` hold for the backward:
+    ({key: [bytes, arrays]}, state_bytes). A pullback's array leaves
+    (`jax.tree_util.tree_leaves(vjp_fn)`) are what it closes over: under a
+    trace the tracers the forward left it, under `jax.checkpoint` the
+    op's inputs and what the armed policy keeps, under a scanned stack
+    the stacked residuals. Each array counts once, by identity, under
+    the first node that reaches it (nodes are walked from the roots
+    down); `step_inputs` (the step's parameters, buffers, batch) are not
+    residuals and sum to `state_bytes`. A node's key is the vocabulary
+    scope open when it was recorded and its op's name, "scope:op" (the
+    one it has where it lacks the other). Reads shapes only: nothing is
+    computed, and a pullback that is no pytree (a PyLayer's, the eager
+    dispatch cache's) shows nothing."""
+    state = {id(a) for a in step_inputs}
+    seen, visited = set(), set()
+    by_key, state_bytes = {}, 0
+    stack = [t._node for t in roots if t._node is not None]
+    while stack:
+        node = stack.pop()
+        if id(node) in visited:
+            continue
+        visited.add(id(node))
+        for leaf in jax.tree_util.tree_leaves(node.vjp_fn):
+            shape = getattr(leaf, "shape", None)
+            dtype = getattr(leaf, "dtype", None)
+            if shape is None or dtype is None or id(leaf) in seen:
+                continue
+            seen.add(id(leaf))
+            nbytes = math.prod(shape) * getattr(dtype, "itemsize", 0)
+            if id(leaf) in state:
+                state_bytes += nbytes
+                continue
+            key = ":".join(k for k in (node.scope, node.name) if k)
+            cell = by_key.setdefault(key or "unnamed", [0, 0])
+            cell[0] += nbytes
+            cell[1] += 1
+        stack.extend(inp._node for inp in node.inputs
+                     if inp is not None and inp._node is not None)
+    return by_key, state_bytes
 
 
 def grad(outputs, inputs, grad_outputs=None, retain_graph=None,

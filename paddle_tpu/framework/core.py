@@ -458,7 +458,6 @@ _flags: dict = {
     "FLAGS_gemm_use_half_precision_compute_type": True,
     # -- profiling / logging (consumed by jit.TrainStep) ---------------
     "FLAGS_benchmark": False,          # print per-step wall time
-    "FLAGS_log_memory_stats": False,   # print device memory after step
     # -- executor/memory behavior (consumed by jit.TrainStep) ----------
     "FLAGS_max_inplace_grad_add": 0,   # >0 enables buffer donation
     "FLAGS_eager_delete_tensor_gb": 0.0,  # <0 disables donation

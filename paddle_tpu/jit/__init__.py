@@ -16,11 +16,13 @@ from __future__ import annotations
 
 import functools
 import itertools
+import time
 from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
+from ..autograd import tape as _tape
 from ..framework import core
 from ..kernels.flash_attention import SPLASH_RESIDUALS
 from ..observability import device_events as _devev
@@ -364,6 +366,52 @@ def resolve_remat_policy(policy):
         f"jax.checkpoint_policies callable")
 
 
+class _LoweredStep:
+    """What `TrainStep.lower()` hands back: jax's `Lowered` (`as_text`,
+    `cost_analysis`, ... are its own), whose `compile()` also leaves the
+    set-up event `train_step.memory`: the compiler's count of the bytes
+    a device holds while the executable runs."""
+
+    __slots__ = ("_lowered", "_tag", "_devices")
+
+    def __init__(self, lowered, tag, devices):
+        self._lowered, self._tag, self._devices = lowered, tag, devices
+
+    def __getattr__(self, name):
+        return getattr(self._lowered, name)
+
+    def compile(self, *args, **kwargs):
+        compiled = self._lowered.compile(*args, **kwargs)
+        t0 = time.perf_counter()
+        mem = compiled.memory_analysis()
+        if mem is None:                 # a backend that keeps no count
+            return compiled
+        attrs = {k + "_bytes": int(getattr(mem, k + "_size_in_bytes"))
+                 for k in ("argument", "output", "alias", "temp",
+                           "generated_code")}
+        # the terms' sum refuses nothing: `temp_bytes` is the size of the
+        # temporaries' region, not what is live at once (Yi at depth 6
+        # sums to 18.1 GiB and compiles for 15.75; PERF.md section 4).
+        # The compiler's own peak (arguments + the program's fullest
+        # moment) is the number its refusal "used X of Y" prints
+        attrs["sum_bytes"] = (
+            attrs["argument_bytes"] + attrs["output_bytes"]
+            - attrs["alias_bytes"] + attrs["temp_bytes"]
+            + attrs["generated_code_bytes"])
+        attrs["peak_bytes"] = int(getattr(mem, "peak_memory_in_bytes", 0)
+                                  or attrs["sum_bytes"])
+        try:
+            stats = self._devices[0].memory_stats() or {}
+        except RuntimeError:            # a device this process cannot
+            stats = {}                  # address (described, remote)
+        if "bytes_limit" in stats:
+            attrs["bytes_limit"] = int(stats["bytes_limit"])
+        _spans.setup_event("train_step.memory", executable=self._tag,
+                           devices=len(self._devices),
+                           dur_s=time.perf_counter() - t0, **attrs)
+        return compiled
+
+
 # ordinal suffixes for TrainStep executable tags (see _exec_tag)
 _TRAIN_STEP_TAGS = itertools.count(1)
 
@@ -470,6 +518,7 @@ class TrainStep:
         self._exec_tag = "train_step" if n == 1 else f"train_step_{n}"
         self._step_flops = None   # executable cost_analysis FLOPs (MFU)
         self._traces = 0          # times the step body was traced
+        self._ledger_trace = 0    # the trace whose residuals were noted
         self._executed = False    # the compiled step has run once
         self._accum = int(accumulate_steps)
         self._quant = None        # (axis, nranks, CommQuantConfig) at build
@@ -616,6 +665,8 @@ class TrainStep:
                 with core.rng_key_context(jax.random.wrap_key_data(mk)):
                     with _scopes.phase("forward", tag):
                         loss = step_fn(*_tree_box(mb))
+                    self._note_residuals(
+                        loss, ([p.data for p in ptensors], bufs, mb))
                     with _scopes.phase("backward", tag):
                         loss.backward()
                 new_acc = []
@@ -691,6 +742,8 @@ class TrainStep:
                         else:
                             with _scopes.phase("forward", tag):
                                 loss = step_fn(*_tree_box(batch))
+                            self._note_residuals(
+                                loss, (params, buffers, batch))
                             with _scopes.phase("backward", tag):
                                 (scaler.scale(loss) if scaler is not None
                                  else loss).backward()
@@ -799,6 +852,34 @@ class TrainStep:
         else:
             self._compiled = jax.jit(pure, donate_argnums=donate)
 
+    def _note_residuals(self, loss, step_inputs):
+        """The set-up events `train_step.residuals` of this trace: what
+        the forward left the backward, summed by "scope:op" with one
+        total under "*" (`autograd.tape.kept_residuals`; `step_inputs`
+        are counted apart, as `state_bytes`). Once a trace: the first
+        walk, where the body runs inside a loop over micro-batches.
+        Shapes are the traced ones: the whole program's under GSPMD, a
+        shard's where the body runs under shard_map (`shapes`)."""
+        if self._ledger_trace == self._traces:
+            return
+        self._ledger_trace = self._traces
+        t0 = time.perf_counter()
+        by_key, state_bytes = _tape.kept_residuals(
+            [loss], jax.tree_util.tree_leaves(step_inputs))
+        tag = self._exec_tag
+        for key, (nbytes, arrays) in by_key.items():
+            _spans.setup_event("train_step.residuals", executable=tag,
+                               trace=self._traces, scope=key, bytes=nbytes,
+                               arrays=arrays)
+        per_shard = self._quant is not None or self._zero is not None
+        _spans.setup_event(
+            "train_step.residuals", executable=tag, trace=self._traces,
+            scope="*", bytes=sum(b for b, _ in by_key.values()),
+            arrays=sum(n for _, n in by_key.values()),
+            state_bytes=state_bytes,
+            shapes="shard" if per_shard else "global",
+            dur_s=time.perf_counter() - t0)
+
     def _call_args(self, batch):
         """The compiled step's arguments for this batch and the current
         model/optimizer state (building the step on first use)."""
@@ -863,13 +944,17 @@ class TrainStep:
     def lower(self, *batch):
         """The step as jax lowers it for this batch and the current state
         — the program `__call__` runs, for `.compile().as_text()` /
-        `.memory_analysis()`. Nothing executes."""
+        `.memory_analysis()`. Nothing executes. `.compile()` leaves the
+        set-up event `train_step.memory` (observability/scopes.SETUP)."""
         tag = self._exec_tag
         with _spans.setup_span("train_step.lower", executable=tag):
             with _spans.setup_span("train_step.call_args", executable=tag):
                 call_args = self._call_args(batch)
             with _devev.tagged(tag):
-                return self._compiled.lower(*call_args)
+                lowered = self._compiled.lower(*call_args)
+        devices = (list(self.shard.mesh.devices.flat)
+                   if self.shard is not None else jax.devices()[:1])
+        return _LoweredStep(lowered, tag, devices)
 
     def __call__(self, *batch):
         bench = core.get_bool_flag("FLAGS_benchmark")
@@ -925,19 +1010,6 @@ class TrainStep:
             print(f"TrainStep[{opt._step_count}]: "
                   f"{(_time.perf_counter() - _t0) * 1e3:.2f} ms",
                   file=_sys.stderr)
-        if core.get_bool_flag("FLAGS_log_memory_stats"):
-            # real device.memory_stats() readings, mirrored into the
-            # metrics registry gauges (device.bytes_in_use /
-            # device.peak_bytes_in_use); backends without memory_stats
-            # (CPU jaxlib returns None) no-op cleanly — no zeros printed
-            import sys as _sys
-            from .. import observability as _obs
-            mem = _obs.update_device_memory_gauges()
-            if mem is not None:
-                print(f"TrainStep[{opt._step_count}] memory: "
-                      f"in_use={mem['bytes_in_use']} "
-                      f"peak={mem['peak_bytes_in_use']}",
-                      file=_sys.stderr)
         if core.get_bool_flag("FLAGS_check_nan_inf"):
             # compiled-path sweep: values can't be branched on at trace
             # time, so the check runs on the step RESULT; rerun in eager

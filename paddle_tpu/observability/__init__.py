@@ -114,7 +114,7 @@ def arm():
     return restore
 
 
-# device-memory gauges (FLAGS_log_memory_stats + Profiler.step); created
+# device-memory gauges (Profiler.step); created
 # here once — consumers import the helper, not their own instruments
 _G_MEM_IN_USE = metrics.gauge("device.bytes_in_use",
                               "device memory currently allocated (bytes); "

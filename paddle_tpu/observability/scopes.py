@@ -49,10 +49,28 @@ STEP_SPANS = ("train_step.call_args", "train_step.dispatch",
 # (kernels/flash_attention: name, bytes a call), the grid a tile-walking
 # kernel of the learned selection was given (kernels/sparse_select_attention:
 # kernel, grid_steps, live_tiles, heads_per_step, rows, keys; once a kernel
-# a trace), and what jax.monitoring reports of lowering, compiling and the
-# cache
+# a trace), the step's device memory, and what jax.monitoring reports of
+# lowering, compiling and the cache.
+# Memory, two events an operator reads with `step.lower(*batch).compile()`
+# and then `observability.spans.ring()` (the runtime's `peak_bytes_in_use`
+# is the process's high-water mark, not the step's: it never showed the
+# step's temporaries): `train_step.memory`, once a compile of the lowered
+# step: the compiler's bytes a device (argument_bytes, output_bytes,
+# alias_bytes = the donated state, temp_bytes = the size of the region the
+# temporaries live in, generated_code_bytes, sum_bytes = their sum with
+# alias subtracted, which over-counts and refuses nothing; peak_bytes =
+# the compiler's own peak, arguments + the program's fullest moment, what
+# its refusal prints as "used X of Y" (the sum on a backend that gives
+# none); bytes_limit where the runtime has one; devices);
+# `train_step.residuals`, once a key a trace (scope = "<vocabulary
+# scope>:<taped op>", the one it has where an op lacks the other) and a
+# total under scope "*": what the forward keeps for the backward (bytes,
+# arrays, trace; the total also state_bytes = the step's own inputs among
+# it, and shapes: "global" under GSPMD, "shard" where the body runs under
+# shard_map)
 SETUP = ("train_step.lower", "train_step.call_args", "train_step.trace",
          "train_step.to_mlir", "train_step.traced", "train_step.kept",
+         "train_step.memory", "train_step.residuals",
          "dsa.grid", "xla.to_mlir", "xla.backend_compile", "xla.cache_hit",
          "xla.cache_miss")
 

@@ -325,7 +325,30 @@ def test_train_step_names_its_device_operations(topo, cell):
                                           sharding=one)
                      if isinstance(a, (np.ndarray, np.generic)) else a
                      for a in args)
-    text = step._compiled.lower(*args).compile().as_text()
+    # through `TrainStep.lower()`'s wrapper (`lower()` itself wants real
+    # arrays): its compile leaves the compiler's byte count a device
+    from paddle_tpu.observability import spans
+    spans.clear()
+    compiled = paddle.jit._LoweredStep(step._compiled.lower(*args),
+                                       step._exec_tag, devices).compile()
+    text = compiled.as_text()
+    (event,) = [ev for ev in spans.ring()
+                if ev["name"] == "train_step.memory"]
+    mem = {k: int(v) for k, v in event["attrs"].items() if v.isdigit()}
+    assert mem["devices"] == len(devices) and "bytes_limit" not in mem
+    assert mem["temp_bytes"] == \
+        compiled.memory_analysis().temp_size_in_bytes > 0
+    assert mem["sum_bytes"] == (
+        mem["argument_bytes"] + mem["output_bytes"] - mem["alias_bytes"]
+        + mem["temp_bytes"] + mem["generated_code_bytes"])
+    # the TPU compiler's own peak: the arguments and the program's
+    # fullest moment, under the sum (`temp_bytes` is a region's size)
+    assert mem["peak_bytes"] == \
+        compiled.memory_analysis().peak_memory_in_bytes
+    assert mem["argument_bytes"] < mem["peak_bytes"] < mem["sum_bytes"]
+    # the donated state comes back in its own buffers
+    assert 0.99 * mem["argument_bytes"] < mem["alias_bytes"] <= \
+        mem["argument_bytes"]
     if plan is None:
         # the scanned layer forward, recomputed and backward: splash
         # forward ONCE (the default remat policy keeps its out and
