@@ -20,14 +20,14 @@ The equations are written out in `tests/reference/solar_open2.py`, which
 the tests hold this file to.
 
 Memory at long sequences decides the structure. Each half of a layer
-(residual + mixer, residual + experts) is ONE taped operation whose
-backward recomputes it. A mixer goes over its heads a group at a time
-(one KV head with its query heads; `kda_head_group` linear-attention
-heads), projections included, so that no [tokens, heads x dim] tensor
-ever exists; a group is recomputed in the backward (`jax.checkpoint`)
-and only the layer's input is kept. The last norm, the head and the
-cross-entropy go over the rows in blocks (`F.linear_cross_entropy`'s
-body) and are recomputed as well.
+(residual + mixer, residual + experts) is ONE taped operation that keeps
+its input alone; its backward recomputes it, a mixer's a group of heads at
+a time (`jax.checkpoint`). The softmax mixer sums its groups' projected
+parts (one KV head with its query heads). The delta-rule mixer norms and
+down-projects once a layer, scans `kda_head_group` heads a group and
+projects the groups' stacked outputs at once: one [tokens, heads x dim]
+array a layer where a float32 [tokens, hidden] sum was. The last norm, the
+head and the loss go over blocks of rows and are recomputed as well.
 """
 from __future__ import annotations
 
@@ -197,8 +197,10 @@ class GatedAttention(Layer):
 # -- the linear-attention layer ------------------------------------------------
 
 class KDAttention(Layer):
-    """x + the gated delta-rule mixer of RMSNorm(x); see the module
-    docstring for why it goes over the heads in groups."""
+    """x + the gated delta-rule mixer of RMSNorm(x). What is of hidden
+    width and the same for every group of heads runs once a layer, around
+    the scan over the groups; the block has a backward of its own
+    (`_backward`) that keeps of the forward the layer's input alone."""
 
     def __init__(self, cfg: SolarOpen2Config):
         super().__init__()
@@ -233,32 +235,47 @@ class KDAttention(Layer):
         nl, hg = self.cfg.linear_num_heads, self.cfg.kda_head_group
         return nl // hg if nl % hg == 0 else 1
 
-    def _group(self, g, x, ln_w, wqkv, wconv, wdd, wdu, a_log, dt_bias,
-               wbeta, wgd, wgu, o_norm_w, wo):
-        """Heads [g * hg, (g + 1) * hg): their part of the mixer's output,
-        [B, T, H] float32 (the groups' parts are summed)."""
+    def _shared(self, x, ln_w, wdd, wgd, wbeta):
+        """Once a layer: (RMSNorm(x), xn @ [decay_down | gate_down |
+        beta_proj]), the second [B, T, 2 rank + heads]."""
+        xn = _rms(x, ln_w, self.cfg.rms_norm_eps)
+        with scope("kda/gate"):
+            return xn, xn @ jnp.concatenate([wdd, wgd, wbeta], axis=1)
+
+    def _of_group(self, g, low, wqkv, wconv, wdu, a_log, dt_bias, wgu, wo):
+        """What group g takes of the shared columns and of the weights
+        that have a head axis."""
+        G, r = self._groups(), self.cfg.kda_low_rank
+        hg = self.cfg.linear_num_heads // G
+        return (low[..., :r], low[..., r:2 * r],
+                jax.lax.dynamic_slice_in_dim(low, 2 * r + g * hg, hg, -1),
+                _group_of(wqkv, 3, G, g), _group_of(wconv, 3, G, g),
+                _group_of(wdu, 1, G, g), _group_of(a_log[None], 1, G, g)[0],
+                _group_of(dt_bias[None], 1, G, g)[0],
+                _group_of(wgu, 1, G, g),
+                jax.lax.dynamic_index_in_dim(
+                    wo.reshape(G, -1, wo.shape[-1]), g, 0, keepdims=False))
+
+    def _group(self, xn, dd, gd, b, wqkv, wconv, wdu, a_log, dt_bias, wgu,
+               o_norm_w):
+        """One group's heads, gated and normed: [B, T, hg * dl] in xn's
+        dtype. dd, gd [B, T, rank] and b [B, T, hg] are its columns of the
+        shared product, the weights its own slices."""
         from ..kernels.gated_delta_rule import chunk_gated_delta_rule
         from ..kernels.short_conv import conv_silu_l2norm
         cfg = self.cfg
-        G = self._groups()
-        hg, dl = cfg.linear_num_heads // G, cfg.linear_head_dim
-        B, T, _ = x.shape
+        dl = cfg.linear_head_dim
+        B, T, hg = b.shape
         f32 = jnp.float32
-        xn = _rms(x, ln_w, cfg.rms_norm_eps)
         with scope("kda/proj"):
-            pre = xn @ _group_of(wqkv, 3, G, g)          # [B, T, 3 hg dl]
+            pre = xn @ wqkv                              # [B, T, 3 hg dl]
         with scope("kda/conv"):
-            q, k, v = conv_silu_l2norm(pre, _group_of(wconv, 3, G, g), hg)
+            q, k, v = conv_silu_l2norm(pre, wconv, hg)
         with scope("kda/gate"):
-            soft = jax.nn.softplus(
-                ((xn @ wdd) @ _group_of(wdu, 1, G, g)).astype(f32)
-                + jax.lax.dynamic_index_in_dim(
-                    dt_bias.reshape(G, hg * dl), g, 0, keepdims=False))
-            rate = jnp.exp(jax.lax.dynamic_index_in_dim(
-                a_log.reshape(G, hg), g, 0, keepdims=False).astype(f32))
-            decay = -rate[:, None] * soft.reshape(B, T, hg, dl)
-            beta = 2.0 * jax.nn.sigmoid(
-                (xn @ _group_of(wbeta, 1, G, g)).astype(f32))
+            soft = jax.nn.softplus((dd @ wdu).astype(f32) + dt_bias)
+            decay = (-jnp.exp(a_log.astype(f32))[:, None]
+                     * soft.reshape(B, T, hg, dl))
+            beta = 2.0 * jax.nn.sigmoid(b.astype(f32))
         with scope("kda/core"):
             o = chunk_gated_delta_rule(q, k, v, decay, beta,
                                        chunk=cfg.kda_chunk)
@@ -266,24 +283,110 @@ class KDAttention(Layer):
             o = o.astype(f32)
             o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
                                   + cfg.rms_norm_eps) * o_norm_w
-            gate = jax.nn.sigmoid(
-                ((xn @ wgd) @ _group_of(wgu, 1, G, g)).astype(f32))
-            o = (o.reshape(B, T, hg * dl) * gate).astype(x.dtype)
-            wo_g = jax.lax.dynamic_index_in_dim(
-                wo.reshape(G, hg * dl, -1), g, 0, keepdims=False)
-            return jnp.matmul(o, wo_g, preferred_element_type=f32)
+            gate = jax.nn.sigmoid((gd @ wgu).astype(f32))
+            return (o.reshape(B, T, hg * dl) * gate).astype(xn.dtype)
+
+    def _projected(self, wo, *args):
+        """A group's part of the output projection, for the backward: its
+        cotangent is the layer's own."""
+        o = self._group(*args)
+        with scope("kda/out"):
+            return o @ wo
+
+    def _mix(self, x, ln_w, wqkv, wconv, wdd, wdu, a_log, dt_bias, wbeta,
+             wgd, wgu, o_norm_w, wo):
+        """The block: the shared part, the groups' heads stacked by a scan,
+        ONE output projection over them (float32 sums), the residual."""
+        G = self._groups()
+        xn, low = self._shared(x, ln_w, wdd, wgd, wbeta)
+
+        def body(_, g):
+            *mine, _ = self._of_group(g, low, wqkv, wconv, wdu, a_log,
+                                      dt_bias, wgu, wo)
+            return None, self._group(xn, *mine, o_norm_w)
+
+        _, o = jax.lax.scan(body, None, jnp.arange(G))   # [G, B, T, hg dl]
+        with scope("kda/out"):
+            mixed = jax.lax.dot_general(
+                o, wo.reshape(G, -1, wo.shape[-1]),
+                (((0, 3), (0, 1)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return x + mixed.astype(x.dtype)
+
+    def _backward(self, saved, dy):
+        """The layer's input and weights -> every cotangent. The shared
+        part is recomputed once, then a group at a time: jax's own
+        backward of the checkpointed group with its part of the output
+        projection (the recomputed forward and the transposed operations
+        carry jax's marks), the cotangents of what the groups share summed
+        in float32, a group's own stacked by the scan."""
+        # behind the cotangent, as jax.checkpoint puts what it recomputes:
+        # XLA would share this pass's norm and weight layouts with the
+        # forward's and keep them alive from there to here
+        saved, dy = jax.lax.optimization_barrier((saved, dy))
+        x, ln_w, wqkv, wconv, wdd, wdu, a_log, dt_bias, wbeta, wgd, wgu, \
+            o_norm_w, wo = saved
+        r = self.cfg.kda_low_rank
+        f32 = jnp.float32
+        (xn, low), shared_vjp = jax.vjp(self._shared, x, ln_w, wdd, wgd,
+                                        wbeta)
+        run = jax.checkpoint(self._projected)
+
+        def body(sums, g):
+            mine = self._of_group(g, low, wqkv, wconv, wdu, a_log, dt_bias,
+                                  wgu, wo)
+            _, vjp = jax.vjp(run, mine[-1], xn, *mine[:-1], o_norm_w)
+            dwo, dxn, ddd, dgd, db, *dws, don = vjp(dy)
+            sums = tuple(a + d.astype(f32)
+                         for a, d in zip(sums, (dxn, ddd, dgd, don)))
+            return sums, (db, *dws, dwo)
+
+        G = self._groups()
+        (dxn, ddd, dgd, don), (db, dwqkv, dwconv, dwdu, da, ddt, dwgu,
+                               dwo) = jax.lax.scan(
+            body, tuple(jnp.zeros(a.shape, f32) for a in (
+                xn, low[..., :r], low[..., :r], o_norm_w)), jnp.arange(G))
+
+        def columns(d, parts, like):
+            """The groups' stacked [G, rows, parts * n] as like's
+            [rows, parts * G * n]: `_group_of`'s inverse."""
+            d = d.reshape(G, d.shape[1], parts, -1)
+            return jnp.moveaxis(d, 0, 2).reshape(like.shape)
+
+        dlow = jnp.concatenate(
+            [ddd.astype(low.dtype), dgd.astype(low.dtype),
+             jnp.moveaxis(db, 0, -2).reshape(*db.shape[1:-1], -1)], -1)
+        dx, dln_w, dwdd, dwgd, dwbeta = shared_vjp(
+            (dxn.astype(xn.dtype), dlow))
+        return (dy + dx, dln_w, columns(dwqkv, 3, wqkv),
+                columns(dwconv, 3, wconv), dwdd, columns(dwdu, 1, wdu),
+                da.reshape(a_log.shape), ddt.reshape(dt_bias.shape), dwbeta,
+                dwgd, columns(dwgu, 1, wgu), don.astype(o_norm_w.dtype),
+                dwo.reshape(wo.shape))
 
     def block(self, x, *ws):
-        mixed = _sum_of_groups(self._group, self._groups(), x, ws)
-        with scope("kda/out"):
-            return x + mixed
+        from ..observability import spans
+        cfg = self.cfg
+        G = self._groups()
+        spans.setup_event(
+            "kda.groups", groups=G, heads_per_group=cfg.linear_num_heads // G,
+            hidden_width_products_in_group=1,
+            shared_columns=2 * cfg.kda_low_rank + cfg.linear_num_heads,
+            stacked_out_bytes=(x.size // cfg.hidden_size * cfg.linear_num_heads
+                               * cfg.linear_head_dim * x.dtype.itemsize))
+        mix = jax.custom_vjp(self._mix)
+        mix.defvjp(lambda *a: (self._mix(*a), a), self._backward)
+        return mix(x, *ws)
+
+    def weights(self):
+        """The layer's leaves in the order `block` takes them."""
+        return (self.qkv_proj, self.conv_weight, self.decay_down,
+                self.decay_up, self.A_log, self.dt_bias, self.beta_proj,
+                self.gate_down, self.gate_up, self.o_norm.weight, self.o_proj)
 
     def forward(self, x, ln_w):
-        return apply_op(
-            self.block, to_tensor_like(x), ln_w, self.qkv_proj,
-            self.conv_weight, self.decay_down, self.decay_up, self.A_log,
-            self.dt_bias, self.beta_proj, self.gate_down, self.gate_up,
-            self.o_norm.weight, self.o_proj, name="kda_attention")
+        return apply_op(self.block, to_tensor_like(x), ln_w, *self.weights(),
+                        name="kda_attention")
 
 
 # -- a layer, the stack, the model ---------------------------------------------

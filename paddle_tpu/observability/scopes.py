@@ -49,8 +49,14 @@ STEP_SPANS = ("train_step.call_args", "train_step.dispatch",
 # (kernels/flash_attention: name, bytes a call), the grid a tile-walking
 # kernel of the learned selection was given (kernels/sparse_select_attention:
 # kernel, grid_steps, live_tiles, heads_per_step, rows, keys; once a kernel
-# a trace), the step's device memory, and what jax.monitoring reports of
-# lowering, compiling and the cache.
+# a trace), how a delta-rule layer went over its heads (models/solar_open2
+# `KDAttention.block`: groups, heads_per_group,
+# hidden_width_products_in_group = the products inside a group's scan that
+# have a hidden_size side, shared_columns = the columns of the one product
+# a layer the groups share, stacked_out_bytes = the groups' outputs in
+# front of the output projection; once a layer a trace), the step's device
+# memory, and what jax.monitoring reports of lowering, compiling and the
+# cache.
 # Memory, two events an operator reads with `step.lower(*batch).compile()`
 # and then `observability.spans.ring()` (the runtime's `peak_bytes_in_use`
 # is the process's high-water mark, not the step's: it never showed the
@@ -71,8 +77,8 @@ STEP_SPANS = ("train_step.call_args", "train_step.dispatch",
 SETUP = ("train_step.lower", "train_step.call_args", "train_step.trace",
          "train_step.to_mlir", "train_step.traced", "train_step.kept",
          "train_step.memory", "train_step.residuals",
-         "dsa.grid", "xla.to_mlir", "xla.backend_compile", "xla.cache_hit",
-         "xla.cache_miss")
+         "dsa.grid", "kda.groups", "xla.to_mlir", "xla.backend_compile",
+         "xla.cache_hit", "xla.cache_miss")
 
 _SCOPES = frozenset(COMPONENTS + COLLECTIVES)
 _tl = threading.local()          # .open: scopes open on this thread

@@ -329,12 +329,14 @@ def test_step_carries_the_new_names():
 # -- the models the benchmark had lower as they did --------------------------------
 
 PARENT = {       # sha256 of the text at commit 0eb8308 (PR 32), read under
-    # this suite's conftest (8 host devices), addresses and step tags out
+    # this suite's conftest (8 host devices), addresses and step tags out;
+    # the two `solar.*` are PR 37's program (its commit, on 2593084: the
+    # delta-rule block's own backward), the other four still PR 32's
     "llama_gqa.cpu_text": "b4b176201bc8d8fbafa942c340cb4a468ec2b616380afc060286c729b452eeeb",
-    "solar.cpu_text": "f71ce5131bd708d50a6bbdf26bfef56c3ca02d123af9234ea35f9c53858b0ffa",
+    "solar.cpu_text": "7a1d0e627cb8d3280be55ed3a56c242ac2ee9a6cdb18aa2ac868b77b7f932919",
     "granite.cpu_text": "08d148d540da6c9271a7e2c82439390d9e3460aa07d063ff5a1216721e7834ab",
     "llama_gqa.tpu_jaxpr": "5324d891f9ab8d1d9e73a12910b401c5d8bf61db6d0ebfed52574e7c5fc20473",
-    "solar.tpu_jaxpr": "814746d06095477691c791ad8ace1662ce11bb938395a89570e3d13d066da1ab",
+    "solar.tpu_jaxpr": "f73c160d60873c82a31dacf3399c142bf4df7465a775c274c24071f6d6fa30d4",
     "granite.tpu_jaxpr": "532c6a60326f070f8015a095125a8f0d74817ba7e848907c22fc10b67dd019ee",
 }
 

@@ -134,18 +134,16 @@ def test_remat_keeps_asks_the_armed_policy(fake_tpu):
 
 
 def test_the_delta_rule_groups_program_is_the_parents():
-    """`_sum_of_groups` hands its checkpoint the armed policy now; a
-    delta-rule group stamps no name, so the program it lowers to under
-    the default policy is the one it lowers to under none (the parent's:
-    it passed none)."""
+    """A delta-rule group stamps no name and, since ISSUE 37, the block's
+    own backward checkpoints its groups without a policy (one that kept a
+    value would keep the forward `jax.vjp` traces there alive: a third
+    forward), so the program it lowers to under the default policy is the
+    one it lowers to under none."""
     paddle.seed(0)
     cfg = solar_open2_tiny()
     layer = KDAttention(cfg)
     ws = [jnp.ones((cfg.hidden_size,), jnp.float32)] + [
-        p.data for p in (layer.qkv_proj, layer.conv_weight,
-                         layer.decay_down, layer.decay_up, layer.A_log,
-                         layer.dt_bias, layer.beta_proj, layer.gate_down,
-                         layer.gate_up, layer.o_norm.weight, layer.o_proj)]
+        p.data for p in layer.weights()]
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 32, cfg.hidden_size))
 
     def lowered(policy):

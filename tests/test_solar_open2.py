@@ -164,3 +164,158 @@ def test_dropped_pairs_is_a_running_sum_over_steps():
     assert short > 0 and once["dropped_pairs"].tolist() == [short]
     step(x, x)
     assert m.moe_counters()["dropped_pairs"].tolist() == [2 * short]
+
+
+# -- the delta-rule layer alone: its own backward, its scans, its event --------
+
+KDA_LEAVES = ("qkv_proj", "conv_weight", "decay_down", "decay_up", "A_log",
+              "dt_bias", "beta_proj", "gate_down", "gate_up", "o_norm.weight",
+              "o_proj")
+
+
+def _kda_layer(**kw):
+    """(layer, its configuration, x [2, 37, H], [the norm's weight, the 11
+    leaves] in `block`'s order, all seeded)."""
+    from paddle_tpu.models.solar_open2 import KDAttention
+    paddle.seed(11)
+    c = solar_open2_tiny(num_hidden_layers=1, gqa_layers=(), **kw)
+    layer = KDAttention(c)
+    keys = jax.random.split(jax.random.PRNGKey(4), 3)
+    leaves = [p.data for p in layer.weights()]        # KDA_LEAVES' order
+    # a norm weight and an o_norm weight that are not all ones
+    ln_w = 1.0 + 0.1 * jax.random.normal(keys[0], (c.hidden_size,))
+    leaves[9] = 1.0 + 0.1 * jax.random.normal(keys[1], leaves[9].shape)
+    x = jax.random.normal(keys[2], (2, 37, c.hidden_size))
+    return layer, c, x, [ln_w] + leaves
+
+
+@pytest.mark.parametrize("head_group, groups", [(4, 1), (2, 2), (3, 1)],
+                         ids=["one-group", "two-groups", "heads-not-divided"])
+def test_kda_block_and_every_gradient_match_the_reference(head_group, groups):
+    """`KDAttention.block` (norm and shared low-rank product once, the
+    groups' scan, the stacked output projection) and the cotangent of x
+    and of each of the 12 weights through its hand-driven backward,
+    against autodiff of the reference's half layer; 4 heads in one group,
+    in two, and in one because 3 does not divide them."""
+    layer, c, x, ws = _kda_layer(kda_head_group=head_group)
+    assert layer._groups() == groups
+    a = ref.arch(cfg_json(c))
+    target = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+
+    def mine(x, *ws):
+        y = layer.block(x, *ws)
+        return jnp.mean((y - target) ** 2), y
+
+    def plain(x, ln_w, *leaves):
+        w = {"ln1": ln_w}
+        w.update({"mixer." + k: v for k, v in zip(KDA_LEAVES, leaves)})
+        y = ref.mixer_half(w, x, "kda", a)
+        return jnp.mean((y - target) ** 2), y
+
+    every = tuple(range(len(ws) + 1))
+    (loss, y), got = jax.jit(jax.value_and_grad(
+        mine, argnums=every, has_aux=True))(x, *ws)
+    with jax.default_matmul_precision("highest"):
+        (want_loss, want_y), want = jax.jit(jax.value_and_grad(
+            plain, argnums=every, has_aux=True))(x, *ws)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), atol=2e-5)
+    assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
+    for name, g, w in zip(("x", "ln1") + KDA_LEAVES, got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        top = float(jnp.abs(w).max())
+        assert top > 0, name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   atol=2e-5 * top, err_msg=name)
+
+
+def _walk(jaxpr, inside=(), found=None):
+    """[(eqn, the scans it lies in)] of a jaxpr and everything under it,
+    kernel bodies apart."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        found.append((eqn, inside))
+        if eqn.primitive.name == "pallas_call":
+            continue
+        under = inside + (eqn,) if eqn.primitive.name == "scan" else inside
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _walk(sub, under, found)
+    return found
+
+
+def test_the_group_scans_hold_one_hidden_width_product_a_pass(monkeypatch):
+    """The jaxpr of the block's gradient, with what nothing reads taken
+    out (`jax.vjp` of the checkpointed group traces a forward whose output
+    the backward never uses): two scans over the groups. The forward's
+    holds ONE product with a `hidden_size` side (q|k|v) and no norm; the
+    backward's recomputed forward one, its transposed operations that
+    product's two and the output projection's two; the delta-rule
+    forward kernel is there twice (forward, recomputed), not three times."""
+    from jax._src.interpreters import partial_eval as pe
+    from paddle_tpu.kernels import gated_delta_rule as gdr
+    monkeypatch.setattr(gdr, "_on_tpu", lambda: True)
+    layer, c, _, ws = _kda_layer(linear_head_dim=128, kda_chunk=64)
+    H = c.hidden_size
+    x = jnp.zeros((1, 128, H))
+
+    def loss(x, *ws):
+        return jnp.sum(layer.block(x, *ws) ** 2)
+
+    traced = jax.make_jaxpr(jax.grad(
+        loss, argnums=tuple(range(len(ws) + 1))))(x, *ws).jaxpr
+    live, _ = pe.dce_jaxpr(traced, [True] * len(traced.outvars))
+    eqns = _walk(live)
+    scans = [e for e, _ in eqns if e.primitive.name == "scan"]
+    assert len(scans) == 2 and all(
+        e.params["length"] == layer._groups() for e in scans)
+
+    def hidden_products(scan, recomputed=None):
+        out = []
+        for e, inside in eqns:
+            if e.primitive.name != "dot_general" or scan not in inside:
+                continue
+            if H not in sum((tuple(v.aval.shape) for v in e.invars), ()):
+                continue
+            marked = "rematted_computation" in str(e.source_info.name_stack)
+            if recomputed is None or marked == recomputed:
+                out.append(e)
+        return out
+
+    forward, backward = scans
+    assert len(hidden_products(forward)) == 1
+    assert len(hidden_products(backward, recomputed=True)) == 1
+    assert len(hidden_products(backward, recomputed=False)) == 4
+    in_scans = [e for e, inside in eqns if inside]
+    assert not [e for e in in_scans if "rms_norm" in str(e.params.get(
+        "name", "")) or "rms_norm" in str(e.source_info.name_stack)]
+    kernels = [e.params["name"] for e, _ in eqns
+               if e.primitive.name == "pallas_call"]
+    assert kernels.count("kda_chunk_states") == 2, kernels
+    assert kernels.count("kda_chunk_states_bwd") == 1, kernels
+    # the norm and the shared product: once in the forward, once recomputed
+    shared = (H, 2 * c.kda_low_rank + c.linear_num_heads)
+    outside = [e for e, inside in eqns if not inside
+               and e.primitive.name == "dot_general"
+               and e.invars[1].aval.shape == shared
+               and e.params["dimension_numbers"][0] == ((2,), (0,))]
+    assert len(outside) == 2
+
+
+def test_a_traced_kda_layer_leaves_one_kda_groups_event():
+    import paddle_tpu.optimizer as popt
+    from paddle_tpu.observability import scopes, spans
+    c = solar_open2_tiny(num_hidden_layers=1, gqa_layers=())
+    paddle.seed(0)
+    m = SolarOpen2ForCausalLM(c)
+    opt = popt.AdamW(learning_rate=1e-3, parameters=m.parameters())
+    step = paddle.jit.TrainStep(m, opt, lambda i, l: m.loss(i, l))
+    x = paddle.to_tensor(_ids(c, 2, 32))
+    step._build()
+    spans.clear()
+    step._compiled.trace(*step._call_args((x, x)))
+    events = [ev["attrs"] for ev in spans.ring() if ev["name"] == "kda.groups"]
+    assert events == [{
+        "groups": "2", "heads_per_group": "2",
+        "hidden_width_products_in_group": "1",
+        "shared_columns": str(2 * c.kda_low_rank + c.linear_num_heads),
+        "stacked_out_bytes": str(2 * 32 * 4 * 8 * 4)}]
+    assert "kda.groups" in scopes.SETUP

@@ -243,6 +243,27 @@ def test_parameters_are_state_bytes_not_residuals():
                              "loss:cross_entropy"}
 
 
+def test_a_delta_rule_layer_keeps_its_input_and_nothing_else():
+    """Tiny Solar-Open2 through `TrainStep`: `layers:kda_attention` is one
+    layer input a delta-rule layer, one array each: nothing the block's
+    own backward recomputes (the norm's output, the shared low-rank
+    product, the groups' stacked outputs) leaks into the forward's
+    residuals. The weights it hands its backward are the step's own."""
+    from paddle_tpu.models.solar_open2 import (SolarOpen2ForCausalLM,
+                                               solar_open2_tiny)
+    paddle.seed(0)
+    cfg = solar_open2_tiny()
+    kda_layers = cfg.num_hidden_layers - len(cfg.gqa_layers)
+    step = _step(SolarOpen2ForCausalLM(cfg))
+    x = _batch()
+    spans.clear()
+    step.lower(x, x)
+    by_scope, _ = _ledger()
+    assert kda_layers == 3
+    assert by_scope["layers:kda_attention"] == (
+        kda_layers * 2 * SEQ * cfg.hidden_size * F32, kda_layers)
+
+
 def test_an_array_two_ops_keep_counts_once():
     B, H = 4, 8
     got = {}

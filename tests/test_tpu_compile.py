@@ -443,6 +443,43 @@ def test_gated_delta_rule_compiles_for_the_chip(one_chip):
     assert _mosaic_calls(remat, "kda_chunk_states") == 2
 
 
+def test_kda_half_layer_compiles_for_the_chip(one_chip):
+    """One delta-rule half layer as the Solar cell runs it ([1, 32768,
+    4096], 64 heads x 128 in 16 groups, rank 128), forward and its own
+    backward: two loops over the groups; the delta-rule forward kernel and
+    the convolution's twice (forward, recomputed: the forward `jax.vjp`
+    traces in the backward and never reads is gone), their backward once;
+    the input norm once a pass and not once a group; and the program's
+    temporaries within 0.8 GiB of what the per-group form took (ISSUE 37:
+    2,952,275,456 bytes at commit 2593084, this compile there)."""
+    from paddle_tpu.models.solar_open2 import KDAttention, SolarOpen2Config
+    cfg = SolarOpen2Config(num_hidden_layers=1, gqa_layers=(), vocab_size=128)
+    layer = KDAttention(cfg)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [sds((1, 32768, cfg.hidden_size)),
+            sds((cfg.hidden_size,), jnp.float32)] + [
+        sds(p.data.shape, p.data.dtype) for p in layer.weights()]
+
+    def loss(x, *ws):
+        y = layer.block(x, *ws).astype(jnp.float32)
+        return jnp.sum(y * y)           # the backward reads the forward's y
+
+    compiled = jax.jit(jax.grad(
+        loss, argnums=tuple(range(len(args))))).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count(" while(") == 2
+    assert _mosaic_calls(text, "kda_chunk_states_bwd") == 1
+    assert _mosaic_calls(text, "kda_chunk_states") == 3     # and its _bwd
+    assert _mosaic_calls(text, "kda_conv_fwd") == 2
+    assert _mosaic_calls(text, "kda_conv_bwd") == 1
+    assert _mosaic_calls(text, "rms_norm") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        2_952_275_456 + 0.8 * 2 ** 30)
+
+
 def test_short_conv_compiles_for_the_chip(one_chip):
     """The delta-rule layer's convolution + SiLU + L2 norm at the cell's
     own shape (a group of 4 heads x 128, q | k | v side by side), as the
