@@ -119,6 +119,19 @@ def dsa_index_train(cfg, batch, seq):
     return flops, batch * seq * (3 * per_token + s["top"]) * 4
 
 
+def moe_experts_train(cfg, pairs):
+    """(flops, bytes) of the routed experts' grouped products for `pairs`
+    (token, expert) pairs computed here, ONE layer, forward + backward,
+    counted as `costs_solar_open2.moe_experts_train` counts them: three
+    [pairs, H] x [H, M] products forward, twice that backward -> 18 pairs
+    H M. Bytes: the held experts' weights read forward and backward and
+    their gradient written, the rows read forward, rows and their
+    gradients read and written backward (2 bytes each)."""
+    s = sizes(cfg)
+    h, m = s["h"], s["m"]
+    return 18 * pairs * h * m, (3 * s["held"] * 3 * h * m + 5 * pairs * h) * 2
+
+
 def train_flops_per_token(cfg, seq):
     """Forward + backward of one token in a causal sequence of `seq`: 6
     per matrix parameter it multiplies, the full layers' cores over the

@@ -108,32 +108,23 @@ def counted(ctx, counters):
         f"{counters['expert_tokens']}")]
 
 
-def control(ctx):
-    """Sound and control readings of one seed, for setting the limits
-    (`chipbench/control.py`): the program's first steps against the
-    reference, then the reference itself in each lower precision in the
-    program's place."""
+def control(ctx, controls=True, faults=False):
+    """Sound, control and fault readings of one seed, for setting the
+    limits (`chipbench/control.py`, `drivers.train.control_sides`)."""
     reference = parts(ctx.config)[1]
     sut = build(ctx)
     got = dense.first_steps(ctx, sut)
     make_state, ids = sut["make_state"], sut["ids"]
     sut["reference_warm"].join()
     sut.clear()
-    harness.release()
     n = dense._check_steps(ctx)
 
-    def state():
-        return make_state(ctx.seed)
+    def follow(mode=None, trainer=ctx.config["trainer"]):
+        harness.release()      # the run before's loaded programs hold memory
+        return reference.train_steps(lambda: make_state(ctx.seed), ids[:n],
+                                     ctx.config, trainer, mode=mode)
 
-    ref = reference.train_steps(state, ids[:n], ctx.config,
-                                ctx.config["trainer"])
-    out = {"sound": dense.compare(ctx, got, ref)}
-    for mode in ctx.cell["correct"]["controls"]:
-        harness.release()      # the sound run's loaded programs hold memory
-        low = reference.train_steps(state, ids[:n], ctx.config,
-                                    ctx.config["trainer"], mode=mode)
-        out[mode] = dense.compare(ctx, low, ref)
-    return out
+    return dense.control_sides(ctx, got, follow, controls, faults)
 
 
 def run(ctx):

@@ -150,18 +150,26 @@ def first_steps(ctx, sut):
             "delta_norms": delta}
 
 
+def loss_rows(limit, got, ref):
+    """`loss_gap` as one row under a number (the largest gap over the
+    checked steps), or as one row a checked step, `loss_gap.step<n>`,
+    under a list: each step held to a limit of its own."""
+    gaps = [abs(a - b) for a, b in zip(got["losses"], ref["losses"])]
+    note = f"program {got['losses']} reference {ref['losses']}"
+    if not isinstance(limit, list):
+        return [harness.compared("loss_gap", max(gaps), limit, note)]
+    return [harness.compared(f"loss_gap.step{i}", gap, lim, note)
+            for i, (gap, lim) in enumerate(zip(gaps, limit), start=1)]
+
+
 def compare(ctx, got, ref):
     """The numbers `correct` rests on, each beside its limit."""
     lim = ctx.cell["correct"]["limits"]
-    loss_gap = max(abs(a - b) for a, b in zip(got["losses"], ref["losses"]))
     g_gap, g_leaf = reference.worst_leaf_gap(got["grad_norms"],
                                              ref["grad_norms"])
     d_gap, d_leaf = reference.worst_leaf_gap(got["delta_norms"],
                                              ref["delta_norms"])
-    return [
-        harness.compared("loss_gap", loss_gap, lim["loss_gap"]["limit"],
-                         f"program {got['losses']} reference "
-                         f"{ref['losses']}"),
+    return loss_rows(lim["loss_gap"]["limit"], got, ref) + [
         harness.compared("first_grad_norm_gap", g_gap,
                          lim["first_grad_norm_gap"]["limit"], g_leaf),
         harness.compared("param_change_norm_gap", d_gap,
@@ -169,10 +177,38 @@ def compare(ctx, got, ref):
     ]
 
 
-def control(ctx):
-    """Sound and control readings of one seed, for setting the limits:
-    the program's first steps against the reference, then the reference
-    itself in each lower precision in the program's place."""
+def control_rows(ctx, got, ref):
+    """`compare`'s rows for `chipbench/control.py`, which reads each
+    checked step's loss gap whatever form the limit has: under a number
+    the steps' rows follow, each beside that number."""
+    rows = compare(ctx, got, ref)
+    limit = ctx.cell["correct"]["limits"]["loss_gap"]["limit"]
+    if not isinstance(limit, list):
+        rows += loss_rows([limit] * len(got["losses"]), got, ref)
+    return rows
+
+
+def control_sides(ctx, got, follow, controls=True, faults=False):
+    """{"sound": rows, <mode>: rows, "fault:<name>": rows}: the program's
+    first steps against `follow()`, the reference; then, in the program's
+    place, the reference in each lower precision of `correct.controls`
+    (`follow(mode=)`) and the reference under each of `correct.faults`,
+    a gross fault written as the trainer settings it changes
+    (`follow(trainer=)`)."""
+    ref = follow()
+    out = {"sound": control_rows(ctx, got, ref)}
+    for mode in ctx.cell["correct"]["controls"] if controls else ():
+        out[mode] = control_rows(ctx, follow(mode=mode), ref)
+    wrong = ctx.cell["correct"].get("faults", {}) if faults else {}
+    for name, changed in wrong.items():
+        low = follow(trainer={**ctx.config["trainer"], **changed})
+        out["fault:" + name] = control_rows(ctx, low, ref)
+    return out
+
+
+def control(ctx, controls=True, faults=False):
+    """Sound, control and fault readings of one seed, for setting the
+    limits (`control_sides`)."""
     sut = build(ctx)
     got = first_steps(ctx, sut)
     make_state, shardings, ids = (sut["make_state"], sut["shardings"],
@@ -180,20 +216,14 @@ def control(ctx):
     sut.clear()
     harness.release()
     n = _check_steps(ctx)
-
-    def state():
-        return make_state(ctx.seed)
-
     keep = _spread(shardings)
-    ref = reference.train_steps(state, ids[:n], ctx.config,
-                                ctx.config["trainer"], keep=keep)
-    out = {"sound": compare(ctx, got, ref)}
-    for mode in ctx.cell["correct"]["controls"]:
-        low = reference.train_steps(state, ids[:n], ctx.config,
-                                    ctx.config["trainer"], mode=mode,
-                                    keep=keep)
-        out[mode] = compare(ctx, low, ref)
-    return out
+
+    def follow(mode=None, trainer=ctx.config["trainer"]):
+        return reference.train_steps(lambda: make_state(ctx.seed), ids[:n],
+                                     ctx.config, trainer, mode=mode,
+                                     keep=keep)
+
+    return control_sides(ctx, got, follow, controls, faults)
 
 
 def run(ctx):

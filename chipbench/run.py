@@ -23,6 +23,7 @@ import argparse
 import importlib
 import importlib.util
 import json
+import math
 import os
 import sys
 
@@ -50,6 +51,13 @@ def load_cell(root, workload):
     config = _load(os.path.join(root, cfg_entry["file"]))
     traffic = _load(os.path.join(bench, "traffic",
                                  entry["traffic"] + ".json"))
+    steps = cell.get("check_steps", traffic.get("check_steps"))
+    for name, lim in cell.get("correct", {}).get("limits", {}).items():
+        if isinstance(lim["limit"], list) and len(lim["limit"]) != steps:
+            raise SystemExit(
+                f"chipbench: cell {workload!r}: the limit of {name} is a "
+                f"list of {len(lim['limit'])}, one number a checked step "
+                f"is {steps}")
     return manifest, entry, cell, config, traffic
 
 
@@ -173,6 +181,13 @@ def run_cell(root, workload, seed, seconds, trace, require_chip=True,
                 value, note = value
                 print(f"layer metric: {m['name']} {note}", flush=True)
             out["metrics"][m["name"]] = {"value": value, "unit": m["unit"]}
+    # last in the line: each number compared beside its limit, so that a
+    # record which keeps only a refused run's last line keeps these
+    out["compared"] = {
+        row["name"]: {"value": row["value"] if math.isfinite(row["value"])
+                      else repr(row["value"]),      # JSON has no nan or inf
+                      "limit": row["limit"]}
+        for row in res["compared"]}
     return out
 
 
@@ -188,6 +203,9 @@ def main(argv=None):
                    bool(args.trace))
     if out is None:
         return 1
+    for name, row in out["compared"].items():       # standard error's end
+        print(f"compared: {name} = {row['value']!r} limit {row['limit']!r}",
+              file=sys.stderr, flush=True)
     print(json.dumps(out), flush=True)
     return 0
 

@@ -2,14 +2,50 @@
 of their OWN (`components_<model_type>.json` beside `components.json`):
 the same reduction (`scope_reduce.reduce`), other rows and groups. The
 trace is loaded and reduced once a table and kept on the run.
+
+A reader several architectures share asks `table_of` and `costs_of` for
+the files of the run's own `model_type`, so a new configuration brings
+`components_<model_type>.json` and `costs_<model_type>.py`, lists its
+cell under the metric, and edits no reader.
 """
 from __future__ import annotations
 
+import importlib
 import os
 
 from chipbench import scope_reduce
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+
+
+def _kind(run):
+    return (run.get("config") or {}).get("model_type")
+
+
+def table_of(run, group, first):
+    """The components table a reader of `group` reads this run through:
+    `components_<model_type>.json` where that file is there and has the
+    group, else `first`, the table of the architecture the reader was
+    written for."""
+    own = f"components_{_kind(run)}.json"
+    path = os.path.join(HERE, own)
+    if own != first and os.path.exists(path) and group in scope_reduce.rules(
+            path)["groups"]:
+        return own
+    return first
+
+
+def costs_of(run, function, first=None):
+    """The module whose `function` counts this run's required work:
+    `chipbench.costs_<model_type>` where it is there and has the
+    function, else `chipbench.<first>`, or None where the reader names
+    no `first`: an architecture that brings no count of it."""
+    kind = _kind(run)
+    if kind and os.path.exists(os.path.join(HERE, f"costs_{kind}.py")):
+        own = importlib.import_module(f"chipbench.costs_{kind}")
+        if hasattr(own, function):
+            return own
+    return first and importlib.import_module("chipbench." + first)
 
 
 def reduced(run, table_file):

@@ -317,9 +317,9 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
 
-@pytest.mark.parametrize("root", [ROOT, DATA])
-def test_manifest_names_files_and_moves(root):
-    m = json.load(open(os.path.join(root, "BENCHMARK.json")))
+def check_manifest(m, root, whole=False):
+    """What every manifest `m` whose files lie under `root` has to hold;
+    `whole` for the benchmark's own (its command, paths and chips)."""
     e2e = {x["name"]: x for x in m["end_to_end"]}
     cells = {w["name"] for w in m["workloads"]}
     for group in ("configs", "workloads", "end_to_end", "per_layer"):
@@ -349,11 +349,17 @@ def test_manifest_names_files_and_moves(root):
                                           or x["unit"] == "%")
         for cell in x.get("workloads", cells):
             assert reports(cell, x["moves"]), (x["name"], cell)
-    if root == ROOT:
+    if whole:
         assert m["command"] == ["python3", "chipbench/run.py"]
         assert m["paths"] == ["chipbench", "tests/chipbench"]
         assert sum(w["chips"] == 4 for w in m["workloads"]) <= max(
             1, len(m["workloads"]) // 4)
+
+
+@pytest.mark.parametrize("root", [ROOT, DATA])
+def test_manifest_names_files_and_moves(root):
+    check_manifest(json.load(open(os.path.join(root, "BENCHMARK.json"))),
+                   root, whole=root == ROOT)
 
 
 def test_run_py_names_no_cell_config_mix_or_metric():

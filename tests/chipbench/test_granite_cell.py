@@ -340,8 +340,9 @@ def test_new_readers_with_nothing_to_read(name):
         assert bench_run.layer_metric(name).compute(dense) is None
 
 
-def test_manifest_names_the_cell_and_the_tiny_root_mirrors_it():
-    m = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+def check_manifest(m, root=ROOT):
+    """What this cell asks of a manifest `m` whose files lie under `root`:
+    by name and by membership, so that cells after it change nothing."""
     tiny = json.load(open(os.path.join(DATA, "BENCHMARK.json")))
     cell = next(w for w in m["workloads"] if w["name"] == CELL)
     assert (cell["chips"], cell["traffic"]) == (1, "pretrain-32k")
@@ -349,7 +350,7 @@ def test_manifest_names_the_cell_and_the_tiny_root_mirrors_it():
     config = next(c for c in m["configs"] if c["name"] == cell["config"])
     assert config["reduced"] == ["num_hidden_layers"]
     assert len(config["why"]) <= 200
-    _, _, cell_file, config, traffic = bench_run.load_cell(ROOT, CELL)
+    _, _, cell_file, config, traffic = bench_run.load_cell(root, CELL)
     assert traffic["kind"] == "pretrain" and traffic["seq_len"] == 32768
     assert cell_file["batch_size"] == 1
     assert set(cell_file["correct"]["limits"]) == {
@@ -358,9 +359,12 @@ def test_manifest_names_the_cell_and_the_tiny_root_mirrors_it():
     mine = {x["name"]: x for x in m["per_layer"]
             if CELL in x.get("workloads", [])}
     assert set(NEW) | set(OLD) <= set(mine)
-    assert all(mine[n]["workloads"] == [CELL] for n in NEW)
-    assert all(mine[n]["workloads"][-1] == CELL for n in OLD)
-    assert {x["name"] for x in tiny["per_layer"]} == set(mine)
+    # the tiny root lists every metric the cell is listed under
+    assert set(mine) <= {x["name"] for x in tiny["per_layer"]}
     assert CELL in next(x for x in m["end_to_end"]
                         if x["name"] == "train_tokens_per_s_chip")["workloads"]
     bench_run.load_cell(DATA, TINY)
+
+
+def test_manifest_names_the_cell_and_the_tiny_root_mirrors_it():
+    check_manifest(json.load(open(os.path.join(ROOT, "BENCHMARK.json"))))

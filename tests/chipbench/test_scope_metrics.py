@@ -24,11 +24,16 @@ YI = json.load(open(os.path.join(ROOT, "chipbench", "configs",
                                  "yi-6b-1chip.json")))
 ACCEPTED = {"device_idle_share.train", "train_mfu", "trace_lower_s",
             "collective_exposed_share", "flash_attention_roofline"}
+# read from set-up events younger than `data/ring_setup_small.json`: the
+# ring of `test_step_memory_readers.py` has them (every cell lists them
+# since ISSUE 40)
+ACCEPTED_ON_ANOTHER_RING = {"step_hbm_peak_bytes", "step_temp_bytes",
+                            "kept_residual_bytes"}
 
 
-def _new_entries():
+def _new_entries(accepted=ACCEPTED):
     m = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    return [x for x in m["per_layer"] if x["name"] not in ACCEPTED]
+    return [x for x in m["per_layer"] if x["name"] not in accepted]
 
 
 def _run(trace, steps=2, chips=1, config=YI):
@@ -147,7 +152,7 @@ def test_every_new_reader_on_the_recorded_chip_trace(recorded, monkeypatch):
                         lambda r=None, _f=scope_reduce.setup_phases: _f(ring))
     run = _run(recorded)
     got = {}
-    for x in _new_entries():
+    for x in _new_entries(ACCEPTED | ACCEPTED_ON_ANOTHER_RING):
         if "yi-6b-1chip.pretrain" not in x["workloads"]:
             continue
         value = bench_run.layer_metric(x["name"]).compute(run)

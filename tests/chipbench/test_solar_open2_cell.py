@@ -238,7 +238,8 @@ def _run(trace, counters=None, steps=1):
     return run
 
 
-def test_new_readers_on_a_hand_written_trace():
+def _hand_written():
+    """(trace, counters) of one step, written by hand."""
     ms = 1_000_000
     lyr = "jit(pure)/forward/model/layers/SolarOpen2DecoderLayer/"
     kda = lyr + "linear_attn/jvp(layers)/while/body/closed_call/checkpoint/"
@@ -262,7 +263,11 @@ def test_new_readers_on_a_hand_written_trace():
              "spans": [["train_step", 0, 700 * ms]]}
     counters = {"expert_tokens": [[800, 838, 0, 819, 801, 830, 850, 816]] * 4,
                 "dropped_pairs": 0}
-    run = _run(trace, counters)
+    return trace, counters
+
+
+def test_new_readers_on_a_hand_written_trace():
+    run = _run(*_hand_written())
     got = {}
     for name in NEW:
         value, note = bench_run.layer_metric(name).compute(run)
@@ -305,13 +310,14 @@ def test_new_readers_with_nothing_to_read():
     assert bench_run.layer_metric("kda_core_roofline").compute(dense) is None
 
 
-def test_manifest_names_the_cell_and_the_tiny_root_mirrors_it():
-    m = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+def check_manifest(m, root=ROOT):
+    """What this cell asks of a manifest `m` whose files lie under `root`:
+    by name and by membership, so that cells after it change nothing."""
     tiny = json.load(open(os.path.join(DATA, "BENCHMARK.json")))
     cell = next(w for w in m["workloads"] if w["name"] == CELL)
     assert (cell["chips"], cell["traffic"]) == (1, "pretrain-32k")
     assert len(cell["why"]) <= 200 and "1/40" in cell["why"]
-    _, _, cell_file, config, traffic = bench_run.load_cell(ROOT, CELL)
+    _, _, cell_file, config, traffic = bench_run.load_cell(root, CELL)
     assert traffic["kind"] == "pretrain" and traffic["seq_len"] == 32768
     assert cell_file["batch_size"] == 1
     assert set(cell_file["correct"]["limits"]) == {
@@ -319,8 +325,8 @@ def test_manifest_names_the_cell_and_the_tiny_root_mirrors_it():
     mine = {x["name"]: x for x in m["per_layer"]
             if CELL in x.get("workloads", [])}
     assert set(NEW) <= set(mine)
-    assert all(mine[n]["workloads"] == [CELL] for n in NEW)
-    assert {x["name"] for x in tiny["per_layer"]} == set(mine)
+    # the tiny root lists every metric the cell is listed under
+    assert set(mine) <= {x["name"] for x in tiny["per_layer"]}
     assert not {"mlp_ms_per_step", "mlp_roofline", "scope_coverage",
                 "flash_attention_roofline"} & set(mine)
     bench_run.load_cell(DATA, TINY)
@@ -328,3 +334,7 @@ def test_manifest_names_the_cell_and_the_tiny_root_mirrors_it():
     from paddle_tpu.observability import scopes
     assert {r["scope"] for r in table["components"] if "scope" in r} <= (
         set(scopes.COMPONENTS) | set(scopes.PHASES))
+
+
+def test_manifest_names_the_cell_and_the_tiny_root_mirrors_it():
+    check_manifest(json.load(open(os.path.join(ROOT, "BENCHMARK.json"))))

@@ -138,12 +138,11 @@ def test_manifest_lists_the_three_under_the_compiled_step():
     m = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
     mine = {x["name"]: x for x in m["per_layer"] if x["name"] in NAMES}
     assert set(mine) == set(NAMES)
-    assert [x["name"] for x in m["per_layer"][-3:]] == list(NAMES)
     for x in mine.values():
         assert (x["unit"], x["better"], x["source"], x["layer"],
                 x["moves"]) == ("bytes", "lower", "program_counter",
                                 "compiled step", "train_tokens_per_s_chip")
-        # membership only: the accepted tests of the other four cells pin
-        # their lists (PERF.md section 7), so those cells join in a
-        # `benchmark` PR
-        assert "yi-6b-4chip.pretrain" in x["workloads"]
+        # membership only, and by name: later metrics and cells append
+        # themselves (every cell of ISSUE 40's manifest is listed)
+        assert {"yi-6b-4chip.pretrain", "yi-6b-1chip.pretrain",
+                "dots3-note-prev-ep32.pretrain-16k"} <= set(x["workloads"])
