@@ -371,15 +371,61 @@ def test_run_py_names_no_cell_config_mix_or_metric():
     assert [n for n in names if n in src] == []
 
 
+LATENT_WIDTHS = ("qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim")
+
+
+def check_head_widths(f):
+    """A configuration file that names its head widths itself (`head_dim`,
+    or the three of latent attention) is held to those: positive whole
+    numbers that `reduced` does not list, whatever `hidden_size` over the
+    head count comes to (2048 over 20 heads of 192 + 64 and 256 is a
+    published model); one that names none states them by that quotient,
+    which then has to be whole."""
+    named = [k for k in ("head_dim",) + LATENT_WIDTHS if k in f]
+    if "head_dim" in f or set(LATENT_WIDTHS) <= set(f):
+        for k in named:
+            assert type(f[k]) is int and f[k] > 0, (k, f[k])
+            assert k not in f["reduced"], k
+    else:
+        assert f["hidden_size"] % f["num_attention_heads"] == 0, named
+
+
 def test_configuration_files_carry_their_cut():
     m = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
     for c in m["configs"]:
         f = json.load(open(os.path.join(ROOT, c["file"])))
         assert f["source"] == c["source"] and f["reduced"] == c["reduced"]
         assert f["assumed"] and f["deployment"]
-        assert f["hidden_size"] % f["num_attention_heads"] == 0
+        check_head_widths(f)
         for k in f["reduced"]:
             assert not re.search(r"(_dim|_rank|_size)$", k), k
+
+
+LATENT = {"hidden_size": 2048, "num_attention_heads": 20, "reduced": [],
+          "qk_nope_head_dim": 192, "qk_rope_head_dim": 64, "v_head_dim": 256}
+
+
+@pytest.mark.parametrize("f,holds", [
+    (LATENT, True),
+    ({"hidden_size": 2048, "num_attention_heads": 20, "reduced": []}, False),
+    ({"hidden_size": 2048, "num_attention_heads": 20, "reduced": [],
+      "head_dim": 128}, True),
+    ({"hidden_size": 4096, "num_attention_heads": 32,
+      "reduced": ["num_hidden_layers"]}, True),
+    # two of the three say nothing of the third: the quotient stands
+    ({k: v for k, v in LATENT.items() if k != "v_head_dim"}, False),
+    (dict(LATENT, reduced=["v_head_dim"]), False),
+    (dict(LATENT, qk_rope_head_dim=0), False),
+    (dict(LATENT, v_head_dim=256.5), False),
+], ids=["latent-2048-over-20", "no-width-2048-over-20", "head_dim-named",
+        "no-width-whole-quotient", "latent-without-v", "width-reduced",
+        "width-zero", "width-not-whole"])
+def test_head_width_rule(f, holds):
+    if holds:
+        check_head_widths(f)
+    else:
+        with pytest.raises(AssertionError):
+            check_head_widths(f)
 
 
 def test_peaks_table_is_keyed_by_exact_device_kind():

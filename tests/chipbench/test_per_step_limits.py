@@ -100,12 +100,15 @@ def test_the_cells_lists_have_the_length_of_their_checked_steps():
         _, _, cell, _, traffic = bench_run.load_cell(ROOT, w["name"])
         limit = cell["correct"]["limits"]["loss_gap"]["limit"]
         forms[w["name"]] = len(limit) if isinstance(limit, list) else None
-    # the four older cells keep the number, and print what they printed
-    assert forms == {
+        assert forms[w["name"]] in (None, traffic["check_steps"]), w["name"]
+    # the four older cells keep the number, and print what they printed;
+    # by name: a later cell has either form, and changes nothing here
+    accepted = {
         "yi-6b-1chip.pretrain": None, "yi-6b-4chip.pretrain": None,
         "solar-open2-250b-ep40.pretrain-32k": None,
         "granite-4.0-h-micro-pp4.pretrain-32k": None,
         "dots3-note-prev-ep32.pretrain-16k": 2}
+    assert {k: v for k, v in forms.items() if k in accepted} == accepted
 
 
 @pytest.mark.parametrize("limit", [[0.001, 0.01, 0.01], 0.0045])

@@ -310,9 +310,11 @@ def test_new_readers_with_nothing_to_read():
     assert bench_run.layer_metric("kda_core_roofline").compute(dense) is None
 
 
-def check_manifest(m, root=ROOT):
-    """What this cell asks of a manifest `m` whose files lie under `root`:
+def check_manifest(m, root=None):
+    """What this cell asks of a manifest `m` whose files lie under `root`
+    (the module's `ROOT` as it stands at the call, not at the definition):
     by name and by membership, so that cells after it change nothing."""
+    root = root or ROOT
     tiny = json.load(open(os.path.join(DATA, "BENCHMARK.json")))
     cell = next(w for w in m["workloads"] if w["name"] == CELL)
     assert (cell["chips"], cell["traffic"]) == (1, "pretrain-32k")
