@@ -20,6 +20,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..observability import scopes as _scopes
+from ..observability import spans as _spans
 from ..tensor import Parameter, Tensor
 
 
@@ -239,17 +240,35 @@ def with_partial_annotation(x, spec: P):
 # Pallas (Mosaic) kernels cannot be partitioned by GSPMD: under a mesh a
 # kernel call has to sit inside shard_map, each device running it on its
 # own slice. compile_train_step arms its mesh here while the step traces;
-# the models' kernel call sites go through shard_kernel.
-_kernel_meshes: List[Mesh] = []
+# the models' kernel call sites go through shard_kernel. Beside each armed
+# mesh: what its shard_kernel calls left unsummed, one entry a mapped call
+# (None for a call without variance tracking), for the set-up event.
+_kernel_meshes: List[tuple] = []
 
 
 @contextlib.contextmanager
 def kernel_mesh_guard(mesh: Mesh):
-    _kernel_meshes.append(mesh)
+    """Arm `mesh` for the kernel call sites traced inside; on the way out
+    one set-up event `shard_kernel.calls` (observability/scopes.py) says
+    how the calls of this trace were mapped."""
+    calls: list = []
+    _kernel_meshes.append((mesh, calls))
     try:
         yield
     finally:
         _kernel_meshes.pop()
+        if calls:
+            tracked = [c for c in calls if c is not None]
+            _spans.setup_event(
+                "shard_kernel.calls", mapped=len(calls),
+                tracked=len(tracked),
+                unsummed=",".join("+".join(c) for c in tracked))
+
+
+def _spec_axes(spec) -> tuple:
+    """The names a PartitionSpec holds, flattened."""
+    return tuple(a for e in spec if e is not None
+                 for a in (e if isinstance(e, tuple) else (e,)))
 
 
 def tp_all_reduce():
@@ -258,9 +277,12 @@ def tp_all_reduce():
     all-reduce is not in the program's own text. A row-parallel matmul's
     output is all-reduced in the forward pass and a column-parallel
     matmul's input gradient in the backward pass, both put in by the
-    partitioner under the matmul's op_name; shard_map's transpose
-    all-reduces (`psum`) the gradient of whatever entered replicated."""
-    mesh = _kernel_meshes[-1] if _kernel_meshes else None
+    partitioner under the matmul's op_name. Of shard_kernel's own sums
+    (`psum`) the ones over mp are swiglu's: the gradient of its
+    activation, which enters replicated over mp and meets a different
+    slice of the weight on each device. A row-wise call (the norms) sums
+    nothing over mp: its copies there are equal."""
+    mesh = _kernel_meshes[-1][0] if _kernel_meshes else None
     if mesh is None or "mp" not in mesh.axis_names or mesh.shape["mp"] < 2:
         return contextlib.nullcontext()
     return _scopes.scope("tp/all_reduce")
@@ -277,8 +299,24 @@ def shard_kernel(fn, in_specs, out_specs, batch: int, heads: int = 1):
     `heads`. Whatever a role does not resolve to is replicated, so the
     kernel always gets whole rows and whole heads. Weights enter
     replicated over the data axes: shard_map gathers the FSDP shards on
-    the way in and sums the weight gradient over them on the way out."""
-    mesh = _kernel_meshes[-1] if _kernel_meshes else None
+    the way in and sums the weight gradient over them on the way out.
+
+    What the backward sums. A call whose specs name "mp" (splash,
+    swiglu) runs without variance tracking: shard_map's transpose sums
+    the cotangent of every operand over every mesh axis its spec does
+    not name, and for these calls each such sum is real (swiglu's da
+    over mp, dw over the data axes). A call whose specs name no "mp"
+    (the norms, the fused cross-entropy) is row-wise: across the axes no
+    spec of it resolves to, every device holds the same rows and computes
+    the same cotangents, and summing those copies (then halving) is an
+    all-reduce of an activation that changes nothing. Such a call runs
+    with variance tracking on and its replicated operands cast to
+    varying over the axes the call IS split over, so JAX's own transpose
+    sums a weight's gradient over exactly those axes and nothing over
+    the rest. The kernels it reaches give their `pallas_call` out_shapes
+    the operand's `vma` (kernels/rms_norm, fused_norm_residual,
+    cross_entropy)."""
+    mesh, calls = _kernel_meshes[-1] if _kernel_meshes else (None, None)
     if mesh is None or mesh.size == 1:
         return fn
     data = data_axes_for(batch, mesh)
@@ -289,12 +327,28 @@ def shard_kernel(fn, in_specs, out_specs, batch: int, heads: int = 1):
     def resolve(spec):
         return P(*[roles[e] if e is not None else None for e in spec])
 
+    out_tuple = (out_specs,) if isinstance(out_specs, P) else tuple(out_specs)
+    ins = tuple(resolve(sp) for sp in in_specs)
+    outs = tuple(resolve(sp) for sp in out_tuple)
+    row_wise = not any("mp" in _spec_axes(sp)
+                       for sp in (*in_specs, *out_tuple))
+    body, unsummed = fn, None
+    if row_wise:
+        named = {a for sp in ins + outs for a in _spec_axes(sp)}
+        split = tuple(n for n in mesh.axis_names if n in named)
+        unsummed = tuple(n for n in mesh.axis_names if n not in named)
+        casts = [tuple(n for n in split if n not in _spec_axes(sp))
+                 for sp in ins]
+
+        def body(*args):
+            return fn(*[jax.lax.pcast(a, c, to="varying") if c else a
+                        for a, c in zip(args, casts)])
+
+    calls.append(unsummed)
     mapped = jax.shard_map(
-        fn, mesh=mesh,
-        in_specs=tuple(resolve(sp) for sp in in_specs),
-        out_specs=(resolve(out_specs) if isinstance(out_specs, P)
-                   else tuple(resolve(sp) for sp in out_specs)),
-        check_vma=False)
+        body, mesh=mesh, in_specs=ins,
+        out_specs=outs[0] if isinstance(out_specs, P) else outs,
+        check_vma=row_wise)
 
     def run(*args):
         with tp_all_reduce():

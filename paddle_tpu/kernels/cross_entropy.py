@@ -143,7 +143,10 @@ def _fwd(logits, labels, ignore_index, blocks=None):
     lbl2 = labels.astype(jnp.int32).reshape(n, 1)
     kern = functools.partial(_fwd_kernel, v_total=v, bv=bv,
                              ignore_index=ignore_index)
-    out_shape = [jax.ShapeDtypeStruct((n, 1), jnp.float32)] * 3
+    # inside a shard_map that tracks variance (distributed/sharding.
+    # shard_kernel) the outputs vary as the rows do; empty outside
+    out_shape = [jax.ShapeDtypeStruct((n, 1), jnp.float32,
+                                      vma=jax.typeof(logits).vma)] * 3
     interpret = not _on_tpu()
     loss, m, l = pl.pallas_call(
         kern, grid=grid,
@@ -174,7 +177,8 @@ def _bwd_rule(ignore_index, blocks, res, g):
         kern, grid=grid,
         in_specs=[x_spec, row_spec, row_spec, row_spec, row_spec],
         out_specs=x_spec,
-        out_shape=jax.ShapeDtypeStruct((n, v), logits.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, v), logits.dtype,
+                                       vma=jax.typeof(logits).vma),
         compiler_params=None if interpret else params,
         interpret=interpret,
         name="fused_ce_bwd",

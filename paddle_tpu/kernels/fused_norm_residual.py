@@ -118,8 +118,10 @@ def _fwd_impl(x, residual, weight, eps, use_pallas, block_rows):
     grid = (pl.cdiv(rows, br),)
     y, h = pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps),
-        out_shape=(jax.ShapeDtypeStruct(xf.shape, x.dtype),
-                   jax.ShapeDtypeStruct(xf.shape, x.dtype)),
+        # inside a shard_map that tracks variance (distributed/sharding.
+        # shard_kernel) both outputs vary as the rows do; empty outside
+        out_shape=(jax.ShapeDtypeStruct(xf.shape, x.dtype,
+                                        vma=jax.typeof(xf).vma),) * 2,
         grid=grid,
         in_specs=[
             pl.BlockSpec((br, H), lambda i: (i, 0)),

@@ -43,7 +43,10 @@ def _fwd_impl(x, weight, eps):
     grid = (pl.cdiv(rows, block_rows),)
     out = pl.pallas_call(
         functools.partial(_rms_kernel, eps=eps),
-        out_shape=jax.ShapeDtypeStruct(xf.shape, x.dtype),
+        # inside a shard_map that tracks variance (distributed/sharding.
+        # shard_kernel) the output varies as the rows do; empty outside
+        out_shape=jax.ShapeDtypeStruct(xf.shape, x.dtype,
+                                       vma=jax.typeof(xf).vma),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows, H), lambda i: (i, 0)),

@@ -54,9 +54,15 @@ STEP_SPANS = ("train_step.call_args", "train_step.dispatch",
 # hidden_width_products_in_group = the products inside a group's scan that
 # have a hidden_size side, shared_columns = the columns of the one product
 # a layer the groups share, stacked_out_bytes = the groups' outputs in
-# front of the output projection; once a layer a trace), the step's device
-# memory, and what jax.monitoring reports of lowering, compiling and the
-# cache.
+# front of the output projection; once a layer a trace), how the kernel
+# calls of a sharded step were mapped (distributed/sharding
+# `kernel_mesh_guard`: mapped = the shard_kernel calls that went into a
+# shard_map, tracked = those of them that ran with variance tracking, the
+# row-wise ones, unsummed = for each of those, in call order and joined by
+# ",", the mesh axes ("+" between them) over which its backward sums no
+# cotangent because no spec of the call is split over them; once a trace
+# that maps a call), the step's device memory, and what jax.monitoring
+# reports of lowering, compiling and the cache.
 # Memory, two events an operator reads with `step.lower(*batch).compile()`
 # and then `observability.spans.ring()` (the runtime's `peak_bytes_in_use`
 # is the process's high-water mark, not the step's: it never showed the
@@ -77,8 +83,8 @@ STEP_SPANS = ("train_step.call_args", "train_step.dispatch",
 SETUP = ("train_step.lower", "train_step.call_args", "train_step.trace",
          "train_step.to_mlir", "train_step.traced", "train_step.kept",
          "train_step.memory", "train_step.residuals",
-         "dsa.grid", "kda.groups", "xla.to_mlir", "xla.backend_compile",
-         "xla.cache_hit", "xla.cache_miss")
+         "dsa.grid", "kda.groups", "shard_kernel.calls", "xla.to_mlir",
+         "xla.backend_compile", "xla.cache_hit", "xla.cache_miss")
 
 _SCOPES = frozenset(COMPONENTS + COLLECTIVES)
 _tl = threading.local()          # .open: scopes open on this thread

@@ -14,6 +14,7 @@ argument) and every such test lives in this one file: under xdist only
 the worker that is handed the file loads the library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -278,6 +279,100 @@ def test_sharded_operands_compile_through_shard_kernel(topo):
         assert kernel in text, kernel
 
 
+def _all_reduces(text, shape):
+    """The all-reduce instructions of `text` with a result of `shape`
+    (XLA may have combined it with others into one tuple-shaped
+    instruction): (from shard_map's transpose, the instruction's line)."""
+    found = []
+    for line in text.splitlines():
+        m = re.search(r"= (.*?) all-reduce(-start)?\(", line)
+        if m and shape in m.group(1):
+            found.append(("/shard_map/" in line, line.strip()))
+    return found
+
+
+@pytest.mark.parametrize("call", ["rms_norm", "fused_add_rms_norm",
+                                  "fused_cross_entropy", "swiglu"])
+def test_shard_kernel_sums_a_cotangent_only_where_its_call_is_split(
+        topo, call):
+    """The backward of each `shard_kernel` call form on sharding 2 x mp 2,
+    in the chip compiler's text. A row-wise call (no "mp" in its specs)
+    leaves the activation's cotangent as it is: over mp every device holds
+    the same rows, and shard_map's transpose without variance tracking
+    summed those copies, one all-reduce of a [1, 4096, 4096] activation a
+    call. What stays is the column-parallel matmul's own (the
+    partitioner's) and dw over the data axis. swiglu keeps its sum of da
+    over mp: there the copies differ."""
+    from paddle_tpu.distributed.sharding import (kernel_mesh_guard,
+                                                 shard_kernel)
+    from paddle_tpu.models.llama import _swiglu
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("sharding", "mp"))
+
+    def arg(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    H, M = WIDTHS["7b"]
+    x = arg((2, 4096, H), jnp.bfloat16, P("sharding", None, None))
+    g = arg((H,), jnp.float32, P(None))
+    wc = arg((H, 2048), jnp.bfloat16, P("sharding", "mp"))   # column-parallel
+    bsh = P("data", None, None)
+
+    def norm(x_, g_, wc_):
+        y = shard_kernel(lambda a, w: rn.rms_norm(a, w, 1e-6),
+                         (bsh, P(None)), bsh, batch=2)(x_, g_)
+        return _sum32(y @ wc_)
+
+    def add_norm(x_, g_, wc_):
+        y, h = shard_kernel(
+            lambda r, d, w: fnr.fused_add_rms_norm(r, d, w, 1e-6),
+            (bsh, bsh, P(None)), (bsh, bsh), batch=2)(x_, 2 * x_, g_)
+        return _sum32(y @ wc_) + _sum32(h)
+
+    def loss(logits, labels):
+        return _sum32(shard_kernel(
+            lambda l, y: ce.fused_cross_entropy(l, y, -100),
+            (P("data", None), P("data")), P("data"),
+            batch=logits.shape[0])(logits, labels))
+
+    def mlp(x_, wgu):
+        return _sum32(_swiglu(x_, wgu))
+
+    fn, args, activation = {
+        "rms_norm": (norm, (x, g, wc), "bf16[1,4096,4096]"),
+        "fused_add_rms_norm": (add_norm, (x, g, wc), "bf16[1,4096,4096]"),
+        "fused_cross_entropy": (
+            loss, (arg((8192, 64000), jnp.bfloat16, P("sharding", None)),
+                   arg((8192,), jnp.int32, P("sharding"))),
+            "bf16[4096,64000]"),
+        "swiglu": (mlp, (x, arg((H, 2 * M), jnp.bfloat16,
+                                P("sharding", "mp"))), "bf16[1,4096,4096]"),
+    }[call]
+
+    def armed(*a):
+        with kernel_mesh_guard(mesh):
+            return fn(*a)
+
+    argnums = (0,) if call == "fused_cross_entropy" else tuple(
+        range(len(args)))
+    text = _compile(jax.grad(armed, argnums=argnums), *args)
+    found = _all_reduces(text, activation)
+    mapped = [line for from_map, line in found if from_map]
+    if call == "swiglu":
+        (da,) = mapped                  # over mp: device pairs (0,1), (2,3)
+        assert "replica_groups={{0,1},{2,3}}" in da, da
+        assert len(found) == 1
+    else:
+        assert not mapped, mapped
+        # the partitioner's, for the matmul's input gradient
+        assert len(found) == (0 if call == "fused_cross_entropy" else 1)
+    if call in ("rms_norm", "fused_add_rms_norm"):
+        # dw: summed over the data axis (device pairs (0,2), (1,3)) alone
+        (dw,) = [line for from_map, line in _all_reduces(text, "f32[4096]")
+                 if from_map]
+        assert "replica_groups={{0,2},{1,3}}" in dw, dw
+
+
 @pytest.mark.parametrize("cell", ["yi-6b-1chip.pretrain",
                                   "yi-6b-4chip.pretrain"])
 def test_train_step_names_its_device_operations(topo, cell):
@@ -374,6 +469,15 @@ def test_train_step_names_its_device_operations(topo, cell):
     if plan is not None:
         for cls in ("tp_all_reduce", "tp_relayout", "zero3"):
             assert sum(n for (c, _), n in counts.items() if c == cls), cls
+        # of shard_map's own sums under tp/all_reduce, the scanned layer
+        # holds ONE of an activation: swiglu's da over mp. The norms' (two
+        # more a layer, and the last norm's after the scan) were sums of
+        # equal copies and are gone
+        mapped = [line for from_map, line in _all_reduces(
+            text, "bf16[1,%d,%d]" % (traffic["seq_len"],
+                                     config["hidden_size"])) if from_map]
+        assert len(mapped) == 1 and "/mlp/" in mapped[0] \
+            and "tp/all_reduce" in mapped[0], mapped
 
 
 # -- models/solar_open2.py at the shapes of solar-open2-250b-ep40.pretrain-32k
