@@ -315,9 +315,13 @@ def _note_kept(batch, heads, seq, dim, dtype):
 
 
 def _through_splash(q, k, v, window):
-    """Whether a call without bias takes the splash route."""
+    """Whether a call without bias takes the splash route: grouped heads,
+    a window, values of another width than the keys, or heads wider than
+    the 128 lanes (latent attention's 256-wide keys and values: splash's
+    forward stamps out and log-sum-exp for the remat policy to keep, the
+    older kernel's forward would run again in the backward)."""
     return (q.shape[2] != k.shape[2] or window is not None
-            or v.shape[-1] != q.shape[-1])
+            or v.shape[-1] != q.shape[-1] or q.shape[-1] > 128)
 
 
 def flash_attention_bshd(q, k, v, causal=False, scale=None,

@@ -24,13 +24,19 @@ from .granite_hybrid import (  # noqa: F401
 )
 
 
-_LAZY = {"Dots3NoteConfig", "Dots3NoteForCausalLM", "Dots3NoteModel",
-         "dots3_note_tiny"}
+_LAZY = {"Dots3NoteConfig": "dots3_note", "Dots3NoteForCausalLM": "dots3_note",
+         "Dots3NoteModel": "dots3_note", "dots3_note_tiny": "dots3_note",
+         "Glm4MoeLiteConfig": "glm4_moe_lite",
+         "Glm4MoeLiteForCausalLM": "glm4_moe_lite",
+         "Glm4MoeLiteModel": "glm4_moe_lite",
+         "glm4_moe_lite_tiny": "glm4_moe_lite"}
 
 
 def __getattr__(name):
-    # models/dots3_note is imported when asked for, not with the package
+    # models/dots3_note and models/glm4_moe_lite (built from its layers) are
+    # imported when asked for, not with the package
     if name in _LAZY:
-        from . import dots3_note
-        return getattr(dots3_note, name)
+        import importlib
+        return getattr(importlib.import_module("." + _LAZY[name], __name__),
+                       name)
     raise AttributeError(name)

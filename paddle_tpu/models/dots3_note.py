@@ -13,11 +13,15 @@ With x the layer's normed input at position t (one sequence):
       o_{t,h} = sum_{s in S_t} softmax_{S_t}(a_{t,.,h}) v_{s,h};
       out_t = [sigmoid(x_t W_G)_h o_{t,h}]_h W_O      (a gate a head)
     a_q = sqrt(hidden / r_q), a_kv = sqrt(hidden / r_kv) where
-    `mla_rescale`. ONE body (`LatentAttention`) serves both kinds: sizes,
+    `mla_rescale`. ONE body (`LatentAttention`) serves every kind: sizes,
     rotary base and S_t differ, the code does not.
   * "sliding_attention": S_t = the `sliding_window_size` positions up to
     and with t (`kernels/flash_attention.py`: splash under a `LocalMask`,
     keys [k^N | k^R] 256 wide, values 128).
+  * "causal_attention" (`models/glm4_moe_lite.py` builds its layers from
+    this body; no dots3-note layer is of this kind): S_t = every s <= t, no
+    gate (out_t = [o_{t,h}]_h W_O) and no rescale; splash under a
+    `CausalMask`, keys [k^N | k^R] and values as wide as the config says.
   * "full_attention": S_t = the `index_topk` keys s <= t of largest index
     score (all while t < index_topk), I_{t,s} = sum_j w_{t,j}
     relu(q^I_{t,j} . k^I_s), q^I = c^Q W_IQ (`index_n_heads` of
@@ -73,6 +77,7 @@ __all__ = ["Dots3NoteConfig", "Dots3NoteModel", "Dots3NoteForCausalLM",
 _F32 = jnp.float32
 _HI = jax.lax.Precision.HIGHEST
 FULL, SLIDING = "full_attention", "sliding_attention"
+CAUSAL = "causal_attention"      # dense causal, ungated: no dots3-note layer
 
 
 @dataclass
@@ -190,12 +195,13 @@ def _latent_norm(a, w, eps, mult):
 
 
 def _windowed(q, k, v, window, scale):
-    """Dense causal attention inside a window: q, k [S, h, d], v
-    [S, h, dv] (the route for shapes no kernel takes)."""
+    """Dense causal attention inside a window (None: every causal key):
+    q, k [S, h, d], v [S, h, dv] (the route for shapes no kernel takes)."""
     S = q.shape[0]
     s = jnp.einsum("thd,shd->hts", q.astype(_F32), k.astype(_F32)) * scale
     t, c = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
-    s = jnp.where((c <= t) & (t - c < window), s, -jnp.inf)
+    seen = c <= t if window is None else (c <= t) & (t - c < window)
+    s = jnp.where(seen, s, -jnp.inf)
     return jnp.einsum("hts,shd->thd", jax.nn.softmax(s, axis=-1),
                       v.astype(_F32)).astype(v.dtype)
 
@@ -241,7 +247,8 @@ class LatentAttention(Layer):
         self.kv_a_layernorm = LlamaRMSNorm(rkv, cfg.rms_norm_eps)
         self.kv_b_proj = _param(self, (rkv, n * (dn + dv)), P(None, "mp"),
                                 dtype=dt)
-        self.gate_proj = _param(self, (h, n), P(None, "mp"), dtype=dt)
+        if kind != CAUSAL:
+            self.gate_proj = _param(self, (h, n), P(None, "mp"), dtype=dt)
         self.o_proj = _param(self, (n * dv, h), P("mp", None), dtype=dt)
         if kind == FULL:
             self.indexer = Indexer(cfg)
@@ -264,7 +271,7 @@ class LatentAttention(Layer):
 
     def _latents(self, x, ln_w, wdq, qn_w, wdkv, kvn_w, wg):
         """(c^Q [S, r_q], c^KV [S, r_kv], rotated k^R [S, d_r], the heads'
-        gates [S, n] float32)."""
+        gates [S, n] float32, or None for a kind without gates)."""
         cfg = self.cfg
         _, _, _, _, _, rkv, theta = cfg.attention(self.kind)
         a_q, a_kv = self._mults()
@@ -275,6 +282,8 @@ class LatentAttention(Layer):
             ckv = _latent_norm(down[:, :rkv], kvn_w, cfg.rms_norm_eps, a_kv)
         with scope("attn/rope"):
             kr = _rope(down[:, rkv:], theta)
+        if wg is None:
+            return cq, ckv, kr, None
         with scope("attn/gate"):
             gate = jax.nn.sigmoid((xn @ wg).astype(_F32))
         return cq, ckv, kr, gate
@@ -312,13 +321,14 @@ class LatentAttention(Layer):
                 o = jnp.swapaxes(o, 0, 1)                  # [S, hg, dv]
         else:
             from ..kernels import flash_attention as fa
-            with scope("attn/core/window"):
+            W = cfg.sliding_window_size if self.kind == SLIDING else None
+            with scope("attn/core/causal" if W is None
+                       else "attn/core/window"):
                 q = jnp.swapaxes(jnp.concatenate([qn, qr], -1), 0, 1)
                 k = jnp.swapaxes(jnp.concatenate(
                     [kn, jnp.broadcast_to(kr[None], (hg,) + kr.shape)], -1),
                     0, 1)
                 vv = jnp.swapaxes(v, 0, 1)
-                W = cfg.sliding_window_size
                 if fa.supported((1,) + q.shape, (1,) + k.shape, True,
                                 v_dim=dv, window=W):
                     o = fa.flash_attention_bshd(
@@ -326,9 +336,10 @@ class LatentAttention(Layer):
                         window=W)[0]
                 else:
                     o = _windowed(q, k, vv, W, scale)
-        with scope("attn/gate"):
-            gg = jax.lax.dynamic_slice_in_dim(gate, g * hg, hg, axis=1)
-            o = (o.astype(_F32) * gg[:, :, None]).astype(cq.dtype)
+        if gate is not None:
+            with scope("attn/gate"):
+                gg = jax.lax.dynamic_slice_in_dim(gate, g * hg, hg, axis=1)
+                o = (o.astype(_F32) * gg[:, :, None]).astype(cq.dtype)
         with scope("attn/out"):
             wo_g = jax.lax.dynamic_index_in_dim(
                 wo.reshape(G, hg * dv, -1), g, 0, keepdims=False)
@@ -338,7 +349,7 @@ class LatentAttention(Layer):
 
     def _mix(self, lat, mask, wuq, wukv, wo):
         """sum over the groups of `_group`, and the groups' log-sum-exp
-        stacked ([G, hg, S]; None for a window layer)."""
+        stacked ([G, hg, S]; None for a window or dense-causal layer)."""
         cq = lat[0]
         run = jax.checkpoint(self._group,
                              policy=core.current_remat_policy())
@@ -398,10 +409,12 @@ class LatentAttention(Layer):
             (jnp.arange(1, self._groups()), lse[1:]))
         return acc
 
-    def _sequence(self, x, ln_w, wdq, qn_w, wuq, wdkv, kvn_w, wukv, wg, wo,
-                  *index_ws):
-        """One sequence x [S, H] -> (x + mixer, L_I, pairs attended)."""
+    def _sequence(self, x, ln_w, wdq, qn_w, wuq, wdkv, kvn_w, wukv, *rest):
+        """One sequence x [S, H] -> (x + mixer, L_I, pairs attended);
+        `rest` = (W_G but for a dense-causal layer, W_O, the indexer's
+        leaves of a full layer), as `forward` lists them."""
         sg = jax.lax.stop_gradient
+        wg, wo, *index_ws = (None,) + rest if self.kind == CAUSAL else rest
         lat = jax.checkpoint(self._latents)(x, ln_w, wdq, qn_w, wdkv, kvn_w,
                                             wg)
         if self.kind != FULL:
@@ -435,8 +448,10 @@ class LatentAttention(Layer):
 
     def forward(self, x, ln_w):
         ws = [ln_w, self.q_a_proj, self.q_a_layernorm.weight, self.q_b_proj,
-              self.kv_a_proj, self.kv_a_layernorm.weight, self.kv_b_proj,
-              self.gate_proj, self.o_proj]
+              self.kv_a_proj, self.kv_a_layernorm.weight, self.kv_b_proj]
+        if self.kind != CAUSAL:
+            ws.append(self.gate_proj)
+        ws.append(self.o_proj)
         if self.kind == FULL:
             ws += self.indexer.weights()
         y, li, pairs = apply_op(self.block, to_tensor_like(x), *ws,

@@ -589,15 +589,18 @@ def margin_cross_entropy(logits, label, margin1=1.0, margin2=0.5,
 
 
 def _linear_cross_entropy(h, w, labels, block_rows, ignore_index,
-                          tied=False, logit_scale=None):
+                          tied=False, logit_scale=None,
+                          scopes=("head", "loss")):
     """Mean cross-entropy of (h @ w) against labels, a block of rows at a
     time: h [N, H], w [H, V], labels [N]. The [N, V] logits never exist:
     a block's are made, reduced and dropped, and the backward makes them
     again (`jax.checkpoint`). `tied`: w is the embedding table [V, H],
     multiplied as it is stored (no transposed copy is made);
     `logit_scale` multiplies the float32 logits (a model that divides
-    them by a constant)."""
+    them by a constant); `scopes` names the product and the reduction (a
+    second pass over the head in one step names its own)."""
     from ...observability.scopes import scope
+    head, loss = scopes
     dims = (((1,), (1 if tied else 0,)), ((), ()))
     n, hidden = h.shape
     block = min(block_rows, n)
@@ -608,12 +611,12 @@ def _linear_cross_entropy(h, w, labels, block_rows, ignore_index,
 
     def rows(args):
         hb, lb = args
-        with scope("head"):
+        with scope(head):
             lg = jax.lax.dot_general(hb, w, dims,
                                      preferred_element_type=jnp.float32)
             if logit_scale is not None:
                 lg = lg * logit_scale
-        with scope("loss"):
+        with scope(loss):
             valid = lb != ignore_index
             lse = jax.nn.logsumexp(lg, axis=-1)
             tgt = jnp.take_along_axis(
@@ -624,7 +627,7 @@ def _linear_cross_entropy(h, w, labels, block_rows, ignore_index,
     sums, counts = jax.lax.map(
         jax.checkpoint(rows), (h.reshape(-1, block, hidden),
                                labels.astype(jnp.int32).reshape(-1, block)))
-    with scope("loss"):
+    with scope(loss):
         return jnp.sum(sums) / jnp.maximum(jnp.sum(counts), 1)
 
 

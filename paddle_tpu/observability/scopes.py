@@ -38,7 +38,15 @@ COMPONENTS = ("embed", "layers", "norm", "attn/qkv", "attn/rope",
               # learned selection's indexer (projections, scores, its
               # loss and that loss's backward) and the top-k itself
               "attn/core/selected", "attn/core/window", "attn/index",
-              "attn/select")
+              "attn/select",
+              # models/glm4_moe_lite: the dense-causal latent core (nests in
+              # attn/core like the two above), and the multi-token-prediction
+              # module after the last layer: the shifted ids' embedding, the
+              # two norms and the [2H, H] product, its one expert layer
+              # (whose inner attn/* and moe/* scopes stay as they are), its
+              # pass over the shared head and its loss
+              "attn/core/causal", "mtp/embed", "mtp/proj", "mtp/block",
+              "mtp/head", "mtp/loss")
 # distributed/sharding: collectives the program itself issues
 COLLECTIVES = ("tp/all_reduce", "tp/relayout")
 # jit.TrainStep.__call__: TraceAnnotations, on the profiler's host plane
@@ -61,7 +69,10 @@ STEP_SPANS = ("train_step.call_args", "train_step.dispatch",
 # row-wise ones, unsummed = for each of those, in call order and joined by
 # ",", the mesh axes ("+" between them) over which its backward sums no
 # cotangent because no spec of the call is split over them; once a trace
-# that maps a call), the step's device memory, and what jax.monitoring
+# that maps a call), what the loss's multi-token-prediction module is
+# (models/glm4_moe_lite `losses`: depth, loss_weight, positions = the rows
+# of a sequence its loss counts, shares_embedding, shares_head, block_kind;
+# once a trace), the step's device memory, and what jax.monitoring
 # reports of lowering, compiling and the cache.
 # Memory, two events an operator reads with `step.lower(*batch).compile()`
 # and then `observability.spans.ring()` (the runtime's `peak_bytes_in_use`
@@ -83,7 +94,8 @@ STEP_SPANS = ("train_step.call_args", "train_step.dispatch",
 SETUP = ("train_step.lower", "train_step.call_args", "train_step.trace",
          "train_step.to_mlir", "train_step.traced", "train_step.kept",
          "train_step.memory", "train_step.residuals",
-         "dsa.grid", "kda.groups", "shard_kernel.calls", "xla.to_mlir",
+         "dsa.grid", "kda.groups", "shard_kernel.calls", "mtp.module",
+         "xla.to_mlir",
          "xla.backend_compile", "xla.cache_hit", "xla.cache_miss")
 
 _SCOPES = frozenset(COMPONENTS + COLLECTIVES)

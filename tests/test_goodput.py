@@ -18,13 +18,21 @@ from paddle_tpu.observability import (device_events, goodput, metrics,
                                       spans, view)
 
 
-@pytest.fixture(autouse=True)
-def _clean():
-    yield
+def _reset():
     obs.enable(False)
     metrics.reset()
     spans.clear()
     goodput.reset()
+
+
+@pytest.fixture(autouse=True)
+def _clean():
+    # before as well: under `--dist loadfile` the file that ran before this
+    # one on the worker may have left a step in the ledger (the whole run
+    # of PR 44 read 4 steps where 3 in the file's first test)
+    _reset()
+    yield
+    _reset()
 
 
 def _toy_step(n_steps=3, arm=True):
