@@ -13,6 +13,19 @@ its weight. Pairs routed to experts held elsewhere add nothing here:
 under expert parallelism their chips add them, and on one chip the
 partial sum is the result. A shared expert, if any, is added once.
 
+How rows move (`kernels/row_moves.py`): dispatch is `gather_rows`, combine
+`scatter_add_rows`, and each is the other's backward, so a layer's four
+row moves are two routines over the LIVE rows (`live` = the pairs that got
+a row; what the buffer holds past them is never used). A row is weighted
+in float32 and rounded to x's dtype; a token's rows are then summed in
+float32 and rounded once. The gathers are `jnp.take` on every route. The
+scatter-adds are a Pallas kernel on a TPU for bf16 rows a multiple of 128
+wide and the compiler's scatter elsewhere: at 10 KB rows that scatter
+took 32.8 ms a call of 16,384 buffer rows where the gather of the same
+rows took 1.4 (the dots3 cell, PERF.md section 6, PR 45). One route for
+every width: no rule of route beyond platform, dtype and tiling was
+needed. The set-up event `moe.rows` says which route a traced layer took.
+
 Dropless: the row buffer has `rows` rows for the whole layer, not a
 capacity an expert. Every pair of a held expert gets a row while
 sum(pairs here) <= rows; what does not fit is COUNTED (`dropped_pairs`,
@@ -69,11 +82,17 @@ def dropless_moe(x, w_router, w_gate_up, w_down, *, first_expert, top_k,
     (y [T, H] in x's dtype, rows per held expert [E_held] int32,
     pairs that found no row [] int32)."""
     from ...kernels.grouped_matmul import ROW_TILE, grouped_matmul
+    from ...kernels import row_moves
+    from ...observability import spans
     T, H = x.shape
     E, M = w_gate_up.shape[0], w_down.shape[1]
     pairs = T * top_k
     rows = -(-pairs // ROW_TILE) * ROW_TILE if rows is None \
         else min(rows, -(-pairs // ROW_TILE) * ROW_TILE)
+    spans.setup_event(
+        "moe.rows", route=row_moves.route(rows, T, H, x.dtype), rows=rows,
+        hidden=H, row_bytes=H * x.dtype.itemsize, tokens=T,
+        tile=row_moves.TILE, chunk=row_moves.CHUNK)
     with scope("moe/router"):
         top_i, top_w = route_top_k(x, w_router, top_k, norm_topk, scaling,
                                    bias)
@@ -89,22 +108,23 @@ def dropless_moe(x, w_router, w_gate_up, w_down, *, first_expert, top_k,
                          axis=0, dtype=jnp.int32)
         ends = jnp.minimum(jnp.cumsum(counts), rows)
         sizes = jnp.diff(ends, prepend=0)
-        dropped = jnp.sum(counts) - ends[-1]
-        valid = jnp.arange(rows) < ends[-1]
+        live = ends[-1]
+        dropped = jnp.sum(counts) - live
+        valid = jnp.arange(rows) < live
         token = jnp.where(valid, order // top_k, 0)
         w_row = jnp.where(valid, jnp.take(top_w.reshape(-1), order), 0.0)
-        xs = jnp.where(valid[:, None], jnp.take(x, token, axis=0), 0)
+        xs = row_moves.gather_rows(x, token, live)
     with scope("moe/experts"):
         gu = grouped_matmul(xs, w_gate_up, sizes)
         act = (jax.nn.silu(gu[:, :M].astype(jnp.float32))
                * gu[:, M:].astype(jnp.float32)).astype(x.dtype)
         out = grouped_matmul(act, w_down, sizes)
     with scope("moe/combine"):
-        # weighted in float32, summed per token in x's dtype (at most
-        # top_k rows a token): no [T, H] float32 buffer
+        # weighted in float32 and rounded to x's dtype a row; a token's
+        # rows (at most top_k) are summed in float32 and rounded once
         out = jnp.where(valid[:, None],
                         out.astype(jnp.float32) * w_row[:, None], 0.0)
-        y = jnp.zeros((T, H), x.dtype).at[token].add(out.astype(x.dtype))
+        y = row_moves.scatter_add_rows(out.astype(x.dtype), token, live, T)
     return y, counts, dropped
 
 

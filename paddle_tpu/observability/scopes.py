@@ -72,8 +72,13 @@ STEP_SPANS = ("train_step.call_args", "train_step.dispatch",
 # that maps a call), what the loss's multi-token-prediction module is
 # (models/glm4_moe_lite `losses`: depth, loss_weight, positions = the rows
 # of a sequence its loss counts, shares_embedding, shares_head, block_kind;
-# once a trace), the step's device memory, and what jax.monitoring
-# reports of lowering, compiling and the cache.
+# once a trace), how an expert layer moves its rows (nn/layer/moe
+# `dropless_moe`: route = "kernel" where the scatter-adds of combine and of
+# dispatch's transpose are kernels/row_moves' Pallas kernel, "xla" where
+# they are the compiler's scatter; rows = the buffer's, hidden, row_bytes,
+# tokens, tile = the targets a grid step holds, chunk = the ordered rows a
+# visit; once an expert layer a trace), the step's device memory, and what
+# jax.monitoring reports of lowering, compiling and the cache.
 # Memory, two events an operator reads with `step.lower(*batch).compile()`
 # and then `observability.spans.ring()` (the runtime's `peak_bytes_in_use`
 # is the process's high-water mark, not the step's: it never showed the
@@ -95,7 +100,7 @@ SETUP = ("train_step.lower", "train_step.call_args", "train_step.trace",
          "train_step.to_mlir", "train_step.traced", "train_step.kept",
          "train_step.memory", "train_step.residuals",
          "dsa.grid", "kda.groups", "shard_kernel.calls", "mtp.module",
-         "xla.to_mlir",
+         "moe.rows", "xla.to_mlir",
          "xla.backend_compile", "xla.cache_hit", "xla.cache_miss")
 
 _SCOPES = frozenset(COMPONENTS + COLLECTIVES)

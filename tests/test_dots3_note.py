@@ -326,17 +326,38 @@ def test_step_carries_the_new_names():
         assert name in text, name
 
 
+def test_a_traced_step_leaves_one_moe_rows_event_an_expert_layer():
+    """`moe.rows` (ISSUE 45): once an expert layer a trace, with the route
+    the layer's scatter-adds took (the compiler's, off the chip) and the
+    shape of its row buffer."""
+    from paddle_tpu.observability import spans
+    model, cfg = build(seed=5)
+    opt = popt.AdamW(learning_rate=1e-3, parameters=model.parameters())
+    step = paddle.jit.TrainStep(model, opt, lambda i, l: model.loss(i, l))
+    x = paddle.to_tensor(np.zeros((1, T), np.int32))
+    step._build()
+    spans.clear()
+    step._compiled.trace(*step._call_args((x, x)))
+    events = [ev["attrs"] for ev in spans.ring() if ev["name"] == "moe.rows"]
+    assert len(events) == cfg.num_hidden_layers - cfg.first_k_dense_replace
+    assert all(ev["route"] == "xla" and ev["tokens"] == str(T)
+               and ev["hidden"] == str(cfg.hidden_size)
+               and int(ev["row_bytes"]) == 4 * cfg.hidden_size
+               for ev in events)
+
+
 # -- the models the benchmark had lower as they did --------------------------------
 
 PARENT = {       # sha256 of the text at commit 0eb8308 (PR 32), read under
     # this suite's conftest (8 host devices), addresses and step tags out;
-    # the two `solar.*` are PR 37's program (its commit, on 2593084: the
-    # delta-rule block's own backward), the other four still PR 32's
+    # the two `solar.*` are PR 45's program (PR 37's delta-rule block with
+    # its own backward, and since PR 45 the expert layer's row moves through
+    # `kernels/row_moves.py`), the other four still PR 32's
     "llama_gqa.cpu_text": "b4b176201bc8d8fbafa942c340cb4a468ec2b616380afc060286c729b452eeeb",
-    "solar.cpu_text": "7a1d0e627cb8d3280be55ed3a56c242ac2ee9a6cdb18aa2ac868b77b7f932919",
+    "solar.cpu_text": "2fcf35eaffa7236a2bee007653adce365117788b98b453452e19921f73e888bf",
     "granite.cpu_text": "08d148d540da6c9271a7e2c82439390d9e3460aa07d063ff5a1216721e7834ab",
     "llama_gqa.tpu_jaxpr": "5324d891f9ab8d1d9e73a12910b401c5d8bf61db6d0ebfed52574e7c5fc20473",
-    "solar.tpu_jaxpr": "f73c160d60873c82a31dacf3399c142bf4df7465a775c274c24071f6d6fa30d4",
+    "solar.tpu_jaxpr": "4058235f7fbeecb8d69e3350bdf6b65b5e4fb84d34f0b6b0db3f39d795b1db3d",
     "granite.tpu_jaxpr": "532c6a60326f070f8015a095125a8f0d74817ba7e848907c22fc10b67dd019ee",
 }
 

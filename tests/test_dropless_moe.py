@@ -2,8 +2,12 @@
 is built from, each against a plain statement of the same sum:
 `nn.DroplessMoE` / `dropless_moe` (its shares against the uncut reference
 layer of `tests/reference/solar_open2.py`, droplessness under a skewed
-router), `kernels.grouped_matmul`, and `F.linear_cross_entropy` against
-the materialised logits."""
+router), the layer's row moves (`kernels/row_moves.py`: both routes against
+`jnp.take` and `.at[].add`, the kernel in the Pallas interpreter; the layer
+against the form it had before, `tests/_moe_parent_rows.py`),
+`kernels.grouped_matmul`, and `F.linear_cross_entropy` against the
+materialised logits."""
+import functools
 import os
 import sys
 
@@ -16,9 +20,11 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import paddle_tpu as paddle  # noqa: E402
 import paddle_tpu.nn.functional as F  # noqa: E402
+from paddle_tpu.kernels import row_moves as rm  # noqa: E402
 from paddle_tpu.kernels.grouped_matmul import grouped_matmul  # noqa: E402
 from paddle_tpu.nn.layer.moe import dropless_moe  # noqa: E402
 from reference import solar_open2 as ref  # noqa: E402
+import _moe_parent_rows as parent  # noqa: E402
 
 
 def _moe_weights(E, H=32, M=16, seed=0, skew=None):
@@ -103,6 +109,227 @@ def test_expert_layer_is_public_and_says_what_it_cannot_hold():
     y = layer(paddle.to_tensor(np.ones((2, 5, 32), np.float32)))
     assert y.shape == [2, 5, 32]
     assert layer.expert_tokens.shape == [2]
+
+
+# -- the row moves -----------------------------------------------------------
+
+ROWS, TOKENS, WIDTH, TOP_K = 256, 512, 128, 8     # shapes the kernel takes
+
+
+@pytest.fixture
+def kernel_route(monkeypatch):
+    """The chip's route on the CPU: the scatter-add kernel in the Pallas
+    interpreter, at tiles small enough that a case walks several."""
+    fused = rm._scatter_add_fused
+    monkeypatch.setattr(rm, "_on_tpu", lambda: True)
+    monkeypatch.setattr(rm, "_scatter_add_fused", functools.partial(
+        fused, interpret=True, tile=64))
+
+
+def _rows_case(case):
+    """(src [ROWS, WIDTH] bf16 with NaN in its dead rows, idx, live)."""
+    rng = np.random.default_rng(3)
+    idx = rng.integers(0, TOKENS, ROWS).astype(np.int32)
+    live = {"top_k_rows_on_one_token": 200, "tokens_with_none": 60,
+            "nothing_live": 0, "all_live": ROWS, "one_live": 1,
+            "a_chunk_and_one": 129}[case]
+    if case == "top_k_rows_on_one_token":
+        idx[40:40 + TOP_K] = 77               # and its rows lie side by side
+        idx[150] = 77                         # one more, far from them
+    if case == "tokens_with_none":
+        idx[:live] = 448 + idx[:live] % 64    # the first seven tiles are empty
+    src = rng.standard_normal((ROWS, WIDTH)).astype(np.float32)
+    src[live:] = np.nan
+    idx[live:] = rng.integers(-5, 2 * TOKENS, ROWS - live)   # never looked at
+    return (jnp.asarray(src, jnp.bfloat16), jnp.asarray(idx),
+            jnp.asarray(live, jnp.int32))
+
+
+def _plain_scatter_add(src, idx, live):
+    out = np.zeros((TOKENS, WIDTH), np.float64)
+    np.add.at(out, np.asarray(idx)[:live],
+              np.asarray(src, np.float32)[:live].astype(np.float64))
+    return out
+
+
+ROW_CASES = ["top_k_rows_on_one_token", "tokens_with_none", "nothing_live",
+             "all_live", "one_live", "a_chunk_and_one"]
+
+
+@pytest.mark.parametrize("case", ROW_CASES)
+@pytest.mark.parametrize("route", ["xla", "kernel"])
+def test_scatter_add_rows_is_the_float32_sum_of_the_live_rows(
+        case, route, request):
+    if route == "kernel":
+        request.getfixturevalue("kernel_route")
+    assert rm.route(ROWS, TOKENS, WIDTH, jnp.bfloat16) == route
+    src, idx, live = _rows_case(case)
+    got = np.asarray(rm.scatter_add_rows(src, idx, live, TOKENS), np.float32)
+    want = _plain_scatter_add(src, idx, int(live))
+    # float32 accumulation, one rounding: the rounded float64 sum
+    np.testing.assert_array_equal(
+        got, np.asarray(jnp.asarray(want, jnp.bfloat16), np.float32))
+    assert not np.any(got[np.setdiff1d(np.arange(TOKENS),
+                                       np.asarray(idx)[:int(live)])])
+
+
+@pytest.mark.parametrize("case", ROW_CASES)
+def test_gather_rows_is_take_over_the_live_rows(case):
+    src, idx, live = _rows_case(case)
+    table = jax.random.normal(jax.random.key(2), (TOKENS, WIDTH), jnp.bfloat16)
+    got = np.asarray(rm.gather_rows(table, idx, live), np.float32)
+    n = int(live)
+    np.testing.assert_array_equal(
+        got[:n], np.asarray(table, np.float32)[np.asarray(idx)[:n]])
+    assert not np.any(got[n:])
+
+
+@pytest.mark.parametrize("case", ["top_k_rows_on_one_token", "nothing_live"])
+@pytest.mark.parametrize("route", ["xla", "kernel"])
+def test_each_row_move_is_the_others_transpose(case, route, request):
+    if route == "kernel":
+        request.getfixturevalue("kernel_route")
+    src, idx, live = _rows_case(case)
+    table = jax.random.normal(jax.random.key(2), (TOKENS, WIDTH), jnp.bfloat16)
+    g_rows = jnp.nan_to_num(src) + 1          # a cotangent of the gather
+    _, pull = jax.vjp(lambda t: rm.gather_rows(t, idx, live), table)
+    np.testing.assert_array_equal(
+        np.asarray(pull(g_rows)[0], np.float32),
+        np.asarray(rm.scatter_add_rows(g_rows, idx, live, TOKENS),
+                   np.float32))
+    _, pull = jax.vjp(lambda s: rm.scatter_add_rows(s, idx, live, TOKENS),
+                      jnp.nan_to_num(src))
+    np.testing.assert_array_equal(
+        np.asarray(pull(table)[0], np.float32),
+        np.asarray(rm.gather_rows(table, idx, live), np.float32))
+
+
+def test_the_kernels_walk_is_tiles_plus_chunks_and_skips_dead_chunks():
+    """`_visits` over a buffer whose first 150 places are live: every tile
+    once at least, a tile's visits side by side, no chunk past the live
+    ones, steps behind the last visit flagged as nothing."""
+    key = np.full(512, 1024, np.int32)
+    key[:150] = np.sort(np.random.default_rng(0).integers(0, 1024, 150))
+    t, c, f = map(np.asarray, rm._visits(jnp.asarray(key), 150, 1024, 256,
+                                         128))
+    assert len(t) == 1024 // 256 + 512 // 128
+    assert sorted(set(t.tolist())) == [0, 1, 2, 3] and (np.diff(t) >= 0).all()
+    assert c.max() <= 149 // 128
+    used = f != 0
+    assert [int((f[t == i] & 2 != 0).sum()) for i in range(4)] == [1] * 4
+    assert [int((f[t == i] & 4 != 0).sum()) for i in range(4)] == [1] * 4
+    for i in range(4):                        # a tile's rows lie in its chunks
+        mine = np.nonzero((key >= 256 * i) & (key < 256 * (i + 1)))[0]
+        assert set(mine // 128) == set(c[(t == i) & (f & 1 != 0)].tolist())
+    assert not used[np.nonzero(used)[0][-1] + 1:].any()
+
+
+def _bf16_layer(E=8, held=4, k=4, seed=0):
+    w, x = _moe_weights(E, H=WIDTH, M=128, seed=seed)
+    x = jnp.tile(x, (4, 1)) + 0.1 * jax.random.normal(
+        jax.random.key(seed + 9), (256, WIDTH))
+    bf = lambda a: a.astype(jnp.bfloat16)
+    return (bf(x), bf(w["router"]), bf(w["experts_gate_up"][:held]),
+            bf(w["experts_down"][:held])), dict(first_expert=0, top_k=k)
+
+
+@pytest.mark.parametrize("rows", [None, 2048, 128],
+                         ids=["rows_are_pairs", "rows_over_pairs",
+                              "buffer_overflows"])
+@pytest.mark.parametrize("route", ["xla", "kernel"])
+def test_layer_and_gradients_match_the_form_before(rows, route, request):
+    """`dropless_moe` in bf16 against the parent's gather / scatter-add
+    form: the output and the gradients of x, the router and both expert
+    weights within bf16 rounding, the counters to the digit; with a buffer
+    that overflows the dropped pairs are counted and add nothing."""
+    if route == "kernel":
+        request.getfixturevalue("kernel_route")
+    args, kw = _bf16_layer()
+    g = jax.random.normal(jax.random.key(5), args[0].shape, jnp.bfloat16)
+
+    def loss(f):
+        def run(*a):
+            y, counts, dropped = f(*a, rows=rows, **kw)
+            return jnp.sum(y.astype(jnp.float32) * g), (y, counts, dropped)
+        return jax.jit(jax.value_and_grad(run, argnums=(0, 1, 2, 3),
+                                          has_aux=True))
+    (_, (y, counts, dropped)), grads = loss(dropless_moe)(*args)
+    (_, (y0, counts0, dropped0)), grads0 = loss(parent.dropless_moe)(*args)
+    assert counts.tolist() == counts0.tolist()
+    assert int(dropped) == int(dropped0)
+    assert (int(dropped) > 0) == (rows == 128)
+    for got, want in zip((y,) + grads, (y0,) + grads0):
+        got, want = (np.asarray(a, np.float32) for a in (got, want))
+        np.testing.assert_allclose(got, want, rtol=2 ** -6,
+                                   atol=2 ** -7 * np.abs(want).max())
+
+
+def test_a_tokens_float32_sum_is_no_further_from_float64_than_bf16_adds():
+    """Eight bf16 rows a token: summed in float32 and rounded once they
+    lie no further from the float64 sum than added one at a time in bf16,
+    token by token, and nearer in all."""
+    rng = np.random.default_rng(1)
+    idx = jnp.asarray(np.repeat(np.arange(ROWS // TOP_K), TOP_K), jnp.int32)
+    src = jnp.asarray(rng.standard_normal((ROWS, WIDTH)), jnp.bfloat16)
+    exact = _plain_scatter_add(src, idx, ROWS)
+    new = np.asarray(rm.scatter_add_rows(src, idx, jnp.int32(ROWS), TOKENS),
+                     np.float64)
+    old = np.asarray(jnp.zeros((TOKENS, WIDTH), jnp.bfloat16).at[idx].add(src),
+                     np.float64)
+    assert (np.abs(new - exact) <= np.abs(old - exact) + 1e-12).all()
+    assert np.abs(new - exact).sum() < 0.5 * np.abs(old - exact).sum()
+
+
+def _primitives(jaxpr, found=None):
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        found.append((eqn.primitive.name,
+                      tuple(getattr(v.aval, "shape", ()) for v in eqn.outvars)))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _primitives(sub, found)
+    return found
+
+
+def test_the_kernel_route_holds_no_scatter_add_of_rows(monkeypatch):
+    """On the chip's route the layer's forward holds no scatter-add at all,
+    and its backward none that writes [tokens, hidden]: the four row moves
+    are two gathers and two calls of the kernel (the router's
+    `take_along_axis` keeps its own small transpose)."""
+    monkeypatch.setattr(rm, "_on_tpu", lambda: True)
+    args, kw = _bf16_layer()
+    T, H = args[0].shape
+    fwd = _primitives(jax.make_jaxpr(
+        lambda *a: dropless_moe(*a, **kw)[0])(*args).jaxpr)
+    assert not [p for p in fwd if p[0] == "scatter-add"]
+    assert [p[0] for p in fwd].count("pallas_call") == 1
+    both = _primitives(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(dropless_moe(*a, **kw)[0].astype(jnp.float32)),
+        argnums=(0, 1, 2, 3)))(*args).jaxpr)
+    assert not [p for p in both if p[0] == "scatter-add" and (T, H) in p[1]]
+    assert [p[0] for p in both].count("pallas_call") == 2
+    monkeypatch.setattr(rm, "_on_tpu", lambda: False)
+    plain = _primitives(jax.make_jaxpr(
+        lambda *a: dropless_moe(*a, **kw)[0])(*args).jaxpr)
+    assert [p for p in plain if p[0] == "scatter-add"]
+
+
+@pytest.mark.parametrize("on_tpu,dtype,route", [
+    (True, "bfloat16", "kernel"), (True, "float32", "xla"),
+    (False, "bfloat16", "xla")])
+def test_a_traced_layer_leaves_one_moe_rows_event(monkeypatch, on_tpu, dtype,
+                                                  route):
+    from paddle_tpu.observability import scopes, spans
+    monkeypatch.setattr(rm, "_on_tpu", lambda: on_tpu)
+    args, kw = _bf16_layer()
+    args = tuple(a.astype(dtype) for a in args)
+    spans.clear()
+    jax.make_jaxpr(lambda *a: dropless_moe(*a, **kw)[0])(*args)
+    events = [ev["attrs"] for ev in spans.ring() if ev["name"] == "moe.rows"]
+    assert events == [{
+        "route": route, "rows": "1024", "hidden": str(WIDTH),
+        "row_bytes": str(WIDTH * jnp.dtype(dtype).itemsize), "tokens": "256",
+        "tile": str(rm.TILE), "chunk": str(rm.CHUNK)}]
+    assert "moe.rows" in scopes.SETUP
 
 
 # -- the blocked head + loss --------------------------------------------------

@@ -103,10 +103,12 @@ def test_a_trace_carries_the_modules_event_and_the_new_names_once():
 
 # -- the model whose layers this one shares traces as it did ------------------
 
-DOTS3_PARENT = {   # sha256 of the step's jaxpr at commit 45b00d7 (PR 43), read
-    # under this suite's conftest, addresses and step tags out
-    False: "820e8110826ed0f0ca86a5cf77929613f8557aafac8ce4a00c4f1fe169a315fa",
-    True: "bd16bd524ba36a1ed587ccb2332aa0a21a898f9e4e8ddecbf2853c05df3dcf9c",
+DOTS3_PARENT = {   # sha256 of the step's jaxpr, read under this suite's
+    # conftest, addresses and step tags out: commit 45b00d7's (PR 43) but for
+    # the expert layer, whose rows move through `kernels/row_moves.py` since
+    # PR 45 (pinned again there; PR 44 held these to 820e8110... / bd16bd52...)
+    False: "5013cc1e4a89bd2cfcc2304610693e429effe7878cf60d0da003000c083ae3cb",
+    True: "16205d7680ee1845b2dc54dd695514efe32258f48c40b2f5c793ea67cc43a3e7",
 }
 
 

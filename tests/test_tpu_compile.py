@@ -32,6 +32,7 @@ from paddle_tpu.kernels import grouped_matmul as gm
 from paddle_tpu.kernels import paged_attention as pa
 from paddle_tpu.kernels import ragged_paged_attention as rpa
 from paddle_tpu.kernels import rms_norm as rn
+from paddle_tpu.kernels import row_moves as rows
 from paddle_tpu.kernels import short_conv as sc
 from paddle_tpu.kernels import sparse_select_attention as dsa
 from paddle_tpu.kernels import ssd
@@ -65,7 +66,8 @@ def _as_on_the_chip(monkeypatch):
     interpret off), compile at the program's own matmul precision, and
     keep these compiles out of the persistent cache: an entry written
     for a described chip cannot be read back without one."""
-    for mod in (ba, ce, dsa, fa, fnr, gdr, gm, pa, rpa, rn, sc, sg, ssd):
+    for mod in (ba, ce, dsa, fa, fnr, gdr, gm, pa, rpa, rn, rows, sc, sg,
+                ssd):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
     from jax.experimental.compilation_cache import compilation_cache as cc
     cache_was = jax.config.jax_enable_compilation_cache
@@ -740,3 +742,80 @@ def test_dots3_note_kernels_at_the_cells_shapes(one_chip):
         fa.flash_attention_bshd(q_, k_, v_, causal=True, window=513)),
         argnums=(0, 1, 2)), q, k, v)
     assert "splash_mqa_fwd" in text and "splash_mqa_dkv" in text
+
+
+# (buffer rows, hidden, tokens) of the three cells that hold an expert layer
+CELL_ROWS = {"dots3": (16384, 5120, 16384), "solar": (16384, 4096, 32768),
+             "glm": (32768, 2048, 16384)}
+
+
+def _no_scatter_of(text, shape):
+    return not [line for line in text.splitlines() if " scatter(" in line
+                and line.split("=")[1].lstrip().startswith(shape)]
+
+
+@pytest.mark.parametrize("cell", CELL_ROWS)
+def test_row_moves_at_the_cells_shapes(one_chip, cell):
+    """`gather_rows` and `scatter_add_rows` forward and backward, inside a
+    `jax.checkpoint` as `Dots3NoteDecoderLayer._experts` runs them, at each
+    cell's buffer rows, width and tokens: every scatter-add is the Pallas
+    kernel (its float32 tile, two chunks and two output blocks fit the
+    VMEM limit the file sets) and the compiled text holds no scatter of a
+    [tokens, hidden] array."""
+    R, H, T = CELL_ROWS[cell]
+    assert rows.route(R, T, H, jnp.bfloat16) == "kernel"
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    @jax.checkpoint
+    def moves(x, w, idx, live):
+        return rows.scatter_add_rows(rows.gather_rows(x, idx, live) * w,
+                                     idx, live, T)
+
+    text = _compile(jax.grad(
+        lambda *a: jnp.sum(moves(*a).astype(jnp.float32) ** 2),
+        argnums=(0, 1)), sds((T, H)), sds((R, 1)), sds((R,), jnp.int32),
+        sds((), jnp.int32))
+    assert _mosaic_calls(text, "moe_scatter_add_rows") >= 2
+    assert _no_scatter_of(text, f"bf16[{T},{H}]")
+
+
+def test_dots3_expert_half_layer_beside_the_form_before(one_chip):
+    """The routed half of the dots3 cell's expert layer (16,384 tokens and
+    buffer rows of 5120, 8 experts of 1536 held, top-8 of 256) under
+    `jax.checkpoint`, forward and backward: two calls of the scatter-add
+    kernel, no scatter of a [tokens, hidden] array, and the program's peak
+    printed beside that of the form before (`tests/_moe_parent_rows.py`);
+    the whole step's two peaks are in PERF.md section 6 (the cell has 0.43
+    GiB of headroom)."""
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    import _moe_parent_rows as parent
+    from paddle_tpu.nn.layer.moe import dropless_moe
+    (R, H, T), M, E, k = CELL_ROWS["dots3"], 1536, 8, 8
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sds((T, H)), sds((H, 32 * E)), sds((E, H, 2 * M)),
+            sds((E, M, H)))
+
+    def half(layer):
+        routed = jax.checkpoint(lambda x, *ws: x + layer(
+            x, *ws, first_expert=0, top_k=k, rows=R)[0])
+        return jax.jit(jax.grad(
+            lambda *a: jnp.sum(routed(*a).astype(jnp.float32) ** 2),
+            argnums=(0, 1, 2, 3))).lower(*args).compile()
+
+    new, old = half(dropless_moe), half(parent.dropless_moe)
+    assert _mosaic_calls(new.as_text(), "moe_scatter_add_rows") == 2
+    assert _no_scatter_of(new.as_text(), f"bf16[{T},{H}]")
+    assert not _no_scatter_of(old.as_text(), f"bf16[{T},{H}]")
+    peak, before = (c.memory_analysis().peak_memory_in_bytes
+                    for c in (new, old))
+    print(f"dots3 expert half layer: peak {peak / 2**30:.3f} GiB, the form "
+          f"before {before / 2**30:.3f}")
+    # the ordered copy and its index lists, and nothing else of the
+    # buffer's size, may be live beside what the form before held
+    assert peak <= before + 1.1 * R * H * 2
