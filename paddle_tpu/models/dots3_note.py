@@ -585,14 +585,17 @@ class Dots3NoteForCausalLM(Layer):
             axis=1).reshape(-1)
 
         def head_loss(x, norm_w, w):
-            xn = _rms(x, norm_w, cfg.rms_norm_eps)
+            # the last norm's output is not kept: the norm alone runs
+            # again in the backward
+            xn = jax.checkpoint(_rms, static_argnums=2)(
+                x, norm_w, cfg.rms_norm_eps)
             return _linear_cross_entropy(
                 xn.reshape(-1, xn.shape[-1]), w, nxt, cfg.loss_block_rows,
                 -100)
 
         x, aux = self.model(input_ids, final_norm=False, with_aux=True)
-        lm = apply_op(jax.checkpoint(head_loss), x, self.model.norm.weight,
-                      self.lm_head, name="head_loss")
+        lm = apply_op(head_loss, x, self.model.norm.weight, self.lm_head,
+                      name="head_loss")
         return lm, aux
 
     def loss(self, input_ids, labels):

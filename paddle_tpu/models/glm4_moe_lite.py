@@ -213,12 +213,15 @@ class Glm4MoeLiteForCausalLM(Layer):
         cfg = self.cfg
 
         def head_loss(x_, norm_w_, w):
-            xn = _rms(x_, norm_w_, cfg.rms_norm_eps)
+            # the last norm's output is not kept: the norm alone runs
+            # again in the backward
+            xn = jax.checkpoint(_rms, static_argnums=2)(
+                x_, norm_w_, cfg.rms_norm_eps)
             return _linear_cross_entropy(
                 xn.reshape(-1, xn.shape[-1]), w, labels,
                 cfg.loss_block_rows, -100, scopes=scopes)
 
-        return apply_op(jax.checkpoint(head_loss), x, norm_w, self.lm_head,
+        return apply_op(head_loss, x, norm_w, self.lm_head,
                         name=name)
 
     def losses(self, input_ids, labels):

@@ -29,10 +29,11 @@ Memory at long sequences decides the structure, as in
 + MLP) is ONE taped operation whose backward recomputes it
 (`jax.checkpoint`), so only the two halves' inputs are kept a layer. A
 Mamba mixer's widest tensors ([tokens, 8512] at the published widths)
-fit whole, so it does not go over its heads in groups. The last norm,
-the tied head and the cross-entropy go over the rows in blocks
-(`F.linear_cross_entropy`'s body, the table multiplied as it is stored)
-and are recomputed as well.
+fit whole, so it does not go over its heads in groups. The tied head
+and the cross-entropy go over the rows in blocks
+(`F.linear_cross_entropy`'s body, the table multiplied as it is stored),
+a block's gradients made beside its loss from the one set of logits;
+only the last norm in front of them is recomputed.
 """
 from __future__ import annotations
 
@@ -362,15 +363,15 @@ class GraniteHybridForCausalLM(Layer):
             axis=1).reshape(-1)
 
         def head_loss(x, norm_w, table):
-            # the last norm is recomputed with the blocks of logits: its
-            # output is not kept either
-            xn = _rms(x, norm_w, cfg.rms_norm_eps)
+            # the last norm's output is not kept: the norm alone runs
+            # again in the backward
+            xn = jax.checkpoint(_rms, static_argnums=2)(
+                x, norm_w, cfg.rms_norm_eps)
             return _linear_cross_entropy(
                 xn.reshape(-1, xn.shape[-1]), table, nxt,
                 cfg.loss_block_rows, -100, tied=True,
                 logit_scale=1.0 / cfg.logits_scaling)
 
-        return apply_op(jax.checkpoint(head_loss),
-                        self.model(input_ids, final_norm=False),
+        return apply_op(head_loss, self.model(input_ids, final_norm=False),
                         self.model.norm.weight, self.model.embed_tokens,
                         name="head_loss")

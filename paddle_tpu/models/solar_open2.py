@@ -26,8 +26,8 @@ a time (`jax.checkpoint`). The softmax mixer sums its groups' projected
 parts (one KV head with its query heads). The delta-rule mixer norms and
 down-projects once a layer, scans `kda_head_group` heads a group and
 projects the groups' stacked outputs at once: one [tokens, heads x dim]
-array a layer where a float32 [tokens, hidden] sum was. The last norm, the
-head and the loss go over blocks of rows and are recomputed as well.
+array a layer where a float32 [tokens, hidden] sum was. Head and loss go
+over blocks of rows, a block's gradients made beside its loss (norm redone).
 """
 from __future__ import annotations
 
@@ -484,15 +484,15 @@ class SolarOpen2ForCausalLM(Layer):
             axis=1).reshape(-1)
 
         def head_loss(x, norm_w, w):
-            # the last norm is recomputed with the blocks of logits: its
-            # output is not kept either
-            xn = _rms(x, norm_w, cfg.rms_norm_eps)
+            # the last norm's output is not kept: the norm alone runs
+            # again in the backward
+            xn = jax.checkpoint(_rms, static_argnums=2)(
+                x, norm_w, cfg.rms_norm_eps)
             return _linear_cross_entropy(
                 xn.reshape(-1, xn.shape[-1]), w, nxt, cfg.loss_block_rows,
                 -100)
 
-        return apply_op(jax.checkpoint(head_loss),
-                        self.model(input_ids, final_norm=False),
+        return apply_op(head_loss, self.model(input_ids, final_norm=False),
                         self.model.norm.weight, self.lm_head,
                         name="head_loss")
 

@@ -592,15 +592,23 @@ def _linear_cross_entropy(h, w, labels, block_rows, ignore_index,
                           tied=False, logit_scale=None,
                           scopes=("head", "loss")):
     """Mean cross-entropy of (h @ w) against labels, a block of rows at a
-    time: h [N, H], w [H, V], labels [N]. The [N, V] logits never exist:
-    a block's are made, reduced and dropped, and the backward makes them
-    again (`jax.checkpoint`). `tied`: w is the embedding table [V, H],
-    multiplied as it is stored (no transposed copy is made);
-    `logit_scale` multiplies the float32 logits (a model that divides
-    them by a constant); `scopes` names the product and the reduction (a
-    second pass over the head in one step names its own)."""
+    time: h [N, H], w [H, V], labels [N]. The [N, V] logits never exist,
+    and a block's are made ONCE: under differentiation (a
+    `jax.custom_vjp`) the block's share of dh and of dw is made beside
+    its loss, from the same float32 logits, and the backward only
+    multiplies the two by the loss's cotangent; an evaluation that is not
+    differentiated makes the one product a block and no gradient, and an
+    argument that is not differentiated (a frozen head) gets none. dw is
+    summed over the blocks in w's dtype, each block's product made in
+    float32. Forward-mode differentiation does not pass a custom_vjp: no
+    caller uses it. `tied`: w is the embedding table [V, H], multiplied as
+    it is stored (no transposed copy is made); `logit_scale` multiplies
+    the float32 logits (a model that divides them by a constant);
+    `scopes` names the products and the reduction (a second pass over the
+    head in one step names its own)."""
     from ...observability.scopes import scope
     head, loss = scopes
+    f32 = jnp.float32
     dims = (((1,), (1 if tied else 0,)), ((), ()))
     n, hidden = h.shape
     block = min(block_rows, n)
@@ -608,12 +616,14 @@ def _linear_cross_entropy(h, w, labels, block_rows, ignore_index,
     if pad:
         h = jnp.pad(h, ((0, pad), (0, 0)))
         labels = jnp.pad(labels, (0, pad), constant_values=ignore_index)
+    labels = labels.astype(jnp.int32).reshape(-1, block)
+    with scope(loss):
+        count = jnp.maximum(jnp.sum(labels != ignore_index), 1).astype(f32)
 
-    def rows(args):
-        hb, lb = args
+    def rows(hb, w_, lb):
+        """A block's (loss sum, float32 logits, their logsumexp)."""
         with scope(head):
-            lg = jax.lax.dot_general(hb, w, dims,
-                                     preferred_element_type=jnp.float32)
+            lg = jax.lax.dot_general(hb, w_, dims, preferred_element_type=f32)
             if logit_scale is not None:
                 lg = lg * logit_scale
         with scope(loss):
@@ -621,14 +631,56 @@ def _linear_cross_entropy(h, w, labels, block_rows, ignore_index,
             lse = jax.nn.logsumexp(lg, axis=-1)
             tgt = jnp.take_along_axis(
                 lg, jnp.where(valid, lb, 0)[:, None], axis=-1)[:, 0]
-            return (jnp.sum(jnp.where(valid, lse - tgt, 0.0)),
-                    jnp.sum(valid))
+            return jnp.sum(jnp.where(valid, lse - tgt, 0.0)), lg, lse
 
-    sums, counts = jax.lax.map(
-        jax.checkpoint(rows), (h.reshape(-1, block, hidden),
-                               labels.astype(jnp.int32).reshape(-1, block)))
-    with scope(loss):
-        return jnp.sum(sums) / jnp.maximum(jnp.sum(counts), 1)
+    def mean(sums):
+        with scope(loss):
+            return jnp.sum(sums) / count
+
+    @jax.custom_vjp
+    def blocks(h3, w_, lb2):
+        return mean(jax.lax.map(lambda a: rows(a[0], w_, a[1])[0],
+                                (h3, lb2)))
+
+    def fwd(h3, w_, lb2):
+        want_dh, want_dw = h3.perturbed, w_.perturbed
+        h3, w_, lb2 = h3.value, w_.value, lb2.value
+
+        def step(dw, args):
+            hb, lb = args
+            s, lg, lse = rows(hb, w_, lb)
+            with scope(loss):
+                # d mean / d logits: (softmax - onehot) on the rows that
+                # count, through the scale
+                each = (lb != ignore_index).astype(f32) / count
+                if logit_scale is not None:
+                    each = each * logit_scale
+                hit = jnp.arange(lg.shape[-1])[None, :] == lb[:, None]
+                dlg = (jnp.exp(lg - lse[:, None]) - hit) * each[:, None]
+            dhb = None
+            with scope(head):
+                if want_dh:
+                    dhb = jax.lax.dot_general(
+                        dlg, w_, (((1,), (0 if tied else 1,)), ((), ())),
+                        preferred_element_type=f32).astype(hb.dtype)
+                if want_dw:
+                    pair = (dlg, hb) if tied else (hb, dlg)
+                    dw = dw + jax.lax.dot_general(
+                        *pair, (((0,), (0,)), ((), ())),
+                        preferred_element_type=f32).astype(dw.dtype)
+            return dw, (s, dhb)
+
+        dw, (sums, dh3) = jax.lax.scan(
+            step, jnp.zeros_like(w_) if want_dw else None, (h3, lb2))
+        return mean(sums), (dh3, dw)
+
+    def bwd(kept, g):
+        with scope(head):
+            return tuple(None if d is None else (g * d).astype(d.dtype)
+                         for d in kept) + (None,)
+
+    blocks.defvjp(fwd, bwd, symbolic_zeros=True)
+    return blocks(h.reshape(-1, block, hidden), w, labels)
 
 
 def linear_cross_entropy(hidden, weight, labels, block_rows=2048,
@@ -638,9 +690,11 @@ def linear_cross_entropy(hidden, weight, labels, block_rows=2048,
     computed `block_rows` rows at a time so that the logits of a long
     sequence over a large vocabulary are never held whole (at 32768 rows
     x 24576 columns they are 3.2 GB in float32, and as much again for
-    their gradient). hidden [..., H], weight [H, V] (or, `tied`, the
-    embedding table [V, H]), labels [...]; `logit_scale` multiplies the
-    logits."""
+    their gradient), and a block's are made once: where the loss is
+    differentiated, the block's gradients are made beside its loss and
+    the backward only scales them by the loss's cotangent. hidden
+    [..., H], weight [H, V] (or, `tied`, the embedding table [V, H]),
+    labels [...]; `logit_scale` multiplies the logits."""
     hidden, weight = to_tensor_like(hidden), to_tensor_like(weight)
     lb = unwrap(labels).reshape(-1)
     H = hidden.shape[-1]

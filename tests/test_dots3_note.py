@@ -350,15 +350,17 @@ def test_a_traced_step_leaves_one_moe_rows_event_an_expert_layer():
 
 PARENT = {       # sha256 of the text at commit 0eb8308 (PR 32), read under
     # this suite's conftest (8 host devices), addresses and step tags out;
-    # the two `solar.*` are PR 45's program (PR 37's delta-rule block with
-    # its own backward, and since PR 45 the expert layer's row moves through
-    # `kernels/row_moves.py`), the other four still PR 32's
+    # the two `llama_gqa.*` still PR 32's; the other four are PR 46's
+    # program: `solar.*` with PR 37's delta-rule block and PR 45's row
+    # moves (`kernels/row_moves.py`), and both models with the head + loss
+    # that makes a block's gradients beside its loss (against commit
+    # 4ccd5e4 their jaxprs differ in that one stretch and nowhere else)
     "llama_gqa.cpu_text": "b4b176201bc8d8fbafa942c340cb4a468ec2b616380afc060286c729b452eeeb",
-    "solar.cpu_text": "2fcf35eaffa7236a2bee007653adce365117788b98b453452e19921f73e888bf",
-    "granite.cpu_text": "08d148d540da6c9271a7e2c82439390d9e3460aa07d063ff5a1216721e7834ab",
+    "solar.cpu_text": "828a6756db725ea97a7568847957159b50837da7a6c1d0b4ac2844606f3d0083",
+    "granite.cpu_text": "557ddf2fb05388c761d8d5d4256b73f3c7542a3d10d555e0f35270360e53f8e0",
     "llama_gqa.tpu_jaxpr": "5324d891f9ab8d1d9e73a12910b401c5d8bf61db6d0ebfed52574e7c5fc20473",
-    "solar.tpu_jaxpr": "4058235f7fbeecb8d69e3350bdf6b65b5e4fb84d34f0b6b0db3f39d795b1db3d",
-    "granite.tpu_jaxpr": "532c6a60326f070f8015a095125a8f0d74817ba7e848907c22fc10b67dd019ee",
+    "solar.tpu_jaxpr": "479a45451596897a73798e72559cf1643c39dad67d8ff619922c6f5d09790152",
+    "granite.tpu_jaxpr": "3643417b69b7f9a24fa25e40435d9c7bb2be836732cabf44334cc7170a6cd946",
 }
 
 

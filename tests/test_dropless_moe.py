@@ -6,7 +6,8 @@ router), the layer's row moves (`kernels/row_moves.py`: both routes against
 `jnp.take` and `.at[].add`, the kernel in the Pallas interpreter; the layer
 against the form it had before, `tests/_moe_parent_rows.py`),
 `kernels.grouped_matmul`, and `F.linear_cross_entropy` against the
-materialised logits."""
+materialised logits (its gradients made in the forward: the products
+counted in its jaxpr and through the four models that call it)."""
 import functools
 import os
 import sys
@@ -334,6 +335,16 @@ def test_a_traced_layer_leaves_one_moe_rows_event(monkeypatch, on_tpu, dtype,
 
 # -- the blocked head + loss --------------------------------------------------
 
+def _dense_ce(h, w, labels, tied=False, logit_scale=None):
+    lg = (h @ (w.T if tied else w)).astype(jnp.float32)
+    if logit_scale is not None:
+        lg = lg * logit_scale
+    keep = labels != -100
+    nll = jax.nn.logsumexp(lg, -1) - jnp.take_along_axis(
+        lg, jnp.where(keep, labels, 0)[:, None], -1)[:, 0]
+    return jnp.sum(jnp.where(keep, nll, 0.0)) / jnp.maximum(keep.sum(), 1)
+
+
 @pytest.mark.parametrize("rows,block", [(37, 8), (64, 16), (5, 2048)])
 def test_linear_cross_entropy_matches_the_materialised_logits(rows, block):
     rng = np.random.default_rng(0)
@@ -347,15 +358,156 @@ def test_linear_cross_entropy_matches_the_materialised_logits(rows, block):
     got.backward()
     gh, gw = np.asarray(h.grad.data), np.asarray(w.grad.data)
 
-    def dense(h_, w_):
-        lg = h_ @ w_
-        keep = labels != -100
-        nll = jax.nn.logsumexp(lg, -1) - jnp.take_along_axis(
-            lg, jnp.where(keep, labels, 0)[:, None], -1)[:, 0]
-        return jnp.sum(jnp.where(keep, nll, 0.0)) / keep.sum()
-
-    want, (wh, ww) = jax.value_and_grad(dense, argnums=(0, 1))(
-        h.data, w.data)
+    want, (wh, ww) = jax.value_and_grad(
+        lambda h_, w_: _dense_ce(h_, w_, labels), argnums=(0, 1))(
+            h.data, w.data)
     assert float(got.data) == pytest.approx(float(want), rel=1e-6)
     np.testing.assert_allclose(gh, np.asarray(wh), atol=1e-6)
     np.testing.assert_allclose(gw, np.asarray(ww), atol=1e-6)
+
+
+@pytest.mark.parametrize("case", [
+    "tied", "logit_scale", "loss_times_3", "loss_used_twice", "all_ignored",
+    "rows_the_block_does_not_divide", "frozen_head"])
+def test_linear_cross_entropy_gradients_made_in_the_forward(case):
+    """Loss, dh and dW against the dense form where the rule's backward has
+    something to get wrong: the table as it is stored, the scale, an
+    upstream cotangent that is not 1, no row that counts, a padded last
+    block, and a head that is not differentiated (no dW, no product for
+    it)."""
+    from paddle_tpu.nn.functional.loss import _linear_cross_entropy
+    rows = 37 if case == "rows_the_block_does_not_divide" else 32
+    kw = {"tied": {"tied": True}, "logit_scale": {"logit_scale": 0.25}}.get(
+        case, {})
+    use = {"loss_times_3": lambda l: l * 3.0,
+           "loss_used_twice": lambda l: l * l + l,
+           "rows_the_block_does_not_divide": lambda l: l * 3.0}.get(
+               case, lambda l: l)
+    rng = np.random.default_rng(1)
+    h = paddle.to_tensor(rng.normal(size=(rows, 16)).astype(np.float32))
+    w = rng.normal(size=(16, 50)).astype(np.float32)
+    w = paddle.to_tensor(w.T.copy() if case == "tied" else w)
+    h.stop_gradient = False
+    w.stop_gradient = case == "frozen_head"
+    labels = rng.integers(0, 50, (rows,)).astype(np.int32)
+    labels[::5] = -100
+    if case == "all_ignored":
+        labels[:] = -100
+    got = use(F.linear_cross_entropy(h, w, paddle.to_tensor(labels),
+                                     block_rows=8, **kw))
+    got.backward()
+    want, (wh, ww) = jax.value_and_grad(
+        lambda h_, w_: use(_dense_ce(h_, w_, labels, **kw)),
+        argnums=(0, 1))(h.data, w.data)
+    assert float(got.data) == pytest.approx(float(want), rel=1e-6)
+    np.testing.assert_allclose(np.asarray(h.grad.data), np.asarray(wh),
+                               atol=1e-6)
+    if case == "all_ignored":
+        assert float(got.data) == 0.0
+        assert not np.asarray(h.grad.data).any()
+        assert not np.asarray(w.grad.data).any()
+    if case != "frozen_head":
+        np.testing.assert_allclose(np.asarray(w.grad.data), np.asarray(ww),
+                                   atol=1e-6)
+        return
+    assert w.grad is None
+    made = _primitives(jax.make_jaxpr(jax.grad(
+        lambda h_: _linear_cross_entropy(h_, w.data, labels, 8, -100)))(
+            h.data).jaxpr)
+    assert [p[0] for p in made].count("dot_general") == 2
+    assert not [p for p in made if w.data.shape in p[1]]
+
+
+def _table_products(jaxpr, vocab):
+    """The `dot_general`s with a side `vocab` long (a block's logits, or
+    what is made from their gradient), each with the chain of equations
+    that hold it."""
+    found = []
+
+    def walk(jp, inside):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "dot_general" and any(
+                    vocab in getattr(v.aval, "shape", ())
+                    for v in (*eqn.invars, *eqn.outvars)):
+                found.append(inside)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, inside + (eqn.primitive.name,))
+
+    walk(jaxpr, ())
+    return found
+
+
+def test_the_head_and_loss_multiply_by_the_table_three_times_a_block():
+    """One set of logits a block: the gradient's jaxpr holds the forward
+    product and the two made from the logits' gradient, all three in the
+    ONE scan of the forward rule (the form before made the logits again in
+    a second, the backward's: four products), and the backward holds no
+    product at all; an evaluation that is not differentiated holds one."""
+    from paddle_tpu.nn.functional.loss import _linear_cross_entropy
+    h, w = jnp.ones((64, 32), jnp.bfloat16), jnp.ones((32, 200), jnp.bfloat16)
+    labels = jnp.arange(64, dtype=jnp.int32)
+
+    def f(h_, w_):
+        return _linear_cross_entropy(h_, w_, labels, 16, -100)
+
+    grad = jax.make_jaxpr(jax.grad(f, argnums=(0, 1)))(h, w).jaxpr
+    assert _table_products(grad, 200) == [("scan",)] * 3
+    names = [p[0] for p in _primitives(grad)]
+    assert names.count("scan") == 1 and names.count("dot_general") == 3
+    plain = _primitives(jax.make_jaxpr(f)(h, w).jaxpr)
+    assert [p[0] for p in plain].count("dot_general") == 1
+
+
+def _tiny(name):
+    from paddle_tpu.models import (dots3_note, glm4_moe_lite, granite_hybrid,
+                                   solar_open2)
+    make = {"granite": (granite_hybrid.GraniteHybridForCausalLM,
+                        granite_hybrid.granite_hybrid_tiny),
+            "solar": (solar_open2.SolarOpen2ForCausalLM,
+                      solar_open2.solar_open2_tiny),
+            "dots3": (dots3_note.Dots3NoteForCausalLM,
+                      dots3_note.dots3_note_tiny),
+            "glm": (glm4_moe_lite.Glm4MoeLiteForCausalLM,
+                    glm4_moe_lite.glm4_moe_lite_tiny)}[name]
+    kw = {"layer_types": ("mamba",)} if name == "granite" else {}
+    cfg = make[1](vocab_size=200, num_hidden_layers=1, **kw)
+    box = {}
+    # the constructor traced abstractly and zeros put in: drawing the
+    # weights is most of a tiny model's seconds, and only shapes are read
+    jax.eval_shape(lambda: box.update(model=make[0](cfg)))
+    paddle.seed(0)      # the traced constructor left a tracer as the key
+    for t in box["model"].state_dict().values():
+        t.data = jnp.asarray(np.zeros(t.data.shape, t.data.dtype))
+    return box["model"]
+
+
+@pytest.mark.parametrize("name,passes", [
+    ("granite", 1), ("solar", 1), ("dots3", 1), ("glm", 2)])
+def test_a_models_step_multiplies_by_the_table_three_times_a_pass(name,
+                                                                  passes):
+    """The whole step of each model that calls the blocked head + loss, at
+    its smallest config, one layer deep, with a vocabulary no other width
+    equals: three products a pass over the head, none under a
+    `jax.checkpoint` (a call site that wraps the rule in one runs its
+    gradient-making forward twice); and what the tape counts as kept for
+    the backward under `head_loss` is the two gradients and at most the
+    pass's input, never the norm's output."""
+    import paddle_tpu.optimizer as popt
+    from paddle_tpu.observability import spans
+    model = _tiny(name)
+    opt = popt.AdamW(learning_rate=1e-3, parameters=model.parameters())
+    step = paddle.jit.TrainStep(model, opt, lambda i, l: model.loss(i, l))
+    x = paddle.to_tensor(np.zeros((1, 32), np.int32))
+    step._build()
+    spans.clear()
+    jaxpr = step._compiled.trace(*step._call_args((x, x))).jaxpr.jaxpr
+    products = _table_products(jaxpr, 200)
+    assert len(products) == 3 * passes
+    assert not [p for p in products if "checkpoint" in p or "remat2" in p]
+    kept = {ev["attrs"]["scope"]: int(ev["attrs"]["bytes"])
+            for ev in spans.ring() if ev["name"] == "train_step.residuals"}
+    hidden = model.cfg.hidden_size
+    dw, dx = 200 * hidden * 4, 32 * hidden * 4
+    assert kept["head_loss"] in (dw + dx, dw + dx + dx)
+    if passes == 2:
+        assert kept["mtp_head_loss"] in (dw + dx, dw + dx + dx)

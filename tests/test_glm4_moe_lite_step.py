@@ -106,9 +106,11 @@ def test_a_trace_carries_the_modules_event_and_the_new_names_once():
 DOTS3_PARENT = {   # sha256 of the step's jaxpr, read under this suite's
     # conftest, addresses and step tags out: commit 45b00d7's (PR 43) but for
     # the expert layer, whose rows move through `kernels/row_moves.py` since
-    # PR 45 (pinned again there; PR 44 held these to 820e8110... / bd16bd52...)
-    False: "5013cc1e4a89bd2cfcc2304610693e429effe7878cf60d0da003000c083ae3cb",
-    True: "16205d7680ee1845b2dc54dd695514efe32258f48c40b2f5c793ea67cc43a3e7",
+    # PR 45, and the head + loss, a block's gradients made beside its loss
+    # since PR 46 (pinned again by each; PR 45 held these to 5013cc1e... /
+    # 16205d76...)
+    False: "da666e50546e68fd28f69ab2b4731de84f79b1312a6610435be2c577ad1980f7",
+    True: "43fd7279ca8978e0dc2beb55777e0a3a6a8bbeb60ae5603e483d798066d3ce36",
 }
 
 

@@ -688,6 +688,31 @@ def test_granite_attention_and_tied_head_at_the_cells_shapes(one_chip):
         4 * 1024 * 100352 * 4 + 100352 * 2048 * 4 * 1.5)
 
 
+@pytest.mark.parametrize("rows,hidden,vocab", [
+    (16384, 5120, 152064), (32768, 4096, 196608)])
+def test_the_widest_heads_and_their_loss_hold_one_block_of_logits(
+        one_chip, rows, hidden, vocab):
+    """The blocked head + loss with its gradients made in the forward, at
+    the two widest heads of the cells' models (dots3-note's and
+    Solar-Open2's whole vocabularies, of which a cell holds an eighth;
+    blocks of 2048 rows): the temporaries are a block's float32 logits,
+    which become their own gradient in place. No second block and no
+    table-sized array beside them: the table's gradient is summed in the
+    output's own buffer."""
+    from paddle_tpu.nn.functional.loss import _linear_cross_entropy
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(jax.value_and_grad(
+        lambda h_, w_, l_: _linear_cross_entropy(h_, w_, l_, 2048, -100),
+        argnums=(0, 1))).lower(
+            sds((rows, hidden)), sds((hidden, vocab)),
+            sds((rows,), jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        1.5 * 2048 * vocab * 4)
+
+
 def test_dots3_note_kernels_at_the_cells_shapes(one_chip):
     """The learned selection's kernels at the dots3-note cell's widths (64
     index heads of 128, a group of 16 heads of 128 | 64 | 128, an int8 mask
