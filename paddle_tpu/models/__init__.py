@@ -14,29 +14,25 @@ from .ernie import (  # noqa: F401
 from .unet import (  # noqa: F401
     UNet2DConditionModel, UNetConfig, unet_sd15, unet_tiny,
 )
-from .solar_open2 import (  # noqa: F401
-    SolarOpen2Config, SolarOpen2ForCausalLM, SolarOpen2Model,
-    solar_open2_tiny,
-)
-from .granite_hybrid import (  # noqa: F401
-    GraniteHybridConfig, GraniteHybridForCausalLM, GraniteHybridModel,
-    granite_hybrid_tiny,
-)
 
 
-_LAZY = {"Dots3NoteConfig": "dots3_note", "Dots3NoteForCausalLM": "dots3_note",
-         "Dots3NoteModel": "dots3_note", "dots3_note_tiny": "dots3_note",
-         "Glm4MoeLiteConfig": "glm4_moe_lite",
-         "Glm4MoeLiteForCausalLM": "glm4_moe_lite",
-         "Glm4MoeLiteModel": "glm4_moe_lite",
-         "glm4_moe_lite_tiny": "glm4_moe_lite"}
+# built from `models/pieces.py`; imported when asked for, not with the package
+_LAZY = {
+    "solar_open2": ("SolarOpen2Config", "SolarOpen2ForCausalLM",
+                    "SolarOpen2Model", "solar_open2_tiny"),
+    "granite_hybrid": ("GraniteHybridConfig", "GraniteHybridForCausalLM",
+                       "GraniteHybridModel", "granite_hybrid_tiny"),
+    "dots3_note": ("Dots3NoteConfig", "Dots3NoteForCausalLM",
+                   "Dots3NoteModel", "dots3_note_tiny"),
+    "glm4_moe_lite": ("Glm4MoeLiteConfig", "Glm4MoeLiteForCausalLM",
+                      "Glm4MoeLiteModel", "glm4_moe_lite_tiny"),
+}
 
 
 def __getattr__(name):
-    # models/dots3_note and models/glm4_moe_lite (built from its layers) are
-    # imported when asked for, not with the package
-    if name in _LAZY:
-        import importlib
-        return getattr(importlib.import_module("." + _LAZY[name], __name__),
-                       name)
+    for module, names in _LAZY.items():
+        if name in names:
+            import importlib
+            return getattr(importlib.import_module("." + module, __name__),
+                           name)
     raise AttributeError(name)

@@ -62,14 +62,13 @@ from jax.sharding import PartitionSpec as P
 from ..autograd.tape import apply_op
 from ..framework import core
 from ..nn import initializer as I
-from ..nn.layer.container import LayerList
 from ..nn.layer.layers import Layer
-from ..nn.layer.moe import DroplessMoE
 from ..observability.scopes import scope
 from ..ops._helpers import to_tensor_like
 from ..tensor import Tensor
-from .llama import LlamaRMSNorm, _param, _swiglu
-from .solar_open2 import _group_of, _rms
+from .pieces import (CausalLM, DecoderStack, RMSNorm, SwiGLUHalf,
+                     blocked_loss, dropless_moe_of, group_of, moe_counters,
+                     moe_half, param, rms, shifted)
 
 __all__ = ["Dots3NoteConfig", "Dots3NoteModel", "Dots3NoteForCausalLM",
            "dots3_note_tiny"]
@@ -216,15 +215,15 @@ class Indexer(Layer):
         super().__init__()
         J, D = cfg.index_n_heads, cfg.index_head_dim
         dt = cfg.dtype
-        self.wq_b = _param(self, (cfg.q_lora_rank, J * D), P(None, "mp"),
-                           dtype=dt)
-        self.wk = _param(self, (cfg.hidden_size, D), P(None, None), dtype=dt)
-        self.k_norm_weight = _param(self, (D,), P(None),
-                                    init=I.Constant(1.0), dtype="float32")
-        self.k_norm_bias = _param(self, (D,), P(None), init=I.Constant(0.0),
-                                  dtype="float32")
-        self.weights_proj = _param(self, (cfg.hidden_size, J),
-                                   P(None, None), dtype=dt)
+        self.wq_b = param(self, (cfg.q_lora_rank, J * D), P(None, "mp"),
+                          dtype=dt)
+        self.wk = param(self, (cfg.hidden_size, D), P(None, None), dtype=dt)
+        self.k_norm_weight = param(self, (D,), P(None),
+                                   init=I.Constant(1.0), dtype="float32")
+        self.k_norm_bias = param(self, (D,), P(None), init=I.Constant(0.0),
+                                 dtype="float32")
+        self.weights_proj = param(self, (cfg.hidden_size, J),
+                                  P(None, None), dtype=dt)
 
     def weights(self):
         return [self.wq_b, self.wk, self.k_norm_weight, self.k_norm_bias,
@@ -239,17 +238,17 @@ class LatentAttention(Layer):
         self.cfg, self.kind = cfg, kind
         h, dt = cfg.hidden_size, cfg.dtype
         n, dn, dr, dv, rq, rkv, _ = cfg.attention(kind)
-        self.q_a_proj = _param(self, (h, rq), P(None, None), dtype=dt)
-        self.q_a_layernorm = LlamaRMSNorm(rq, cfg.rms_norm_eps)
-        self.q_b_proj = _param(self, (rq, n * (dn + dr)), P(None, "mp"),
+        self.q_a_proj = param(self, (h, rq), P(None, None), dtype=dt)
+        self.q_a_layernorm = RMSNorm(rq, cfg.rms_norm_eps)
+        self.q_b_proj = param(self, (rq, n * (dn + dr)), P(None, "mp"),
+                              dtype=dt)
+        self.kv_a_proj = param(self, (h, rkv + dr), P(None, None), dtype=dt)
+        self.kv_a_layernorm = RMSNorm(rkv, cfg.rms_norm_eps)
+        self.kv_b_proj = param(self, (rkv, n * (dn + dv)), P(None, "mp"),
                                dtype=dt)
-        self.kv_a_proj = _param(self, (h, rkv + dr), P(None, None), dtype=dt)
-        self.kv_a_layernorm = LlamaRMSNorm(rkv, cfg.rms_norm_eps)
-        self.kv_b_proj = _param(self, (rkv, n * (dn + dv)), P(None, "mp"),
-                                dtype=dt)
         if kind != CAUSAL:
-            self.gate_proj = _param(self, (h, n), P(None, "mp"), dtype=dt)
-        self.o_proj = _param(self, (n * dv, h), P("mp", None), dtype=dt)
+            self.gate_proj = param(self, (h, n), P(None, "mp"), dtype=dt)
+        self.o_proj = param(self, (n * dv, h), P("mp", None), dtype=dt)
         if kind == FULL:
             self.indexer = Indexer(cfg)
             self.register_buffer("attended_pairs",
@@ -275,7 +274,7 @@ class LatentAttention(Layer):
         cfg = self.cfg
         _, _, _, _, _, rkv, theta = cfg.attention(self.kind)
         a_q, a_kv = self._mults()
-        xn = _rms(x, ln_w, cfg.rms_norm_eps)
+        xn = rms(x, ln_w, cfg.rms_norm_eps)
         with scope("attn/qkv"):
             cq = _latent_norm(xn @ wdq, qn_w, cfg.rms_norm_eps, a_q)
             down = xn @ wdkv
@@ -295,8 +294,8 @@ class LatentAttention(Layer):
         hg = n // G
         S = cq.shape[0]
         with scope("attn/qkv"):
-            q = (cq @ _group_of(wuq, 1, G, g)).reshape(S, hg, dn + dr)
-            kv = (ckv @ _group_of(wukv, 1, G, g)).reshape(S, hg, dn + dv)
+            q = (cq @ group_of(wuq, 1, G, g)).reshape(S, hg, dn + dr)
+            kv = (ckv @ group_of(wukv, 1, G, g)).reshape(S, hg, dn + dv)
         with scope("attn/rope"):
             qr = _rope(q[..., dn:], theta)
         sw = lambda a: jnp.swapaxes(a, 0, 1)
@@ -464,139 +463,58 @@ class LatentAttention(Layer):
 
 # -- a layer, the stack, the model ---------------------------------------------
 
-class Dots3NoteMLP(Layer):
+class Dots3NoteMLP(SwiGLUHalf):
     """h + SwiGLU(RMSNorm(h)) of a leading dense layer."""
 
-    def __init__(self, cfg: Dots3NoteConfig):
-        super().__init__()
-        self.cfg = cfg
-        h, m = cfg.hidden_size, cfg.intermediate_size
-        self.gate_up_proj = _param(self, (h, 2 * m), P(None, "mp"),
-                                   dtype=cfg.dtype)
-        self.down_proj = _param(self, (m, h), P("mp", None), dtype=cfg.dtype)
-
-    def block(self, h, ln_w, wgu, wd):
-        a = _rms(h, ln_w, self.cfg.rms_norm_eps)
-        with scope("mlp"):
-            return h + (_swiglu(a, wgu) @ wd)
-
-    def forward(self, h, ln_w):
-        return apply_op(
-            jax.checkpoint(self.block, policy=core.current_remat_policy()),
-            to_tensor_like(h), ln_w, self.gate_up_proj, self.down_proj,
-            name="dots3_note_mlp")
+    def __init__(self, cfg):
+        super().__init__(cfg, "dots3_note_mlp")
 
 
 class Dots3NoteDecoderLayer(Layer):
     def __init__(self, cfg: Dots3NoteConfig, index: int):
         super().__init__()
         self.cfg = cfg
-        self.input_layernorm = LlamaRMSNorm(cfg.hidden_size,
-                                            cfg.rms_norm_eps)
+        self.input_layernorm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
         self.self_attn = LatentAttention(cfg, cfg.layer_types[index])
-        self.post_attention_layernorm = LlamaRMSNorm(cfg.hidden_size,
-                                                     cfg.rms_norm_eps)
+        self.post_attention_layernorm = RMSNorm(cfg.hidden_size,
+                                                cfg.rms_norm_eps)
         if index < cfg.first_k_dense_replace:
             self.mlp = Dots3NoteMLP(cfg)
         else:
-            self.mlp = DroplessMoE(
-                cfg.hidden_size, cfg.moe_intermediate_size,
-                cfg.n_routed_experts, cfg.num_experts_per_tok,
-                experts_held=cfg.experts_held,
-                first_expert=cfg.expert_offset,
-                shared_experts=cfg.n_shared_experts,
-                norm_topk_prob=cfg.norm_topk_prob,
-                routed_scaling_factor=cfg.routed_scaling_factor,
-                rows=cfg.moe_rows, dtype=cfg.dtype, selection_bias=True)
-
-    def _experts(self, h, ln_w, *ws):
-        y, counts, dropped = self.mlp.compute(
-            _rms(h, ln_w, self.cfg.rms_norm_eps), *ws)
-        return h + y, counts, dropped
+            self.mlp = dropless_moe_of(cfg, selection_bias=True)
 
     def forward(self, x):
         """(y, L_I or None). Two taped operations: the mixer keeps x, its
         latents and what the attention kernels name; the second half keeps
         the mixer's output and recomputes itself whole."""
         h, li = self.self_attn(x, self.input_layernorm.weight)
+        ln_w = self.post_attention_layernorm.weight
         if isinstance(self.mlp, Dots3NoteMLP):
-            return self.mlp(h, self.post_attention_layernorm.weight), li
-        run = jax.checkpoint(self._experts,
-                             policy=core.current_remat_policy())
-        y, counts, dropped = apply_op(
-            run, h, self.post_attention_layernorm.weight,
-            *self.mlp.weights(), n_outputs=3, name="moe_block")
-        self.mlp.record(counts.data, dropped.data)
-        return y, li
+            return self.mlp(h, ln_w), li
+        return moe_half(self.mlp, h, ln_w, self.cfg.rms_norm_eps), li
 
 
-class Dots3NoteModel(Layer):
+class Dots3NoteModel(DecoderStack):
     def __init__(self, cfg: Dots3NoteConfig):
-        super().__init__()
-        self.cfg = cfg
-        self.embed_tokens = _param(self, (cfg.vocab_size, cfg.hidden_size),
-                                   P("mp", None), dtype=cfg.dtype)
-        self.layers = LayerList([Dots3NoteDecoderLayer(cfg, i)
-                                 for i in range(cfg.num_hidden_layers)])
-        self.norm = LlamaRMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
+        super().__init__(cfg, Dots3NoteDecoderLayer)
 
     def forward(self, input_ids, final_norm=True, with_aux=False):
-        def embed(ids, w):
-            with scope("embed"):
-                return jnp.take(w, ids.astype(jnp.int32), axis=0)
-
-        x = apply_op(embed, to_tensor_like(input_ids), self.embed_tokens,
-                     name="embed")
         aux = []
-        for lyr in self.layers:
-            with scope("layers"):
-                x, li = lyr(x)
-            if li is not None:
-                aux.append(li)
-        x = self.norm(x) if final_norm else x
+        x = super().forward(input_ids, final_norm, aux)
         return (x, aux) if with_aux else x
 
 
-def _head(a, w):
-    with scope("head"):
-        return a @ w
-
-
-class Dots3NoteForCausalLM(Layer):
+class Dots3NoteForCausalLM(CausalLM):
     def __init__(self, cfg: Dots3NoteConfig):
-        super().__init__()
-        self.cfg = cfg
-        self.model = Dots3NoteModel(cfg)
-        self.lm_head = _param(self, (cfg.hidden_size, cfg.vocab_size),
-                              P(None, "mp"), dtype=cfg.dtype)
-
-    def forward(self, input_ids):
-        return apply_op(_head, self.model(input_ids), self.lm_head,
-                        name="lm_head")
+        super().__init__(cfg, Dots3NoteModel)
 
     def losses(self, input_ids, labels):
         """(L_LM, [L_I of each full layer]): the shifted next-token
         cross-entropy, head and loss a block of rows at a time."""
-        from ..nn.functional.loss import _linear_cross_entropy
-        cfg = self.cfg
-        lb = to_tensor_like(labels).data
-        nxt = jnp.concatenate(
-            [lb[:, 1:], jnp.full((lb.shape[0], 1), -100, lb.dtype)],
-            axis=1).reshape(-1)
-
-        def head_loss(x, norm_w, w):
-            # the last norm's output is not kept: the norm alone runs
-            # again in the backward
-            xn = jax.checkpoint(_rms, static_argnums=2)(
-                x, norm_w, cfg.rms_norm_eps)
-            return _linear_cross_entropy(
-                xn.reshape(-1, xn.shape[-1]), w, nxt, cfg.loss_block_rows,
-                -100)
-
+        nxt = shifted(labels)
         x, aux = self.model(input_ids, final_norm=False, with_aux=True)
-        lm = apply_op(head_loss, x, self.model.norm.weight, self.lm_head,
-                      name="head_loss")
-        return lm, aux
+        return blocked_loss(self.cfg, x, self.model.norm.weight,
+                            self.lm_head, nxt), aux
 
     def loss(self, input_ids, labels):
         """L_LM + `indexer_loss_weight` * the full layers' L_I."""
@@ -610,18 +528,9 @@ class Dots3NoteForCausalLM(Layer):
         return apply_op(total, lm, *aux, name="total_loss")
 
     def moe_counters(self):
-        """{"expert_tokens": [expert layers, experts held], "dropped_pairs":
-        [expert layers], "attended_pairs": [full layers]} as the last step
-        left them (host arrays; not for a timed region: reading waits for
-        the device)."""
-        import numpy as np
-        mlps = [lyr.mlp for lyr in self.model.layers
-                if isinstance(lyr.mlp, DroplessMoE)]
-        full = [lyr.self_attn for lyr in self.model.layers
-                if lyr.self_attn.kind == FULL]
-        return {"expert_tokens": np.stack(
-                    [np.asarray(m.expert_tokens.data) for m in mlps]),
-                "dropped_pairs": np.asarray(
-                    [np.asarray(m.dropped_pairs.data) for m in mlps]),
-                "attended_pairs": np.asarray(
-                    [np.asarray(a.attended_pairs.data) for a in full])}
+        """`pieces.moe_counters` of the expert layers, and
+        "attended_pairs": [full layers]."""
+        layers = self.model.layers
+        return moe_counters(layers, attended_pairs=[
+            lyr.self_attn.attended_pairs for lyr in layers
+            if lyr.self_attn.kind == FULL])

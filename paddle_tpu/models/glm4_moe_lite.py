@@ -42,15 +42,14 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..autograd.tape import apply_op
-from ..nn.layer.container import LayerList
 from ..nn.layer.layers import Layer
 from ..nn.layer.moe import DroplessMoE
 from ..observability.scopes import scope
 from ..ops._helpers import to_tensor_like
 from ..tensor import Tensor
-from .dots3_note import CAUSAL, Dots3NoteDecoderLayer, _head
-from .llama import LlamaRMSNorm, _param
-from .solar_open2 import _rms
+from .dots3_note import CAUSAL, Dots3NoteDecoderLayer
+from .pieces import (CausalLM, DecoderStack, RMSNorm, blocked_loss, embed,
+                     moe_counters, param, rms, shifted)
 
 __all__ = ["Glm4MoeLiteConfig", "Glm4MoeLiteModel", "Glm4MoeLiteForCausalLM",
            "glm4_moe_lite_tiny"]
@@ -120,31 +119,9 @@ def glm4_moe_lite_tiny(**kw):
     return Glm4MoeLiteConfig(**base)
 
 
-def _embed(ids, w):
-    return jnp.take(w, ids.astype(jnp.int32), axis=0)
-
-
-class Glm4MoeLiteModel(Layer):
+class Glm4MoeLiteModel(DecoderStack):
     def __init__(self, cfg: Glm4MoeLiteConfig):
-        super().__init__()
-        self.cfg = cfg
-        self.embed_tokens = _param(self, (cfg.vocab_size, cfg.hidden_size),
-                                   P("mp", None), dtype=cfg.dtype)
-        self.layers = LayerList([Dots3NoteDecoderLayer(cfg, i)
-                                 for i in range(cfg.num_hidden_layers)])
-        self.norm = LlamaRMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
-
-    def forward(self, input_ids, final_norm=True):
-        def embed(ids, w):
-            with scope("embed"):
-                return _embed(ids, w)
-
-        x = apply_op(embed, to_tensor_like(input_ids), self.embed_tokens,
-                     name="embed")
-        for lyr in self.layers:
-            with scope("layers"):
-                x, _ = lyr(x)
-        return self.norm(x) if final_norm else x
+        super().__init__(cfg, Dots3NoteDecoderLayer)
 
 
 class MultiTokenPredictor(Layer):
@@ -155,21 +132,20 @@ class MultiTokenPredictor(Layer):
         super().__init__()
         self.cfg = cfg
         h = cfg.hidden_size
-        self.enorm = LlamaRMSNorm(h, cfg.rms_norm_eps)
-        self.hnorm = LlamaRMSNorm(h, cfg.rms_norm_eps)
-        self.eh_proj = _param(self, (2 * h, h), P(None, None),
-                              dtype=cfg.dtype)
+        self.enorm = RMSNorm(h, cfg.rms_norm_eps)
+        self.hnorm = RMSNorm(h, cfg.rms_norm_eps)
+        self.eh_proj = param(self, (2 * h, h), P(None, None),
+                             dtype=cfg.dtype)
         self.block = Dots3NoteDecoderLayer(cfg, cfg.num_hidden_layers)
-        self.norm = LlamaRMSNorm(h, cfg.rms_norm_eps)
+        self.norm = RMSNorm(h, cfg.rms_norm_eps)
 
     def _join(self, ids, x, emb_w, enorm_w, hnorm_w, w):
         """u = [RMSNorm_e(Emb ids) ; RMSNorm_h(x)] W_EH on [B, S]."""
         eps = self.cfg.rms_norm_eps
-        with scope("mtp/embed"):
-            e = _embed(ids, emb_w)
+        e = embed(ids, emb_w, under="mtp/embed")
         with scope("mtp/proj"):
-            return jnp.concatenate([_rms(e, enorm_w, eps),
-                                    _rms(x, hnorm_w, eps)], -1) @ w
+            return jnp.concatenate([rms(e, enorm_w, eps),
+                                    rms(x, hnorm_w, eps)], -1) @ w
 
     def forward(self, next_ids, x, embed_tokens):
         """g [B, S, H] (before the module's final norm) from the trunk's
@@ -181,57 +157,22 @@ class MultiTokenPredictor(Layer):
             return self.block(u)[0]
 
 
-class Glm4MoeLiteForCausalLM(Layer):
+class Glm4MoeLiteForCausalLM(CausalLM):
     def __init__(self, cfg: Glm4MoeLiteConfig):
-        super().__init__()
-        self.cfg = cfg
-        self.model = Glm4MoeLiteModel(cfg)
-        self.lm_head = _param(self, (cfg.hidden_size, cfg.vocab_size),
-                              P(None, "mp"), dtype=cfg.dtype)
+        super().__init__(cfg, Glm4MoeLiteModel)
         self.mtp = (MultiTokenPredictor(cfg)
                     if cfg.num_nextn_predict_layers else None)
         # the last step's two losses, beside the one the step returns
         for name in ("main_loss", "mtp_loss"):
             self.register_buffer(name, Tensor(jnp.zeros((), _F32)))
 
-    def forward(self, input_ids):
-        return apply_op(_head, self.model(input_ids), self.lm_head,
-                        name="lm_head")
-
-    def _shifted(self, labels, by):
-        """Row i's label is the token `by` positions on; the last `by`
-        rows of a sequence have none."""
-        lb = to_tensor_like(labels).data
-        return jnp.concatenate(
-            [lb[:, by:], jnp.full((lb.shape[0], by), -100, lb.dtype)],
-            axis=1).reshape(-1)
-
-    def _head_loss(self, x, norm_w, labels, name, scopes):
-        """Mean CE of RMSNorm(x) W_head against `labels` [B * S], head and
-        loss a block of rows at a time, the two under `scopes`."""
-        from ..nn.functional.loss import _linear_cross_entropy
-        cfg = self.cfg
-
-        def head_loss(x_, norm_w_, w):
-            # the last norm's output is not kept: the norm alone runs
-            # again in the backward
-            xn = jax.checkpoint(_rms, static_argnums=2)(
-                x_, norm_w_, cfg.rms_norm_eps)
-            return _linear_cross_entropy(
-                xn.reshape(-1, xn.shape[-1]), w, labels,
-                cfg.loss_block_rows, -100, scopes=scopes)
-
-        return apply_op(head_loss, x, norm_w, self.lm_head,
-                        name=name)
-
     def losses(self, input_ids, labels):
         """(L_main, L_MTP or None): the next-token cross-entropy of the
         trunk and the module's of the token after it."""
         cfg = self.cfg
         x = self.model(input_ids, final_norm=False)
-        lm = self._head_loss(x, self.model.norm.weight,
-                             self._shifted(labels, 1), "head_loss",
-                             ("head", "loss"))
+        lm = blocked_loss(cfg, x, self.model.norm.weight, self.lm_head,
+                          shifted(labels))
         if self.mtp is None or not cfg.mtp_loss_weight:
             return lm, None
         from ..observability import spans
@@ -245,9 +186,9 @@ class Glm4MoeLiteForCausalLM(Layer):
         nxt = jnp.concatenate(
             [ids[:, 1:], jnp.zeros((ids.shape[0], 1), ids.dtype)], axis=1)
         g = self.mtp(nxt, x, self.model.embed_tokens)
-        return lm, self._head_loss(g, self.mtp.norm.weight,
-                                   self._shifted(labels, 2), "mtp_head_loss",
-                                   ("mtp/head", "mtp/loss"))
+        return lm, blocked_loss(
+            cfg, g, self.mtp.norm.weight, self.lm_head, shifted(labels, 2),
+            name="mtp_head_loss", scopes=("mtp/head", "mtp/loss"))
 
     def loss(self, input_ids, labels):
         """L_main + `mtp_loss_weight` * L_MTP."""
@@ -265,15 +206,6 @@ class Glm4MoeLiteForCausalLM(Layer):
         return apply_op(total, lm, extra, name="total_loss")
 
     def moe_counters(self):
-        """{"expert_tokens": [expert layers, experts held], "dropped_pairs":
-        [expert layers]} as the last step left them, the module's layer
-        last (host arrays; not for a timed region: reading waits for the
-        device)."""
-        import numpy as np
-        blocks = list(self.model.layers) + (
-            [self.mtp.block] if self.mtp is not None else [])
-        mlps = [b.mlp for b in blocks if isinstance(b.mlp, DroplessMoE)]
-        return {"expert_tokens": np.stack(
-                    [np.asarray(m.expert_tokens.data) for m in mlps]),
-                "dropped_pairs": np.asarray(
-                    [np.asarray(m.dropped_pairs.data) for m in mlps])}
+        """`pieces.moe_counters` of the expert layers, the module's last."""
+        return moe_counters(list(self.model.layers) + (
+            [self.mtp.block] if self.mtp is not None else []))

@@ -48,11 +48,11 @@ from jax.sharding import PartitionSpec as P
 from ..autograd.tape import apply_op
 from ..framework import core
 from ..nn import initializer as I
-from ..nn.layer.container import LayerList
 from ..nn.layer.layers import Layer
 from ..observability.scopes import scope
 from ..ops._helpers import to_tensor_like
-from .llama import LlamaRMSNorm, _param, _sdpa, _swiglu
+from .pieces import (DecoderStack, RMSNorm, SwiGLUHalf, blocked_loss, branch,
+                     param, rms, sdpa, shifted)
 
 __all__ = ["GraniteHybridConfig", "GraniteHybridModel",
            "GraniteHybridForCausalLM", "granite_hybrid_tiny"]
@@ -115,18 +115,6 @@ def granite_hybrid_tiny(**kw):
     return GraniteHybridConfig(**base)
 
 
-def _rms(a, w, eps):
-    from ..kernels import rms_norm as krn
-    with scope("norm"):
-        return krn.rms_norm(a, w, eps)
-
-
-def _branch(x, out, r):
-    """x + r * out, out the float32 accumulator of a branch's last
-    product: one rounding, to x's dtype."""
-    return (x.astype(jnp.float32) + r * out).astype(x.dtype)
-
-
 # -- the state-space layer -----------------------------------------------------
 
 class MambaMixer(Layer):
@@ -139,28 +127,28 @@ class MambaMixer(Layer):
         h, dt = cfg.hidden_size, cfg.dtype
         nh, n, inner = cfg.mamba_n_heads, cfg.mamba_d_state, cfg.mamba_d_inner
         conv = inner + 2 * n
-        self.in_proj = _param(self, (h, inner + conv + nh), P(None, "mp"),
-                              dtype=dt)
+        self.in_proj = param(self, (h, inner + conv + nh), P(None, "mp"),
+                             dtype=dt)
         bound = 1.0 / math.sqrt(cfg.mamba_d_conv)
-        self.conv_weight = _param(self, (cfg.mamba_d_conv, conv),
-                                  P(None, "mp"),
-                                  init=I.Uniform(-bound, bound), dtype=dt)
-        self.conv_bias = _param(self, (conv,), P(None), dtype=dt)
+        self.conv_weight = param(self, (cfg.mamba_d_conv, conv),
+                                 P(None, "mp"),
+                                 init=I.Uniform(-bound, bound), dtype=dt)
+        self.conv_bias = param(self, (conv,), P(None), dtype=dt)
         # a decay of exp(-A dt) a token: A in (1, 16), dt in (1e-3, 1e-1),
         # so heads remember from a few tokens to a few thousand. These
         # three and the norm's weight stay float32 beside bf16 matrices
-        self.A_log = _param(self, (nh,), P(None), init=I.Uniform(1.0, 16.0),
-                            dtype="float32")
+        self.A_log = param(self, (nh,), P(None), init=I.Uniform(1.0, 16.0),
+                           dtype="float32")
         self.A_log.data = jnp.log(self.A_log.data)
-        self.dt_bias = _param(
+        self.dt_bias = param(
             self, (nh,), P(None),
             init=I.Uniform(math.log(1e-3), math.log(1e-1)), dtype="float32")
         step = jnp.exp(self.dt_bias.data)
         self.dt_bias.data = step + jnp.log(-jnp.expm1(-step))
-        self.D = _param(self, (nh,), P(None), init=I.Constant(1.0),
-                        dtype="float32")
-        self.norm = LlamaRMSNorm(inner, cfg.rms_norm_eps)
-        self.out_proj = _param(self, (inner, h), P("mp", None), dtype=dt)
+        self.D = param(self, (nh,), P(None), init=I.Constant(1.0),
+                       dtype="float32")
+        self.norm = RMSNorm(inner, cfg.rms_norm_eps)
+        self.out_proj = param(self, (inner, h), P("mp", None), dtype=dt)
 
     def block(self, x, ln_w, w_in, w_conv, b_conv, a_log, dt_bias, d_skip,
               norm_w, w_out):
@@ -170,7 +158,7 @@ class MambaMixer(Layer):
         nh, p, n = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
         inner, f32 = nh * p, jnp.float32
         B, T, _ = x.shape
-        xn = _rms(x, ln_w, cfg.rms_norm_eps)
+        xn = rms(x, ln_w, cfg.rms_norm_eps)
         with scope("ssm/proj"):
             # a product a part: a slice of ONE wide product's output
             # would be a copy of it
@@ -194,8 +182,8 @@ class MambaMixer(Layer):
                                    + cfg.rms_norm_eps) * norm_w
                  ).astype(x.dtype)
         with scope("ssm/out"):
-            return _branch(x, jnp.matmul(g, w_out, preferred_element_type=f32),
-                           cfg.residual_multiplier)
+            return branch(x, jnp.matmul(g, w_out, preferred_element_type=f32),
+                          cfg.residual_multiplier)
 
     def forward(self, x, ln_w):
         return apply_op(
@@ -216,17 +204,17 @@ class GraniteAttention(Layer):
         self.cfg = cfg
         h, d = cfg.hidden_size, cfg.head_dim
         nh, kvh = cfg.num_attention_heads, cfg.num_key_value_heads
-        self.qkv_proj = _param(self, (h, (nh + 2 * kvh) * d), P(None, "mp"),
-                               dtype=cfg.dtype)
-        self.o_proj = _param(self, (nh * d, h), P("mp", None),
-                             dtype=cfg.dtype)
+        self.qkv_proj = param(self, (h, (nh + 2 * kvh) * d), P(None, "mp"),
+                              dtype=cfg.dtype)
+        self.o_proj = param(self, (nh * d, h), P("mp", None),
+                            dtype=cfg.dtype)
 
     def block(self, x, ln_w, wqkv, wo):
         cfg = self.cfg
         nh, kvh, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                       cfg.head_dim)
         B, T, _ = x.shape
-        xn = _rms(x, ln_w, cfg.rms_norm_eps)
+        xn = rms(x, ln_w, cfg.rms_norm_eps)
         with scope("attn/qkv"):
             q = (xn @ wqkv[:, :nh * d]).reshape(B, T, nh, d)
             k = (xn @ wqkv[:, nh * d:(nh + kvh) * d]).reshape(B, T, kvh, d)
@@ -239,13 +227,13 @@ class GraniteAttention(Layer):
             else:
                 # _sdpa divides by sqrt(d): hand it q times what is left
                 rep = nh // kvh
-                o = _sdpa(q * (cfg.attention_multiplier * math.sqrt(d)),
-                          jnp.repeat(k, rep, axis=2),
-                          jnp.repeat(v, rep, axis=2))
+                o = sdpa(q * (cfg.attention_multiplier * math.sqrt(d)),
+                         jnp.repeat(k, rep, axis=2),
+                         jnp.repeat(v, rep, axis=2))
         with scope("attn/out"):
-            return _branch(x, jnp.matmul(o.reshape(B, T, nh * d), wo,
-                                         preferred_element_type=jnp.float32),
-                           cfg.residual_multiplier)
+            return branch(x, jnp.matmul(o.reshape(B, T, nh * d), wo,
+                                        preferred_element_type=jnp.float32),
+                          cfg.residual_multiplier)
 
     def forward(self, x, ln_w):
         return apply_op(
@@ -256,45 +244,27 @@ class GraniteAttention(Layer):
 
 # -- the MLP, a layer, the stack, the model ------------------------------------
 
-class GraniteMLP(Layer):
-    """h + r * (silu(a Wg) * (a Wu)) Wd, a = RMSNorm(h); gate | up stored
-    as one [h, 2m] projection (`kernels/swiglu.py`)."""
+class GraniteMLP(SwiGLUHalf):
+    """h + r * SwiGLU(RMSNorm(h)) Wd, the width `shared_intermediate_size`."""
 
     def __init__(self, cfg: GraniteHybridConfig):
-        super().__init__()
-        self.cfg = cfg
-        h, m = cfg.hidden_size, cfg.intermediate_size
-        self.gate_up_proj = _param(self, (h, 2 * m), P(None, "mp"),
-                                   dtype=cfg.dtype)
-        self.down_proj = _param(self, (m, h), P("mp", None), dtype=cfg.dtype)
+        super().__init__(cfg, "granite_mlp")
 
-    def block(self, h, ln_w, wgu, wd):
-        cfg = self.cfg
-        a = _rms(h, ln_w, cfg.rms_norm_eps)
-        with scope("mlp"):
-            o = _swiglu(a, wgu)
-            return _branch(h, jnp.matmul(o, wd,
-                                         preferred_element_type=jnp.float32),
-                           cfg.residual_multiplier)
-
-    def forward(self, h, ln_w):
-        return apply_op(
-            jax.checkpoint(self.block, policy=core.current_remat_policy()),
-            to_tensor_like(h), ln_w, self.gate_up_proj, self.down_proj,
-            name="granite_mlp")
+    def add(self, h, o, wd):
+        return branch(h, jnp.matmul(o, wd, preferred_element_type=jnp.float32),
+                      self.cfg.residual_multiplier)
 
 
 class GraniteHybridDecoderLayer(Layer):
     def __init__(self, cfg: GraniteHybridConfig, index: int):
         super().__init__()
-        self.input_layernorm = LlamaRMSNorm(cfg.hidden_size,
-                                            cfg.rms_norm_eps)
+        self.input_layernorm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
         if cfg.layer_types[index] == "attention":
             self.self_attn = GraniteAttention(cfg)
         else:
             self.mamba = MambaMixer(cfg)
-        self.post_attention_layernorm = LlamaRMSNorm(cfg.hidden_size,
-                                                     cfg.rms_norm_eps)
+        self.post_attention_layernorm = RMSNorm(cfg.hidden_size,
+                                                cfg.rms_norm_eps)
         self.shared_mlp = GraniteMLP(cfg)
 
     def forward(self, x):
@@ -305,30 +275,10 @@ class GraniteHybridDecoderLayer(Layer):
         return self.shared_mlp(h, self.post_attention_layernorm.weight)
 
 
-class GraniteHybridModel(Layer):
+class GraniteHybridModel(DecoderStack):
     def __init__(self, cfg: GraniteHybridConfig):
-        super().__init__()
-        self.cfg = cfg
-        self.embed_tokens = _param(self, (cfg.vocab_size, cfg.hidden_size),
-                                   P("mp", None), dtype=cfg.dtype)
-        self.layers = LayerList([GraniteHybridDecoderLayer(cfg, i)
-                                 for i in range(cfg.num_hidden_layers)])
-        self.norm = LlamaRMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
-
-    def forward(self, input_ids, final_norm=True):
-        mult = self.cfg.embedding_multiplier
-
-        def embed(ids, w):
-            with scope("embed"):
-                rows = jnp.take(w, ids.astype(jnp.int32), axis=0)
-                return (rows.astype(jnp.float32) * mult).astype(w.dtype)
-
-        x = apply_op(embed, to_tensor_like(input_ids), self.embed_tokens,
-                     name="embed")
-        for lyr in self.layers:
-            with scope("layers"):
-                x = lyr(x)
-        return self.norm(x) if final_norm else x
+        super().__init__(cfg, GraniteHybridDecoderLayer,
+                         cfg.embedding_multiplier)
 
 
 class GraniteHybridForCausalLM(Layer):
@@ -355,23 +305,8 @@ class GraniteHybridForCausalLM(Layer):
     def loss(self, input_ids, labels):
         """Shifted next-token cross-entropy, the head and the loss a block
         of rows at a time: the last position of a sequence has no label."""
-        from ..nn.functional.loss import _linear_cross_entropy
-        cfg = self.cfg
-        lb = to_tensor_like(labels).data
-        nxt = jnp.concatenate(
-            [lb[:, 1:], jnp.full((lb.shape[0], 1), -100, lb.dtype)],
-            axis=1).reshape(-1)
-
-        def head_loss(x, norm_w, table):
-            # the last norm's output is not kept: the norm alone runs
-            # again in the backward
-            xn = jax.checkpoint(_rms, static_argnums=2)(
-                x, norm_w, cfg.rms_norm_eps)
-            return _linear_cross_entropy(
-                xn.reshape(-1, xn.shape[-1]), table, nxt,
-                cfg.loss_block_rows, -100, tied=True,
-                logit_scale=1.0 / cfg.logits_scaling)
-
-        return apply_op(head_loss, self.model(input_ids, final_norm=False),
-                        self.model.norm.weight, self.model.embed_tokens,
-                        name="head_loss")
+        nxt = shifted(labels)
+        return blocked_loss(
+            self.cfg, self.model(input_ids, final_norm=False),
+            self.model.norm.weight, self.model.embed_tokens, nxt, tied=True,
+            logit_scale=1.0 / self.cfg.logits_scaling)

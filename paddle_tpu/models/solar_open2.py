@@ -40,14 +40,14 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..autograd.tape import apply_op
-from ..framework import core
 from ..nn import initializer as I
-from ..nn.layer.container import LayerList
 from ..nn.layer.layers import Layer
-from ..nn.layer.moe import DroplessMoE
 from ..observability.scopes import scope
 from ..ops._helpers import to_tensor_like
-from .llama import LlamaRMSNorm, _param, _sdpa
+from .pieces import (CausalLM, DecoderStack, RMSNorm, blocked_loss,
+                     dropless_moe_of,
+                     group_of, moe_counters, moe_half, param, rms, sdpa,
+                     shifted, sum_of_groups)
 
 __all__ = ["SolarOpen2Config", "SolarOpen2Model", "SolarOpen2ForCausalLM",
            "solar_open2_tiny"]
@@ -102,37 +102,6 @@ def solar_open2_tiny(**kw):
     return SolarOpen2Config(**base)
 
 
-def _rms(a, w, eps):
-    from ..kernels import rms_norm as krn
-    with scope("norm"):
-        return krn.rms_norm(a, w, eps)
-
-
-def _group_of(w, parts, groups, g):
-    """Columns of group g: w [rows, parts * groups * n] viewed as
-    [rows, parts, groups, n] -> [rows, parts * n]."""
-    rows = w.shape[0]
-    w4 = w.reshape(rows, parts, groups, -1)
-    return jax.lax.dynamic_index_in_dim(w4, g, 2, keepdims=False).reshape(
-        rows, -1)
-
-
-def _sum_of_groups(group, n, x, ws):
-    """sum over g < n of group(g, x, *ws), in x's dtype: one group of heads
-    at a time, summed in float32; the backward recomputes a group
-    (`jax.checkpoint`) and keeps of it what the armed remat policy names
-    (the attention kernel's out and logsumexp, stacked over the groups by
-    the scan; a delta-rule group stamps nothing)."""
-    run = jax.checkpoint(group, policy=core.current_remat_policy())
-
-    def body(acc, g):
-        return acc + run(g, x, *ws), None
-
-    acc, _ = jax.lax.scan(body, jnp.zeros(x.shape, jnp.float32),
-                          jnp.arange(n))
-    return acc.astype(x.dtype)
-
-
 # -- the softmax layer ---------------------------------------------------------
 
 class GatedAttention(Layer):
@@ -143,12 +112,12 @@ class GatedAttention(Layer):
         self.cfg = cfg
         h, d = cfg.hidden_size, cfg.head_dim
         nh, kvh = cfg.num_attention_heads, cfg.num_key_value_heads
-        self.qkv_proj = _param(self, (h, (nh + 2 * kvh) * d), P(None, "mp"),
+        self.qkv_proj = param(self, (h, (nh + 2 * kvh) * d), P(None, "mp"),
+                              dtype=cfg.dtype)
+        self.gate_proj = param(self, (h, nh * d), P(None, "mp"),
                                dtype=cfg.dtype)
-        self.gate_proj = _param(self, (h, nh * d), P(None, "mp"),
-                                dtype=cfg.dtype)
-        self.o_proj = _param(self, (nh * d, h), P("mp", None),
-                             dtype=cfg.dtype)
+        self.o_proj = param(self, (nh * d, h), P("mp", None),
+                            dtype=cfg.dtype)
 
     def _group(self, g, x, ln_w, wqkv, wg, wo):
         """KV head g with its query heads: their part of the output
@@ -158,24 +127,24 @@ class GatedAttention(Layer):
         nh, kvh, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                       cfg.head_dim)
         rep = nh // kvh
-        xn = _rms(x, ln_w, cfg.rms_norm_eps)
+        xn = rms(x, ln_w, cfg.rms_norm_eps)
         with scope("attn/qkv"):
-            q = (xn @ _group_of(wqkv[:, :nh * d], 1, kvh, g)).reshape(
+            q = (xn @ group_of(wqkv[:, :nh * d], 1, kvh, g)).reshape(
                 B, T, rep, d)
-            k = (xn @ _group_of(wqkv[:, nh * d:(nh + kvh) * d], 1, kvh,
-                                g)).reshape(B, T, 1, d)
-            v = (xn @ _group_of(wqkv[:, (nh + kvh) * d:], 1, kvh,
-                                g)).reshape(B, T, 1, d)
+            k = (xn @ group_of(wqkv[:, nh * d:(nh + kvh) * d], 1, kvh,
+                               g)).reshape(B, T, 1, d)
+            v = (xn @ group_of(wqkv[:, (nh + kvh) * d:], 1, kvh,
+                               g)).reshape(B, T, 1, d)
         with scope("attn/core"):
             from ..kernels import flash_attention as fa
             if fa.supported(q.shape, k.shape, True):
                 o = fa.flash_attention_bshd(q, k, v, causal=True)
             else:
-                o = _sdpa(q, jnp.repeat(k, rep, axis=2),
-                          jnp.repeat(v, rep, axis=2))
+                o = sdpa(q, jnp.repeat(k, rep, axis=2),
+                         jnp.repeat(v, rep, axis=2))
         with scope("attn/gate"):
             gate = jax.nn.sigmoid(
-                (xn @ _group_of(wg, 1, kvh, g)).astype(jnp.float32))
+                (xn @ group_of(wg, 1, kvh, g)).astype(jnp.float32))
             o = (o.reshape(B, T, rep * d).astype(jnp.float32)
                  * gate).astype(x.dtype)
         with scope("attn/out"):
@@ -184,8 +153,8 @@ class GatedAttention(Layer):
             return jnp.matmul(o, wo_g, preferred_element_type=jnp.float32)
 
     def block(self, x, *ws):
-        mixed = _sum_of_groups(self._group, self.cfg.num_key_value_heads,
-                               x, ws)
+        mixed = sum_of_groups(self._group, self.cfg.num_key_value_heads,
+                              x, ws)
         with scope("attn/out"):
             return x + mixed
 
@@ -208,28 +177,28 @@ class KDAttention(Layer):
         h, r = cfg.hidden_size, cfg.kda_low_rank
         nl, dl = cfg.linear_num_heads, cfg.linear_head_dim
         dt = cfg.dtype
-        self.qkv_proj = _param(self, (h, 3 * nl * dl), P(None, "mp"),
-                               dtype=dt)
-        self.conv_weight = _param(
+        self.qkv_proj = param(self, (h, 3 * nl * dl), P(None, "mp"),
+                              dtype=dt)
+        self.conv_weight = param(
             self, (cfg.short_conv_kernel_size, 3 * nl * dl), P(None, "mp"),
             init=I.Uniform(-0.5, 0.5), dtype=dt)
-        self.decay_down = _param(self, (h, r), P(None, None), dtype=dt)
-        self.decay_up = _param(self, (r, nl * dl), P(None, "mp"), dtype=dt)
+        self.decay_down = param(self, (h, r), P(None, None), dtype=dt)
+        self.decay_up = param(self, (r, nl * dl), P(None, "mp"), dtype=dt)
         # a decay of exp(-A dt) a token: A in (1, 16), dt in (1e-3, 1e-1),
         # so heads remember from a few tokens to a few thousand
-        self.A_log = _param(self, (nl,), P(None), init=I.Uniform(1.0, 16.0),
-                            dtype="float32")
+        self.A_log = param(self, (nl,), P(None), init=I.Uniform(1.0, 16.0),
+                           dtype="float32")
         self.A_log.data = jnp.log(self.A_log.data)
-        self.dt_bias = _param(
+        self.dt_bias = param(
             self, (nl * dl,), P(None),
             init=I.Uniform(math.log(1e-3), math.log(1e-1)), dtype="float32")
         step = jnp.exp(self.dt_bias.data)
         self.dt_bias.data = step + jnp.log(-jnp.expm1(-step))
-        self.beta_proj = _param(self, (h, nl), P(None, "mp"), dtype=dt)
-        self.gate_down = _param(self, (h, r), P(None, None), dtype=dt)
-        self.gate_up = _param(self, (r, nl * dl), P(None, "mp"), dtype=dt)
-        self.o_norm = LlamaRMSNorm(dl, cfg.rms_norm_eps)
-        self.o_proj = _param(self, (nl * dl, h), P("mp", None), dtype=dt)
+        self.beta_proj = param(self, (h, nl), P(None, "mp"), dtype=dt)
+        self.gate_down = param(self, (h, r), P(None, None), dtype=dt)
+        self.gate_up = param(self, (r, nl * dl), P(None, "mp"), dtype=dt)
+        self.o_norm = RMSNorm(dl, cfg.rms_norm_eps)
+        self.o_proj = param(self, (nl * dl, h), P("mp", None), dtype=dt)
 
     def _groups(self):
         nl, hg = self.cfg.linear_num_heads, self.cfg.kda_head_group
@@ -238,7 +207,7 @@ class KDAttention(Layer):
     def _shared(self, x, ln_w, wdd, wgd, wbeta):
         """Once a layer: (RMSNorm(x), xn @ [decay_down | gate_down |
         beta_proj]), the second [B, T, 2 rank + heads]."""
-        xn = _rms(x, ln_w, self.cfg.rms_norm_eps)
+        xn = rms(x, ln_w, self.cfg.rms_norm_eps)
         with scope("kda/gate"):
             return xn, xn @ jnp.concatenate([wdd, wgd, wbeta], axis=1)
 
@@ -249,10 +218,10 @@ class KDAttention(Layer):
         hg = self.cfg.linear_num_heads // G
         return (low[..., :r], low[..., r:2 * r],
                 jax.lax.dynamic_slice_in_dim(low, 2 * r + g * hg, hg, -1),
-                _group_of(wqkv, 3, G, g), _group_of(wconv, 3, G, g),
-                _group_of(wdu, 1, G, g), _group_of(a_log[None], 1, G, g)[0],
-                _group_of(dt_bias[None], 1, G, g)[0],
-                _group_of(wgu, 1, G, g),
+                group_of(wqkv, 3, G, g), group_of(wconv, 3, G, g),
+                group_of(wdu, 1, G, g), group_of(a_log[None], 1, G, g)[0],
+                group_of(dt_bias[None], 1, G, g)[0],
+                group_of(wgu, 1, G, g),
                 jax.lax.dynamic_index_in_dim(
                     wo.reshape(G, -1, wo.shape[-1]), g, 0, keepdims=False))
 
@@ -395,27 +364,14 @@ class SolarOpen2DecoderLayer(Layer):
     def __init__(self, cfg: SolarOpen2Config, index: int):
         super().__init__()
         self.cfg = cfg
-        self.input_layernorm = LlamaRMSNorm(cfg.hidden_size,
-                                            cfg.rms_norm_eps)
+        self.input_layernorm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
         if index in cfg.gqa_layers:
             self.self_attn = GatedAttention(cfg)
         else:
             self.linear_attn = KDAttention(cfg)
-        self.post_attention_layernorm = LlamaRMSNorm(cfg.hidden_size,
-                                                     cfg.rms_norm_eps)
-        self.mlp = DroplessMoE(
-            cfg.hidden_size, cfg.moe_intermediate_size, cfg.n_routed_experts,
-            cfg.num_experts_per_tok, experts_held=cfg.experts_held,
-            first_expert=cfg.expert_offset,
-            shared_experts=cfg.n_shared_experts,
-            norm_topk_prob=cfg.norm_topk_prob,
-            routed_scaling_factor=cfg.routed_scaling_factor,
-            rows=cfg.moe_rows, dtype=cfg.dtype)
-
-    def _experts(self, h, ln_w, *ws):
-        y, counts, dropped = self.mlp.compute(
-            _rms(h, ln_w, self.cfg.rms_norm_eps), *ws)
-        return h + y, counts, dropped
+        self.post_attention_layernorm = RMSNorm(cfg.hidden_size,
+                                                cfg.rms_norm_eps)
+        self.mlp = dropless_moe_of(cfg)
 
     def forward(self, x):
         """Two taped operations: the mixer keeps only x and recomputes a
@@ -424,85 +380,26 @@ class SolarOpen2DecoderLayer(Layer):
         mixer = self.self_attn if hasattr(self, "self_attn") \
             else self.linear_attn
         h = mixer(x, self.input_layernorm.weight)
-        run = jax.checkpoint(self._experts,
-                             policy=core.current_remat_policy())
-        y, counts, dropped = apply_op(
-            run, h, self.post_attention_layernorm.weight,
-            *self.mlp.weights(), n_outputs=3, name="moe_block")
-        self.mlp.record(counts.data, dropped.data)
-        return y
+        return moe_half(self.mlp, h, self.post_attention_layernorm.weight,
+                        self.cfg.rms_norm_eps)
 
 
-class SolarOpen2Model(Layer):
+class SolarOpen2Model(DecoderStack):
     def __init__(self, cfg: SolarOpen2Config):
-        super().__init__()
-        self.cfg = cfg
-        self.embed_tokens = _param(self, (cfg.vocab_size, cfg.hidden_size),
-                                   P("mp", None), dtype=cfg.dtype)
-        self.layers = LayerList([SolarOpen2DecoderLayer(cfg, i)
-                                 for i in range(cfg.num_hidden_layers)])
-        self.norm = LlamaRMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
-
-    def forward(self, input_ids, final_norm=True):
-        def embed(ids, w):
-            with scope("embed"):
-                return jnp.take(w, ids.astype(jnp.int32), axis=0)
-
-        x = apply_op(embed, to_tensor_like(input_ids), self.embed_tokens,
-                     name="embed")
-        for lyr in self.layers:
-            with scope("layers"):
-                x = lyr(x)
-        return self.norm(x) if final_norm else x
+        super().__init__(cfg, SolarOpen2DecoderLayer)
 
 
-def _head(a, w):
-    with scope("head"):
-        return a @ w
-
-
-class SolarOpen2ForCausalLM(Layer):
+class SolarOpen2ForCausalLM(CausalLM):
     def __init__(self, cfg: SolarOpen2Config):
-        super().__init__()
-        self.cfg = cfg
-        self.model = SolarOpen2Model(cfg)
-        self.lm_head = _param(self, (cfg.hidden_size, cfg.vocab_size),
-                              P(None, "mp"), dtype=cfg.dtype)
-
-    def forward(self, input_ids):
-        return apply_op(_head, self.model(input_ids), self.lm_head,
-                        name="lm_head")
+        super().__init__(cfg, SolarOpen2Model)
 
     def loss(self, input_ids, labels):
         """Shifted next-token cross-entropy, the head and the loss a block
         of rows at a time: the last position of a sequence has no label."""
-        from ..nn.functional.loss import _linear_cross_entropy
-        cfg = self.cfg
-        lb = to_tensor_like(labels).data
-        nxt = jnp.concatenate(
-            [lb[:, 1:], jnp.full((lb.shape[0], 1), -100, lb.dtype)],
-            axis=1).reshape(-1)
-
-        def head_loss(x, norm_w, w):
-            # the last norm's output is not kept: the norm alone runs
-            # again in the backward
-            xn = jax.checkpoint(_rms, static_argnums=2)(
-                x, norm_w, cfg.rms_norm_eps)
-            return _linear_cross_entropy(
-                xn.reshape(-1, xn.shape[-1]), w, nxt, cfg.loss_block_rows,
-                -100)
-
-        return apply_op(head_loss, self.model(input_ids, final_norm=False),
-                        self.model.norm.weight, self.lm_head,
-                        name="head_loss")
+        nxt = shifted(labels)
+        return blocked_loss(self.cfg, self.model(input_ids, final_norm=False),
+                            self.model.norm.weight, self.lm_head, nxt)
 
     def moe_counters(self):
-        """{"expert_tokens": [layers, experts held], "dropped_pairs":
-        [layers]} as the last step left them (host arrays; not for a
-        timed region: reading waits for the device)."""
-        import numpy as np
-        mlps = [lyr.mlp for lyr in self.model.layers]
-        return {"expert_tokens": np.stack(
-                    [np.asarray(m.expert_tokens.data) for m in mlps]),
-                "dropped_pairs": np.asarray(
-                    [np.asarray(m.dropped_pairs.data) for m in mlps])}
+        """`pieces.moe_counters` of every layer."""
+        return moe_counters(self.model.layers)

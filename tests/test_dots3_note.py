@@ -3,11 +3,12 @@
 dense layer 0 included: logits, the loss and every leaf's gradient, which
 loss reaches which leaf, the selected sets, the degenerate selections
 (top-k and window of the whole sequence), the expert layer's shares with
-the selection bias, and the model through `TrainStep`. The programs of
-the models the benchmark already had are held to the parent's text."""
-import hashlib
+the selection bias, and the model through `TrainStep`. A parity check
+runs its model under one `jit` (`tests/_compiled.py`) and the step's three
+tests read one `TrainStep`: a test file compiles what it checks once. (The
+step programs' text is held to the parent's in
+`tests/test_step_program_text.py`.)"""
 import os
-import re
 import sys
 
 import jax
@@ -17,14 +18,15 @@ import pytest
 
 import paddle_tpu as paddle
 import paddle_tpu.optimizer as popt
-from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels import sparse_select_attention as dsa
 from paddle_tpu.models.dots3_note import (FULL, SLIDING,
                                           Dots3NoteForCausalLM,
                                           dots3_note_tiny)
 from paddle_tpu.nn.layer.moe import DroplessMoE, route_top_k
 
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+import _compiled  # noqa: E402
 from chipbench import reference_dots3_note as ref  # noqa: E402
 
 B, T = 2, 64
@@ -88,13 +90,17 @@ def test_layers_are_the_dense_one_then_a_period(tiny):
     assert len({type(lyr.self_attn) for lyr in model.model.layers}) == 1
 
 
+def logits_of(model, ids):
+    return _compiled.run(model, model, ids)
+
+
 def test_logits_against_the_reference(tiny):
+    """Through the public entry, `paddle.jit.to_static`: one program."""
     model, _, cj, ids, state = tiny
     model.eval()
     got = paddle.jit.to_static(model)(paddle.to_tensor(ids)).data
     model.train()
-    with jax.default_matmul_precision("highest"):
-        want = ref.logits(state, jnp.asarray(ids), cj, HELD)
+    want = _compiled.reference(ref.logits, state, ids, cj, HELD)
     assert float(jnp.max(jnp.abs(got - want))) < 2e-6
     assert float(jnp.max(jnp.abs(want))) > 0.1
 
@@ -102,26 +108,20 @@ def test_logits_against_the_reference(tiny):
 @pytest.fixture(scope="module")
 def grads(tiny):
     """The program's loss and gradients of L_LM + L_I, of L_LM alone and of
-    the L_I alone, and the reference's of the whole."""
+    the L_I alone (a compiled program each), and the reference's of the
+    whole."""
     model, _, cj, ids, state = tiny
-    x = paddle.to_tensor(ids)
-    out = {}
-    for which in ("total", "lm", "indexer"):
-        for p in model.parameters():
-            p.grad = None
-        if which == "total":
-            loss = model.loss(x, x)
-        else:
-            lm, aux = model.losses(x, x)
-            loss = lm if which == "lm" else aux[0] + aux[1]
-        loss.backward()
-        out[which] = (float(loss.data), {
-            k: None if p.grad is None else np.asarray(p.grad.data)
-            for k, p in model.named_parameters()})
-    for p in model.parameters():
-        p.grad = None
-    out["want"] = ref.loss_and_grads(state, jnp.asarray(ids), cj, HELD)
-    out["want_lm"] = ref.losses(state, jnp.asarray(ids), cj, HELD)
+    def indexers(i, l):
+        first, second = model.losses(i, l)[1]
+        return first + second
+
+    out = {"total": _compiled.loss_and_grads(model, model.loss, ids, ids),
+           "lm": _compiled.loss_and_grads(
+               model, lambda i, l: model.losses(i, l)[0], ids, ids),
+           "indexer": _compiled.loss_and_grads(model, indexers, ids, ids)}
+    out["want"] = _compiled.reference(ref.loss_and_grads, state, ids, cj,
+                                      HELD)
+    out["want_lm"] = _compiled.reference(ref.losses, state, ids, cj, HELD)
     return out
 
 
@@ -175,7 +175,7 @@ def test_selected_sets_are_the_references(tiny):
     """Both full layers, rows t < top-k among them: the program's mask from
     its own index inputs equals the reference's, pair for pair."""
     model, cfg, cj, ids, state = tiny
-    want = ref.selected_sets(state, jnp.asarray(ids), cj, HELD)
+    want = _compiled.reference(ref.selected_sets, state, ids, cj, HELD)
     a = ref.arch(cj)
     x = jnp.take(state["model.embed_tokens"], jnp.asarray(ids), axis=0)
     for i in (0, 1):
@@ -183,11 +183,10 @@ def test_selected_sets_are_the_references(tiny):
         sa = lyr.self_attn
         ws = [sa.q_a_proj.data, sa.q_a_layernorm.weight.data] + [
             t.data for t in sa.indexer.weights()]
+        selected = jax.jit(lambda xb, *ws: dsa.select_top_k(
+            dsa.index_scores(*sa._index_inputs(xb, *ws)), cfg.index_topk)[0])
         for b in range(B):
-            qi, ki, w = sa._index_inputs(
-                x[b], lyr.input_layernorm.weight.data, *ws)
-            mask, _ = dsa.select_top_k(dsa.index_scores(qi, ki, w),
-                                       cfg.index_topk)
+            mask = selected(x[b], lyr.input_layernorm.weight.data, *ws)
             assert bool(jnp.all((mask != 0) == want[i][b])), (i, b)
             rows = np.asarray(mask).sum(1)
             assert (rows == np.minimum(np.arange(T) + 1, 16)).all()
@@ -208,16 +207,14 @@ def test_selecting_and_windowing_the_whole_sequence_is_plain_causal_mla():
         np.int32)
     state = {k: t.data for k, t in model.state_dict().items()}
     cj = config_json(cfg)
-    got = model(paddle.to_tensor(ids)).data
-    with jax.default_matmul_precision("highest"):
-        want = ref.logits(state, jnp.asarray(ids), cj, HELD)
-        sets = ref.selected_sets(state, jnp.asarray(ids), cj, HELD)
+    got = logits_of(model, ids)
+    want = _compiled.reference(ref.logits, state, ids, cj, HELD)
+    sets = _compiled.reference(ref.selected_sets, state, ids, cj, HELD)
     assert float(jnp.max(jnp.abs(got - want))) < 2e-6
     assert all(bool(jnp.all(m[0] == jnp.tril(jnp.ones((T, T), bool))))
                for m in sets)
     narrow, _ = build(seed=3)
-    assert float(jnp.max(jnp.abs(
-        narrow(paddle.to_tensor(ids)).data - got))) > 1e-4
+    assert float(jnp.max(jnp.abs(logits_of(narrow, ids) - got))) > 1e-4
 
 
 # -- the expert layer with the selection bias --------------------------------------
@@ -248,11 +245,11 @@ def test_shares_with_the_bias_add_up_to_the_uncut_layer():
     whole = DroplessMoE(32, 16, 8, 2, dtype="float32", selection_bias=True)
     whole.e_score_correction_bias.data = jnp.asarray(
         np.random.default_rng(1).normal(0, 0.1, (8,)), jnp.float32)
-    x = paddle.to_tensor(np.random.default_rng(2).normal(
-        0, 1, (48, 32)).astype(np.float32))
-    want = whole(x).data
-    shared = (jax.nn.silu(x.data @ whole.shared_gate_up.data[:, :16])
-              * (x.data @ whole.shared_gate_up.data[:, 16:])
+    x = jnp.asarray(np.random.default_rng(2).normal(0, 1, (48, 32)),
+                    jnp.float32)
+    want = _compiled.run(whole, whole, x)
+    shared = (jax.nn.silu(x @ whole.shared_gate_up.data[:, :16])
+              * (x @ whole.shared_gate_up.data[:, 16:])
               ) @ whole.shared_down.data
     total = jnp.zeros_like(want)
     rows = 0
@@ -265,7 +262,7 @@ def test_shares_with_the_bias_add_up_to_the_uncut_layer():
         part.experts_down.data = whole.experts_down.data[e0:e0 + 2]
         part.shared_gate_up.data = whole.shared_gate_up.data
         part.shared_down.data = whole.shared_down.data
-        total = total + part(x).data - shared
+        total = total + _compiled.run(part, part, x) - shared
         rows += int(part.expert_tokens.data.sum())
     assert rows == 48 * 2
     np.testing.assert_allclose(total + shared, want, atol=2e-6)
@@ -283,18 +280,30 @@ def test_model_told_its_share_matches_the_reference_told_the_same():
               reduced_from={"n_routed_experts": 8})
     assert state["model.layers.1.mlp.experts_down"].shape[0] == 4
     assert state["model.layers.1.mlp.router"].shape[1] == 8
-    with jax.default_matmul_precision("highest"):
-        want = ref.logits(state, jnp.asarray(ids), cj, (4, 4))
-    assert float(jnp.max(jnp.abs(
-        model(paddle.to_tensor(ids)).data - want))) < 2e-6
+    want = _compiled.reference(ref.logits, state, ids, cj, (4, 4))
+    assert float(jnp.max(jnp.abs(logits_of(model, ids) - want))) < 2e-6
 
 
 # -- through TrainStep -------------------------------------------------------------
 
-def test_trains_through_train_step_without_retracing():
+@pytest.fixture(scope="module")
+def stepped():
+    """One model and its `TrainStep`, traced once, by the lowering whose
+    text (with the names) and set-up events the tests below read; the
+    steps that train it run the same trace."""
+    from paddle_tpu.observability import spans
     model, cfg = build(seed=4)
     opt = popt.AdamW(learning_rate=3e-3, parameters=model.parameters())
     step = paddle.jit.TrainStep(model, opt, lambda i, l: model.loss(i, l))
+    x = paddle.to_tensor(np.zeros((1, T), np.int32))
+    spans.clear()
+    text = step.lower(x, x).as_text(debug_info=True)
+    events = [ev["attrs"] for ev in spans.ring() if ev["name"] == "moe.rows"]
+    return model, cfg, step, text, events
+
+
+def test_trains_through_train_step_without_retracing(stepped):
+    model, cfg, step = stepped[:3]
     rng = np.random.default_rng(0)
     x = paddle.to_tensor(rng.integers(0, cfg.vocab_size, (1, T)).astype(
         np.int32))
@@ -314,92 +323,21 @@ def test_trains_through_train_step_without_retracing():
     assert not any("e_score" in k for k, _ in model.named_parameters())
 
 
-def test_step_carries_the_new_names():
-    model, cfg = build(seed=5)
-    opt = popt.AdamW(learning_rate=1e-3, parameters=model.parameters())
-    step = paddle.jit.TrainStep(model, opt, lambda i, l: model.loss(i, l))
-    x = paddle.to_tensor(np.zeros((1, T), np.int32))
-    text = step.lower(x, x).as_text(debug_info=True)
+def test_step_carries_the_new_names(stepped):
+    text = stepped[3]
     for name in ("attn/index", "attn/select", "attn/core/selected",
                  "attn/core/window", "attn/qkv", "attn/gate", "attn/rope",
                  "attn/out", "moe/router", "moe/experts"):
         assert name in text, name
 
 
-def test_a_traced_step_leaves_one_moe_rows_event_an_expert_layer():
+def test_a_traced_step_leaves_one_moe_rows_event_an_expert_layer(stepped):
     """`moe.rows` (ISSUE 45): once an expert layer a trace, with the route
     the layer's scatter-adds took (the compiler's, off the chip) and the
     shape of its row buffer."""
-    from paddle_tpu.observability import spans
-    model, cfg = build(seed=5)
-    opt = popt.AdamW(learning_rate=1e-3, parameters=model.parameters())
-    step = paddle.jit.TrainStep(model, opt, lambda i, l: model.loss(i, l))
-    x = paddle.to_tensor(np.zeros((1, T), np.int32))
-    step._build()
-    spans.clear()
-    step._compiled.trace(*step._call_args((x, x)))
-    events = [ev["attrs"] for ev in spans.ring() if ev["name"] == "moe.rows"]
+    _, cfg, _, _, events = stepped
     assert len(events) == cfg.num_hidden_layers - cfg.first_k_dense_replace
     assert all(ev["route"] == "xla" and ev["tokens"] == str(T)
                and ev["hidden"] == str(cfg.hidden_size)
                and int(ev["row_bytes"]) == 4 * cfg.hidden_size
                for ev in events)
-
-
-# -- the models the benchmark had lower as they did --------------------------------
-
-PARENT = {       # sha256 of the text at commit 0eb8308 (PR 32), read under
-    # this suite's conftest (8 host devices), addresses and step tags out;
-    # the two `llama_gqa.*` still PR 32's; the other four are PR 46's
-    # program: `solar.*` with PR 37's delta-rule block and PR 45's row
-    # moves (`kernels/row_moves.py`), and both models with the head + loss
-    # that makes a block's gradients beside its loss (against commit
-    # 4ccd5e4 their jaxprs differ in that one stretch and nowhere else)
-    "llama_gqa.cpu_text": "b4b176201bc8d8fbafa942c340cb4a468ec2b616380afc060286c729b452eeeb",
-    "solar.cpu_text": "828a6756db725ea97a7568847957159b50837da7a6c1d0b4ac2844606f3d0083",
-    "granite.cpu_text": "557ddf2fb05388c761d8d5d4256b73f3c7542a3d10d555e0f35270360e53f8e0",
-    "llama_gqa.tpu_jaxpr": "5324d891f9ab8d1d9e73a12910b401c5d8bf61db6d0ebfed52574e7c5fc20473",
-    "solar.tpu_jaxpr": "479a45451596897a73798e72559cf1643c39dad67d8ff619922c6f5d09790152",
-    "granite.tpu_jaxpr": "3643417b69b7f9a24fa25e40435d9c7bb2be836732cabf44334cc7170a6cd946",
-}
-
-
-def _existing(name):
-    from paddle_tpu.models.granite_hybrid import (GraniteHybridForCausalLM,
-                                                  granite_hybrid_tiny)
-    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-    from paddle_tpu.models.solar_open2 import (SolarOpen2ForCausalLM,
-                                               solar_open2_tiny)
-    paddle.seed(0)
-    if name == "llama_gqa":
-        return LlamaForCausalLM(LlamaConfig(
-            vocab_size=96, hidden_size=256, intermediate_size=256,
-            num_hidden_layers=2, num_attention_heads=4,
-            num_key_value_heads=2, max_position_embeddings=128,
-            dtype="float32"))
-    if name == "solar":
-        return SolarOpen2ForCausalLM(solar_open2_tiny(head_dim=64))
-    return GraniteHybridForCausalLM(granite_hybrid_tiny())
-
-
-@pytest.mark.parametrize("key", sorted(PARENT))
-def test_existing_models_lower_to_the_parents_program(key, monkeypatch):
-    """The Yi cells' model (LLaMA, GQA), Solar-Open2 and Granite through
-    `TrainStep`: the StableHLO text of the CPU route, and the jaxpr of the
-    TPU route (`flash_attention._on_tpu` patched: the splash wrapper with
-    its window and value width unset), are the parent commit's, character
-    for character (memory addresses in a repr apart)."""
-    name, route = key.split(".")
-    monkeypatch.setattr(fa, "_on_tpu", lambda: route == "tpu_jaxpr")
-    model = _existing(name)
-    opt = popt.AdamW(learning_rate=1e-3, parameters=model.parameters())
-    step = paddle.jit.TrainStep(model, opt, lambda i, l: model.loss(i, l))
-    x = paddle.to_tensor(np.zeros((1, 128), np.int32))
-    if route == "tpu_jaxpr":
-        step._build()
-        text = str(step._compiled.trace(*step._call_args((x, x))).jaxpr)
-    else:
-        text = step.lower(x, x).as_text()
-    # a step's executable tag counts the steps the process has built
-    text = re.sub(r"0x[0-9a-f]+|train_step_\d+", "0x", text)
-    assert hashlib.sha256(text.encode()).hexdigest() == PARENT[key]

@@ -25,6 +25,7 @@ from paddle_tpu.kernels import row_moves as rm  # noqa: E402
 from paddle_tpu.kernels.grouped_matmul import grouped_matmul  # noqa: E402
 from paddle_tpu.nn.layer.moe import dropless_moe  # noqa: E402
 from reference import solar_open2 as ref  # noqa: E402
+import _compiled  # noqa: E402
 import _moe_parent_rows as parent  # noqa: E402
 
 
@@ -471,14 +472,7 @@ def _tiny(name):
                     glm4_moe_lite.glm4_moe_lite_tiny)}[name]
     kw = {"layer_types": ("mamba",)} if name == "granite" else {}
     cfg = make[1](vocab_size=200, num_hidden_layers=1, **kw)
-    box = {}
-    # the constructor traced abstractly and zeros put in: drawing the
-    # weights is most of a tiny model's seconds, and only shapes are read
-    jax.eval_shape(lambda: box.update(model=make[0](cfg)))
-    paddle.seed(0)      # the traced constructor left a tracer as the key
-    for t in box["model"].state_dict().values():
-        t.data = jnp.asarray(np.zeros(t.data.shape, t.data.dtype))
-    return box["model"]
+    return _compiled.shapes_only(lambda: make[0](cfg))
 
 
 @pytest.mark.parametrize("name,passes", [

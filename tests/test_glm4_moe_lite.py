@@ -4,7 +4,8 @@ both losses, every leaf's gradient and AdamW steps through `TrainStep`; 20
 heads on a hidden size they do not divide, the head-group sizes, the
 shares of experts and vocabulary against the uncut layer and loss, what
 the multi-token-prediction module does to the shared leaves, and the names
-a trace of the step carries."""
+a trace of the step carries. A parity check runs its model, and the
+reference, under one `jit` (`tests/_compiled.py`)."""
 import os
 import sys
 
@@ -19,7 +20,9 @@ from paddle_tpu.models.dots3_note import CAUSAL
 from paddle_tpu.models.glm4_moe_lite import (Glm4MoeLiteForCausalLM,
                                              glm4_moe_lite_tiny)
 
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+import _compiled  # noqa: E402
 from chipbench import reference_glm4_moe_lite as ref  # noqa: E402
 
 B, T = 2, 32
@@ -91,43 +94,28 @@ def test_logits_against_the_reference(tiny):
     model.eval()
     got = paddle.jit.to_static(model)(paddle.to_tensor(ids)).data
     model.train()
-    with jax.default_matmul_precision("highest"):
-        want = ref.logits(state, jnp.asarray(ids), cj, HELD)
+    want = _compiled.reference(ref.logits, state, ids, cj, HELD)
     assert float(jnp.max(jnp.abs(got - want))) < 2e-6
     assert float(jnp.max(jnp.abs(want))) > 0.1
-
-
-def _backward(model, loss):
-    for p in model.parameters():
-        p.grad = None
-    loss.backward()
-    out = {k: None if p.grad is None else np.asarray(p.grad.data)
-           for k, p in model.named_parameters()}
-    for p in model.parameters():
-        p.grad = None
-    return out
 
 
 @pytest.fixture(scope="module")
 def grads(tiny):
     """The program's losses and gradients of the whole loss, of the loss
-    with the module's weight 0 (L_main) and of L_MTP alone, and the
-    reference's three."""
+    with the module's weight 0 (L_main) and of L_MTP alone (a compiled
+    program each), and the reference's three."""
     model, cfg, cj, ids, state = tiny
-    x = paddle.to_tensor(ids)
-    out = {}
-    total = model.loss(x, x)
+    out = {"total": _compiled.loss_and_grads(model, model.loss, ids, ids)}
     out["kept"] = (float(model.main_loss.data), float(model.mtp_loss.data))
-    out["total"] = (float(total.data), _backward(model, total))
     cfg.mtp_loss_weight = 0.0
     try:
-        main = model.loss(x, x)
-        out["main"] = (float(main.data), _backward(model, main))
+        out["main"] = _compiled.loss_and_grads(model, model.loss, ids, ids)
     finally:
         cfg.mtp_loss_weight = 0.3
-    extra = model.losses(x, x)[1]
-    out["mtp"] = (float(extra.data), _backward(model, extra))
-    want = ref.loss_and_grads(state, jnp.asarray(ids), cj, HELD, "all")
+    out["mtp"] = _compiled.loss_and_grads(
+        model, lambda i, l: model.losses(i, l)[1], ids, ids)
+    want = _compiled.reference(ref.loss_and_grads, state, ids, cj, HELD,
+                               "all")
     out.update({"want_" + k: v for k, v in want.items()})
     return out
 
@@ -235,8 +223,8 @@ def test_the_modules_labels_are_two_on_and_the_last_position_is_masked(tiny):
     # reference's per-row terms against t_{i+2}
     lse, lg = mtp_rows(one)
     want = np.mean([lse[i] - lg[i, one[0, i + 2]] for i in range(T - 2)])
-    x = paddle.to_tensor(one)
-    assert float(model.losses(x, x)[1].data) == pytest.approx(want, rel=1e-5)
+    got = _compiled.run(model, lambda i, l: model.losses(i, l)[1], one, one)
+    assert float(got) == pytest.approx(want, rel=1e-5)
     # the last token is row T-2's input and row T-3's label: the rows
     # that have a label do not move with it
     other = one.copy()
@@ -352,9 +340,7 @@ def test_model_told_its_share_matches_the_reference_told_the_same():
               reduced_from={"n_routed_experts": 8})
     assert state["mtp.block.mlp.experts_down"].shape[0] == 2
     assert state["model.layers.1.mlp.router"].shape[1] == 8
-    x = paddle.to_tensor(ids)
-    with jax.default_matmul_precision("highest"):
-        want = ref.losses(state, jnp.asarray(ids), cj, (4, 2))
-    got = model.losses(x, x)
-    assert float(got[0].data) == pytest.approx(float(want[0]), rel=1e-6)
-    assert float(got[1].data) == pytest.approx(float(want[1]), rel=1e-6)
+    want = _compiled.reference(ref.losses, state, ids, cj, (4, 2))
+    got = _compiled.run(model, model.losses, ids, ids)
+    assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-6)
+    assert float(got[1]) == pytest.approx(float(want[1]), rel=1e-6)

@@ -11,6 +11,9 @@ import paddle_tpu as paddle
 import paddle_tpu.optimizer as popt
 from paddle_tpu.observability import spans
 
+import _compiled
+from paddle_tpu.models.glm4_moe_lite import (Glm4MoeLiteForCausalLM,
+                                             glm4_moe_lite_tiny)
 from test_glm4_moe_lite import (B, T, build, config_json, ids_of, ref,
                                 state_of)
 
@@ -28,9 +31,9 @@ def _step(model):
 
 
 def test_adamw_steps_through_train_step_follow_the_reference():
-    """Two steps on two batches: the losses (the second depends on the
-    first update of every leaf) and each leaf's change are the
-    reference's, half a layer at a time with its summed shared
+    """Two steps on two batches of two sequences: the losses (the second
+    depends on the first update of every leaf) and each leaf's change are
+    the reference's, half a layer at a time with its summed shared
     gradients."""
     model, cfg = build(seed=4)
     start = {k: jnp.array(v) for k, v in state_of(model).items()}
@@ -71,9 +74,14 @@ def test_adamw_steps_through_train_step_follow_the_reference():
 
 
 def test_a_trace_carries_the_modules_event_and_the_new_names_once():
-    model, cfg = build(seed=5)
+    """One sequence a batch, as the cell has it, in a lowering of its own
+    (two go through `jax.lax.map`, whose loop takes the names off the lines
+    read here): the text alone is read, so the weights are zeros."""
+    model = _compiled.shapes_only(
+        lambda: Glm4MoeLiteForCausalLM(glm4_moe_lite_tiny()))
     step = _step(model)
-    x = paddle.to_tensor(ids_of(cfg, 1, 1))
+    x = paddle.to_tensor(ids_of(model.cfg, 1, 1))
+
     def noted():
         return [e for e in spans.ring() if e.get("name") == "mtp.module"]
 
@@ -128,10 +136,10 @@ def test_dots3_notes_step_traces_to_the_parents_jaxpr(tpu_route, monkeypatch):
     from paddle_tpu.models.dots3_note import (Dots3NoteForCausalLM,
                                               dots3_note_tiny)
     monkeypatch.setattr(fa, "_on_tpu", lambda: tpu_route)
-    paddle.seed(0)
     wide = dict(index_n_heads=8, swa_qk_nope_head_dim=60, swa_v_head_dim=64,
                 v_head_dim=64, qk_nope_head_dim=60) if tpu_route else {}
-    model = Dots3NoteForCausalLM(dots3_note_tiny(**wide))
+    model = _compiled.shapes_only(
+        lambda: Dots3NoteForCausalLM(dots3_note_tiny(**wide)))
     opt = popt.AdamW(learning_rate=1e-3, parameters=model.parameters())
     step = paddle.jit.TrainStep(model, opt, lambda i, l: model.loss(i, l))
     x = paddle.to_tensor(np.zeros((1, 128), np.int32))

@@ -16,8 +16,10 @@ import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
+import _compiled  # noqa: E402
 import paddle_tpu as paddle  # noqa: E402
 from chipbench import reference_granitemoehybrid as ref  # noqa: E402
 from paddle_tpu.models import (GraniteHybridConfig,  # noqa: E402
@@ -71,25 +73,23 @@ def _logits(c, batch=2, seq=37):
     ids = _ids(c, batch, seq)
     cj = cfg_json(c)
     want = jax.jit(lambda s, i: ref.logits(s, i, cj))(state, ids)
-    return np.asarray(m(paddle.to_tensor(ids)).data), np.asarray(want)
+    return np.asarray(_compiled.run(m, m, ids)), np.asarray(want)
 
 
 @functools.lru_cache(maxsize=None)
 def _whole():
     """The published pattern at the tiny preset (m m A m): program and
-    reference, logits, loss and gradients."""
+    reference, logits, loss and gradients, each under one `jit`."""
     c = granite_hybrid_tiny()
     m, state = _model(c)
     ids = _ids(c, 2, 37)
-    x, cj = paddle.to_tensor(ids), cfg_json(c)
-    logits = np.asarray(m(x).data)
-    loss = m.loss(x, x)
-    loss.backward()
+    cj = cfg_json(c)
+    logits = np.asarray(_compiled.run(m, m, ids))
+    loss, grads = _compiled.loss_and_grads(m, m.loss, ids, ids)
     want_logits = jax.jit(lambda s, i: ref.logits(s, i, cj))(state, ids)
     want_loss, want_g = jax.jit(
         lambda s, i: ref.loss_and_grads(s, i, cj))(state, ids)
-    grads = {k: np.asarray(t.grad.data) for k, t in m.named_parameters()}
-    return (logits, float(loss.data), grads, np.asarray(want_logits),
+    return (logits, loss, grads, np.asarray(want_logits),
             float(want_loss), {k: np.asarray(v) for k, v in want_g.items()})
 
 
@@ -169,9 +169,9 @@ def test_the_convolutions_bias_moves_the_output():
     name = "model.layers.0.mamba.conv_bias"
     assert float(jnp.abs(state[name]).max()) > 0.1
     m.state_dict()[name].data = jnp.zeros_like(state[name])
-    got = np.asarray(m(paddle.to_tensor(ids)).data)
-    want = np.asarray(ref.logits({**state, name: jnp.zeros_like(state[name])},
-                                 ids, cfg_json(c)))
+    got = np.asarray(_compiled.run(m, m, ids))
+    want = np.asarray(jax.jit(lambda s, i: ref.logits(s, i, cfg_json(c)))(
+        {**state, name: jnp.zeros_like(state[name])}, ids))
     np.testing.assert_allclose(got, want, atol=TOL * np.abs(want).max())
     assert np.abs(got - _small_base()).max() > 1e-3 * np.abs(want).max()
 

@@ -1,7 +1,8 @@
 """models/solar_open2.py against its plain reference
 (`tests/reference/solar_open2.py`) on seeded weights at tiny widths: each
 kind of layer and the whole model (logits, loss, every gradient leaf),
-the counters the compiled step writes and the names it carries. The
+the counters the compiled step writes and the names it carries. Program
+and reference each run under one `jit` (`tests/_compiled.py`). The
 operator is `tests/test_gated_delta_rule.py`'s, the expert layer and the
 blocked head + loss `tests/test_dropless_moe.py`'s."""
 import os
@@ -14,6 +15,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import _compiled  # noqa: E402
 import paddle_tpu as paddle  # noqa: E402
 from paddle_tpu.models import (SolarOpen2Config, SolarOpen2ForCausalLM,  # noqa: E402
                                solar_open2_tiny)
@@ -56,26 +58,23 @@ def _against_reference(c, batch=2, seq=37, tol=2e-5):
     m = SolarOpen2ForCausalLM(c)
     state = {k: t.data for k, t in m.state_dict().items()}
     ids = _ids(c, batch, seq)
-    x = paddle.to_tensor(ids)
     cj = cfg_json(c)
     held = ref.held_of(cj)
     # the reference under jit: a program a call, not one an operation
     want = jax.jit(lambda s, i: ref.logits(s, i, cj, held))(state, ids)
-    np.testing.assert_allclose(np.asarray(m(x).data), np.asarray(want),
+    np.testing.assert_allclose(np.asarray(_compiled.run(m, m, ids)),
+                               np.asarray(want),
                                atol=tol * float(jnp.max(jnp.abs(want))))
-    loss = m.loss(x, x)
-    loss.backward()
+    loss, grads = _compiled.loss_and_grads(m, m.loss, ids, ids)
     want_loss, want_g = jax.jit(
         lambda s, i: ref.loss_and_grads(s, i, cj, held))(state, ids)
-    assert float(loss.data) == pytest.approx(float(want_loss), rel=1e-5)
-    leaves = _params(m)
-    assert leaves and set(leaves) <= set(want_g)
-    for name, t in leaves.items():
-        assert t.grad is not None, name
+    assert loss == pytest.approx(float(want_loss), rel=1e-5)
+    assert grads and set(grads) <= set(want_g)
+    for name, got in grads.items():
+        assert got is not None, name
         g = np.asarray(want_g[name])
         np.testing.assert_allclose(
-            np.asarray(t.grad.data), g, atol=tol * max(np.abs(g).max(), 1e-6),
-            err_msg=name)
+            got, g, atol=tol * max(np.abs(g).max(), 1e-6), err_msg=name)
     return m
 
 
