@@ -8,9 +8,69 @@ TPU); cos/sin caches are precomputed once per (seq, dim).
 from __future__ import annotations
 
 import functools
+import math
+from typing import NamedTuple
 
 import jax.numpy as jnp
 import numpy as np
+
+
+class Yarn(NamedTuple):
+    """A rotary base under YaRN scaling (`rope_scaling.type` "yarn"), as the
+    released latent-attention code of the family reads its keys: `theta`
+    the base, `factor` s, `original` L0 the trained context, `beta_fast`
+    and `beta_slow` the rotations at L0 between which a frequency goes from
+    kept to divided by s, `mscale` and `mscale_all_dim` the two attention
+    factors. Hashable: a table cache's key as a plain base is."""
+    theta: float
+    factor: float
+    original: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(s, m):
+    """0.1 m ln s + 1 (1 for s <= 1)."""
+    return 1.0 if s <= 1 else 0.1 * m * math.log(s) + 1.0
+
+
+def inv_freq(dim, theta):
+    """The dim / 2 rotary frequencies, float64 on the host. A plain base:
+    f_i = theta^(-2i/dim). A `Yarn`: with dim(b) = dim ln(L0 / (2 pi b)) /
+    (2 ln theta), low = max(floor(dim(beta_fast)), 0), high =
+    min(ceil(dim(beta_slow)), dim/2 - 1), ramp_i = clip((i - low) / (high -
+    low), 0, 1): f_i (1 - ramp_i) + (f_i / s) ramp_i."""
+    if not isinstance(theta, Yarn):
+        return theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    y = theta
+    f = float(y.theta) ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    at = lambda b: (dim * math.log(y.original / (2 * math.pi * b))
+                    / (2 * math.log(y.theta)))
+    low = max(math.floor(at(y.beta_fast)), 0)
+    high = min(math.ceil(at(y.beta_slow)), dim // 2 - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return f * (1.0 - ramp) + (f / y.factor) * ramp
+
+
+def table_scale(theta):
+    """What cos and sin are multiplied by: mscale(s, `mscale`) /
+    mscale(s, `mscale_all_dim`) under YaRN, 1 for a plain base."""
+    if not isinstance(theta, Yarn):
+        return 1.0
+    return (yarn_mscale(theta.factor, theta.mscale)
+            / yarn_mscale(theta.factor, theta.mscale_all_dim))
+
+
+def softmax_scale(theta, d):
+    """The attention scores' factor for keys of width d: d^-0.5, times
+    mscale(s, `mscale_all_dim`)^2 under YaRN where that key is set."""
+    if isinstance(theta, Yarn) and theta.mscale_all_dim:
+        return yarn_mscale(theta.factor, theta.mscale_all_dim) ** 2 \
+            / math.sqrt(d)
+    return 1.0 / math.sqrt(d)
 
 
 @functools.lru_cache(maxsize=32)

@@ -26,6 +26,8 @@ _LAZY = {
                    "Dots3NoteModel", "dots3_note_tiny"),
     "glm4_moe_lite": ("Glm4MoeLiteConfig", "Glm4MoeLiteForCausalLM",
                       "Glm4MoeLiteModel", "glm4_moe_lite_tiny"),
+    "xing4_0": ("Xing40Config", "Xing40ForCausalLM", "Xing40Model",
+                "xing4_0_tiny"),
 }
 
 

@@ -61,12 +61,13 @@ from jax.sharding import PartitionSpec as P
 
 from ..autograd.tape import apply_op
 from ..framework import core
+from ..kernels.rope import softmax_scale
 from ..nn import initializer as I
 from ..nn.layer.layers import Layer
 from ..observability.scopes import scope
 from ..ops._helpers import to_tensor_like
 from ..tensor import Tensor
-from .pieces import (CausalLM, DecoderStack, RMSNorm, SwiGLUHalf,
+from .pieces import (PLAIN, CausalLM, DecoderStack, RMSNorm, SwiGLUHalf,
                      blocked_loss, dropless_moe_of, group_of, moe_counters,
                      moe_half, param, rms, shifted)
 
@@ -169,18 +170,25 @@ def dots3_note_tiny(**kw):
 def _rope_tables(seq_len, dim, theta):
     """cos and sin [S, dim] float32 of the rotate-half layout, angles made
     in float64 on the host (constants of the program): `kernels/rope.py`
-    rounds the angle itself to float32, 1e-3 rad at 16384 positions."""
-    inv_freq = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
-    ang = np.outer(np.arange(seq_len, dtype=np.float64), inv_freq)
+    rounds the angle itself to float32, 1e-3 rad at 16384 positions.
+    `theta` is the base, or a `kernels.rope.Yarn` (the frequencies and the
+    tables' factor are `kernels.rope.inv_freq` / `table_scale`)."""
+    from ..kernels import rope
+    ang = np.outer(np.arange(seq_len, dtype=np.float64),
+                   rope.inv_freq(dim, theta))
     ang = np.concatenate([ang, ang], axis=-1)
-    return np.cos(ang).astype(np.float32), np.sin(ang).astype(np.float32)
+    cos, sin = np.cos(ang), np.sin(ang)
+    k = rope.table_scale(theta)
+    if k != 1.0:
+        cos, sin = cos * k, sin * k
+    return cos.astype(np.float32), sin.astype(np.float32)
 
 
 def _rope(x, theta):
     """Rotate-half rotary (the pairing of `kernels/rope.py`) on x
     [S, ..., d] at positions 0..S-1, float32 inside."""
     from ..kernels.rope import _rotate_half
-    cos, sin = _rope_tables(x.shape[0], x.shape[-1], float(theta))
+    cos, sin = _rope_tables(x.shape[0], x.shape[-1], theta)
     lead = (x.shape[0],) + (1,) * (x.ndim - 2) + (x.shape[-1],)
     xf = x.astype(_F32)
     return (xf * cos.reshape(lead)
@@ -231,7 +239,9 @@ class Indexer(Layer):
 
 
 class LatentAttention(Layer):
-    """x + MLA(RMSNorm(x)) of one layer kind; see the module docstring."""
+    """MLA(RMSNorm(.)) of one layer kind as a half-layer on the residual
+    path its caller hands it (`pieces.PLAIN`: x + MLA(RMSNorm(x))); see the
+    module docstring."""
 
     def __init__(self, cfg: Dots3NoteConfig, kind: str):
         super().__init__()
@@ -305,11 +315,11 @@ class LatentAttention(Layer):
         """Heads of group g: (their part of the output projection [S, H]
         float32, their log-sum-exp [hg, S] or None)."""
         cfg = self.cfg
-        n, dn, dr, dv, _, _, _ = cfg.attention(self.kind)
+        n, dn, dr, dv, _, _, theta = cfg.attention(self.kind)
         G = self._groups()
         hg = n // G
         S = cq.shape[0]
-        scale = 1.0 / math.sqrt(dn + dr)
+        scale = softmax_scale(theta, dn + dr)
         qn, qr, kn, v = self._heads(g, cq, ckv, wuq, wukv)
         lse = None
         if self.kind == FULL:
@@ -328,6 +338,16 @@ class LatentAttention(Layer):
                     [kn, jnp.broadcast_to(kr[None], (hg,) + kr.shape)], -1),
                     0, 1)
                 vv = jnp.swapaxes(v, 0, 1)
+                # keys 128 + 64 wide: zeros up to the next 128 lanes add
+                # nothing to q.k and cost the matrix unit nothing (it
+                # contracts 128 at a time), and the kernel takes them
+                pad = -q.shape[-1] % 128
+                if pad and q.shape[-1] > 128 and fa.supported(
+                        (1,) + q.shape[:-1] + (q.shape[-1] + pad,),
+                        (1,) + k.shape[:-1] + (k.shape[-1] + pad,), True,
+                        v_dim=dv, window=W):
+                    q, k = (jnp.pad(a, ((0, 0), (0, 0), (0, pad)))
+                            for a in (q, k))
                 if fa.supported((1,) + q.shape, (1,) + k.shape, True,
                                 v_dim=dv, window=W):
                     o = fa.flash_attention_bshd(
@@ -391,9 +411,9 @@ class LatentAttention(Layer):
         float32, a group at a time (forward only: a constant)."""
         from ..kernels import sparse_select_attention as dsa
         cfg = self.cfg
-        _, dn, dr, _, _, _, _ = cfg.attention(self.kind)
+        _, dn, dr, _, _, _, theta = cfg.attention(self.kind)
         cq, ckv, kr, _ = lat
-        scale = 1.0 / math.sqrt(dn + dr)
+        scale = softmax_scale(theta, dn + dr)
 
         def add(acc, g, lse_g):
             qn, qr, kn, _ = self._heads(g, cq, ckv, wuq, wukv)
@@ -409,18 +429,16 @@ class LatentAttention(Layer):
         return acc
 
     def _sequence(self, x, ln_w, wdq, qn_w, wuq, wdkv, kvn_w, wukv, *rest):
-        """One sequence x [S, H] -> (x + mixer, L_I, pairs attended);
-        `rest` = (W_G but for a dense-causal layer, W_O, the indexer's
-        leaves of a full layer), as `forward` lists them."""
+        """One sequence x [S, H] -> (the mixer's output, no add; L_I;
+        pairs attended); `rest` = (W_G but for a dense-causal layer, W_O,
+        the indexer's leaves of a full layer), as `forward` lists them."""
         sg = jax.lax.stop_gradient
         wg, wo, *index_ws = (None,) + rest if self.kind == CAUSAL else rest
         lat = jax.checkpoint(self._latents)(x, ln_w, wdq, qn_w, wdkv, kvn_w,
                                             wg)
         if self.kind != FULL:
             mixed, _ = self._mix(lat, None, wuq, wukv, wo)
-            with scope("attn/out"):
-                return x + mixed, jnp.zeros((), _F32), jnp.zeros(
-                    (), jnp.int32)
+            return mixed, jnp.zeros((), _F32), jnp.zeros((), jnp.int32)
         from ..kernels import sparse_select_attention as dsa
         with scope("attn/index"):
             qi, ki, w = jax.checkpoint(self._index_inputs)(
@@ -434,18 +452,20 @@ class LatentAttention(Layer):
             psum = self._target(sg(lat), sg(lse), mask, sg(wuq), sg(wukv))
             li = dsa.indexer_loss(qi, ki, w, scores, mask, lse_i, psum,
                                   self.cfg.attention(FULL)[0])
-        with scope("attn/out"):
-            return x + mixed, li, pairs
+        return mixed, li, pairs
 
-    def block(self, x, *ws):
-        """x [B, S, H] -> (x + mixer [B, S, H], mean L_I [], pairs [])."""
-        if x.shape[0] == 1:
-            y, li, pairs = self._sequence(x[0], *ws)
-            return y[None], li, pairs
-        y, li, pairs = jax.lax.map(lambda xs: self._sequence(xs, *ws), x)
-        return y, jnp.mean(li), jnp.sum(pairs)
+    def block(self, x, *ws, path=PLAIN):
+        """The path's half around `_sequence`, which takes one sequence: on
+        the plain path x [B, S, H] -> (x + mixer [B, S, H], mean L_I [],
+        pairs [], then what the path appends); `ws` the path's leaves
+        first."""
+        k = len(path.leaves())
+        return path.by_sequence(
+            lambda u: self._sequence(u, *ws[k:]), x, ws[:k],
+            gather=lambda li, pairs: (jnp.mean(li), jnp.sum(pairs)),
+            under="attn/out")
 
-    def forward(self, x, ln_w):
+    def forward(self, x, ln_w, path=PLAIN):
         ws = [ln_w, self.q_a_proj, self.q_a_layernorm.weight, self.q_b_proj,
               self.kv_a_proj, self.kv_a_layernorm.weight, self.kv_b_proj]
         if self.kind != CAUSAL:
@@ -453,8 +473,11 @@ class LatentAttention(Layer):
         ws.append(self.o_proj)
         if self.kind == FULL:
             ws += self.indexer.weights()
-        y, li, pairs = apply_op(self.block, to_tensor_like(x), *ws,
-                                n_outputs=3, name="latent_attention")
+        y, li, pairs, *extra = apply_op(
+            functools.partial(self.block, path=path), to_tensor_like(x),
+            *path.leaves(), *ws, n_outputs=3 + path.extra,
+            name="latent_attention")
+        path.record(*(e.data for e in extra))
         if self.kind == FULL:
             self.attended_pairs.data = pairs.data
             return y, li

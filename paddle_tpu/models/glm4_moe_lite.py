@@ -126,9 +126,10 @@ class Glm4MoeLiteModel(DecoderStack):
 
 class MultiTokenPredictor(Layer):
     """The prediction module of depth 1: two norms, W_EH, one expert layer
-    and a final norm of its own; embedding and head are its caller's."""
+    (`layer_of(cfg, num_hidden_layers)`: the trunk's own kind of layer) and
+    a final norm of its own; embedding and head are its caller's."""
 
-    def __init__(self, cfg: Glm4MoeLiteConfig):
+    def __init__(self, cfg, layer_of=Dots3NoteDecoderLayer):
         super().__init__()
         self.cfg = cfg
         h = cfg.hidden_size
@@ -136,7 +137,7 @@ class MultiTokenPredictor(Layer):
         self.hnorm = RMSNorm(h, cfg.rms_norm_eps)
         self.eh_proj = param(self, (2 * h, h), P(None, None),
                              dtype=cfg.dtype)
-        self.block = Dots3NoteDecoderLayer(cfg, cfg.num_hidden_layers)
+        self.block = layer_of(cfg, cfg.num_hidden_layers)
         self.norm = RMSNorm(h, cfg.rms_norm_eps)
 
     def _join(self, ids, x, emb_w, enorm_w, hnorm_w, w):
@@ -158,9 +159,12 @@ class MultiTokenPredictor(Layer):
 
 
 class Glm4MoeLiteForCausalLM(CausalLM):
-    def __init__(self, cfg: Glm4MoeLiteConfig):
-        super().__init__(cfg, Glm4MoeLiteModel)
-        self.mtp = (MultiTokenPredictor(cfg)
+    # the trunk and the kind of layer trunk and module are made of
+    stack, layer = Glm4MoeLiteModel, Dots3NoteDecoderLayer
+
+    def __init__(self, cfg):
+        super().__init__(cfg, self.stack)
+        self.mtp = (MultiTokenPredictor(cfg, self.layer)
                     if cfg.num_nextn_predict_layers else None)
         # the last step's two losses, beside the one the step returns
         for name in ("main_loss", "mtp_loss"):

@@ -250,9 +250,11 @@ class GraniteMLP(SwiGLUHalf):
     def __init__(self, cfg: GraniteHybridConfig):
         super().__init__(cfg, "granite_mlp")
 
-    def add(self, h, o, wd):
-        return branch(h, jnp.matmul(o, wd, preferred_element_type=jnp.float32),
-                      self.cfg.residual_multiplier)
+    def down(self, o, wd):
+        return jnp.matmul(o, wd, preferred_element_type=jnp.float32)
+
+    def join(self, h, y):
+        return branch(h, y, self.cfg.residual_multiplier)
 
 
 class GraniteHybridDecoderLayer(Layer):

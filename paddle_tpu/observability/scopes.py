@@ -46,7 +46,13 @@ COMPONENTS = ("embed", "layers", "norm", "attn/qkv", "attn/rope",
               # (whose inner attn/* and moe/* scopes stay as they are), its
               # pass over the shared head and its loss
               "attn/core/causal", "mtp/embed", "mtp/proj", "mtp/block",
-              "mtp/head", "mtp/loss")
+              "mtp/head", "mtp/loss",
+              # models/xing4_0 (`pieces.HyperConnection`): the n-stream
+              # residual path of a half-layer: the three maps (norm, the
+              # product with Phi, sigmoids, Sinkhorn), n streams -> the
+              # branch's input, streams and branch -> n streams; the
+              # embedding's expansion and the sum in front of a final norm
+              "hc/map", "hc/pre", "hc/post", "hc/expand", "hc/reduce")
 # distributed/sharding: collectives the program itself issues
 COLLECTIVES = ("tp/all_reduce", "tp/relayout")
 # jit.TrainStep.__call__: TraceAnnotations, on the profiler's host plane
@@ -72,7 +78,12 @@ STEP_SPANS = ("train_step.call_args", "train_step.dispatch",
 # that maps a call), what the loss's multi-token-prediction module is
 # (models/glm4_moe_lite `losses`: depth, loss_weight, positions = the rows
 # of a sequence its loss counts, shares_embedding, shares_head, block_kind;
-# once a trace), how an expert layer moves its rows (nn/layer/moe
+# once a trace), what the n-stream residual path is (models/xing4_0
+# `losses`: n, iterations = Sinkhorn's, halves = the half-layers on such a
+# path, stream_array_bytes = one [n, B, S, H] array, kept_one_stream_bytes
+# = one [B, S, H] array, attention_half_keeps / ffn_half_keeps = what a
+# half's taped operation keeps for the backward, in words; once a trace),
+# how an expert layer moves its rows (nn/layer/moe
 # `dropless_moe`: route = "kernel" where the scatter-adds of combine and of
 # dispatch's transpose are kernels/row_moves' Pallas kernel, "xla" where
 # they are the compiler's scatter; rows = the buffer's, hidden, row_bytes,
@@ -100,7 +111,7 @@ SETUP = ("train_step.lower", "train_step.call_args", "train_step.trace",
          "train_step.to_mlir", "train_step.traced", "train_step.kept",
          "train_step.memory", "train_step.residuals",
          "dsa.grid", "kda.groups", "shard_kernel.calls", "mtp.module",
-         "moe.rows", "xla.to_mlir",
+         "hc.streams", "moe.rows", "xla.to_mlir",
          "xla.backend_compile", "xla.cache_hit", "xla.cache_miss")
 
 _SCOPES = frozenset(COMPONENTS + COLLECTIVES)

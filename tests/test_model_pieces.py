@@ -232,3 +232,86 @@ def test_decoder_stack_embeds_runs_the_layers_and_norms_last():
     np.testing.assert_allclose(
         stack(paddle.to_tensor(ids)).data,
         plain_rms(rows, stack.norm.weight.data), atol=1e-5)
+
+
+# -- the half-layers on the shared residual piece (ISSUE 48) --------------------
+
+def test_the_moved_half_layers_give_x_plus_their_branch_to_the_bit():
+    """The accepted models' half-layers are written against `pieces.PLAIN`:
+    each gives, bit for bit, its input plus the branch it hands the path
+    (the expert half, the dense half and its Granite form with the
+    multiplier inside one rounding)."""
+    from paddle_tpu.models.granite_hybrid import GraniteMLP
+    paddle.seed(4)
+    mlp = pieces.dropless_moe_of(moe_config(moe_rows=64))
+    h, ln_w = normal(0, 1, 24, 16), 1.0 + normal(1, 16, scale=0.1)
+    ws = [t.data for t in mlp.weights()]
+    y, counts, dropped = jax.jit(
+        lambda *a: pieces.expert_half(mlp, EPS, *a))(h, ln_w, *ws)
+    inner, want_counts, _ = jax.jit(
+        lambda a, *ws_: mlp.compute(pieces.rms(a, ln_w, EPS), *ws_))(h, *ws)
+    np.testing.assert_array_equal(y, h + inner)
+    assert counts.tolist() == want_counts.tolist() and int(dropped) == 0
+    for r in (None, 0.22):
+        cfg = SimpleNamespace(hidden_size=16, intermediate_size=24,
+                              rms_norm_eps=EPS, dtype="float32",
+                              residual_multiplier=r)
+        half = (pieces.SwiGLUHalf(cfg, "mlp") if r is None
+                else GraniteMLP(cfg))
+        x = normal(2, 2, 5, 16)
+        wgu, wd = half.gate_up_proj.data, half.down_proj.data
+        got = jax.jit(half.block)(x, ln_w, wgu, wd)
+        branch = jax.jit(half.branch)(x, ln_w, wgu, wd)
+        want = x + branch if r is None else pieces.branch(x, branch, r)
+        np.testing.assert_array_equal(got, want)
+        assert (half.join is None) == (r is None)
+
+
+@pytest.mark.parametrize("batch", [1, 3])
+def test_latent_attentions_block_is_x_plus_its_branch_on_the_plain_path(
+        batch):
+    """`LatentAttention.block` on `pieces.PLAIN` (what dots3-note and
+    GLM-4.7-Flash run): each sequence's x + `_sequence`(x) bit for bit, one
+    sequence (the cells' batch) and three (`jax.lax.map`); its other outputs
+    handed through."""
+    from paddle_tpu.models.dots3_note import CAUSAL, LatentAttention
+    from paddle_tpu.models.glm4_moe_lite import glm4_moe_lite_tiny
+    paddle.seed(5)
+    layer = LatentAttention(glm4_moe_lite_tiny(), CAUSAL)
+    x = normal(3, batch, 16, 48)
+    ws = [1.0 + normal(4, 48, scale=0.1)] + [t.data for t in (
+        layer.q_a_proj, layer.q_a_layernorm.weight, layer.q_b_proj,
+        layer.kv_a_proj, layer.kv_a_layernorm.weight, layer.kv_b_proj,
+        layer.o_proj)]
+    y, li, pairs = jax.jit(layer.block)(x, *ws)
+    mixed = jnp.stack([jax.jit(layer._sequence)(x[b], *ws)[0]
+                       for b in range(batch)])
+    np.testing.assert_array_equal(y, x + mixed)
+    assert float(li) == 0.0 and int(pairs) == 0
+    assert float(jnp.max(jnp.abs(mixed))) > 1e-3
+
+    # the helper both forms map a one-sequence branch with
+    f = pieces.over_sequences(lambda xs: (xs * 2.0, jnp.sum(xs)),
+                              lambda s: (jnp.max(s),))
+    doubled, top = jax.jit(f)(x)
+    np.testing.assert_array_equal(doubled, x * 2.0)
+    assert float(top) == pytest.approx(
+        max(float(jnp.sum(x[b])) for b in range(batch)), rel=1e-5)
+
+
+def test_decoder_stack_expands_and_sums_the_streams_it_is_told_of():
+    paddle.seed(6)
+    cfg = SimpleNamespace(vocab_size=12, hidden_size=8, num_hidden_layers=2,
+                          rms_norm_eps=EPS, dtype="float32")
+    plain = pieces.DecoderStack(cfg, _Doubling)
+    wide = pieces.DecoderStack(cfg, _Doubling, streams=4)
+    wide.embed_tokens.data = plain.embed_tokens.data
+    ids = paddle.to_tensor(np.asarray([[1, 7, 7, 0]]))
+    assert plain.streams == 1
+    np.testing.assert_allclose(
+        wide(ids, final_norm=False).data,
+        4.0 * plain(ids, final_norm=False).data, rtol=1e-6)
+    assert wide.layers[0].index == 0
+    text = names_in(lambda i: wide(Tensor(i), final_norm=False).data,
+                    ids.data)
+    assert "hc/expand" in text and "hc/reduce" in text

@@ -29,6 +29,7 @@ from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels import fused_norm_residual as fnr
 from paddle_tpu.kernels import gated_delta_rule as gdr
 from paddle_tpu.kernels import grouped_matmul as gm
+from paddle_tpu.kernels import hyper_connection as hc
 from paddle_tpu.kernels import paged_attention as pa
 from paddle_tpu.kernels import ragged_paged_attention as rpa
 from paddle_tpu.kernels import rms_norm as rn
@@ -66,7 +67,7 @@ def _as_on_the_chip(monkeypatch):
     interpret off), compile at the program's own matmul precision, and
     keep these compiles out of the persistent cache: an entry written
     for a described chip cannot be read back without one."""
-    for mod in (ba, ce, dsa, fa, fnr, gdr, gm, pa, rpa, rn, rows, sc, sg,
+    for mod in (ba, ce, dsa, fa, fnr, gdr, gm, hc, pa, rpa, rn, rows, sc, sg,
                 ssd):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
     from jax.experimental.compilation_cache import compilation_cache as cc
@@ -844,3 +845,34 @@ def test_dots3_expert_half_layer_beside_the_form_before(one_chip):
     # the ordered copy and its index lists, and nothing else of the
     # buffer's size, may be live beside what the form before held
     assert peak <= before + 1.1 * R * H * 2
+
+
+def test_hyper_connection_mixing_at_the_cells_shape(one_chip):
+    """The four-stream half-layer's mixing around a stand-in branch, forward
+    and backward, at the xing4_0 cell's shape (two sequences of 4096 tokens,
+    four streams of 3584 columns, bf16): `hc_pre_fwd` and `hc_post_fwd` are
+    Mosaic calls whose blocks (all four streams of 256 rows x 512 columns,
+    in and out, and the float32 maps) fit the kernel's VMEM, and the step
+    keeps no float32 array of the streams' size."""
+    B, n, S, C = 2, 4, 4096, 3584
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def half(X, phi, scale, bias, w):
+        h_pre, h_post, h_res = hc.maps(
+            X, phi, scale, bias, eps=1e-6, iters=20, hc_eps=1e-6,
+            clamp=(-30.0, 30.0))
+        u = hc.pre(X, h_pre)
+        return hc.post(X, jnp.tanh(u @ w), h_res, h_post)
+
+    text = _compile(jax.grad(
+        lambda *a: jnp.sum(half(*a).astype(jnp.float32) ** 2),
+        argnums=(0, 1, 2, 3)), sds((n, B, S, C)), sds((n * C, 24)),
+        sds((3,), jnp.float32), sds((24,), jnp.float32), sds((C, C)))
+    assert _mosaic_calls(text, "hc_pre_fwd") == 1
+    assert _mosaic_calls(text, "hc_post_fwd") == 1
+    wide = f"f32[{B},{n},{S},{C}]"
+    assert not [ln for ln in text.splitlines()
+                if ln.split("=")[0].strip().startswith(("ROOT", "%"))
+                and f" = {wide}" in ln and " fusion(" not in ln], wide
