@@ -238,6 +238,9 @@ class GradScaler:
         # prime() first so lazily-created accumulators exist at their TRUE
         # initial values (e.g. Adagrad's initial_accumulator) before the
         # snapshot — otherwise a skipped first step would blend them to 0.
+        # It makes only what is missing: from the second step on (and in
+        # a compiled step, whose TrainStep primed before tracing) it
+        # returns at once and the moments are not touched.
         if hasattr(optimizer, "prime"):
             optimizer.prime()
         old_params = [(p, p.data) for p in optimizer._parameter_list]

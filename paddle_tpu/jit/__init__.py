@@ -886,7 +886,9 @@ class TrainStep:
         if self._compiled is None:
             # materialize optimizer state before the first trace: otherwise
             # the state tree widens after step 1 and the whole step
-            # recompiles (minutes for large models). NOT under an armed
+            # recompiles (minutes for large models). prime() makes what is
+            # missing in ONE compiled program (a restored optimizer's
+            # slots are left as they are, at no program). NOT under an armed
             # ZeRO plan: priming would allocate the full replicated
             # state the mode exists to avoid — the body creates
             # shard-shaped slots inside the first step instead (one

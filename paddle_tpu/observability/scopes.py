@@ -88,8 +88,12 @@ STEP_SPANS = ("train_step.call_args", "train_step.dispatch",
 # dispatch's transpose are kernels/row_moves' Pallas kernel, "xla" where
 # they are the compiler's scatter; rows = the buffer's, hidden, row_bytes,
 # tokens, tile = the targets a grid step holds, chunk = the ordered rows a
-# visit; once an expert layer a trace), the step's device memory, and what
-# jax.monitoring reports of lowering, compiling and the cache.
+# visit; once an expert layer a trace), what a call of `Optimizer.prime()`
+# that made anything made (optimizer/optimizer: programs = 1, or 0 where it
+# ran inline under a trace; slots_made, slots_kept = the slots that were
+# there and were left alone, parameters = those that got a slot; dur_s),
+# the step's device memory, and what jax.monitoring reports of lowering,
+# compiling and the cache.
 # Memory, two events an operator reads with `step.lower(*batch).compile()`
 # and then `observability.spans.ring()` (the runtime's `peak_bytes_in_use`
 # is the process's high-water mark, not the step's: it never showed the
@@ -111,7 +115,7 @@ SETUP = ("train_step.lower", "train_step.call_args", "train_step.trace",
          "train_step.to_mlir", "train_step.traced", "train_step.kept",
          "train_step.memory", "train_step.residuals",
          "dsa.grid", "kda.groups", "shard_kernel.calls", "mtp.module",
-         "hc.streams", "moe.rows", "xla.to_mlir",
+         "hc.streams", "moe.rows", "optimizer.prime", "xla.to_mlir",
          "xla.backend_compile", "xla.cache_hit", "xla.cache_miss")
 
 _SCOPES = frozenset(COMPONENTS + COLLECTIVES)

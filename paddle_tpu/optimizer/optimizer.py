@@ -8,6 +8,7 @@ this; XLA fuses the whole update chain for free).
 """
 from __future__ import annotations
 
+import time
 from typing import Iterable, List, Optional
 
 import jax
@@ -15,6 +16,7 @@ import jax.numpy as jnp
 
 from ..autograd import no_grad
 from ..framework import core
+from ..observability import spans as _spans
 from ..tensor import Tensor
 from .lr import LRScheduler
 
@@ -32,6 +34,9 @@ class Optimizer:
         self._weight_decay = weight_decay
         self._grad_clip = grad_clip
         self._state: dict = {}
+        # id(p) -> the slot names p's update rule uses, as prime() last
+        # traced them: what lets it see that nothing is missing
+        self._slot_names: dict = {}
         self._step_count = 0
         # Optional master-weight map (fp32 copies for low-precision params),
         # populated by amp.decorate(level='O2') (ref: mix_precision_utils.py)
@@ -63,30 +68,98 @@ class Optimizer:
         return self._state[key]
 
     def prime(self):
-        """Materialize accumulator state for every trainable param now.
+        """Make the accumulator slots that are missing, now, in ONE compiled
+        program, and leave every slot that exists as it is.
 
         State is otherwise created lazily inside the first `step()`, which
         widens the state pytree between the first and second compiled
         TrainStep call and forces an extra trace+compile of the full step
-        (expensive for large models). Priming runs each param's update rule
-        once with a zero gradient and zero LR — accumulators initialize
-        exactly as they would on a real first step (zeros / eps), weights
-        are untouched because the update result is discarded.
+        (expensive for large models). A trainable parameter whose slots
+        are all there is skipped; if that is all of them the call returns
+        at once: no program, no device work, no write. The rest get what a
+        FRESH optimizer starts from (`_fresh_slots`), under one `jax.jit`
+        whose body the compiler folds to constants, each new slot of its
+        target's shape placed as its target is (the master weight where
+        there is one, else the parameter): the values depend on no input,
+        so the partitioner would replicate them unless told. With tracers
+        for targets (a `GradScaler.step` inside a compiled step) the same
+        body runs inline. `_step_count`, the learning rate, the parameters
+        and the slots that were there are not touched; a whole-step rule
+        (LBFGS) has no `_apply_one` and returns quietly. Leaves one set-up
+        event `optimizer.prime` a call that made anything.
         """
-        saved_count = self._step_count
-        self._step_count = 1  # Adam-style bias correction needs t >= 1
-        try:
-            for p in self._parameter_list:
-                if p.stop_gradient:
-                    continue
+        todo = []
+        for p in self._parameter_list:
+            if p.stop_gradient:
+                continue
+            names = self._slot_names.get(id(p))
+            if names is None or any((id(p), n) not in self._state
+                                    for n in names):
                 master = self._master_weights.get(id(p))
-                target = master if master is not None else p.data
-                try:
-                    self._apply_one(p, target, jnp.zeros_like(target), 0.0)
-                except NotImplementedError:  # e.g. LBFGS (whole-step update)
-                    return
+                todo.append((p, master if master is not None else p.data))
+        if not todo:
+            return
+        t0 = time.perf_counter()
+        params, targets = zip(*todo)
+        inline = any(isinstance(w, jax.core.Tracer) for w in targets)
+        placed = {id(p): (w.shape, None if inline
+                          or len(w.sharding.device_set) == 1 else w.sharding)
+                  for p, w in todo}
+
+        keys = []  # of the slots made, in the rules' own order
+
+        def make(targets):
+            fresh = self._fresh_slots(params, targets)
+            names = {id(p): () for p in params}
+            for pid, name in fresh:
+                names[pid] += (name,)
+            self._slot_names.update(names)
+            keys[:] = [k for k in fresh if k not in self._state]
+            values = []
+            for key in keys:
+                shape, sharding = placed[key[0]]
+                v = fresh[key]
+                if sharding is not None and v.shape == shape:
+                    v = jax.lax.with_sharding_constraint(v, sharding)
+                values.append(v)
+            return values
+
+        try:
+            if inline:
+                values = make(targets)
+            else:
+                # traced first: what is missing is known only then, and an
+                # optimizer that has it all (an eager step() made it)
+                # compiles nothing
+                traced = jax.jit(make).trace(targets)
+                values = (traced.lower().compile()(targets)
+                          if keys else [])
+        except NotImplementedError:  # e.g. LBFGS (whole-step update)
+            return
+        if not keys:
+            return
+        kept = len(self._state)
+        self._state.update(zip(keys, values))
+        _spans.setup_event(
+            "optimizer.prime", time.perf_counter() - t0,
+            programs=0 if inline else 1, slots_made=len(keys),
+            slots_kept=kept, parameters=len({pid for pid, _ in keys}))
+
+    def _fresh_slots(self, params, targets):
+        """{(id(p), name): value} of every slot a fresh optimizer holds for
+        `params` after one update: each rule run once against an EMPTY
+        state with a zero gradient and a zero learning rate, the update
+        itself discarded (zeros, Adagrad's initial accumulator, ...).
+        The state proper and `_step_count` are as before on return."""
+        saved = self._state, self._step_count
+        # Adam-style bias correction needs t >= 1
+        self._state, self._step_count = {}, 1
+        try:
+            for p, w in zip(params, targets):
+                self._apply_one(p, w, jnp.zeros_like(w), 0.0)
+            return self._state
         finally:
-            self._step_count = saved_count
+            self._state, self._step_count = saved
 
     def state_dict(self):
         # group state by param id ONCE — the former params × state nested
