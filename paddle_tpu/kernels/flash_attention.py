@@ -24,6 +24,17 @@ Three routes, all Pallas:
 Block sizes come from the autotune cache (kernels/autotune.py) when a
 sweep has recorded a winner for the shape class, else a 512 heuristic.
 
+The splash route's backward has two forms and `splash_backward` chooses
+between them from the call's shape and mask alone: under a dense mask
+(causal or full) ONE kernel makes dq, dk and dv from one set of a tile's
+scores, dq leaving it once an outer kv block (the widest of 4096 / 2048 /
+1024 / 512 that divides Sk and fits VMEM at the call's key and value
+widths, and under a causal mask at most a quarter of Sk) as copies that
+are summed after, a few kv heads a kernel call where all heads' copies at
+once would hold more than a stated number of bytes; under a window the
+dkv and dq kernels run one after the other as they did. The set-up event
+`train_step.splash_backward` says which a traced call took.
+
 What the backward needs from the forward has a name. The splash route
 stamps its forward's `out` and `logsumexp` (the residuals of the kernel's
 `custom_vjp`) with `SPLASH_RESIDUALS`, and `jit.TrainStep`'s default remat
@@ -41,6 +52,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -107,20 +119,123 @@ def _block_sizes(Sq, Sk, D, causal, blocks=None):
     )
 
 
-def _splash_block_sizes(Sq, Sk, D, blocks=None):
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as sk)
-
+def _splash_blocks(Sq, Sk, D, blocks=None):
+    """(block_q, block_kv) of the splash kernels: explicit override
+    (sweeps), else the autotune cache's winner, else 512-square."""
     from . import autotune
     if blocks is None:
         default = (min(512, Sq), min(512, Sk))
         key = autotune.cache_key("splash", Sq=Sq, Sk=Sk, D=D)
         blocks = autotune.lookup(key) or default
-    bq, bk = min(blocks[0], Sq), min(blocks[1], Sk)
-    return sk.BlockSizes(block_q=bq, block_kv=bk, block_kv_compute=bk,
-                         block_q_dkv=bq, block_kv_dkv=bk,
-                         block_kv_dkv_compute=bk,
-                         block_q_dq=bq, block_kv_dq=bk)
+    return min(blocks[0], Sq), min(blocks[1], Sk)
+
+
+class SplashBackward(NamedTuple):
+    """How one splash call's backward is made (`splash_backward`)."""
+    form: str            # "one_kernel" | "two_kernels"
+    block_kv_dkv: int    # the dkv kernel's OUTER kv block
+    partials: int        # copies of dq that leave the one kernel (0: none)
+    partial_bytes: int   # what the copies live at one time hold in HBM
+    kv_heads_a_call: int  # kv heads one kernel call takes (the rest after)
+
+
+# The one-kernel backward's outer kv block: the widest of these that
+# divides Sk and fits Mosaic's scoped VMEM (the library's call sets no
+# limit of its own, so the chip's default holds: 16 MiB on a v5e).
+_OUTER_KV_BLOCKS = (4096, 2048, 1024, 512)
+_SCOPED_VMEM_BYTES = 16 * 2 ** 20
+# Under a causal mask the outer block is at most a quarter of Sk: the one
+# kernel computes every compute block of an outer block that holds a live
+# tile, so an outer block on the diagonal runs its masked tiles too, a
+# share (w - 1) / (n + 1) of the triangle's (w compute blocks an outer
+# block, n a row). A quarter keeps that under an eighth; at a half it is a
+# third, and a Xing call (S = 4096) read 1.18 ms at 2048 where 1.06 at
+# 1024 (my chip run, PR 50).
+_CAUSAL_OUTER_SHARE = 4
+# dq leaves the one kernel as Sk // block_kv_dkv copies of q, summed
+# after: temporaries of the backward. A call whose copies would hold more
+# than this goes a few kv heads at a time, one kernel call after the
+# other, so that only one call's copies are live; where one kv head's
+# copies alone hold more, the call keeps two kernels. The cells' calls
+# hold 0.13-0.67 GB whole (GLM 16 copies of 5 heads x 16384 x 256, Solar
+# 8 of 8 x 32768 x 128) against 2.3-5.4 GiB of headroom; Granite's one
+# call of all 32 heads at 32768 would hold 4.3 GB (16 copies, 64-wide
+# heads stored 128 lanes wide) where the step has 2.2 GiB, and goes 2 of
+# its 8 kv heads at a time: PERF.md section 6, PR 50.
+_ONE_KERNEL_MAX_PARTIAL_BYTES = 2 ** 30
+
+
+def _lanes(d):
+    return -(-d // 128) * 128
+
+
+def _one_kernel_vmem_bytes(bq, bkv, D, Dv, itemsize, batched):
+    """What the one-kernel backward's blocks take of VMEM, as the chip's
+    compiler counted them (sandbox compiles, PR 50): k, v in and dk, dv
+    out twice buffered, dk and dv float32 scratches (twice buffered too
+    once the call is vmapped over more than one kv head), and the q-side
+    blocks: q, do, dq out twice buffered, dq's float32 scratch."""
+    wide = _lanes(D) + _lanes(Dv)
+    kv_side = bkv * wide * (4 * itemsize + (8 if batched else 4))
+    q_side = bq * (2 * itemsize * wide + _lanes(D) * (2 * itemsize + 4))
+    return kv_side + q_side
+
+
+def splash_backward(q_shape, kv_heads, Sk, v_dim, dtype, causal, window,
+                    blocks=None):
+    """The form of one splash call's backward, from the call's own shape
+    and mask and nothing else. q_shape [B, Hq, Sq, D].
+
+    One kernel (the library's `use_fused_bwd_kernel`) makes a tile's
+    scores and probabilities once for dq, dk and dv: five products, one
+    `exp` and one mask pass where the two kernels make seven, two and
+    two. Its price: dq leaves it once an OUTER kv block, as
+    Sk // block_kv_dkv copies of q that are summed after. Two kernels
+    stay where the one is the wrong tool:
+    - under a window (`LocalMask`): the one kernel's grid is not shrunk
+      to the band and a wide outer block covers many times the band;
+    - where one kv head's copies alone would hold more than
+      `_ONE_KERNEL_MAX_PARTIAL_BYTES`, or not even the compute block
+      fits the VMEM count."""
+    B, Hq, Sq, D = q_shape
+    bq, bk = _splash_blocks(Sq, Sk, D, blocks)
+    two = SplashBackward("two_kernels", bk, 0, 0, kv_heads)
+    if window is not None:
+        return two
+    itemsize = jnp.dtype(dtype).itemsize
+    widest = max(Sk // _CAUSAL_OUTER_SHARE, bk) if causal else Sk
+    for heads in (h for h in range(kv_heads, 0, -1) if kv_heads % h == 0):
+        fits = [c for c in _OUTER_KV_BLOCKS + (bk,)
+                if bk <= c <= widest and c % bk == 0 and Sk % c == 0
+                and _one_kernel_vmem_bytes(bq, c, D, v_dim, itemsize,
+                                           B * heads > 1)
+                <= _SCOPED_VMEM_BYTES]
+        if not fits:
+            continue
+        outer = max(fits)
+        partials = Sk // outer
+        partial_bytes = (partials * B * heads * (Hq // kv_heads) * Sq
+                         * _lanes(D) * itemsize)
+        if partial_bytes <= _ONE_KERNEL_MAX_PARTIAL_BYTES:
+            return SplashBackward("one_kernel", outer, partials,
+                                  partial_bytes, heads)
+    return two
+
+
+def _splash_block_sizes(Sq, Sk, D, blocks, backward):
+    """The library's BlockSizes: forward and compute blocks as
+    `_splash_blocks` gives them, the backward in the form `backward`
+    (a `SplashBackward`) says."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk)
+    bq, bk = _splash_blocks(Sq, Sk, D, blocks)
+    common = dict(block_q=bq, block_kv=bk, block_kv_compute=bk,
+                  block_q_dkv=bq, block_kv_dkv_compute=bk)
+    if backward.form == "one_kernel":
+        return sk.BlockSizes(block_kv_dkv=backward.block_kv_dkv,
+                             use_fused_bwd_kernel=True, **common)
+    return sk.BlockSizes(block_kv_dkv=bk, block_q_dq=bq, block_kv_dq=bk,
+                         **common)
 
 
 def _splash_gqa(qt, kt, vt, causal, scale, padding_mask, interpret=False,
@@ -146,8 +261,10 @@ def _splash_gqa(qt, kt, vt, causal, scale, padding_mask, interpret=False,
         mask_cls = (sm.CausalMask((Sq, Sk)) if causal
                     else sm.FullMask((Sq, Sk)))
     mask = sm.MultiHeadMask([mask_cls] * group)
+    backward = splash_backward(qt.shape, Hk, Sk, vt.shape[-1], qt.dtype,
+                               causal, window, blocks)
     kernel = sk.make_splash_mqa_single_device(
-        mask, block_sizes=_splash_block_sizes(Sq, Sk, D, blocks),
+        mask, block_sizes=_splash_block_sizes(Sq, Sk, D, blocks, backward),
         residual_checkpoint_name=SPLASH_RESIDUALS, interpret=interpret)
     # splash takes pre-scaled q and no sm_scale argument
     qg = (qt * scale).reshape(B, Hk, group, Sq, D)
@@ -163,7 +280,19 @@ def _splash_gqa(qt, kt, vt, causal, scale, padding_mask, interpret=False,
     run = jax.vmap(  # batch
         jax.vmap(kernel, in_axes=(0, 0, 0, None)),  # kv heads
         in_axes=(0, 0, 0, 0))
-    out = run(qg, kt, vt, seg)  # [B, Hk, group, Sq, Dv]
+    heads = backward.kv_heads_a_call
+    if heads == Hk:
+        out = run(qg, kt, vt, seg)  # [B, Hk, group, Sq, Dv]
+    else:
+        # a few kv heads a kernel call, the calls one after the other:
+        # only one call's copies of dq are live in the backward
+        def turns(a):
+            return jnp.moveaxis(
+                a.reshape(B, Hk // heads, heads, *a.shape[2:]), 1, 0)
+
+        out = jax.lax.map(lambda qkv: run(*qkv, seg),
+                          (turns(qg), turns(kt), turns(vt)))
+        out = jnp.moveaxis(out, 0, 1)
     return out.reshape(B, Hq, Sq, vt.shape[-1])
 
 
@@ -314,6 +443,21 @@ def _note_kept(batch, heads, seq, dim, dtype):
             bytes=batch * heads * seq * (dim * jnp.dtype(dtype).itemsize + 4))
 
 
+def _note_backward(q, backward):
+    """The set-up event `train_step.splash_backward`, once a traced call
+    of the splash route (`q` a tracer: an eager call has no program to
+    describe): the form `splash_backward` chose from the call's shape and
+    mask, the copies of dq that leave the one kernel and their bytes."""
+    if isinstance(q, jax.core.Tracer):
+        from ..observability import spans
+        spans.setup_event(
+            "train_step.splash_backward", form=backward.form,
+            partials=backward.partials,
+            partial_bytes=backward.partial_bytes,
+            block_kv_dkv=backward.block_kv_dkv,
+            kv_heads_a_call=backward.kv_heads_a_call)
+
+
 def _through_splash(q, k, v, window):
     """Whether a call without bias takes the splash route: grouped heads,
     a window, values of another width than the keys, or heads wider than
@@ -344,8 +488,11 @@ def flash_attention_bshd(q, k, v, causal=False, scale=None,
     if window is not None and (bias is not None or not causal):
         raise ValueError("a window is causal and takes no bias")
     if bias is None and _through_splash(q, k, v, window):
-        B, Sq, Hq, _ = q.shape
+        B, Sq, Hq, D = q.shape
         _note_kept(B, Hq, Sq, v.shape[-1], q.dtype)
+        _note_backward(q, splash_backward(
+            (B, Hq, Sq, D), k.shape[2], k.shape[1], v.shape[-1], q.dtype,
+            causal, window, blocks))
     return _bshd(q, k, v, causal=causal, scale=scale,
                  padding_mask=padding_mask, bias=bias, interpret=interpret,
                  blocks=blocks, window=window)
@@ -498,6 +645,8 @@ def flash_attention_packed(q, k, v, seg_q, seg_kv, causal=False,
     vt = jnp.swapaxes(vp, 0, 1)[None]
     if Hq != Hk:
         _note_kept(1, Hq, qt.shape[2], D, q.dtype)
+        _note_backward(q, splash_backward(qt.shape, Hk, kt.shape[2], D,
+                                          q.dtype, causal, None))
         out = _splash_gqa(qt, kt, vt, causal, scale, None,
                           segments=(sq[None], sk[None]))
         out = jnp.swapaxes(out[0], 0, 1)[:Tq]

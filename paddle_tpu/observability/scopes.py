@@ -60,7 +60,17 @@ STEP_SPANS = ("train_step.call_args", "train_step.dispatch",
               "train_step.write_back")
 # set-up events in spans.ring(): lower() and its parts, the trace count,
 # what the armed remat policy keeps of a kernel's forward for its backward
-# (kernels/flash_attention: name, bytes a call), the grid a tile-walking
+# (kernels/flash_attention: name, bytes a call), how a splash call's
+# backward is made (kernels/flash_attention `splash_backward`,
+# `train_step.splash_backward`: form = "one_kernel" where dq, dk and dv
+# come from the library's one kernel, "two_kernels" under a window or where
+# one kv head's copies of dq alone would pass the stated bound; partials =
+# the copies of dq that leave the one kernel, Sk // block_kv_dkv, summed
+# after; partial_bytes = what the copies live at one time hold;
+# block_kv_dkv = the dkv kernel's outer kv block; kv_heads_a_call = the kv
+# heads one kernel call takes, fewer than the call's where all at once
+# would pass the bound: the calls then go one after the other; once a
+# traced call), the grid a tile-walking
 # kernel of the learned selection was given (kernels/sparse_select_attention:
 # kernel, grid_steps, live_tiles, heads_per_step, rows, keys; once a kernel
 # a trace), how a delta-rule layer went over its heads (models/solar_open2
@@ -113,7 +123,8 @@ STEP_SPANS = ("train_step.call_args", "train_step.dispatch",
 # shard_map)
 SETUP = ("train_step.lower", "train_step.call_args", "train_step.trace",
          "train_step.to_mlir", "train_step.traced", "train_step.kept",
-         "train_step.memory", "train_step.residuals",
+         "train_step.splash_backward", "train_step.memory",
+         "train_step.residuals",
          "dsa.grid", "kda.groups", "shard_kernel.calls", "mtp.module",
          "hc.streams", "moe.rows", "optimizer.prime", "xla.to_mlir",
          "xla.backend_compile", "xla.cache_hit", "xla.cache_miss")

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _compiled
 import paddle_tpu as paddle
 import paddle_tpu.optimizer as popt
 from paddle_tpu.framework import core
@@ -68,9 +69,9 @@ def _pallas_calls(jaxpr, found):
     return found
 
 
-def _traced_step(model, remat_policy):
-    """(names of the Pallas calls in the step's jaxpr, the `train_step.kept`
-    events its trace left in the ring)."""
+def _traced_step(model, remat_policy, event="train_step.kept"):
+    """(names of the Pallas calls in the step's jaxpr, the `event`s its
+    trace left in the ring)."""
     opt = popt.AdamW(learning_rate=1e-3, parameters=model.parameters())
     step = paddle.jit.TrainStep(model, opt, lambda i, l: model.loss(i, l),
                                 remat_policy=remat_policy)
@@ -78,9 +79,8 @@ def _traced_step(model, remat_policy):
     step._build()
     spans.clear()
     jaxpr = step._compiled.trace(*step._call_args((x, x))).jaxpr
-    kept = [ev["attrs"] for ev in spans.ring()
-            if ev["name"] == "train_step.kept"]
-    return _pallas_calls(jaxpr.jaxpr, []), kept
+    found = [ev["attrs"] for ev in spans.ring() if ev["name"] == event]
+    return _pallas_calls(jaxpr.jaxpr, []), found
 
 
 @pytest.mark.parametrize("body", sorted(BODIES))
@@ -91,13 +91,43 @@ def test_the_forward_kernel_is_in_the_step_once(fake_tpu, body,
                                                 remat_policy, forwards):
     """One forward call a body in the jaxpr (a scan turns it once a layer
     or a group) under the default policy; without a policy the backward
-    holds a second one. The backward kernels are there once either way."""
+    holds a second one. The backward is there once either way."""
     paddle.seed(0)
     calls, kept = _traced_step(BODIES[body][0](), remat_policy)
     assert calls.count("splash_mqa_fwd_residuals") == forwards, calls
     assert calls.count("splash_mqa_dkv_no_residuals") == 1, calls
-    assert calls.count("splash_mqa_dq_no_residuals") == 1, calls
     assert len(kept) == (1 if forwards == 1 else 0)
+
+
+@pytest.mark.parametrize("body", sorted(BODIES) + ["dots3-window-layers"])
+def test_the_dq_kernel_follows_the_routed_form(fake_tpu, body):
+    """`train_step.splash_backward` says the form a call site's backward
+    took, and the kernels in the step are that form's: a dense-causal call
+    makes dq, dk and dv in the dkv kernel (ISSUE 50: no
+    `splash_mqa_dq_no_residuals`), a call under a window keeps the dq
+    kernel beside it (dots3-note's three window layers; its full layer's
+    core is the `dsa_*` kernels)."""
+    paddle.seed(0)
+    if body in BODIES:
+        model, sites, form = BODIES[body][0](), 1, "one_kernel"
+    else:
+        from paddle_tpu.models.dots3_note import (Dots3NoteForCausalLM,
+                                                  dots3_note_tiny)
+        model = _compiled.shapes_only(lambda: Dots3NoteForCausalLM(
+            dots3_note_tiny(index_n_heads=8, swa_qk_nope_head_dim=60,
+                            swa_v_head_dim=64, v_head_dim=64,
+                            qk_nope_head_dim=60)))
+        sites, form = 3, "two_kernels"
+    calls, said = _traced_step(model, "save_matmul_outputs",
+                               "train_step.splash_backward")
+    assert [ev["form"] for ev in said] == [form] * sites, said
+    assert calls.count("splash_mqa_dkv_no_residuals") == sites, calls
+    assert calls.count("splash_mqa_dq_no_residuals") == (
+        sites if form == "two_kernels" else 0), calls
+    for ev in said:
+        assert sorted(ev) == ["block_kv_dkv", "form", "kv_heads_a_call",
+                              "partial_bytes", "partials"]
+        assert ev["partials"] == ("1" if form == "one_kernel" else "0")
 
 
 @pytest.mark.parametrize("body", sorted(BODIES))

@@ -32,17 +32,25 @@ PARENT = {       # sha256 of the normalised text at commit 7e1cdc7 (PR 46),
     # ten held through PR 48, which moved the half-layers these models
     # share (`LatentAttention`, the expert half, `SwiGLUHalf`) onto
     # `pieces.Residual`: the plain form's add is where it was, to the
-    # character
+    # character. PR 50 pinned `llama_gqa.tpu_jaxpr`, `solar.tpu_jaxpr` and
+    # `glm.tpu_jaxpr` again: their dense-causal splash calls take the ONE
+    # backward kernel. Read side by side, primitive by primitive outside
+    # the kernels' bodies: a call site loses `splash_mqa_dq_no_residuals`
+    # with the broadcasts and squeezes that fed it, its dkv call gains the
+    # output of dq's copies, and one `reduce_sum` over them is new; every
+    # other count is the parent's. Every `*.cpu_text`, `granite.tpu_jaxpr`
+    # (its tiny heads take no kernel) and `dots3.tpu_jaxpr` (its splash
+    # calls are under a window: two kernels) hold unedited
     "llama_gqa.cpu_text": "b4b176201bc8d8fbafa942c340cb4a468ec2b616380afc060286c729b452eeeb",
     "solar.cpu_text": "828a6756db725ea97a7568847957159b50837da7a6c1d0b4ac2844606f3d0083",
     "granite.cpu_text": "557ddf2fb05388c761d8d5d4256b73f3c7542a3d10d555e0f35270360e53f8e0",
-    "llama_gqa.tpu_jaxpr": "5324d891f9ab8d1d9e73a12910b401c5d8bf61db6d0ebfed52574e7c5fc20473",
-    "solar.tpu_jaxpr": "479a45451596897a73798e72559cf1643c39dad67d8ff619922c6f5d09790152",
+    "llama_gqa.tpu_jaxpr": "a8e05eb9257573901e89b9c02d9bcbc53ade549911a6a994227bd29d0d8288d6",
+    "solar.tpu_jaxpr": "61d96f8e35b01b8cebfa0d74612cd35759c478145b65f41a0d5791d5d860297e",
     "granite.tpu_jaxpr": "3643417b69b7f9a24fa25e40435d9c7bb2be836732cabf44334cc7170a6cd946",
     "dots3.cpu_text": "5ebd5b1382dd6aafaa88b05739bb2c226653207d889be5c87b7a1ab9aa52fba0",
     "dots3.tpu_jaxpr": "43fd7279ca8978e0dc2beb55777e0a3a6a8bbeb60ae5603e483d798066d3ce36",
     "glm.cpu_text": "7b3cb6fa5de3c4920a65f7ea239298fec8c530a7a04407ba0e9de39afa64c35e",
-    "glm.tpu_jaxpr": "ab5b6428c1b9a8498d93382d91983e62e6ba851e81d1745eda648e00a1e4e660",
+    "glm.tpu_jaxpr": "b655fdcb5f4032112e6ca6a8a34127b6e97bbe2c23f4b8fe170b2553e616ab9a",
 }
 
 
