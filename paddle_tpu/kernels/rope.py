@@ -11,6 +11,7 @@ import functools
 import math
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -71,6 +72,42 @@ def softmax_scale(theta, d):
         return yarn_mscale(theta.factor, theta.mscale_all_dim) ** 2 \
             / math.sqrt(d)
     return 1.0 / math.sqrt(d)
+
+
+@functools.lru_cache(maxsize=16)
+def tables(seq_len, dim, theta):
+    """cos and sin [S, dim] float32 of the rotate-half layout, angles made
+    in float64 on the host (constants of the program): `_cos_sin_cache`
+    rounds the angle itself to float32, 1e-3 rad at 16384 positions.
+    `theta` is the base, or a `Yarn` (the frequencies and the tables'
+    factor are `inv_freq` / `table_scale`)."""
+    ang = np.outer(np.arange(seq_len, dtype=np.float64),
+                   inv_freq(dim, theta))
+    ang = np.concatenate([ang, ang], axis=-1)
+    cos, sin = np.cos(ang), np.sin(ang)
+    k = table_scale(theta)
+    if k != 1.0:
+        cos, sin = cos * k, sin * k
+    return cos.astype(np.float32), sin.astype(np.float32)
+
+
+def head_norm(x, w, eps):
+    """A head's own RMSNorm, float32 out: x [..., heads, d] * rsqrt(mean
+    over d of x^2 + eps) * w, w [d] one weight for every head. What
+    follows it (`rotate`) rounds."""
+    xf = x.astype(jnp.float32)
+    return xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps) \
+        * w.astype(jnp.float32)
+
+
+def rotate(x, theta, dtype=None):
+    """Rotary over all d dims of x [B, S, heads, d] at positions 0..S-1 by
+    `tables(S, d, theta)`, float32 inside, rounded once to `dtype` (x's
+    own where None)."""
+    cos, sin = (t[None, :, None, :]
+                for t in tables(x.shape[1], x.shape[-1], theta))
+    xf = x.astype(jnp.float32)
+    return (xf * cos + _rotate_half(xf) * sin).astype(dtype or x.dtype)
 
 
 @functools.lru_cache(maxsize=32)

@@ -44,6 +44,26 @@ row-blocked tiles: the bias, the norm and the outputs' widths are
 decided where the kernel is traced, so the delta-rule layer's kernels
 are what they were; the bias's gradient leaves with dw, as the partial
 sums of one more tap that reads ones.
+
+A third form, the gated short convolution's (`gate_conv_gate`): no bias,
+NO activation, two gates around the taps. `bcx` [B, T, 3 C] holds the
+columns B | C | X of one projection side by side, `w` [taps, C]:
+
+    u    = B * X
+    v_t  = sum_j w_j * u_{t - (taps-1) + j}          (zeros before t = 0)
+    y    = C * v                                     [B, T, C]
+
+The same two kernel bodies again (`gate_conv_fwd`, `gate_conv_bwd`) over
+the same row-blocked tiles: the product u is what the float32 scratch
+holds, made in VMEM for the block's rows and for the halo's alike (the
+forward carries the block before's last rows of u in the scratch, the
+backward makes them from the second, 16-row block of `bcx`), the second
+gate takes the activation's place, and the backward writes the three
+column ranges of ONE [B, T, 3 C] cotangent (dB = du * X, dC = dy * v,
+dX = du * B) from `bcx` and `w` alone; dw leaves as the same partial
+sums. Memory-bound: a token reads 3 C and writes C forward. Which form a
+body is traced for is decided where the kernel is traced, so the other
+two forms' kernels are the programs they were.
 """
 from __future__ import annotations
 
@@ -57,7 +77,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ._tpu import LANES, SUBLANES, row_block
 from ._tpu import on_tpu as _on_tpu
 
-__all__ = ["conv_silu_l2norm", "conv_bias_silu"]
+__all__ = ["conv_silu_l2norm", "conv_bias_silu", "gate_conv_gate"]
 
 _F32 = jnp.float32
 _EPS = 1e-6
@@ -85,6 +105,16 @@ def _plain(pre, w, heads):
     act = _causal_conv_silu(pre, w).reshape(B, T, 3, heads, C // (3 * heads))
     q, k, v = act[:, :, 0], act[:, :, 1], act[:, :, 2]
     return tuple(x.astype(pre.dtype) for x in (_l2norm(q), _l2norm(k), v))
+
+
+def _plain_gated(bcx, w):
+    """The third form over the whole sequence: bcx [B, T, 3 C], w [taps, C]."""
+    C, T, taps = w.shape[1], bcx.shape[1], w.shape[0]
+    x = bcx.astype(_F32)
+    u = jnp.pad(x[..., :C] * x[..., 2 * C:], ((0, 0), (taps - 1, 0), (0, 0)))
+    w = w.astype(_F32)
+    v = sum(u[:, j:j + T] * w[j] for j in range(taps))
+    return (x[..., C:2 * C] * v).astype(bcx.dtype)
 
 
 # -- the chip's route: one Mosaic kernel a pass --------------------------------
@@ -128,21 +158,31 @@ def _places(widths, d):
     return [(i, c) for i, n in enumerate(widths) for c in range(0, n, d)]
 
 
-def _fwd_kernel(x_ref, w_ref, *rest, d, normed, bias):
+def _taps_input(ref, C, gate):
+    """What the taps read of a block of rows, float32 [rows, C]: the rows
+    themselves, or with `gate` the product B * X of a block of B | C | X
+    columns."""
+    if not gate:
+        return ref[...].astype(_F32)
+    return ref[:, :C].astype(_F32) * ref[:, 2 * C:].astype(_F32)
+
+
+def _fwd_kernel(x_ref, w_ref, *rest, d, normed, bias, gate=False):
     """`rest`: the bias [1, C] where there is one, the outputs (the
     input's columns side by side, the first `normed` of them normalised
-    a tile of d), the scratch."""
+    a tile of d), the scratch. With `gate` the input is B | C | X, the
+    scratch holds B * X and the one output is C * (the taps' sum)."""
     b_ref = rest[0] if bias else None
     o_refs, xs_ref = rest[bias:-1], rest[-1]
     R, taps = x_ref.shape[0], w_ref.shape[0]
-    C = x_ref.shape[-1]
+    C = xs_ref.shape[-1]
     places = _places([o.shape[-1] for o in o_refs], d)
 
     @pl.when(pl.program_id(1) == 0)
     def _():
         xs_ref[:_HALO] = jnp.zeros((_HALO, C), _F32)
 
-    xs_ref[_HALO:] = x_ref[...].astype(_F32)
+    xs_ref[_HALO:] = _taps_input(x_ref, C, gate)
 
     def tile(r0):
         for c, (o, at) in zip(range(0, C, d), places):
@@ -151,7 +191,10 @@ def _fwd_kernel(x_ref, w_ref, *rest, d, normed, bias):
             y = _act(xs_ref, w, r0, cols)[1]
             if bias:
                 y = y + b_ref[:, cols]
-            a = y * jax.nn.sigmoid(y)
+            if gate:
+                a = y * x_ref[pl.ds(r0, _ROWS), C + c:C + c + d].astype(_F32)
+            else:
+                a = y * jax.nn.sigmoid(y)
             if c < normed:
                 a = a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + _EPS)
             o_refs[o][pl.ds(r0, _ROWS), at:at + d] = a.astype(
@@ -161,15 +204,17 @@ def _fwd_kernel(x_ref, w_ref, *rest, d, normed, bias):
     xs_ref[:_HALO] = xs_ref[R:]
 
 
-def _bwd_kernel(x_ref, halo_ref, w_ref, *rest, d, T, normed, bias):
+def _bwd_kernel(x_ref, halo_ref, w_ref, *rest, d, T, normed, bias,
+                gate=False):
     """`rest`: the bias where there is one, the outputs' cotangents, then
     dx, dw (the bias's gradient in eight rows after the taps') and the
-    two scratches."""
+    two scratches. With `gate` x and dx are B | C | X wide: the scratch
+    holds B * X, and dx's three column ranges are written from it."""
     b_ref = rest[0] if bias else None
     ct_refs = rest[bias:-4]
     dx_ref, dw_ref, xs_ref, da_ref = rest[-4:]
     R, taps = x_ref.shape[0], w_ref.shape[0]
-    C = x_ref.shape[-1]
+    C = xs_ref.shape[-1]
     places = _places([ct.shape[-1] for ct in ct_refs], d)
     blk = pl.num_programs(1) - 1 - pl.program_id(1)
 
@@ -178,7 +223,7 @@ def _bwd_kernel(x_ref, halo_ref, w_ref, *rest, d, T, normed, bias):
         da_ref[R:] = jnp.zeros((_HALO, C), _F32)
         dw_ref[...] = jnp.zeros_like(dw_ref)
 
-    x = x_ref[...].astype(_F32)
+    x = _taps_input(x_ref, C, gate)
     inside = T - blk * R           # rows of the block that the sequence has
     if T % R:
         # the last block's rows past T: whatever they hold, they are
@@ -187,7 +232,8 @@ def _bwd_kernel(x_ref, halo_ref, w_ref, *rest, d, T, normed, bias):
         x = jnp.where(rows < inside, x, 0.0)
     xs_ref[_HALO:] = x
     xs_ref[:_HALO] = jnp.where(
-        blk > 0, halo_ref[...].astype(_F32)[halo_ref.shape[0] - _HALO:], 0.0)
+        blk > 0, _taps_input(halo_ref, C, gate)[halo_ref.shape[0] - _HALO:],
+        0.0)
 
     def tile(r0):
         for c, (o, at) in zip(range(0, C, d), places):
@@ -196,21 +242,35 @@ def _bwd_kernel(x_ref, halo_ref, w_ref, *rest, d, T, normed, bias):
             xj, y = _act(xs_ref, w, r0, cols)
             if bias:
                 y = y + b_ref[:, cols]
-            s = jax.nn.sigmoid(y)
             da = ct_refs[o][pl.ds(r0, _ROWS), at:at + d].astype(_F32)
-            if c < normed:
-                a = y * s
-                r = jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + _EPS)
-                da = r * da - a * (r * r * r * jnp.sum(
-                    da * a, -1, keepdims=True))
-            dy = da * (s * (1.0 + y * (1.0 - s)))
+            if gate:
+                # y = C * v: the gate's own cotangent leaves here, the
+                # taps' sum gets the other factor
+                side = [x_ref[pl.ds(r0, _ROWS), g * C + c:g * C + c + d]
+                        .astype(_F32) for g in range(3)]
+                dx_ref[pl.ds(r0, _ROWS), C + c:C + c + d] = (da * y).astype(
+                    dx_ref.dtype)
+                dy = da * side[1]
+            else:
+                s = jax.nn.sigmoid(y)
+                if c < normed:
+                    a = y * s
+                    r = jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + _EPS)
+                    da = r * da - a * (r * r * r * jnp.sum(
+                        da * a, -1, keepdims=True))
+                dy = da * (s * (1.0 + y * (1.0 - s)))
             if T % R:
                 rows = r0 + jax.lax.broadcasted_iota(jnp.int32, (_ROWS, 1), 0)
                 dy = jnp.where(rows < inside, dy, 0.0)
             da_ref[pl.ds(r0, _ROWS), cols] = dy
             dx = sum(a_j * w_j for a_j, w_j in zip(_shifted(
                 da_ref, r0, cols, [taps - 1 - j for j in range(taps)]), w))
-            dx_ref[pl.ds(r0, _ROWS), cols] = dx.astype(dx_ref.dtype)
+            if gate:               # u = B * X: each factor's from the other
+                for g in (0, 2):
+                    dx_ref[pl.ds(r0, _ROWS), g * C + c:g * C + c + d] = (
+                        dx * side[2 - g]).astype(dx_ref.dtype)
+            else:
+                dx_ref[pl.ds(r0, _ROWS), cols] = dx.astype(dx_ref.dtype)
             # dw_j, eight partial sums a column: a sublane each; the
             # bias's are those of a tap that reads ones
             for j, x_j in enumerate(xj + [None] * bias):
@@ -222,13 +282,14 @@ def _bwd_kernel(x_ref, halo_ref, w_ref, *rest, d, T, normed, bias):
     da_ref[R:] = da_ref[:_HALO]
 
 
-def _block_rows(T, C, itemsize):
+def _block_rows(T, C, itemsize, operands=3):
     """Rows of a block of either pass, a multiple of _ROWS: what fits the
-    backward's three [rows, C] operands in the model's dtype, each
-    double-buffered, and its two float32 scratches; where the fit does
-    not divide T and a block no less than half of it does, that one (a
-    ragged last block costs the backward a select a tile)."""
-    R = row_block(T, C * (6 * itemsize + 8))
+    backward's `operands` [rows, C] operands in the model's dtype (three;
+    the gated form's B | C | X, its cotangent and the output's: seven),
+    each double-buffered, and its two float32 scratches; where the fit
+    does not divide T and a block no less than half of it does, that one
+    (a ragged last block costs the backward a select a tile)."""
+    R = row_block(T, C * (2 * operands * itemsize + 8))
     if R == T:                     # the whole sequence, and a ragged turn
         return -(-T // _ROWS) * _ROWS
     R = max(_ROWS, R // _ROWS * _ROWS)
@@ -236,12 +297,13 @@ def _block_rows(T, C, itemsize):
     return whole[0] if whole else R
 
 
-def _call_fwd(pre, w, bias, widths, d, normed, name, interpret):
+def _call_fwd(pre, w, bias, widths, d, normed, name, interpret, gate=False):
     """The outputs [B, T, width] of the forward kernel: `pre`'s columns
-    side by side, a tile of d columns at a time."""
-    B, T, C = pre.shape
-    taps = w.shape[0]
-    R = _block_rows(T, C, pre.dtype.itemsize)
+    side by side (with `gate` the one output of the taps' width from
+    B | C | X), a tile of d columns at a time."""
+    B, T, wide = pre.shape
+    taps, C = w.shape
+    R = _block_rows(T, C, pre.dtype.itemsize, 7 if gate else 3)
 
     def rows(width):
         return pl.BlockSpec((None, R, width), lambda b, i: (b, i, 0))
@@ -251,9 +313,10 @@ def _call_fwd(pre, w, bias, widths, d, normed, name, interpret):
 
     has_bias = bias is not None
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, d=d, normed=normed, bias=has_bias),
+        functools.partial(_fwd_kernel, d=d, normed=normed, bias=has_bias,
+                          gate=gate),
         grid=(B, pl.cdiv(T, R)),
-        in_specs=[rows(C), whole(taps)] + [whole(1)] * has_bias,
+        in_specs=[rows(wide), whole(taps)] + [whole(1)] * has_bias,
         out_specs=[rows(n) for n in widths],
         out_shape=[jax.ShapeDtypeStruct((B, T, n), pre.dtype)
                    for n in widths],
@@ -265,12 +328,12 @@ def _call_fwd(pre, w, bias, widths, d, normed, name, interpret):
             *([bias.astype(_F32)[None]] if has_bias else []))
 
 
-def _call_bwd(pre, w, bias, cts, d, normed, name, interpret):
+def _call_bwd(pre, w, bias, cts, d, normed, name, interpret, gate=False):
     """(dpre, dw [taps (+ 1 with a bias: its gradient), C] float32) of
     the backward kernel from the outputs' cotangents [B, T, width]."""
-    B, T, C = pre.shape
-    taps = w.shape[0]
-    R = _block_rows(T, C, pre.dtype.itemsize)
+    B, T, wide = pre.shape
+    taps, C = w.shape
+    R = _block_rows(T, C, pre.dtype.itemsize, 7 if gate else 3)
     N = pl.cdiv(T, R)
     per = R // SUBLANES        # 16-row blocks of `pre` a block of rows
 
@@ -284,15 +347,15 @@ def _call_bwd(pre, w, bias, cts, d, normed, name, interpret):
     sums = (taps + has_bias) * _HALO
     dpre, dw = pl.pallas_call(
         functools.partial(_bwd_kernel, d=d, T=T, normed=normed,
-                          bias=has_bias),
+                          bias=has_bias, gate=gate),
         grid=(B, N),
-        in_specs=[rows(C),
-                  pl.BlockSpec((None, SUBLANES, C), lambda b, i: (
+        in_specs=[rows(wide),
+                  pl.BlockSpec((None, SUBLANES, wide), lambda b, i: (
                       b, jnp.maximum((N - 1 - i) * per - 1, 0), 0)),
                   whole(taps)] + [whole(1)] * has_bias
         + [rows(ct.shape[-1]) for ct in cts],
-        out_specs=[rows(C), pl.BlockSpec((None, sums, C),
-                                         lambda b, i: (b, 0, 0))],
+        out_specs=[rows(wide), pl.BlockSpec((None, sums, C),
+                                            lambda b, i: (b, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct(pre.shape, pre.dtype),
                    jax.ShapeDtypeStruct((B, sums, C), _F32)],
         scratch_shapes=[pltpu.VMEM((R + _HALO, C), _F32)] * 2,
@@ -357,6 +420,40 @@ def _fused_bias(pre, w, bias, widths, interpret=False):
 
 
 _fused_bias.defvjp(_bias_fwd, _bias_bwd)
+
+
+# the gated short convolution's form: two gates, no bias, no activation
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _gated_fwd(bcx, w, interpret):
+    (y,) = _call_fwd(bcx, w, None, (w.shape[1],), LANES, 0, "gate_conv_fwd",
+                     interpret, gate=True)
+    return y, (bcx, w)
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _gated_bwd(interpret, saved, dy):
+    bcx, w = saved
+    dbcx, dw = _call_bwd(bcx, w, None, [dy], LANES, 0, "gate_conv_bwd",
+                         interpret, gate=True)
+    return dbcx, dw.astype(w.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _fused_gated(bcx, w, interpret=False):
+    return _gated_fwd(bcx, w, interpret)[0]
+
+
+_fused_gated.defvjp(_gated_fwd, _gated_bwd)
+
+
+def gate_conv_gate(bcx, w):
+    """C * causal conv(B * X), no bias and no activation: bcx [B, T, 3 C]
+    (the columns B | C | X of one projection), w [taps, C]. Returns
+    y [B, T, C] in `bcx`'s dtype; a sequence's first rows read zeros."""
+    if _on_tpu() and w.shape[1] % LANES == 0:
+        return _fused_gated(bcx, w, False)
+    return _plain_gated(bcx, w)
 
 
 def conv_bias_silu(pre, w, bias, widths):

@@ -28,6 +28,8 @@ _LAZY = {
                       "Glm4MoeLiteModel", "glm4_moe_lite_tiny"),
     "xing4_0": ("Xing40Config", "Xing40ForCausalLM", "Xing40Model",
                 "xing4_0_tiny"),
+    "lfm2_moe": ("Lfm2MoeConfig", "Lfm2MoeForCausalLM", "Lfm2MoeModel",
+                 "lfm2_moe_tiny"),
 }
 
 

@@ -56,12 +56,12 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..autograd.tape import apply_op
 from ..framework import core
 from ..kernels.rope import softmax_scale
+from ..kernels.rope import tables as _rope_tables
 from ..nn import initializer as I
 from ..nn.layer.layers import Layer
 from ..observability.scopes import scope
@@ -164,24 +164,6 @@ def dots3_note_tiny(**kw):
                 loss_block_rows=8, dtype="float32")
     base.update(kw)
     return Dots3NoteConfig(**base)
-
-
-@functools.lru_cache(maxsize=16)
-def _rope_tables(seq_len, dim, theta):
-    """cos and sin [S, dim] float32 of the rotate-half layout, angles made
-    in float64 on the host (constants of the program): `kernels/rope.py`
-    rounds the angle itself to float32, 1e-3 rad at 16384 positions.
-    `theta` is the base, or a `kernels.rope.Yarn` (the frequencies and the
-    tables' factor are `kernels.rope.inv_freq` / `table_scale`)."""
-    from ..kernels import rope
-    ang = np.outer(np.arange(seq_len, dtype=np.float64),
-                   rope.inv_freq(dim, theta))
-    ang = np.concatenate([ang, ang], axis=-1)
-    cos, sin = np.cos(ang), np.sin(ang)
-    k = rope.table_scale(theta)
-    if k != 1.0:
-        cos, sin = cos * k, sin * k
-    return cos.astype(np.float32), sin.astype(np.float32)
 
 
 def _rope(x, theta):

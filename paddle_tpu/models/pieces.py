@@ -1,6 +1,7 @@
-"""What `solar_open2`, `granite_hybrid`, `dots3_note`, `glm4_moe_lite` and
-`xing4_0` are built from, each piece written once: the norm, the embedding
-and the head under their scopes, the shifted labels and the blocked head +
+"""What `solar_open2`, `granite_hybrid`, `dots3_note`, `glm4_moe_lite`,
+`xing4_0` and `lfm2_moe` are built from, each piece written once: the norm
+(and a head's own norm in front of rotary), the embedding and the head
+under their scopes, the shifted labels and the blocked head +
 loss, the residual path in its two forms (`Residual`: x + F(x);
 `HyperConnection`: n streams mixed around F), the two kinds of feed-forward
 half-layer (experts, dense SwiGLU) written against it, the scan that sums a
@@ -12,7 +13,7 @@ The arrows point one way: a model module imports from here (and
 names has its public name here, so that no model module reaches for
 another's private one.
 
-Memory at long sequences decides the structure, the same in all four: a
+Memory at long sequences decides the structure, the same in all of them: a
 half of a layer is ONE taped operation that keeps its input alone and is
 recomputed in the backward (`jax.checkpoint`), head and loss go over blocks
 of rows, and only the last norm in front of them runs again.
@@ -41,7 +42,8 @@ from .llama import _param as param
 from .llama import _sdpa as sdpa  # noqa: F401
 from .llama import _swiglu as swiglu
 
-__all__ = ["RMSNorm", "param", "sdpa", "swiglu", "rms", "branch", "embed",
+__all__ = ["RMSNorm", "param", "sdpa", "swiglu", "rms", "qk_norm_rope",
+           "branch", "embed",
            "head", "shifted", "head_loss", "blocked_loss", "Residual",
            "PLAIN", "HyperConnection", "over_sequences", "group_of",
            "sum_of_groups",
@@ -53,6 +55,19 @@ def rms(a, w, eps):
     from ..kernels import rms_norm as krn
     with scope("norm"):
         return krn.rms_norm(a, w, eps)
+
+
+def qk_norm_rope(q, k, wq, wk, eps, theta):
+    """(q, k) [B, S, heads, d] after an RMSNorm over each head's d channels
+    (one [d] weight for q, one for k) and rotary over all d at positions
+    0..S-1: float32 from the projections' outputs to the one rounding
+    (`kernels/rope.py`: `head_norm`, `rotate`)."""
+    from ..kernels import rope
+    with scope("attn/qk_norm"):
+        qf, kf = rope.head_norm(q, wq, eps), rope.head_norm(k, wk, eps)
+    with scope("attn/rope"):
+        return rope.rotate(qf, theta, q.dtype), rope.rotate(kf, theta,
+                                                            k.dtype)
 
 
 def branch(x, out, r):

@@ -53,14 +53,16 @@ __all__ = ["DroplessMoE", "dropless_moe", "route_top_k"]
 
 
 def route_top_k(x, w_router, top_k, norm_topk=True, scaling=1.0,
-                bias=None):
+                bias=None, norm_eps=0.0):
     """(expert ids [T, k] int32, weights [T, k] f32): sigmoid scores over
     all the router's outputs, accumulated in float32 at full precision (a
     score's rounding picks another expert; bf16 operands multiply
     exactly, so no float32 copy of x is made), the top-k, normalised.
     With `bias` [experts] the top-k are those of score + bias and the
     weights still the scores' own (selection bias: it balances the load
-    and carries no gradient)."""
+    and carries no gradient). `norm_eps` is added to the sum the chosen
+    scores are divided by (a published block that divides by sum + 1e-6
+    says so; 0 divides by the sum itself)."""
     s = jax.nn.sigmoid(jnp.matmul(
         x, w_router, precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32))
@@ -71,12 +73,14 @@ def route_top_k(x, w_router, top_k, norm_topk=True, scaling=1.0,
             s + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
         top_s = jnp.take_along_axis(s, top_i, axis=-1)
     if norm_topk:
-        top_s = top_s / jnp.sum(top_s, -1, keepdims=True)
+        total = jnp.sum(top_s, -1, keepdims=True)
+        top_s = top_s / (total + norm_eps if norm_eps else total)
     return top_i.astype(jnp.int32), top_s * scaling
 
 
 def dropless_moe(x, w_router, w_gate_up, w_down, *, first_expert, top_k,
-                 norm_topk=True, scaling=1.0, rows=None, bias=None):
+                 norm_topk=True, scaling=1.0, rows=None, bias=None,
+                 norm_eps=0.0):
     """The held experts' part of the routed sum for x [T, H]. w_gate_up
     [E_held, H, 2M] (gate | up), w_down [E_held, M, H]. Returns
     (y [T, H] in x's dtype, rows per held expert [E_held] int32,
@@ -95,7 +99,7 @@ def dropless_moe(x, w_router, w_gate_up, w_down, *, first_expert, top_k,
         tile=row_moves.TILE, chunk=row_moves.CHUNK)
     with scope("moe/router"):
         top_i, top_w = route_top_k(x, w_router, top_k, norm_topk, scaling,
-                                   bias)
+                                   bias, norm_eps)
     with scope("moe/dispatch"):
         local = top_i - first_expert
         key = jnp.where((local >= 0) & (local < E), local, E).reshape(-1)
@@ -136,7 +140,8 @@ class DroplessMoE(Layer):
     def __init__(self, hidden_size, expert_size, num_experts, top_k,
                  experts_held=None, first_expert=0, shared_experts=1,
                  norm_topk_prob=True, routed_scaling_factor=1.0, rows=None,
-                 dtype=None, std=0.02, selection_bias=False):
+                 dtype=None, std=0.02, selection_bias=False,
+                 norm_topk_eps=0.0):
         super().__init__()
         held = num_experts if experts_held is None else experts_held
         if not 0 <= first_expert <= first_expert + held <= num_experts:
@@ -148,6 +153,7 @@ class DroplessMoE(Layer):
         self.num_experts, self.top_k = num_experts, top_k
         self.first_expert, self.experts_held = first_expert, held
         self.norm_topk_prob = norm_topk_prob
+        self.norm_topk_eps = norm_topk_eps
         self.routed_scaling_factor = routed_scaling_factor
         self.rows = rows
         h, m = hidden_size, expert_size
@@ -190,7 +196,8 @@ class DroplessMoE(Layer):
         y, counts, dropped = dropless_moe(
             x2, w_router, w_gate_up, w_down, first_expert=self.first_expert,
             top_k=self.top_k, norm_topk=self.norm_topk_prob,
-            scaling=self.routed_scaling_factor, rows=self.rows, bias=bias)
+            scaling=self.routed_scaling_factor, rows=self.rows, bias=bias,
+            norm_eps=self.norm_topk_eps)
         if w_shared_gate_up is not None:
             from ...kernels.swiglu import swiglu
             with scope("moe/shared"):

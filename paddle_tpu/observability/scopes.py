@@ -52,7 +52,13 @@ COMPONENTS = ("embed", "layers", "norm", "attn/qkv", "attn/rope",
               # product with Phi, sigmoids, Sinkhorn), n streams -> the
               # branch's input, streams and branch -> n streams; the
               # embedding's expansion and the sum in front of a final norm
-              "hc/map", "hc/pre", "hc/post", "hc/expand", "hc/reduce")
+              "hc/map", "hc/pre", "hc/post", "hc/expand", "hc/reduce",
+              # models/lfm2_moe: the parts of a gated short-convolution
+              # layer (proj: the one [H, 3H] product into B | C | X; core:
+              # B * X, the taps, C *; out: the output projection and the
+              # residual add), and a head's own RMSNorm of q and k in front
+              # of rotary (`pieces.qk_norm_rope`)
+              "conv/proj", "conv/core", "conv/out", "attn/qk_norm")
 # distributed/sharding: collectives the program itself issues
 COLLECTIVES = ("tp/all_reduce", "tp/relayout")
 # jit.TrainStep.__call__: TraceAnnotations, on the profiler's host plane

@@ -699,6 +699,32 @@ def test_ssm_conv_compiles_for_the_chip(one_chip):
     assert "convolution" not in text and " pad(" not in text
 
 
+def test_gated_short_conv_compiles_for_the_chip(one_chip):
+    """models/lfm2_moe.py at lfm2-8b-a1b-ep4.pretrain-4k-batch's shape: the
+    gated short convolution (B | C | X of 2048 columns each, three taps,
+    no bias, no activation) over four sequences of 4096 tokens, under a
+    `jax.checkpoint` as the conv half has it and in front of something
+    that reads y again in its backward: the forward kernel twice, the
+    backward once, no convolution, no padded copy and no float32
+    [T, 2048] product left for XLA."""
+    B, T, C = 4, 4096, 2048
+    bcx = jax.ShapeDtypeStruct((B, T, 3 * C), jnp.bfloat16,
+                               sharding=one_chip)
+    w = jax.ShapeDtypeStruct((3, C), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(jax.value_and_grad(
+        lambda a, w_: _sum32(jax.checkpoint(lambda *c: jnp.square(
+            sc.gate_conv_gate(*c)))(a, w_)), argnums=(0, 1))).lower(
+                bcx, w).compile()
+    text = compiled.as_text()
+    assert _mosaic_calls(text, "gate_conv_fwd") == 2
+    assert _mosaic_calls(text, "gate_conv_bwd") == 1
+    assert "convolution" not in text and " pad(" not in text
+    # y and its cotangent in bf16, dw's partial sums: nothing float32 the
+    # size of B * X
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        2 * B * T * C * 2 + B * 3 * 8 * C * 4) * 1.05 < B * T * C * 4 * 1.1
+
+
 def test_granite_attention_and_tied_head_at_the_cells_shapes(one_chip):
     """Splash attention at head width 64 (32 query heads over 8 KV heads,
     32768 tokens, scores times 1/64), never run at a benchmark shape
